@@ -16,7 +16,7 @@ whose *behavior* (not code) each component mirrors.
 
 __version__ = "0.3.0"
 
-# The persistent XLA compilation cache is configured in
-# cometbft_tpu/ops/__init__.py (every device-kernel path imports it);
-# this jax build ignores the JAX_COMPILATION_CACHE_DIR env var, so the
-# config must be applied via jax.config.update after jax is imported.
+# The persistent XLA compilation cache is placed by
+# cometbft_tpu/ops/__init__.py (every device-kernel path imports it):
+# jax honours JAX_COMPILATION_CACHE_DIR, and where that is unset the
+# cache goes to `.jax_cache/` at the root of the checkout.

@@ -1,10 +1,18 @@
 """ctypes binding for the C++ Ed25519 engine (csrc/ed25519_native.cpp).
 
-Build-on-demand: the shared object compiles once per machine into the
-package directory (g++ is in the base image; pybind11 is not, hence the
-plain C ABI + ctypes). Every entry point degrades gracefully — callers
-fall back to the pure-Python oracle when the toolchain or binary is
-unavailable, so the framework never hard-depends on a compiler.
+Build-on-demand: the shared object compiles into the package directory
+(g++ is in the base image; pybind11 is not, hence the plain C ABI +
+ctypes) under a name keyed by what it was built FROM and FOR: a hash of
+the source contents, the compiler flags and this host's CPU features.
+`-march=native` code is only valid on a CPU with the features it was
+built for, so a binary copied in from another machine (or left by an
+older tree) has another key and is never loaded; the engine is rebuilt
+from the committed sources instead. build_state() says which happened.
+
+Every entry point degrades to the pure-Python oracle when the toolchain
+is missing, so the framework never hard-depends on a compiler — but a
+failed build is logged, not silent, and programs that need the engine's
+speed (chip_smoke.py) check available() and fail without it.
 
 This is the host-side native path the reference gets from
 curve25519-voi's assembly (reference crypto/ed25519/ed25519.go:13):
@@ -15,14 +23,19 @@ handshake identity. Batch verification stays on the TPU kernels.
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
+import platform
 import subprocess
 import threading
 
+_log = logging.getLogger(__name__)
+
 _SRC = os.path.join(os.path.dirname(__file__), "..", "csrc",
                     "ed25519_native.cpp")
-# sources whose edits must trigger a rebuild (the .cpp includes the
-# IFMA engine from the .inc)
+# every source the build reads (the .cpp includes the .inc engines):
+# their contents are part of the binary's key
 _SRC_DEPS = (
     _SRC,
     os.path.join(os.path.dirname(_SRC), "ed25519_ifma.inc"),
@@ -36,59 +49,100 @@ _SRC_DEPS = (
     os.path.join(os.path.dirname(_SRC), "rs_gf16.inc"),
     os.path.join(os.path.dirname(_SRC), "g1_msm.inc"),
 )
-_SO = os.path.join(os.path.dirname(__file__), "_ed25519_native.so")
+# -std=c++17 explicitly: the IFMA engine uses std::shared_mutex and
+# g++ <= 10 still defaults to gnu++14, which fails the whole build
+_FLAGS = ("-std=c++17", "-O3", "-march=native", "-pthread", "-fPIC",
+          "-shared")
+_BUILD_TIMEOUT_S = 600  # ~19 s on an idle 8-core host; six test workers
+#                         building at once on a loaded one take longer
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
+_state = {"path": None, "built": None, "error": None}
 
 
-def _build() -> bool:
-    src = os.path.abspath(_SRC)
-    if not os.path.exists(src):
-        return False
-    # -std=c++17 explicitly: the IFMA engine uses std::shared_mutex and
-    # g++ <= 10 still defaults to gnu++14, which fails the whole build
-    cmd = ["g++", "-std=c++17", "-O3", "-march=native", "-pthread",
-           "-fPIC", "-shared", "-o", _SO, src]
+def _cpu_features() -> str:
+    """What -march=native keys on: the CPU's feature flags (Linux), else
+    the coarsest honest stand-in."""
     try:
-        proc = subprocess.run(cmd, capture_output=True, timeout=120)
-        return proc.returncode == 0 and os.path.exists(_SO)
-    except (OSError, subprocess.TimeoutExpired):
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return " ".join(sorted(line.split(":", 1)[1].split()))
+    except OSError:
+        pass
+    return platform.machine() + "|" + platform.processor()
+
+
+def _so_path() -> str | None:
+    """The binary for these sources, these flags and this CPU; None
+    when the sources are absent."""
+    h = hashlib.sha256()
+    try:
+        for p in _SRC_DEPS:
+            with open(p, "rb") as f:
+                h.update(hashlib.sha256(f.read()).digest())
+    except OSError:
+        return None
+    h.update(" ".join(_FLAGS).encode())
+    h.update(_cpu_features().encode())
+    return os.path.join(os.path.dirname(__file__),
+                        f"_ed25519_native.{h.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> bool:
+    # built under a private name and renamed into place: concurrent
+    # processes (test workers) never load a half-written file
+    tmp = f"{so}.{os.getpid()}.tmp"
+    cmd = ["g++", *_FLAGS, "-o", tmp, os.path.abspath(_SRC)]
+    try:
+        proc = subprocess.run(cmd, capture_output=True,
+                              timeout=_BUILD_TIMEOUT_S)
+        if proc.returncode != 0:
+            _state["error"] = proc.stderr.decode(errors="replace")[-2000:]
+            return False
+        os.replace(tmp, so)
+        return True
+    except (OSError, subprocess.TimeoutExpired) as e:
+        _state["error"] = repr(e)
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def get_lib():
-    """The loaded library, building it if needed; None if unavailable."""
+    """The loaded library, building it if needed; None if unavailable
+    (no sources, no toolchain, failed build — logged once)."""
     global _lib, _tried
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        try:
-            src_mtime = max(
-                (os.path.getmtime(p) for p in _SRC_DEPS if os.path.exists(p)),
-                default=None,
-            )
-            if not os.path.exists(_SO) or (
-                src_mtime is not None
-                and src_mtime > os.path.getmtime(_SO)
-            ):
-                if not _build():
-                    return None
-            lib = ctypes.CDLL(_SO)
-        except OSError:
-            return None
-        try:
-            _bind(lib)
-        except AttributeError:
-            # a stale prebuilt .so missing newer symbols (shipped without
-            # the csrc tree, so the mtime rebuild guard never fires):
-            # degrade to the pure-Python paths rather than crash the hot
-            # submit path — "every entry point degrades gracefully"
-            return None
-        _lib = lib
-        return _lib
+        so = _so_path()
+        if so is None:
+            _state["error"] = "csrc sources not found"
+        else:
+            _state["path"] = so
+            _state["built"] = not os.path.exists(so)
+            if not _state["built"] or _build(so):
+                lib = ctypes.CDLL(so)
+                _bind(lib)
+                _lib = lib
+                return _lib
+        _state["built"] = None
+        _log.warning("native engine unavailable, pure-Python oracles "
+                     "take over (orders slower): %s", _state["error"])
+        return None
+
+
+def build_state() -> dict:
+    """{'path', 'built', 'error'} of this process's engine: built=True
+    when this process compiled the binary, False when one with the
+    matching key was already there, None when there is no engine."""
+    get_lib()
+    return dict(_state)
 
 
 def _bind(lib) -> None:

@@ -4,7 +4,7 @@ Every verify consumer in the node — consensus commit validation,
 blocksync replay windows, light-serve VerifiedCommitCache misses, and
 mempool admission signature windows — used to run its own
 Ed25519BatchVerifier dispatch. The engines are wire-bound per call
-(BENCH_r05: fixed per-dispatch cost dwarfs the per-sig cost at small n),
+(round-5 bench: fixed per-dispatch cost dwarfs the per-sig cost at small n),
 so under mixed load the device sees many small calls where it could see
 few large ones. This module puts ONE scheduler between all of them and
 the crypto dispatch:

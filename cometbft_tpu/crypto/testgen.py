@@ -17,58 +17,6 @@ import numpy as np
 from . import ed25519_ref as ref
 
 
-def generate_signed_batch_cached(
-    n: int, seed: int = 0, msg_len: int = 120, vote_shaped: bool = False
-):
-    """generate_signed_batch behind a disk cache: generation runs device
-    kernels whose XLA compile is expensive on slow hosts, and bench
-    datasets are deterministic per (n, seed, msg_len, shape)."""
-    import os
-
-    cache_dir = os.path.join(
-        os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-        "cometbft_tpu",
-    )
-    path = os.path.join(
-        cache_dir,
-        f"signed_{n}_{seed}_{msg_len}_{int(vote_shaped)}.npz",
-    )
-    try:
-        z = np.load(path)
-        pubs, sigs, msgs = z["pubs"], z["sigs"], z["msgs"]
-        lens = z["lens"]
-        return [
-            (
-                pubs[i].tobytes(),
-                msgs[i, : lens[i]].tobytes(),
-                sigs[i].tobytes(),
-            )
-            for i in range(n)
-        ]
-    except (OSError, KeyError, ValueError):
-        pass
-    out = generate_signed_batch(n, seed=seed, msg_len=msg_len,
-                                vote_shaped=vote_shaped)
-    maxlen = max(len(m) for _, m, _ in out)
-    pubs = np.zeros((n, 32), np.uint8)
-    sigs = np.zeros((n, 64), np.uint8)
-    msgs = np.zeros((n, maxlen), np.uint8)
-    lens = np.zeros((n,), np.int64)
-    for i, (p, m, s) in enumerate(out):
-        pubs[i] = np.frombuffer(p, np.uint8)
-        sigs[i] = np.frombuffer(s, np.uint8)
-        msgs[i, : len(m)] = np.frombuffer(m, np.uint8)
-        lens[i] = len(m)
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        np.savez_compressed(
-            path, pubs=pubs, sigs=sigs, msgs=msgs, lens=lens
-        )
-    except OSError:
-        pass
-    return out
-
-
 def generate_signed_batch(
     n: int, seed: int = 0, msg_len: int = 120, vote_shaped: bool = False
 ):
@@ -101,8 +49,16 @@ def generate_signed_batch(
     def fixed_base_compress(digs):
         return C.compress(C.fixed_base(digs))
 
-    a_enc = np.asarray(fixed_base_compress(jnp.asarray(C.scalar_digits(a_sc))))
-    r_enc = np.asarray(fixed_base_compress(jnp.asarray(C.scalar_digits(r_sc))))
+    # pad to the verify bucket: one compiled shape per bucket, and a
+    # width the Pallas field kernels tile (an odd n would take the XLA
+    # value-form on the chip)
+    from .ed25519 import _bucket
+
+    pad = [1] * (_bucket(n) - n)
+    a_enc = np.asarray(fixed_base_compress(
+        jnp.asarray(C.scalar_digits(a_sc + pad))))[:n]
+    r_enc = np.asarray(fixed_base_compress(
+        jnp.asarray(C.scalar_digits(r_sc + pad))))[:n]
 
     out = []
     for i in range(n):

@@ -9,24 +9,45 @@ import os as _os
 
 import jax as _jax
 
-# Persistent XLA compilation cache: the verify graph compiles in
-# 20-40 s and the MSM accumulate kernel in ~2 min; without a disk cache
-# every fresh process (each test run, each bench invocation) pays that
-# again before its first verification. The JAX_COMPILATION_CACHE_DIR
-# env var set in the package root is not honored by this jax build, so
-# the config is applied here — every kernel module imports this package
-# and jax is being imported anyway.
-if _jax.config.jax_compilation_cache_dir is None:
-    _jax.config.update(
-        "jax_compilation_cache_dir",
-        _os.environ.get(
-            "JAX_COMPILATION_CACHE_DIR",
-            _os.path.join(
-                _os.environ.get(
-                    "XDG_CACHE_HOME", _os.path.expanduser("~/.cache")
-                ),
-                "cometbft_tpu",
-                "jax",
-            ),
-        ),
-    )
+# Persistent XLA compilation cache: on the chip's compiler each verify
+# shape takes ~25-30 s and the RLC graph ~100-130 s (Mosaic kernels
+# dominate, whatever the bucket), so a cold process that warms what it
+# uses pays minutes; with the cache a second process pays none.
+#
+# Where it lives is decided from outside: jax itself reads
+# JAX_COMPILATION_CACHE_DIR, and when that is set nothing here touches
+# the directory. When it is not (and no caller configured one), the
+# cache goes to ONE fixed path inside the checkout — the path is part
+# of the cache key, so it must not move with home, a temp name, a pid
+# or a time. `.jax_cache/` is git-ignored and chiprun-ignored.
+CACHE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.dirname(
+        _os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+if (not _os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        and _jax.config.jax_compilation_cache_dir is None):
+    _jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+
+# What gets written: every program that took >= 1 s to compile, of any
+# size. Every kernel of the main path is far above that (the cheapest,
+# decompress_pubkeys, takes ~5 s), while the sub-second glue programs
+# (a stack of summary scalars, a device_put layout change) cost less to
+# recompile than a cache of hundreds of tiny files costs to keep. Pinned
+# here rather than left to jax's defaults, unless the environment says
+# otherwise.
+if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in _os.environ:
+    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+if "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES" not in _os.environ:
+    _jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+# The cache key of a program that holds a Pallas kernel covers the
+# kernel's serialized Mosaic module, and by default jax writes the whole
+# Python call stack of every op into that module's locations — which the
+# key's canonicalisation does not strip. The same shape first reached
+# through another caller (verify_commit, the replay engine, RLC's blame
+# fallback) then has another key and compiles again: on the chip a warm
+# run recompiled both ladder shapes while the RLC graph, always first
+# traced from the same stack, hit (PR 21). Locations keep the op's own
+# frame; the key is then a function of the program alone.
+_jax.config.update("jax_include_full_tracebacks_in_locations", False)

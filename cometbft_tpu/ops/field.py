@@ -235,13 +235,23 @@ def _sq_kernel(a_ref, o_ref):
     o_ref[...] = _fold_wide(_conv_rows_shifted(a, a))
 
 
-def _use_pallas(*arrs) -> bool:
+def _on_tpu() -> bool:
     import jax
 
-    if jax.default_backend() != "tpu":
-        return False
-    b = arrs[0].shape[-1]
+    return jax.default_backend() == "tpu"
+
+
+def kernel_width(b: int) -> bool:
+    """Does a batch axis of b lanes take the Pallas kernels? One block
+    when b < _PALLAS_TILE (any width >= 128), whole tiles otherwise.
+    Every BUCKETS entry that reaches the device, and its per-shard width
+    on a 4-device mesh, satisfies this (tests/test_tpu_device.py); a
+    width that does not takes the XLA value-form of the same math."""
     return b >= 128 and (b % _PALLAS_TILE == 0 or b < _PALLAS_TILE)
+
+
+def _use_pallas(*arrs) -> bool:
+    return _on_tpu() and kernel_width(arrs[0].shape[-1])
 
 
 def _pallas_binop(kernel, *arrs):

@@ -537,6 +537,13 @@ class CryptoMetrics:
             "crypto", "verify_seconds",
             "Batch-verify wall time submit→result",
             labels=("path", "curve"))
+        self.gave_way_total = reg.counter(
+            "crypto", "gave_way_total",
+            "Device work handed to another engine: RLC batches whose "
+            "host layout declined (rlc_declined, per batch; the ladder "
+            "takes them) and on-device-SHA lanes too long for the "
+            "kernel (oversize, per lane; the host reference takes them)",
+            labels=("reason",))
         self.calibration_us_per_sig = reg.gauge(
             "crypto", "calibration_us_per_sig",
             "Calibrated host-stage dispatch terms", labels=("term",))

@@ -15,7 +15,7 @@ sinks. Findings become structured verdicts:
 - in-memory `verdicts` / `safety_verdicts()` — what the e2e runner
   fails an audited world on.
 
-Check taxonomy (the `check` label everywhere):
+Check classes (the `check` label everywhere):
 
 ==============  ======  ==============================================
 check           safety  trigger

@@ -2,7 +2,7 @@
 one-decode-path migration, fold fallbacks, verdict pins vs the
 signature column, the blockstore evidence window, WAL framing,
 an in-process all-BLS net committing cert-native end to end with the
-cert-gossip outcome taxonomy, light verification over cert headers,
+cert-gossip outcome classification, light verification over cert headers,
 replication feed frames, and cert-path replay accept/reject.
 """
 
@@ -340,7 +340,7 @@ def test_bls_net_commits_cert_native(tmp_path):
     """4 BLS validators reach consensus; every stored commit is a
     CertCommit that re-verifies against the validator set, catchup
     serves the certificate (not a reconstructed vote column), and the
-    cert-gossip outcome taxonomy behaves."""
+    cert-gossip outcome classification behaves."""
     from cometbft_tpu.consensus.net import InProcessNetwork
     from cometbft_tpu.consensus.wal import AggregateCommitMessage
     from cometbft_tpu.utils.metrics import consensus_metrics

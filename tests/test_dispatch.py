@@ -378,8 +378,8 @@ def test_rlc_crossover_numpy_host_still_loses(monkeypatch):
     assert not e._rlc_beats_ladder(10000, 10240)
 
 
-def test_rlc_crossover_tunneled_wire_still_loses(monkeypatch):
-    """1-core tunneled profile (~30 MB/s): even with the native packer,
+def test_rlc_crossover_30mbps_wire_still_loses(monkeypatch):
+    """1-core host on a 30 MB/s link: even with the native packer,
     RLC's 116 B/lane wire (39.6 ms) exceeds the ladder's 96 B/lane
     (32.8 ms) — the dispatch must still pick the ladder, so a slow link
     is never regressed by this PR."""
@@ -535,7 +535,7 @@ def test_mesh_flips_device_bound_batch(monkeypatch):
 
 
 def test_mesh_never_wins_wire_bound(monkeypatch):
-    """Tunneled link (30 MB/s): the mesh ships the same 96 B/lane PLUS
+    """30 MB/s link: the mesh ships the same 96 B/lane PLUS
     d fixed shard stagings, so its wire stage strictly exceeds the
     ladder's binding wire stage — splitting device time buys nothing
     and dispatch must keep the single chip."""
@@ -548,7 +548,8 @@ def test_mesh_never_wins_wire_bound(monkeypatch):
 
 
 def test_mesh_loses_on_expensive_staging(monkeypatch):
-    """100 ms fixed cost per shard device_put (tunneled-runtime class):
+    """100 ms fixed cost per shard device_put (a remote-device class of
+    link, three orders above a local one):
     8 stagings = 0.8 s of wire overhead — the calibrated put term must
     keep the mesh off even on a device-bound batch."""
     e = _pin_model(monkeypatch, link_mbps=1000.0, rlc_us=1.1)

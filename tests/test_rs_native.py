@@ -153,3 +153,35 @@ def test_reconstruct_shards_raises_beyond_budget():
 
 def test_threads_reported():
     assert native.rs_threads() >= 1
+
+
+def test_worker_pool_survives_concurrent_callers():
+    """ctypes drops the GIL, so two Python threads reach the engine's one
+    worker pool together (a core committing while a replica re-encodes).
+    Before Pool::run serialized its callers the second reset the first's
+    counters and both waited forever — tests/test_replication.py hung."""
+    import threading
+
+    from cometbft_tpu.crypto import native
+
+    if not native.available():
+        pytest.skip("native engine unavailable")
+    k, m, shard_len = 16, 16, 4096
+    blob = bytes(range(256)) * (k * shard_len // 256)
+    want = native.rs_encode(blob, k, m, shard_len, nchunks=8)
+    assert want is not None
+    bad = []
+
+    def hammer():
+        for _ in range(400):
+            if native.rs_encode(blob, k, m, shard_len, nchunks=8) != want:
+                bad.append(1)
+
+    threads = [threading.Thread(target=hammer, daemon=True)
+               for _ in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads), "worker pool deadlocked"
+    assert not bad

@@ -2,7 +2,7 @@
 """Compare fresh bench/workload JSON against the last committed round.
 
 Loads the working-tree copies of the benchmark artifacts (default:
-WORKLOADS.json and BENCH_r05.json) and their committed baselines via
+WORKLOADS.json) and their committed baselines via
 ``git show <ref>:<file>``, flattens every numeric leaf to a dotted key,
 and reports relative changes that move in the WRONG direction past a
 threshold. Direction is inferred from the key name:
@@ -36,7 +36,7 @@ import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-DEFAULT_FILES = ("WORKLOADS.json", "BENCH_r05.json")
+DEFAULT_FILES = ("WORKLOADS.json",)
 
 _HIGHER = ("per_sec", "per_s", "throughput", "speedup", "improvement",
            "per_app_call", "per_core", "headers_per", "txs_per",
