@@ -1,0 +1,9 @@
+"""benchmark/tests is run by hand (`pytest benchmark/tests`), not by tier-1.
+The harness modules import as `benchmark.harness.*` from the checkout's root."""
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
