@@ -206,15 +206,6 @@ def _host_terms() -> dict:
     global _HOST_TERMS
     if _HOST_TERMS is None:
         _HOST_TERMS = _calibrate_host_terms()
-        cm = crypto_metrics()
-        for term in ("ladder_us", "rlc_us"):
-            cm.calibration_us_per_sig.set(_HOST_TERMS[term], term)
-        if "msm_us" in _HOST_TERMS:
-            cm.calibration_us_per_sig.set(
-                _HOST_TERMS["msm_us"], "msm_us")
-        cm.calibration_us_per_sig.set(
-            float(_HOST_TERMS.get("calibrated", False)), "calibrated"
-        )
     return _HOST_TERMS
 
 
@@ -509,19 +500,23 @@ class Ed25519BatchVerifier(BatchVerifier):
         self._lazy: list[tuple] = []
 
     def _materialize(self) -> None:
-        if not self._lazy:
-            return
-        for pub_rows, sig_rows, msg_blob, lens in self._lazy:
-            off = 0
-            for i in range(len(lens)):
-                ln = int(lens[i])
-                self._items.append((
-                    pub_rows[i].tobytes(),
-                    bytes(msg_blob[off:off + ln]),
-                    sig_rows[i].tobytes(),
-                ))
-                off += ln
-        self._lazy.clear()
+        """Expand the lazy whole-commit columns into per-item tuples.
+        Called once per dispatch by the paths that need them (add()
+        calls it only when columns wait), so the span is per dispatch
+        even where nothing waits (`n` 0: add() built the tuples)."""
+        with _trace.span("crypto.materialize",
+                         n=self.count() - len(self._items)):
+            for pub_rows, sig_rows, msg_blob, lens in self._lazy:
+                off = 0
+                for i in range(len(lens)):
+                    ln = int(lens[i])
+                    self._items.append((
+                        pub_rows[i].tobytes(),
+                        bytes(msg_blob[off:off + ln]),
+                        sig_rows[i].tobytes(),
+                    ))
+                    off += ln
+            self._lazy.clear()
 
     def add_batch(self, pub_rows, sig_rows, msg_blob, msg_lens) -> None:
         """Vectorized add() for a whole commit's worth of ed25519 lanes.
@@ -557,7 +552,8 @@ class Ed25519BatchVerifier(BatchVerifier):
     def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> bool:
         if not isinstance(pub_key, Ed25519PubKey):
             return False
-        self._materialize()
+        if self._lazy:
+            self._materialize()
         ok = len(sig) == SIG_SIZE
         if ok:
             s = int.from_bytes(sig[32:], "little")
@@ -594,7 +590,8 @@ class Ed25519BatchVerifier(BatchVerifier):
             # logical order within a verifier is _items then _lazy;
             # interleaving other's eager items after our pending lazy
             # chunks would reorder OUR lanes, so expand ours first
-            self._materialize()
+            if self._lazy:
+                self._materialize()
             self._items.extend(other._items)
         self._lazy.extend(other._lazy)
         self._precheck_fail.extend(other._precheck_fail)
@@ -610,20 +607,19 @@ class Ed25519BatchVerifier(BatchVerifier):
             return False, []
         if self.backend == "cpu":
             t0 = _time.perf_counter()
-            self._materialize()
-            bits = [
-                (not bad) and ref.verify(p, m, s)
-                for (p, m, s), bad in zip(self._items, self._precheck_fail)
-            ]
+            with _trace.span("crypto.batch_verify", path="cpu",
+                             n=self.count()):
+                self._materialize()
+                bits = [
+                    (not bad) and ref.verify(p, m, s)
+                    for (p, m, s), bad in zip(self._items,
+                                              self._precheck_fail)
+                ]
             dt = _time.perf_counter() - t0
             m = crypto_metrics()
             m.batch_size.observe(self.count())
             m.path_selected_total.inc(1.0, "cpu", "ed25519")
             m.verify_seconds.observe(dt, "cpu", "ed25519")
-            if _trace.enabled:
-                _trace.emit("crypto.batch_verify", "span",
-                            dur_ms=round(dt * 1e3, 3), path="cpu",
-                            n=self.count())
             return all(bits), bits
         return self.submit().result()
 
@@ -642,69 +638,52 @@ class Ed25519BatchVerifier(BatchVerifier):
         t0 = _time.perf_counter()
         pending = None
         path = "ladder"
-        if not self._force_perlane:
-            if n < _native_limit(n):
-                pending = self._native_batch()
-                if pending is not None:
-                    path = "native"
-            if pending is None and n >= MESH_MIN:
-                eng = _mesh_engine()
-                if eng is not None and _mesh_beats_single(n, _bucket(n)):
-                    pending = self._launch_mesh(eng)
+        # one span per dispatch: the host time inside submit(), split by
+        # its children (materialize, rlc_prepare, pack, device_launch or
+        # native_verify)
+        with _trace.span("crypto.batch_verify", n=n,
+                         bucket=_bucket(n)) as sp:
+            if not self._force_perlane:
+                if n < _native_limit(n):
+                    pending = self._native_batch()
                     if pending is not None:
-                        path = "mesh"
-            if (pending is None and n >= RLC_MIN
-                    and _rlc_beats_ladder(n, _bucket(n))):
-                pending = self._launch_rlc()
-                if pending is not None:
-                    path = "rlc"
-        if pending is None:
-            bits, all_ok = self._launch_device()
-            path = self._device_path
-            # Snapshot per-batch state: the verifier may be reused/mutated
-            # after submit() without corrupting in-flight results.
-            pending = PendingBatch(
-                bits,
-                all_ok,
-                n,
-                list(self._precheck_fail),
-                [self._items[i] for i in self._oversize],
-                list(self._oversize),
-            )
-        self._record_dispatch(path, n, t0, pending)
-        return pending
-
-    def _record_dispatch(self, path: str, n: int, t0: float,
-                         pending) -> None:
-        """Crypto-dispatch observability: per-path selection counter,
-        batch-size histogram, and (via the pending handle) the
-        submit→result latency; one trace span per batch with the
-        dispatch_model() stage terms behind the decision."""
-        host_s = _time.perf_counter() - t0
+                        path = "native"
+                if pending is None and n >= MESH_MIN:
+                    eng = _mesh_engine()
+                    if eng is not None and _mesh_beats_single(
+                            n, _bucket(n)):
+                        pending = self._launch_mesh(eng)
+                        if pending is not None:
+                            path = "mesh"
+                if (pending is None and n >= RLC_MIN
+                        and _rlc_beats_ladder(n, _bucket(n))):
+                    pending = self._launch_rlc()
+                    if pending is not None:
+                        path = "rlc"
+            if pending is None:
+                bits, all_ok = self._launch_device()
+                path = self._device_path
+                # Snapshot per-batch state: the verifier may be
+                # reused/mutated after submit() without corrupting
+                # in-flight results.
+                pending = PendingBatch(
+                    bits,
+                    all_ok,
+                    n,
+                    list(self._precheck_fail),
+                    [self._items[i] for i in self._oversize],
+                    list(self._oversize),
+                )
+            sp.add(path=path)
+        # dispatch observability: per-path selection counter, batch-size
+        # histogram and, through the handle, the submit→result latency
         m = crypto_metrics()
         m.batch_size.observe(n)
         m.path_selected_total.inc(1.0, path, "ed25519")
         pending._path = path
         pending._t0 = t0
-        if _trace.enabled:
-            fields = {"path": path, "n": n}
-            if path in ("rlc", "ladder", "delta", "mesh"):
-                mdl = dispatch_model(n, _bucket(n))
-                if path == "rlc":
-                    stages = mdl["rlc"]
-                elif path == "mesh" and "mesh" in mdl:
-                    stages = mdl["mesh"]
-                    fields["n_devices"] = mdl["n_devices"]
-                else:
-                    stages = mdl["ladder"]
-                fields.update(
-                    model_host_ms=round(stages["host"] * 1e3, 3),
-                    model_wire_ms=round(stages["wire"] * 1e3, 3),
-                    model_device_ms=round(stages["device"] * 1e3, 3),
-                    link_mbps=round(mdl["link_mbps"], 1),
-                )
-            _trace.emit("crypto.batch_verify", "span",
-                        dur_ms=round(host_s * 1e3, 3), **fields)
+        pending._batch = sp.id
+        return pending
 
     def _native_batch(self):
         """Synchronous C++ RLC batch for commit-sized batches; None when
@@ -714,18 +693,22 @@ class Ed25519BatchVerifier(BatchVerifier):
         if not native.available():
             return None
         self._materialize()
-        live = [
-            it for it, bad in zip(self._items, self._precheck_fail) if not bad
-        ]
-        ok = bool(live) and native.batch_verify(live)
-        if ok:
-            bits = [not bad for bad in self._precheck_fail]
+        with _trace.span("crypto.native_verify", n=self.count()) as sp:
+            live = [
+                it for it, bad in zip(self._items, self._precheck_fail)
+                if not bad
+            ]
+            ok = bool(live) and native.batch_verify(live)
+            sp.add(ok=ok)
+            if ok:
+                bits = [not bad for bad in self._precheck_fail]
+                return DonePending(all(bits), bits)
+            # blame via per-signature native verification
+            bits = []
+            for (pub, msg, sig), bad in zip(self._items,
+                                            self._precheck_fail):
+                bits.append(not bad and native.verify(pub, msg, sig))
             return DonePending(all(bits), bits)
-        # blame via per-signature native verification
-        bits = []
-        for (pub, msg, sig), bad in zip(self._items, self._precheck_fail):
-            bits.append(not bad and native.verify(pub, msg, sig))
-        return DonePending(all(bits), bits)
 
     def _launch_rlc(self):
         """RLC/MSM path: one multi-scalar multiplication for the whole
@@ -746,29 +729,38 @@ class Ed25519BatchVerifier(BatchVerifier):
         skip = np.asarray(self._precheck_fail, bool)
         # the columnar blobs already exist on this path: hand them to the
         # native packer so it skips the per-item join (~0.35 us/sig)
-        prep = _rlc.prepare(
-            self._items, skip, b,
-            blobs=(self._pub_buf, self._sig_buf, self._msg_buf,
-                   np.asarray(self._msg_lens, np.uint64)),
-        )
+        with _trace.span("crypto.rlc_prepare", n=n) as sp:
+            prep = _rlc.prepare(
+                self._items, skip, b,
+                blobs=(self._pub_buf, self._sig_buf, self._msg_buf,
+                       np.asarray(self._msg_lens, np.uint64)),
+            )
+            sp.add(declined=prep is None)
         if prep is None:
             crypto_metrics().gave_way_total.inc(1.0, "rlc_declined")
             return None
-        a_bytes = np.zeros((b, 32), np.uint8)
-        r_bytes = np.zeros((b, 32), np.uint8)
-        live = np.zeros((b,), bool)
-        pub_arr = np.frombuffer(bytes(self._pub_buf), np.uint8).reshape(n, 32)
-        sig_arr = np.frombuffer(bytes(self._sig_buf), np.uint8).reshape(n, 64)
-        a_bytes[:n] = pub_arr
-        r_bytes[:n] = sig_arr[:, :32]
-        live[:n] = ~skip
-        # pad the round count to a power of two (min 8): S is a static
-        # jit arg and the batch's max lane occupancy moves with the
-        # random z digits, so tiering keeps the compiled-variant count
-        # at ~2 per bucket instead of one per distinct occupancy
-        s_pad = 8
-        while s_pad < prep["s_rounds"]:
-            s_pad *= 2
+        with _trace.span("crypto.pack", n=n, bucket=b):
+            a_bytes = np.zeros((b, 32), np.uint8)
+            r_bytes = np.zeros((b, 32), np.uint8)
+            live = np.zeros((b,), bool)
+            pub_arr = np.frombuffer(
+                bytes(self._pub_buf), np.uint8).reshape(n, 32)
+            sig_arr = np.frombuffer(
+                bytes(self._sig_buf), np.uint8).reshape(n, 64)
+            a_bytes[:n] = pub_arr
+            r_bytes[:n] = sig_arr[:, :32]
+            live[:n] = ~skip
+            # pad the round count to a power of two (min 8): S is a
+            # static jit arg and the batch's max lane occupancy moves
+            # with the random z digits, so tiering keeps the
+            # compiled-variant count at ~2 per bucket instead of one per
+            # distinct occupancy
+            s_pad = 8
+            while s_pad < prep["s_rounds"]:
+                s_pad *= 2
+            wire = (a_bytes, r_bytes, live, prep["stream"],
+                    prep["stream_neg"], prep["counts"], prep["weights"],
+                    prep["c_digits"])
         global _LAST_WIRE_B_PER_LANE
         _LAST_WIRE_B_PER_LANE = round(
             (
@@ -779,21 +771,10 @@ class Ed25519BatchVerifier(BatchVerifier):
             )
             / b
         )
-        ok = rlc_verify_stream_jit(
-            *jax.device_put(
-                (
-                    a_bytes,
-                    r_bytes,
-                    live,
-                    prep["stream"],
-                    prep["stream_neg"],
-                    prep["counts"],
-                    prep["weights"],
-                    prep["c_digits"],
-                )
-            ),
-            s_rounds=s_pad,
-        )
+        with _trace.span("crypto.device_launch",
+                         bytes=sum(a.nbytes for a in wire)):
+            ok = rlc_verify_stream_jit(
+                *jax.device_put(wire), s_rounds=s_pad)
         return PendingRLC(
             ok, n, list(self._precheck_fail), list(self._items)
         )
@@ -842,7 +823,8 @@ class Ed25519BatchVerifier(BatchVerifier):
                 self._materialize()
                 self._device_path = "delta"
                 return self._launch_device_delta(self._delta)
-        rsk, live, pub_blob = self._pack_rsk_live(n, b)
+        with _trace.span("crypto.pack", n=n, bucket=b):
+            rsk, live, pub_blob = self._pack_rsk_live(n, b)
         # Streamed placement: when a multi-device mesh is up, each whole
         # single-chip batch lands on the next device round-robin, so d
         # independent commits verify concurrently with no collective at
@@ -858,23 +840,28 @@ class Ed25519BatchVerifier(BatchVerifier):
         # (keyed by content hash — 1 ms vs 50 ms of wire + exponentiation;
         # streamed batches key per device so each chip keeps its own copy).
         fp = (hashlib.sha256(pub_blob).digest(), b, dev)
-        cached = _A_CACHE.get(fp)
-        if cached is None:
-            a_bytes = np.zeros((b, 32), np.uint8)
-            a_bytes[:n] = np.frombuffer(pub_blob, np.uint8).reshape(n, 32)
-            cached = decompress_pubkeys_jit(jax.device_put(a_bytes, dev))
-            _A_CACHE[fp] = cached
-            while len(_A_CACHE) > _A_CACHE_SIZE:
-                _A_CACHE.pop(next(iter(_A_CACHE)))
-        ok_a, neg_a = cached
         global _LAST_WIRE_B_PER_LANE
         _LAST_WIRE_B_PER_LANE = _WIRE_LADDER_B
-        if dev is not None and _trace.enabled:
-            _trace.emit("crypto.stream_place", "event",
-                        device=str(getattr(dev, "id", dev)), n=n, b=b)
-        return verify_batch_cached_a_jit(
-            ok_a, neg_a, *jax.device_put((rsk, live), dev)
-        )
+        with _trace.span("crypto.device_launch",
+                         bytes=rsk.nbytes + live.nbytes) as sp:
+            cached = _A_CACHE.get(fp)
+            if cached is None:
+                a_bytes = np.zeros((b, 32), np.uint8)
+                a_bytes[:n] = np.frombuffer(
+                    pub_blob, np.uint8).reshape(n, 32)
+                sp.add(bytes=rsk.nbytes + live.nbytes + a_bytes.nbytes)
+                cached = decompress_pubkeys_jit(
+                    jax.device_put(a_bytes, dev))
+                _A_CACHE[fp] = cached
+                while len(_A_CACHE) > _A_CACHE_SIZE:
+                    _A_CACHE.pop(next(iter(_A_CACHE)))
+            ok_a, neg_a = cached
+            if dev is not None and _trace.enabled:
+                _trace.emit("crypto.stream_place", "event",
+                            device=str(getattr(dev, "id", dev)), n=n, b=b)
+            return verify_batch_cached_a_jit(
+                ok_a, neg_a, *jax.device_put((rsk, live), dev)
+            )
 
     def _pack_rsk_live(self, n: int, b: int):
         """Pack the (b,96) R||S||k rows + live mask shared by the
@@ -1081,6 +1068,22 @@ def _observe_latency(p) -> None:
     )
 
 
+def _await_verdict(p) -> tuple[bool, list[bool]]:
+    """result() of an in-flight device batch: block on the summary
+    scalar, then finalize. The span's `batch` is the id of the
+    crypto.batch_verify span that submitted it (the two are one unit of
+    work but not nested in time); `since_submit_ms`, taken as the fetch
+    returns, is the device program as the host sees it."""
+    with _trace.span("crypto.verdict_wait", path=p._path, n=p._n,
+                     batch=p._batch) as sp:
+        dev_all_ok = bool(np.asarray(p._all_ok))
+        if p._t0 is not None:
+            sp.add(since_submit_ms=round(
+                (_time.perf_counter() - p._t0) * 1e3, 3))
+        sp.add(blame_rerun=not dev_all_ok)
+        return p._finalize_fast(dev_all_ok)
+
+
 def _prefetch_summary(arr) -> None:
     """Start an async device->host copy of a summary scalar (no-op for
     host-resident or stubbed summaries)."""
@@ -1100,7 +1103,8 @@ class PendingBatch:
     only when some lane failed."""
 
     __slots__ = ("_dev", "_all_ok", "_n", "_precheck_fail",
-                 "_oversize_items", "_oversize_idx", "_path", "_t0")
+                 "_oversize_items", "_oversize_idx", "_path", "_t0",
+                 "_batch")
 
     def __init__(self, dev, all_ok, n, precheck_fail, oversize_items,
                  oversize_idx):
@@ -1112,6 +1116,7 @@ class PendingBatch:
         self._oversize_idx = oversize_idx
         self._path = None
         self._t0 = None
+        self._batch = None
 
     def _finalize(self, bits) -> tuple[bool, list[bool]]:
         out = [bool(x) and not bad for x, bad in zip(bits, self._precheck_fail)]
@@ -1143,13 +1148,13 @@ class PendingBatch:
         _prefetch_summary(self._all_ok)
 
     def result(self) -> tuple[bool, list[bool]]:
-        return self._finalize_fast(bool(np.asarray(self._all_ok)))
+        return _await_verdict(self)
 
 
 class DonePending:
     """Already-resolved batch (native CPU path) behind the pending API."""
 
-    __slots__ = ("_ok", "_bits", "_all_ok", "_path", "_t0")
+    __slots__ = ("_ok", "_bits", "_all_ok", "_path", "_t0", "_batch")
 
     def __init__(self, ok, bits):
         self._ok = ok
@@ -1157,6 +1162,7 @@ class DonePending:
         self._all_ok = np.asarray(ok)  # collect_pending stacks this
         self._path = None
         self._t0 = None
+        self._batch = None
 
     def _finalize_fast(self, _dev_all_ok) -> tuple[bool, list[bool]]:
         _observe_latency(self)
@@ -1177,7 +1183,7 @@ class PendingRLC:
     reference's batch->single fallback (types/validation.go:304-311)."""
 
     __slots__ = ("_all_ok", "_n", "_precheck_fail", "_items", "_path",
-                 "_t0")
+                 "_t0", "_batch")
 
     def __init__(self, all_ok, n, precheck_fail, items):
         self._all_ok = all_ok
@@ -1186,6 +1192,7 @@ class PendingRLC:
         self._items = items
         self._path = None
         self._t0 = None
+        self._batch = None
 
     def _finalize_fast(self, dev_all_ok: bool) -> tuple[bool, list[bool]]:
         _observe_latency(self)
@@ -1202,7 +1209,7 @@ class PendingRLC:
         _prefetch_summary(self._all_ok)
 
     def result(self) -> tuple[bool, list[bool]]:
-        return self._finalize_fast(bool(np.asarray(self._all_ok)))
+        return _await_verdict(self)
 
 
 def collect_pending(pendings: list[PendingBatch]) -> list[tuple[bool, list[bool]]]:

@@ -42,12 +42,23 @@ if "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES" not in _os.environ:
     _jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 # The cache key of a program that holds a Pallas kernel covers the
-# kernel's serialized Mosaic module, and by default jax writes the whole
-# Python call stack of every op into that module's locations — which the
-# key's canonicalisation does not strip. The same shape first reached
-# through another caller (verify_commit, the replay engine, RLC's blame
-# fallback) then has another key and compiles again: on the chip a warm
-# run recompiled both ladder shapes while the RLC graph, always first
-# traced from the same stack, hit (PR 21). Locations keep the op's own
-# frame; the key is then a function of the program alone.
-_jax.config.update("jax_include_full_tracebacks_in_locations", False)
+# kernel's serialized Mosaic module, and by default jax writes the Python
+# call stack of every op (ten frames of it) into that module's locations
+# — which the key's canonicalisation does not strip. The same shape first
+# reached through another caller (verify_commit, the replay engine, RLC's
+# blame fallback) then has another key and compiles again: on the chip a
+# warm run recompiled both ladder shapes while the RLC graph, always
+# first traced from the same stack, hit (PR 21). One frame: locations
+# keep the op's own line and nothing of its callers; the key is then a
+# function of the program alone
+# (tests/test_tpu_device.py::test_cache_key_does_not_depend_on_the_caller).
+#
+# Why the limit and not jax_include_full_tracebacks_in_locations=False,
+# which also keeps one frame (PR 21 to 23): with that switch off, this
+# jax (0.9) inlines each operation's cached lowering under a location
+# whose name stack the HLO converter drops, so op_name is the bare
+# primitive ("and", "add") and the phases below (jax.named_scope, the
+# names of utils/trace.KERNEL_SCOPES) never reach a profiler trace. With
+# tracebacks on, op_name is the whole path, "jit(rlc_verify_stream)/
+# rlc.accumulate/...", which utils/traceview.device_join reads.
+_jax.config.update("jax_traceback_in_locations_limit", 1)

@@ -224,6 +224,7 @@ def _decompress_pallas(y, sign):
         grid=(batch // tile,),
         in_specs=[point_spec, row_spec, bias_spec, consts_spec],
         out_specs=[row_spec, point_spec, point_spec],
+        name="curve_decompress",
     )(y, sign[None, :], jnp.asarray(F._SUB_BIAS), jnp.asarray(_CONSTS_NP))
     return valid[0] != 0, x, t
 
@@ -471,6 +472,7 @@ def _ladder_sub_mul8_pallas(s_digits, k_digits, a_point, r_point):
                                      bias_spec, consts_spec],
         out_specs=[point_spec] * 3,
         scratch_shapes=[pltpu.VMEM((9 * 4 * nl, tile), jnp.int32)],
+        name="curve_ladder_sub_mul8",
     )(*a_point, *r_point, s_digits, k_digits, base_flat, bias, consts)
     return tuple(out)
 

@@ -51,26 +51,33 @@ def verify_batch(a_bytes, r_bytes, s_bytes, msg_words, two_blocks, live):
 
     Returns (B,) bool validity bitmap.
     """
-    hi, lo = H.sha512_two_blocks(msg_words, two_blocks)  # (8, B) u32, BE
-    digest_bytes = _digest_to_bytes(hi, lo)  # (B, 64)
+    # the phases are utils/trace.KERNEL_SCOPES: names on the operations,
+    # for a profiler trace; the program is what it is without them
+    with jax.named_scope("ladder.sha512"):
+        hi, lo = H.sha512_two_blocks(msg_words, two_blocks)  # (8, B) u32, BE
+        digest_bytes = _digest_to_bytes(hi, lo)  # (B, 64)
 
-    k = SC.reduce512(digest_bytes)  # (22, B) canonical < L
-    k_digits = SC.recode_signed(k)
-    s_digits = SC.digits_from_bytes(s_bytes)
-    s_ok = SC.lt_l(s_bytes)
+    with jax.named_scope("ladder.scalar_reduce"):
+        k = SC.reduce512(digest_bytes)  # (22, B) canonical < L
+        k_digits = SC.recode_signed(k)
+        s_digits = SC.digits_from_bytes(s_bytes)
+        s_ok = SC.lt_l(s_bytes)
 
-    ok_a, a_pt = C.decompress(a_bytes)
-    ok_r, r_pt = C.decompress(r_bytes)
-    X, Y, Z = C.ladder_sub_mul8(s_digits, k_digits, C.neg(a_pt), r_pt)
-    ok_eq = F.is_zero(X) & F.eq(Y, Z)
-    bits = ok_a & ok_r & ok_eq & s_ok & live
-    # scalar summary: every LIVE lane verified (padding/oversize lanes are
-    # excluded). Fetching this single bool instead of the bitmap keeps the
-    # happy-path device→host transfer at pure round-trip latency; the
-    # bitmap is only pulled when the summary says some lane failed
-    # (reference types/validation.go:304 falls back to a per-sig scan
-    # only when the batch verify fails).
-    return bits, jnp.all(bits | ~live)
+    with jax.named_scope("ladder.decompress"):
+        ok_a, a_pt = C.decompress(a_bytes)
+        ok_r, r_pt = C.decompress(r_bytes)
+    with jax.named_scope("ladder.double_scalar"):
+        X, Y, Z = C.ladder_sub_mul8(s_digits, k_digits, C.neg(a_pt), r_pt)
+    with jax.named_scope("ladder.compare"):
+        ok_eq = F.is_zero(X) & F.eq(Y, Z)
+        bits = ok_a & ok_r & ok_eq & s_ok & live
+        # scalar summary: every LIVE lane verified (padding/oversize lanes
+        # are excluded). Fetching this single bool instead of the bitmap
+        # keeps the happy-path device→host transfer at pure round-trip
+        # latency; the bitmap is only pulled when the summary says some
+        # lane failed (reference types/validation.go:304 falls back to a
+        # per-sig scan only when the batch verify fails).
+        return bits, jnp.all(bits | ~live)
 
 
 verify_batch_jit = jax.jit(verify_batch)
@@ -87,15 +94,19 @@ def verify_batch_prehashed(a_bytes, r_bytes, s_bytes, k_bytes, live):
     stages entirely. The curve-side check is identical to verify_batch:
     [8]([S]B + [k](-A) - R) == identity with liberal decoding.
     """
-    k_digits = SC.digits_from_bytes(k_bytes)
-    s_digits = SC.digits_from_bytes(s_bytes)
-    s_ok = SC.lt_l(s_bytes)
-    ok_a, a_pt = C.decompress(a_bytes)
-    ok_r, r_pt = C.decompress(r_bytes)
-    X, Y, Z = C.ladder_sub_mul8(s_digits, k_digits, C.neg(a_pt), r_pt)
-    ok_eq = F.is_zero(X) & F.eq(Y, Z)
-    bits = ok_a & ok_r & ok_eq & s_ok & live
-    return bits, jnp.all(bits | ~live)
+    with jax.named_scope("ladder.scalar_reduce"):
+        k_digits = SC.digits_from_bytes(k_bytes)
+        s_digits = SC.digits_from_bytes(s_bytes)
+        s_ok = SC.lt_l(s_bytes)
+    with jax.named_scope("ladder.decompress"):
+        ok_a, a_pt = C.decompress(a_bytes)
+        ok_r, r_pt = C.decompress(r_bytes)
+    with jax.named_scope("ladder.double_scalar"):
+        X, Y, Z = C.ladder_sub_mul8(s_digits, k_digits, C.neg(a_pt), r_pt)
+    with jax.named_scope("ladder.compare"):
+        ok_eq = F.is_zero(X) & F.eq(Y, Z)
+        bits = ok_a & ok_r & ok_eq & s_ok & live
+        return bits, jnp.all(bits | ~live)
 
 
 verify_batch_prehashed_jit = jax.jit(verify_batch_prehashed)
@@ -110,8 +121,9 @@ def decompress_pubkeys(a_bytes):
     32 bytes/lane of A never need to re-cross the host->device link and
     the sqrt-decompression (one of the two per-lane exponentiations)
     runs once per validator-set change instead of once per commit."""
-    ok_a, a_pt = C.decompress(a_bytes)
-    return ok_a, C.neg(a_pt)
+    with jax.named_scope("ladder.decompress"):
+        ok_a, a_pt = C.decompress(a_bytes)
+        return ok_a, C.neg(a_pt)
 
 
 decompress_pubkeys_jit = jax.jit(decompress_pubkeys)
@@ -217,21 +229,26 @@ def verify_batch_delta(ok_a, neg_a, a_enc, packed, meta):
     h = DELTA_META_HEADER
     prefix = meta[h : h + DELTA_PMAX]
     suffix = meta[h + DELTA_PMAX :]
-    words, two = build_delta_msgs(
-        a_enc, rs_mid, mlens, plen, slen, prefix, suffix
-    )
-    hi, lo = H.sha512_two_blocks(words, two)
-    digest_bytes = _digest_to_bytes(hi, lo)
-    k = SC.reduce512(digest_bytes)
-    k_digits = SC.recode_signed(k)
-    s_bytes = rs_mid[:, 32:64]
-    s_digits = SC.digits_from_bytes(s_bytes)
-    s_ok = SC.lt_l(s_bytes)
-    ok_r, r_pt = C.decompress(rs_mid[:, :32])
-    X, Y, Z = C.ladder_sub_mul8(s_digits, k_digits, neg_a, r_pt)
-    ok_eq = F.is_zero(X) & F.eq(Y, Z)
-    bits = ok_a & ok_r & ok_eq & s_ok & live
-    return bits, jnp.all(bits | ~live)
+    with jax.named_scope("ladder.sha512"):
+        words, two = build_delta_msgs(
+            a_enc, rs_mid, mlens, plen, slen, prefix, suffix
+        )
+        hi, lo = H.sha512_two_blocks(words, two)
+        digest_bytes = _digest_to_bytes(hi, lo)
+    with jax.named_scope("ladder.scalar_reduce"):
+        k = SC.reduce512(digest_bytes)
+        k_digits = SC.recode_signed(k)
+        s_bytes = rs_mid[:, 32:64]
+        s_digits = SC.digits_from_bytes(s_bytes)
+        s_ok = SC.lt_l(s_bytes)
+    with jax.named_scope("ladder.decompress"):
+        ok_r, r_pt = C.decompress(rs_mid[:, :32])
+    with jax.named_scope("ladder.double_scalar"):
+        X, Y, Z = C.ladder_sub_mul8(s_digits, k_digits, neg_a, r_pt)
+    with jax.named_scope("ladder.compare"):
+        ok_eq = F.is_zero(X) & F.eq(Y, Z)
+        bits = ok_a & ok_r & ok_eq & s_ok & live
+        return bits, jnp.all(bits | ~live)
 
 
 verify_batch_delta_jit = jax.jit(verify_batch_delta)
@@ -247,14 +264,18 @@ def verify_batch_cached_a(ok_a, neg_a, rsk, live):
     r_bytes = rsk[:, :32]
     s_bytes = rsk[:, 32:64]
     k_bytes = rsk[:, 64:]
-    k_digits = SC.digits_from_bytes(k_bytes)
-    s_digits = SC.digits_from_bytes(s_bytes)
-    s_ok = SC.lt_l(s_bytes)
-    ok_r, r_pt = C.decompress(r_bytes)
-    X, Y, Z = C.ladder_sub_mul8(s_digits, k_digits, neg_a, r_pt)
-    ok_eq = F.is_zero(X) & F.eq(Y, Z)
-    bits = ok_a & ok_r & ok_eq & s_ok & live
-    return bits, jnp.all(bits | ~live)
+    with jax.named_scope("ladder.scalar_reduce"):
+        k_digits = SC.digits_from_bytes(k_bytes)
+        s_digits = SC.digits_from_bytes(s_bytes)
+        s_ok = SC.lt_l(s_bytes)
+    with jax.named_scope("ladder.decompress"):
+        ok_r, r_pt = C.decompress(r_bytes)
+    with jax.named_scope("ladder.double_scalar"):
+        X, Y, Z = C.ladder_sub_mul8(s_digits, k_digits, neg_a, r_pt)
+    with jax.named_scope("ladder.compare"):
+        ok_eq = F.is_zero(X) & F.eq(Y, Z)
+        bits = ok_a & ok_r & ok_eq & s_ok & live
+        return bits, jnp.all(bits | ~live)
 
 
 verify_batch_cached_a_jit = jax.jit(verify_batch_cached_a)

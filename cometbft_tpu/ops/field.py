@@ -254,7 +254,7 @@ def _use_pallas(*arrs) -> bool:
     return _on_tpu() and kernel_width(arrs[0].shape[-1])
 
 
-def _pallas_binop(kernel, *arrs):
+def _pallas_binop(kernel, name, *arrs):
     import jax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -268,6 +268,7 @@ def _pallas_binop(kernel, *arrs):
         grid=(b // tile,),
         in_specs=[spec] * len(arrs),
         out_specs=spec,
+        name=name,
     )(*arrs)
 
 
@@ -292,7 +293,7 @@ def mul(a, b):
     if _IN_KERNEL:
         return _fold_wide(_conv_rows_shifted(a, b))
     if _use_pallas(a, b):
-        return _pallas_binop(_mul_kernel, a, b)
+        return _pallas_binop(_mul_kernel, "field_mul", a, b)
     return _fold_wide(_conv_rows_shifted(a, b))
 
 
@@ -302,7 +303,7 @@ def sq(a):
     if _IN_KERNEL:
         return _fold_wide(_conv_rows_shifted(a, a))
     if _use_pallas(a):
-        return _pallas_binop(_sq_kernel, a)
+        return _pallas_binop(_sq_kernel, "field_sq", a)
     return _fold_wide(_conv_rows_shifted(a, a))
 
 

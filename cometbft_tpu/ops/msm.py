@@ -199,6 +199,7 @@ def _accumulate_weighted_pallas(niels, gather_idx, gather_neg, weights):
         in_specs=[stream_spec, w_spec, bias_spec, consts_spec],
         out_specs=[out_spec] * 4,
         scratch_shapes=[pltpu.VMEM((4 * nl, tile), jnp.int32)],
+        name="msm_accumulate_weighted",
     )(stream, w_arr, bias, consts)
     return tuple(out)
 
@@ -391,34 +392,43 @@ def rlc_verify(a_bytes, r_bytes, live, gather_idx, gather_neg, weights,
 
     Returns scalar bool: the whole batch verifies.
     """
-    ok_a, a_pt = C.decompress(a_bytes)
-    ok_r, r_pt = C.decompress(r_bytes)
+    # the phases are utils/trace.KERNEL_SCOPES: names on the operations,
+    # for a profiler trace; the program is what it is without them
+    with jax.named_scope("rlc.decompress"):
+        ok_a, a_pt = C.decompress(a_bytes)
+        ok_r, r_pt = C.decompress(r_bytes)
 
-    # affine niels (Z=1 after decompress): (Y+X, Y-X, 2dT)
-    def niels_of(p):
-        n = C.to_niels(p)
-        return n[0], n[1], n[2]
+        # affine niels (Z=1 after decompress): (Y+X, Y-X, 2dT)
+        def niels_of(p):
+            n = C.to_niels(p)
+            return n[0], n[1], n[2]
 
-    na, nr = niels_of(a_pt), niels_of(r_pt)
-    ident = _identity_niels(1)
-    niels = tuple(
-        jnp.concatenate([r_c, a_c, i_c], axis=1)
-        for r_c, a_c, i_c in zip(nr, na, ident)
-    )
+        na, nr = niels_of(a_pt), niels_of(r_pt)
+        ident = _identity_niels(1)
+        niels = tuple(
+            jnp.concatenate([r_c, a_c, i_c], axis=1)
+            for r_c, a_c, i_c in zip(nr, na, ident)
+        )
 
     if F._use_pallas(jnp.zeros((F.NLIMBS, WK), jnp.int32)):
-        weighted = _accumulate_weighted_pallas(
-            niels, gather_idx, gather_neg, weights
-        )
-        win_sums = _region_tree_sum(weighted)
+        with jax.named_scope("rlc.accumulate"):
+            weighted = _accumulate_weighted_pallas(
+                niels, gather_idx, gather_neg, weights
+            )
+        with jax.named_scope("rlc.bucket_reduce"):
+            win_sums = _region_tree_sum(weighted)
     else:
-        acc = _accumulate(niels, gather_idx, gather_neg)
-        win_sums = _bucket_reduce(acc, weights)
-    msm = _window_combine(win_sums)
-    total = C.add(msm, C.fixed_base(c_digits))
-    ok_eq = C.is_identity(C.mul8(total))[0]
-    ok_points = jnp.all(ok_a | ~live) & jnp.all(ok_r | ~live)
-    return ok_eq & ok_points
+        with jax.named_scope("rlc.accumulate"):
+            acc = _accumulate(niels, gather_idx, gather_neg)
+        with jax.named_scope("rlc.bucket_reduce"):
+            win_sums = _bucket_reduce(acc, weights)
+    with jax.named_scope("rlc.window_combine"):
+        msm = _window_combine(win_sums)
+    with jax.named_scope("rlc.final_check"):
+        total = C.add(msm, C.fixed_base(c_digits))
+        ok_eq = C.is_identity(C.mul8(total))[0]
+        ok_points = jnp.all(ok_a | ~live) & jnp.all(ok_r | ~live)
+        return ok_eq & ok_points
 
 
 rlc_verify_jit = jax.jit(rlc_verify)
@@ -429,9 +439,10 @@ def rlc_verify_stream(a_bytes, r_bytes, live, stream, stream_neg, counts,
     """rlc_verify over the compact wire format: the (S, WK) table is
     expanded on device (expand_stream) from the dense contribution
     stream, so the host->device link carries ~2 B/contribution."""
-    gather_idx, gather_neg = expand_stream(
-        stream, stream_neg, counts, s_rounds
-    )
+    with jax.named_scope("rlc.expand_stream"):
+        gather_idx, gather_neg = expand_stream(
+            stream, stream_neg, counts, s_rounds
+        )
     return rlc_verify(a_bytes, r_bytes, live, gather_idx, gather_neg,
                       weights, c_digits)
 

@@ -348,6 +348,17 @@ class BlockExecutor:
         block: Block,
         last_commit_preverified: bool = False,
     ) -> State:
+        from ..utils import trace
+
+        # one span per ApplyBlock; _apply_block adds the per-stage
+        # breakdown (validate = commit-sig verification, the crypto path)
+        with trace.span("state.apply_block", height=block.header.height,
+                        txs=len(block.data.txs)) as span:
+            return self._apply_block(
+                state, block_id, block, last_commit_preverified, span)
+
+    def _apply_block(self, state, block_id, block,
+                     last_commit_preverified, span) -> State:
         import time as _time
 
         from ..abci.types import FinalizeBlockRequest
@@ -461,12 +472,7 @@ class BlockExecutor:
         t_end = _time.perf_counter()
         state_metrics().block_processing_time.observe(t_end - t0)
         if trace.enabled:
-            # One span per ApplyBlock carrying the per-stage breakdown
-            # (validate = commit-sig verification, i.e. the crypto path).
-            trace.emit(
-                "state.apply_block", "span",
-                height=block.header.height, txs=len(block.data.txs),
-                dur_ms=round((t_end - t0) * 1e3, 3),
+            span.add(
                 validate_ms=round((t_validate - t0) * 1e3, 3),
                 finalize_ms=round((t_finalize - t_validate) * 1e3, 3),
                 commit_ms=round((t_commit - t_finalize) * 1e3, 3),

@@ -93,23 +93,27 @@ def _verify_items(items, backend: str):
 
         groups: dict[str, tuple[object, list[int]]] = {}
         singles: dict[str, list[int]] = {}
-        for i, (pub, msg, sig, _) in enumerate(items):
-            tag = pub.type_tag()
-            if tag not in groups:
-                groups[tag] = (create_batch_verifier(pub, backend=backend), [])
-            bv, idxs = groups[tag]
-            if bv is None:
-                singles.setdefault(tag, []).append(i)
-                continue
-            before = bv.count()
-            added = bv.add(pub, msg, sig)
-            if bv.count() > before:
-                # verifier took the item (possibly pre-marked invalid):
-                # its bitmap stays index-aligned
-                idxs.append(i)
-            elif not added:
-                # rejected outright: decide singly
-                singles.setdefault(tag, []).append(i)
+        with _trace.span("types.verify_items_fill", n=len(items)) as sp:
+            for i, (pub, msg, sig, _) in enumerate(items):
+                tag = pub.type_tag()
+                if tag not in groups:
+                    groups[tag] = (
+                        create_batch_verifier(pub, backend=backend), [])
+                bv, idxs = groups[tag]
+                if bv is None:
+                    singles.setdefault(tag, []).append(i)
+                    continue
+                before = bv.count()
+                added = bv.add(pub, msg, sig)
+                if bv.count() > before:
+                    # verifier took the item (possibly pre-marked
+                    # invalid): its bitmap stays index-aligned
+                    idxs.append(i)
+                elif not added:
+                    # rejected outright: decide singly
+                    singles.setdefault(tag, []).append(i)
+            sp.add(groups=len(groups),
+                   singles=sum(len(v) for v in singles.values()))
         # Launch every batch group async FIRST (submit() returns an
         # in-flight handle; on a multi-device mesh each group can land
         # on a different chip), then verify the singles while the
@@ -362,29 +366,41 @@ def verify_commit(
     if isinstance(commit, CertCommit):
         return _verify_cert_commit(
             chain_id, vals, block_id, height, commit, backend=backend)
-    _check_commit_basics(vals, commit, height, block_id)
-    if len(vals) != commit.size():
-        raise ErrInvalidCommitSize(
-            f"validator set size {len(vals)} != commit size {commit.size()}"
-        )
-    items = []
-    tally_power = 0
-    for idx, cs in enumerate(commit.signatures):
-        if cs.is_absent():
-            continue
-        val = vals.get_by_index(idx)
-        if val.address != cs.validator_address:
-            raise ErrInvalidSignature(
-                f"address mismatch at index {idx}"
+    with _trace.span("types.verify_commit", height=height) as root:
+        _check_commit_basics(vals, commit, height, block_id)
+        if len(vals) != commit.size():
+            raise ErrInvalidCommitSize(
+                f"validator set size {len(vals)} != commit size {commit.size()}"
             )
-        counted = val.voting_power if cs.is_commit() else 0
-        items.append((val.pub_key, commit.vote_sign_bytes(chain_id, idx), cs.signature, counted))
-    tally_power = _verify_items(items, backend)
-    threshold = vals.total_voting_power() * 2 // 3
-    if tally_power <= threshold:
-        raise ErrNotEnoughVotingPower(
-            f"tallied {tally_power} <= threshold {threshold}"
-        )
+        items = []
+        # per-lane time is never a span: accumulated under a local flag
+        timed = _trace.enabled
+        sign_s = 0.0
+        with _trace.span("types.commit_items") as sp:
+            for idx, cs in enumerate(commit.signatures):
+                if cs.is_absent():
+                    continue
+                val = vals.get_by_index(idx)
+                if val.address != cs.validator_address:
+                    raise ErrInvalidSignature(
+                        f"address mismatch at index {idx}"
+                    )
+                counted = val.voting_power if cs.is_commit() else 0
+                if timed:
+                    t0 = _time.perf_counter()
+                    msg = commit.vote_sign_bytes(chain_id, idx)
+                    sign_s += _time.perf_counter() - t0
+                else:
+                    msg = commit.vote_sign_bytes(chain_id, idx)
+                items.append((val.pub_key, msg, cs.signature, counted))
+            sp.add(n=len(items), sign_bytes_ms=round(sign_s * 1e3, 3))
+        root.add(n=len(items))
+        tally_power = _verify_items(items, backend)
+        threshold = vals.total_voting_power() * 2 // 3
+        if tally_power <= threshold:
+            raise ErrNotEnoughVotingPower(
+                f"tallied {tally_power} <= threshold {threshold}"
+            )
 
 
 def verify_commit_light(
@@ -403,27 +419,43 @@ def verify_commit_light(
     if isinstance(commit, CertCommit):
         return _verify_cert_commit(
             chain_id, vals, block_id, height, commit, backend=backend)
-    _check_commit_basics(vals, commit, height, block_id)
-    if len(vals) != commit.size():
-        raise ErrInvalidCommitSize(
-            f"validator set size {len(vals)} != commit size {commit.size()}"
-        )
-    items = []
-    threshold = vals.total_voting_power() * 2 // 3
-    running = 0
-    for idx, cs in enumerate(commit.signatures):
-        if not cs.is_commit():
-            continue
-        val = vals.get_by_index(idx)
-        if val.address != cs.validator_address:
-            raise ErrInvalidSignature(f"address mismatch at index {idx}")
-        items.append((val.pub_key, commit.vote_sign_bytes(chain_id, idx), cs.signature, val.voting_power))
-        running += val.voting_power
-        if not verify_all_signatures and running > threshold:
-            break
-    tally = _verify_items(items, backend)
-    if tally <= threshold:
-        raise ErrNotEnoughVotingPower(f"tallied {tally} <= threshold {threshold}")
+    with _trace.span("types.verify_commit", height=height,
+                     light=True) as root:
+        _check_commit_basics(vals, commit, height, block_id)
+        if len(vals) != commit.size():
+            raise ErrInvalidCommitSize(
+                f"validator set size {len(vals)} != commit size {commit.size()}"
+            )
+        items = []
+        threshold = vals.total_voting_power() * 2 // 3
+        running = 0
+        timed = _trace.enabled
+        sign_s = 0.0
+        with _trace.span("types.commit_items") as sp:
+            for idx, cs in enumerate(commit.signatures):
+                if not cs.is_commit():
+                    continue
+                val = vals.get_by_index(idx)
+                if val.address != cs.validator_address:
+                    raise ErrInvalidSignature(
+                        f"address mismatch at index {idx}")
+                if timed:
+                    t0 = _time.perf_counter()
+                    msg = commit.vote_sign_bytes(chain_id, idx)
+                    sign_s += _time.perf_counter() - t0
+                else:
+                    msg = commit.vote_sign_bytes(chain_id, idx)
+                items.append(
+                    (val.pub_key, msg, cs.signature, val.voting_power))
+                running += val.voting_power
+                if not verify_all_signatures and running > threshold:
+                    break
+            sp.add(n=len(items), sign_bytes_ms=round(sign_s * 1e3, 3))
+        root.add(n=len(items))
+        tally = _verify_items(items, backend)
+        if tally <= threshold:
+            raise ErrNotEnoughVotingPower(
+                f"tallied {tally} <= threshold {threshold}")
 
 
 def verify_commit_light_trusting(

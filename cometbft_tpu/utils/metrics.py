@@ -544,9 +544,6 @@ class CryptoMetrics:
             "takes them) and on-device-SHA lanes too long for the "
             "kernel (oversize, per lane; the host reference takes them)",
             labels=("reason",))
-        self.calibration_us_per_sig = reg.gauge(
-            "crypto", "calibration_us_per_sig",
-            "Calibrated host-stage dispatch terms", labels=("term",))
         self.msm_native_total = reg.counter(
             "crypto", "msm_native_total",
             "G1 multi-scalar multiplications run on the native "

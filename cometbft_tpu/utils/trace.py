@@ -6,7 +6,8 @@ across consensus steps, ApplyBlock stages, blocksync fetch→verify→apply
 and crypto batch-verify dispatch (ISSUE 3 tentpole part 1). ISSUE 6
 grows it into the data plane of the cross-node flight recorder: every
 record carries a stable node identity, and p2p wire-message hooks give
-the merger (utils/traceview.py) send→recv edges between sinks.
+the merger (utils/traceview.py) send→recv edges between sinks. ISSUE 24
+makes the spans a tree on a clock the profiler shares.
 
 Design constraints:
 
@@ -14,21 +15,43 @@ Design constraints:
   hot paths guard with ``if trace.enabled:`` so the disabled cost is one
   global load. `span()` returns a shared no-op context manager so
   un-guarded ``with trace.span(...)`` sites stay cheap too.
-* One JSON object per line. Writes are buffered with a bounded
-  staleness: the sink is flushed when FLUSH_INTERVAL_S has passed since
-  the last flush (checked at each emit), by `tail()`, and at graceful
-  shutdown — per-record flushing costs a syscall per consensus wire
-  message once the p2p hooks are on, which measurably slows a loaded
-  multi-node host. A SIGKILLed node loses at most the last interval's
-  records. Every record carries ``ts`` (epoch seconds), ``pid`` (merge
-  safety across e2e nodes), ``name`` and ``kind`` ("span" or "event");
-  spans add ``dur_ms``; callers attach free-form fields. Once
-  `set_node()` ran, records also carry ``node`` — the cross-process join
-  key the traceview merger aligns sinks on.
+* One JSON object per line. Every record carries ``ts`` (epoch seconds
+  at emit, i.e. a span's END), ``pid`` (merge safety across e2e nodes),
+  ``name`` and ``kind`` ("span" or "event"); spans add ``dur_ms``;
+  callers attach free-form fields. Once `set_node()` ran, records also
+  carry ``node`` — the cross-process join key the traceview merger
+  aligns sinks on.
+* A span made by `span()` is a node of a tree: ``id`` (unique in the
+  process), ``parent`` (the span open on this thread when it began,
+  null at a root), ``root`` (the id of its tree's root: the spans of one
+  verify_commit, one ReplayEngine.run share it), ``t0_ns``/``t1_ns``
+  from time.perf_counter_ns(), and ``self_ms``: its duration less what
+  its direct children covered (each child adds its duration to its
+  parent at exit). A record written by emit() while a span is open on
+  the thread carries that span as ``parent``/``root``. Work that is one
+  unit but not nested in time shares a field instead (``window`` on the
+  spans of a replay window, ``batch`` on the result() of a submit).
+  configure() writes one ``trace.clock`` event pairing perf_counter_ns
+  with time_ns, which puts every span on the wall clock.
+* Under a profiler session the same spans lie in the profiler's trace:
+  while tracing is enabled and jax is ALREADY imported, entering a span
+  also enters jax.profiler.TraceAnnotation(name, span_id=id), on the
+  profiler's own clock beside the device operations
+  (utils/traceview.device_join reads both). This module never imports
+  jax.
+* Records are kept in memory and serialised by flush(): when
+  FLUSH_INTERVAL_S has passed at a record that closes with no span open
+  on its thread, when NESTED_FLUSH_INTERVALS of them have passed or
+  MAX_BUFFERED records wait at one that closes inside a span, by
+  `tail()`, `disable()` and at exit — never at every record (per-record
+  flushing costs a syscall per consensus wire message once the p2p
+  hooks are on), and as a rule not inside the span whose self time the
+  work would be booked to. A SIGKILLed node loses at most the last
+  interval's records (the last few under a long root span).
 * Fork safety: ``pid`` is re-stamped and the sink reopened via an
   at-fork hook, so a process forked after configure() never stamps the
   parent's pid on its records (and never shares the parent's buffered
-  file object).
+  file object or its unwritten records).
 * Sink selection: `configure(path)` from node config
   (``[instrumentation] trace_sink``), or the ``COMETBFT_TPU_TRACE``
   environment variable at import time (picked up by subprocess nodes
@@ -37,23 +60,51 @@ Design constraints:
 
 from __future__ import annotations
 
+import atexit
+import gc
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 
 enabled = False
 _path: str | None = None
 _fh = None
-_lock = threading.Lock()
+# re-entrant: a collection that starts while a flush serialises records
+# runs _on_gc, which queues its own record, on the same thread
+_lock = threading.RLock()
 _pid = os.getpid()
 _node = ""
 
-# bounded write staleness: flush at most this long after a record was
-# buffered (see module docstring — per-record flush is too expensive
-# once the p2p wire hooks multiply the record rate)
+# bounded write staleness: records wait in memory at most this long
+# before a record that closes outside any span flushes them (see module
+# docstring — per-record flush is too expensive once the p2p wire hooks
+# multiply the record rate)
 FLUSH_INTERVAL_S = 0.25
+# ... and this many intervals, or this many records, before one that
+# closes inside a span does (a replay holds one root span open for its
+# whole run)
+NESTED_FLUSH_INTERVALS = 8
+MAX_BUFFERED = 8192
 _last_flush = 0.0
+_buf: list[dict] = []
+
+_ids = itertools.count(1)
+_tls = threading.local()
+_annotation = None  # jax.profiler.TraceAnnotation once jax is imported
+
+# a collection is recorded when it is a full one or pauses longer
+GC_PAUSE_MIN_NS = 1_000_000
+
+
+def _stack() -> list:
+    try:
+        return _tls.stack
+    except AttributeError:
+        _tls.stack = []
+        return _tls.stack
 
 
 def configure(path: str) -> None:
@@ -61,6 +112,7 @@ def configure(path: str) -> None:
     global enabled, _path, _fh, _pid, _last_flush
     with _lock:
         if _fh is not None:
+            _write_locked()
             _fh.close()
         d = os.path.dirname(path)
         if d:
@@ -70,6 +122,10 @@ def configure(path: str) -> None:
         _pid = os.getpid()
         _last_flush = 0.0
         enabled = True
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    event("trace.clock", perf_ns=time.perf_counter_ns(),
+          time_ns=time.time_ns())
 
 
 def disable() -> None:
@@ -77,10 +133,14 @@ def disable() -> None:
     with _lock:
         enabled = False
         if _fh is not None:
+            _write_locked()
             _fh.close()
         _fh = None
         _path = None
         _node = ""
+        del _buf[:]
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
 
 
 def path() -> str | None:
@@ -103,13 +163,12 @@ def node_id() -> str:
 
 
 def _before_fork() -> None:
-    # Drain the buffer in the parent so the child's inherited copy is
-    # empty — otherwise the child's close() below would re-write records
-    # the parent also flushes later (duplicate lines in the sink).
+    # Write the records out in the parent so the child's inherited copy
+    # of the buffers is empty — otherwise the child would re-write
+    # records the parent also flushes later (duplicate lines in the
+    # sink).
     try:
-        with _lock:
-            if _fh is not None:
-                _fh.flush()
+        flush()
     except Exception:  # noqa: BLE001 — fork must proceed regardless
         pass
 
@@ -120,10 +179,11 @@ def _after_fork_in_child() -> None:
     # lock is replaced too: another thread may have held it at fork
     # time, which would deadlock the child forever.
     global _pid, _fh, _lock, _last_flush
-    _lock = threading.Lock()
+    _lock = threading.RLock()
     _pid = os.getpid()
+    _buf.clear()
     # first emit in the child flushes at once: multiprocessing children
-    # exit via os._exit(), which skips buffered-file shutdown
+    # exit via os._exit(), which skips atexit and buffered-file shutdown
     _last_flush = 0.0
     if _fh is not None:
         try:
@@ -142,59 +202,141 @@ if hasattr(os, "register_at_fork"):  # POSIX only; harmless otherwise
                         after_in_child=_after_fork_in_child)
 
 
+def _write_locked() -> None:
+    """Serialise the waiting records into the sink; `_lock` is held."""
+    global _last_flush, _buf
+    _last_flush = time.monotonic()
+    batch, _buf = _buf, []  # a record queued meanwhile waits its turn
+    if batch:
+        dumps = json.dumps
+        _fh.write("".join(
+            dumps(rec, separators=(",", ":"), default=str) + "\n"
+            for rec in batch))
+    _fh.flush()
+
+
+def _record(rec: dict, nested: bool) -> None:
+    """Queue one finished record; flush when the module docstring's
+    staleness rule says so."""
+    with _lock:
+        if _fh is None:  # raced with disable()
+            return
+        _buf.append(rec)
+        due = time.monotonic() - _last_flush
+        if due >= FLUSH_INTERVAL_S and (
+                not nested or len(_buf) >= MAX_BUFFERED
+                or due >= NESTED_FLUSH_INTERVALS * FLUSH_INTERVAL_S):
+            _write_locked()
+
+
 def emit(name: str, kind: str = "event", **fields) -> None:
-    """Write one record. No-op (single bool check) when disabled."""
+    """Queue one record. No-op (single bool check) when disabled."""
     if not enabled:
         return
     rec = {"ts": time.time(), "pid": _pid, "name": name, "kind": kind}
     if _node:
         rec["node"] = _node
+    stack = _stack()
+    if stack:
+        rec["parent"] = stack[-1].id
+        rec["root"] = stack[-1].root
     rec.update(fields)
-    line = json.dumps(rec, separators=(",", ":"), default=str) + "\n"
-    global _last_flush
-    with _lock:
-        if _fh is None:  # raced with disable()
-            return
-        _fh.write(line)
-        now = time.monotonic()
-        if now - _last_flush >= FLUSH_INTERVAL_S:
-            _fh.flush()
-            _last_flush = now
+    _record(rec, bool(stack))
 
 
 def flush() -> None:
-    """Force buffered records to disk (readers that bypass tail())."""
+    """Force the waiting records to disk (readers that bypass tail())."""
     with _lock:
         if _fh is not None:
-            _fh.flush()
+            _write_locked()
+
+
+atexit.register(flush)  # records still waiting when the process ends
 
 
 def event(name: str, **fields) -> None:
     emit(name, "event", **fields)
 
 
+def _find_annotation():
+    """jax.profiler.TraceAnnotation if jax is already imported (never
+    imported from here: a node that has not touched jax stays off it)."""
+    global _annotation
+    jax = sys.modules.get("jax")
+    cls = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+    if cls is not None:
+        _annotation = cls
+    return cls
+
+
 class _Span:
-    __slots__ = ("name", "fields", "_t0")
+    __slots__ = ("name", "fields", "id", "parent", "root", "t0_ns",
+                 "_child_ns", "_ann")
 
     def __init__(self, name: str, fields: dict):
         self.name = name
         self.fields = fields
+        self.id = next(_ids)
+        self._child_ns = 0
+        self._ann = None
 
     def add(self, **fields) -> None:
         self.fields.update(fields)
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        self._open(True)
         return self
 
     def __exit__(self, *exc):
-        dur_ms = (time.perf_counter() - self._t0) * 1e3
-        emit(self.name, "span", dur_ms=round(dur_ms, 3), **self.fields)
+        self._close(0)
         return False
+
+    def _open(self, annotate: bool) -> None:
+        stack = _stack()
+        if stack:
+            self.parent = stack[-1].id
+            self.root = stack[-1].root
+        else:
+            self.parent = None
+            self.root = self.id
+        stack.append(self)
+        ann = annotate and (_annotation or _find_annotation())
+        if ann:
+            self._ann = ann(self.name, span_id=self.id)
+            self._ann.__enter__()
+        self.t0_ns = time.perf_counter_ns()
+
+    def _close(self, min_ns: int) -> None:
+        """Pops the span and queues its record, unless it lasted less
+        than `min_ns` (then it leaves no mark on its parent either)."""
+        t1_ns = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        stack = _stack()
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:  # closed out of order: drop it and the
+            del stack[stack.index(self):]  # spans left open inside it
+        dur_ns = t1_ns - self.t0_ns
+        if dur_ns < min_ns or not enabled:
+            return
+        if stack:
+            stack[-1]._child_ns += dur_ns
+        rec = {"ts": time.time(), "pid": _pid, "name": self.name,
+               "kind": "span"}
+        if _node:
+            rec["node"] = _node
+        rec.update(id=self.id, parent=self.parent, root=self.root,
+                   t0_ns=self.t0_ns, t1_ns=t1_ns,
+                   dur_ms=round(dur_ns / 1e6, 3),
+                   self_ms=round((dur_ns - self._child_ns) / 1e6, 3))
+        rec.update(self.fields)
+        _record(rec, bool(stack))
 
 
 class _NoopSpan:
     __slots__ = ()
+    id = None
 
     def add(self, **fields) -> None:
         pass
@@ -210,10 +352,31 @@ _NOOP = _NoopSpan()
 
 
 def span(name: str, **fields):
-    """Context manager timing a block; writes one span record on exit."""
+    """Context manager timing a block; queues one span record on exit."""
     if not enabled:
         return _NOOP
     return _Span(name, fields)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """gc.callbacks hook (installed by configure()): one
+    ``runtime.gc_pause`` span per full collection, or per pause over
+    GC_PAUSE_MIN_NS, as a child of whatever span the collection
+    interrupted — so that span's self time excludes the pause, and the
+    record says WHICH span a long pause fell in. Only a full collection
+    is worth an annotation in the profiler's trace."""
+    if phase == "start":
+        if enabled:
+            full = info["generation"] == 2
+            _tls.gc_span = sp = _Span(
+                "runtime.gc_pause", {"generation": info["generation"]})
+            sp._open(full)
+        return
+    sp = getattr(_tls, "gc_span", None)
+    if sp is not None:
+        _tls.gc_span = None
+        sp.fields["collected"] = info.get("collected", 0)
+        sp._close(0 if sp.fields["generation"] == 2 else GC_PAUSE_MIN_NS)
 
 
 def tail(n: int = 100) -> list[dict]:
@@ -227,9 +390,7 @@ def tail(n: int = 100) -> list[dict]:
     p = _path
     if p is None or not os.path.exists(p):
         return []
-    with _lock:
-        if _fh is not None:
-            _fh.flush()
+    flush()
     with open(p, "rb") as f:
         window = 256 * 1024
         while True:
@@ -315,14 +476,31 @@ class TailReader:
 # reconstruction on these names, so renaming one is a cross-cutting
 # change, not a local edit.
 SPAN_REGISTRY = {
+    "trace.clock": "written by configure(): perf_ns (time.perf_counter_ns, the clock of t0_ns/t1_ns) paired with time_ns (the wall clock of a profiler session's start)",
+    "runtime.gc_pause": "one garbage collection that was full or paused over 1 ms, a child of the span it interrupted (generation/collected)",
     "node.boot": "node identity: moniker + full node id, once per process start",
     "consensus.step": "span closing the consensus step being left (height/round/dur_ms/next)",
     "consensus.finalize_commit": "block decided at height/round, with tx count",
     "consensus.propose_speculative": "one speculative proposal assembly overlapping the previous height's commit gap (height/txs/bytes)",
     "consensus.cert_aggregate": "one aggregate-precommit certificate verified from catchup gossip (height/round/signers/outcome/dur_ms)",
     "state.apply_block": "ApplyBlock with validate/finalize/commit/save stage breakdown",
+    "types.verify_commit": "one verify_commit / verify_commit_light (height/n = signatures judged/light); self_ms is the entry layer from inside",
+    "types.commit_items": "the per-signature loop of one commit: CommitSig access, address check, sign bytes (n/sign_bytes_ms accumulated)",
+    "types.verify_items_fill": "_verify_items up to the first submit: grouping by key type and the add() loop (n/groups/singles)",
     "blocksync.block": "one fast-synced block: fetch→verify→apply breakdown",
-    "crypto.batch_verify": "one batch-verify dispatch: path, n, modeled host/wire/device terms",
+    "blocksync.replay": "one ReplayEngine.run (from/to/depth/mode); self_ms is the engine loop",
+    "blocksync.window_load": "blocks of one replay window read and decoded from the store (window = first height/blocks)",
+    "blocksync.window_queue": "every signature check of one replay window queued and submitted (window/blocks)",
+    "blocksync.window_fill": "the commits of one window filled into the batch verifier (window/commits/lanes/columnar = commits on the columnar path)",
+    "blocksync.window_resolve": "one window's verdict awaited and its +2/3 tallies checked (window/sigs)",
+    "blocksync.window_apply": "the blocks of one verified window applied (window/blocks/txs); children state.apply_block",
+    "crypto.batch_verify": "one batch-verify dispatch, the host time inside submit() (path/n/bucket); its children split it",
+    "crypto.materialize": "lazy whole-commit columns expanded into per-item tuples for one dispatch (n = lanes expanded, 0 when add() already built them)",
+    "crypto.rlc_prepare": "host RLC layout for one dispatch: coefficients, digit stream, lane budget (n/declined)",
+    "crypto.pack": "fixed-shape wire arrays of one dispatch built on the host (n/bucket)",
+    "crypto.device_launch": "jax.device_put of one dispatch's wire arrays plus the jitted call's return (bytes)",
+    "crypto.native_verify": "one batch judged by the host C++ engine, blame rescan included (n/ok)",
+    "crypto.verdict_wait": "result() blocking on one batch's verdict (path/n/batch = id of its crypto.batch_verify/since_submit_ms/blame_rerun)",
     "crypto.commit_partition": "per-curve share of one commit verification",
     "crypto.bls_aggregate": "one BLS partition collapsed to aggregate pairing check(s) (n/pairing_checks)",
     "crypto.mesh_submit": "one sharded mega-batch across the verify mesh (n/b/n_devices/shard_lanes)",
@@ -345,6 +523,34 @@ SPAN_REGISTRY = {
     "consensus.conflicting_vote": "conflicting signed votes from one validator at one HRS (height/round/type/vote_a/vote_b hex) — the watchtower's equivocation feed",
     "watchtower.audit": "one audited feed frame: every check run against a height (node/height/checks/dur_ms)",
     "watchtower.verdict": "one watchtower finding (check/node/height/safety/detail) — safety verdicts fail an audited e2e run",
+}
+
+
+# Kernel-scope registry: every phase of ops/ (a jax.named_scope) and
+# every pallas_call name= there (a scope inside its phase) must be declared
+# here and every declared name must be in use (tools/trace_lint.py, both
+# directions). A scope is metadata of the
+# compiled program: it reaches the profiler's trace as a component of
+# each device operation's op_name, which is how utils/traceview
+# device_join books device time to a phase that survives an edit to
+# ops/ (HLO instruction numbers do not).
+KERNEL_SCOPES = {
+    "rlc.decompress": "RLC program: ZIP-215 decoding of A and R, affine niels table",
+    "rlc.expand_stream": "RLC program: dense contribution stream -> (S, WK) gather table",
+    "rlc.accumulate": "RLC program: S rounds of lane-parallel mixed adds into the bucket lanes (row gather + msm_accumulate_weighted on the chip)",
+    "rlc.bucket_reduce": "RLC program: bucket lanes folded to per-window sums",
+    "rlc.window_combine": "RLC program: Horner over the windows' sums",
+    "rlc.final_check": "RLC program: + [c]B, cofactor clearing, identity test, decode flags",
+    "ladder.decompress": "per-lane program: ZIP-215 decoding of R (and of A in decompress_pubkeys)",
+    "ladder.sha512": "per-lane program: SHA-512(R||A||M) on the device (device_sha and delta wires)",
+    "ladder.scalar_reduce": "per-lane program: k mod L, signed-digit recoding of s and k, S < L",
+    "ladder.double_scalar": "per-lane program: [8]([s]B + [k](-A) - R), one fused kernel on the chip",
+    "ladder.compare": "per-lane program: identity test, lane bitmap and its all-ok summary",
+    "curve_decompress": "pallas kernel (ops/curve.py): fused sqrt candidate and checks",
+    "curve_ladder_sub_mul8": "pallas kernel (ops/curve.py): the whole double-scalar ladder",
+    "field_mul": "pallas kernel (ops/field.py): one 22-limb field multiply outside a fused kernel",
+    "field_sq": "pallas kernel (ops/field.py): one field squaring outside a fused kernel",
+    "msm_accumulate_weighted": "pallas kernel (ops/msm.py): weighted bucket accumulation over the gathered stream",
 }
 
 

@@ -571,6 +571,253 @@ def merge(paths) -> MergedTrace:
 
 
 # ----------------------------------------------------------------------
+# the join of a span sink with a profiler trace (ISSUE 24): why was the
+# chip idle, and which phase of which program kept it busy
+# ----------------------------------------------------------------------
+NO_SPAN = "host:outside any span"
+NO_SCOPE = "(no scope)"
+
+
+def _union(intervals) -> list[list[float]]:
+    out: list[list[float]] = []
+    for s, e in sorted(intervals):
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return out
+
+
+def _innermost_segments(spans: list[dict]) -> list[tuple]:
+    """[(t0, t1, span)] over the stretches where some span is open, the
+    span being the innermost one: the shortest that covers the stretch
+    (spans of one thread nest, so the shortest is the deepest)."""
+    edges = sorted({t for sp in spans
+                    for t in (sp["start_ns"], sp["start_ns"] + sp["dur_ns"])})
+    starts = sorted(spans, key=lambda sp: sp["start_ns"])
+    out, active, nxt = [], [], 0
+    for t0, t1 in zip(edges, edges[1:]):
+        while nxt < len(starts) and starts[nxt]["start_ns"] <= t0:
+            active.append(starts[nxt])
+            nxt += 1
+        active = [sp for sp in active
+                  if sp["start_ns"] + sp["dur_ns"] >= t1]
+        if active:
+            out.append((t0, t1, min(active, key=lambda sp: sp["dur_ns"])))
+    return out
+
+
+def _scope_of(op_name: str, scopes) -> tuple[str | None, str | None]:
+    """(phase, kernel) of an operation's op_name: its outermost and its
+    innermost component that is a registered kernel scope. The phase is
+    what device time is booked to; a pallas kernel's name= lies inside
+    its phase ("jit(f)/rlc.accumulate/msm_accumulate_weighted/
+    pallas_call") and names the operation in place of its HLO
+    instruction ("tpu_custom_call.27"). kernel is None where the two are
+    one."""
+    found = [part for part in op_name.split("/") if part in scopes]
+    if not found:
+        return None, None
+    return found[0], found[-1] if found[-1] != found[0] else None
+
+
+def _device_time_by_scope(ops: list[dict], lo: float, hi: float,
+                          scopes) -> tuple[dict, dict]:
+    """Self time of every operation inside [lo, hi) by kernel scope, and
+    by (scope, op). An operation that encloses others on its line (a
+    while loop and its body) keeps only the time its children leave. A
+    scope is the operation's own (from its op_name), else the enclosing
+    operation's, else the one most of the time of its nearest scoped
+    descendants carries (which its unscoped children then inherit); the
+    second result says how much time was booked each way."""
+    ops = sorted((o for o in ops if o["start_ns"] < hi
+                  and o["start_ns"] + o["dur_ns"] > lo),
+                 key=lambda o: (o["start_ns"], -o["dur_ns"]))
+    nodes, stack = [], []
+    for o in ops:
+        own, kernel = _scope_of(o.get("op_name") or "", scopes)
+        node = {"op": kernel or o["op"], "s": max(o["start_ns"], lo),
+                "e": min(o["start_ns"] + o["dur_ns"], hi),
+                "own": own, "kids": []}
+        while stack and stack[-1]["e"] <= node["s"]:
+            stack.pop()
+        (stack[-1]["kids"] if stack else nodes).append(node)
+        stack.append(node)
+    by_scope: dict[str, float] = defaultdict(float)
+    by_op: dict[tuple, float] = defaultdict(float)
+    how: dict[str, float] = defaultdict(float)
+
+    def carried(node) -> dict:
+        """Time by scope of the nearest descendants that name one."""
+        out: dict[str, float] = defaultdict(float)
+        for kid in node["kids"]:
+            if kid["own"]:
+                out[kid["own"]] += kid["e"] - kid["s"]
+            else:
+                for k, v in carried(kid).items():
+                    out[k] += v
+        return out
+
+    def book(node, inherited) -> None:
+        scope, way = node["own"] or inherited, "own"
+        if not node["own"]:
+            way = "enclosing"
+            if scope is None:
+                sub = carried(node)
+                if sub:
+                    scope = max(sub.items(), key=lambda kv: kv[1])[0]
+                    way = "children"
+        self_ns = (node["e"] - node["s"]
+                   - sum(k["e"] - k["s"] for k in node["kids"]))
+        by_scope[scope or NO_SCOPE] += self_ns
+        by_op[(scope or NO_SCOPE, node["op"])] += self_ns
+        how[way if scope else "none"] += self_ns
+        for kid in node["kids"]:
+            book(kid, scope)
+
+    for node in nodes:
+        book(node, None)
+    return ({"scope": dict(by_scope), "op": dict(by_op)}, dict(how))
+
+
+def device_join(xp: dict, records: list[dict] | None = None,
+                stretch: tuple[float, float] | None = None,
+                scopes=()) -> dict:
+    """Join a profiler trace (utils/xplane.load) with a span sink.
+
+    For the stretch [lo, hi) in ns since the session began (default:
+    first to last event kept): the busiest device's idle time by the
+    innermost PROGRAM span the host was in, and every device's busy time
+    by kernel scope (`scopes`: trace.KERNEL_SCOPES). With `records` an
+    idle row is labelled by the span's whole ancestry ("root > ... >
+    leaf", joined to the sink by span id), which tells a
+    crypto.batch_verify under verify_commit from one under a replay
+    window; without, by the span's name alone."""
+    dev = [p for p in xp["planes"] if p.get("ops")]
+    spans = [sp for p in xp["planes"] for sp in p.get("spans", [])]
+    times = [t for p in dev for o in p["ops"]
+             for t in (o["start_ns"], o["start_ns"] + o["dur_ns"])]
+    times += [t for sp in spans
+              for t in (sp["start_ns"], sp["start_ns"] + sp["dur_ns"])]
+    if not times:
+        raise ValueError("the profiler trace holds no device operation "
+                         "and no program span")
+    lo, hi = stretch if stretch is not None else (min(times), max(times))
+
+    busiest, per_dev = None, []
+    scope_ns: dict[str, float] = defaultdict(float)
+    op_ns: dict[tuple, float] = defaultdict(float)
+    how_ns: dict[str, float] = defaultdict(float)
+    for p in dev:
+        busy = _union((max(o["start_ns"], lo),
+                       min(o["start_ns"] + o["dur_ns"], hi))
+                      for o in p["ops"] if o["start_ns"] < hi
+                      and o["start_ns"] + o["dur_ns"] > lo)
+        total = sum(e - s for s, e in busy)
+        if total <= 0:
+            continue
+        per_dev.append((p["name"], total))
+        if busiest is None or total > busiest[1]:
+            busiest = (p["name"], total, busy)
+        by, how = _device_time_by_scope(p["ops"], lo, hi, scopes)
+        for k, v in by["scope"].items():
+            scope_ns[k] += v
+        for k, v in by["op"].items():
+            op_ns[k] += v
+        for k, v in how.items():
+            how_ns[k] += v
+
+    by_id = {r["id"]: r for r in records or ()
+             if r.get("kind") == "span" and "id" in r}
+
+    def label(sp: dict) -> str:
+        names, rec = [sp["name"]], by_id.get(sp["span_id"])
+        while rec is not None and rec.get("parent") is not None:
+            rec = by_id.get(rec["parent"])
+            if rec is not None:
+                names.append(rec["name"])
+        return " > ".join(reversed(names))
+
+    idle: dict[str, float] = defaultdict(float)
+    if busiest is not None:
+        edges = [lo] + [t for iv in busiest[2] for t in iv] + [hi]
+        gaps = [(a, b) for a, b in zip(edges[0::2], edges[1::2]) if b > a]
+        segs = _innermost_segments(spans)
+        i = 0
+        for g0, g1 in gaps:
+            covered = 0.0
+            while i < len(segs) and segs[i][1] <= g0:
+                i += 1
+            j = i
+            while j < len(segs) and segs[j][0] < g1:
+                t0, t1, sp = segs[j]
+                part = min(t1, g1) - max(t0, g0)
+                if part > 0:
+                    idle[label(sp)] += part
+                    covered += part
+                j += 1
+            idle[NO_SPAN] += (g1 - g0) - covered
+    idle_ns = sum(idle.values())
+    busy_ns = sum(scope_ns.values())
+
+    def top(d: dict, n: int = 0) -> list:
+        rows = sorted(d.items(), key=lambda kv: -kv[1])
+        return [[k, v / 1e9] for k, v in (rows[:n] if n else rows)
+                if v > 0]
+
+    return {
+        "stretch_s": (hi - lo) / 1e9,
+        "devices": [[name, ns / 1e9] for name, ns in per_dev],
+        "busiest": busiest[0] if busiest else None,
+        "busy_s": busiest[1] / 1e9 if busiest else 0.0,
+        "idle_s": idle_ns / 1e9,
+        "idle_by_span": top(idle),
+        "idle_named_share": (1.0 - idle.get(NO_SPAN, 0.0) / idle_ns
+                             if idle_ns else None),
+        "busy_by_scope": top(scope_ns),
+        "busy_scoped_share": (1.0 - scope_ns.get(NO_SCOPE, 0.0) / busy_ns
+                              if busy_ns else None),
+        "busy_booked_by": top(how_ns),
+        "ops_by_scope": {
+            scope: top({op: v for (sc, op), v in op_ns.items()
+                        if sc == scope}, 5)
+            for scope in scope_ns},
+        "spans_joined": sum(1 for sp in spans if sp["span_id"] in by_id),
+        "spans_in_trace": len(spans),
+    }
+
+
+def render_device_join(j: dict) -> str:
+    lines = ["stretch %.4f s; %d device(s) ran operations; busiest %s: "
+             "busy %.4f s, idle %.4f s (%.1f%%)" % (
+                 j["stretch_s"], len(j["devices"]), j["busiest"],
+                 j["busy_s"], j["idle_s"],
+                 100 * j["idle_s"] / j["stretch_s"] if j["stretch_s"] else 0)]
+    lines.append("program spans in the trace: %d, of them in the sink: %d"
+                 % (j["spans_in_trace"], j["spans_joined"]))
+    if j["idle_named_share"] is not None:
+        lines.append("idle time of the busiest device by the innermost "
+                     "program span the host was in (%.1f%% inside a span):"
+                     % (100 * j["idle_named_share"]))
+        for name, s in j["idle_by_span"]:
+            lines.append("  %9.4f s  %5.1f%%  %s" % (
+                s, 100 * s / j["idle_s"], name))
+    if j["busy_scoped_share"] is not None:
+        busy = sum(s for _k, s in j["busy_by_scope"])
+        lines.append("device time by kernel scope, all devices (%.1f%% "
+                     "under a registered scope; booked by: %s):" % (
+                         100 * j["busy_scoped_share"],
+                         ", ".join("%s %.1f%%" % (k, 100 * s / busy)
+                                   for k, s in j["busy_booked_by"])))
+        for scope, s in j["busy_by_scope"]:
+            ops = ", ".join("%s %.4f" % (op, t)
+                            for op, t in j["ops_by_scope"][scope])
+            lines.append("  %9.4f s  %5.1f%%  %-20s %s" % (
+                s, 100 * s / busy, scope, ops))
+    return "\n".join(lines)
+
+
+# ----------------------------------------------------------------------
 # text renderers (tools/trace_analyze.py and the e2e runner's report)
 # ----------------------------------------------------------------------
 def render_summary(mt: MergedTrace) -> str:

@@ -168,3 +168,105 @@ def test_replay_deep_pipeline_matches(chain):
     assert sa.sigs_verified == sb.sigs_verified > 0  # depth never changes lanes
     assert a.app_hash == b.app_hash == final_state.app_hash
     assert a.last_block_height == b.last_block_height == 8
+
+
+# ----------------------------------------------------------------------
+# ISSUE 24: the span tree of one ReplayEngine.run
+# ----------------------------------------------------------------------
+def test_traced_replay_gives_one_tree_with_per_window_spans(
+        chain, tmp_path, monkeypatch):
+    """Batched replay of the 8-block chain, windows of 3, with tracing on
+    and the batches on the device path (NATIVE_MAX 0: XLA:CPU ladder at
+    bucket 64), so each window's verdict is really waited for."""
+    import json
+    import os
+
+    from cometbft_tpu.crypto import ed25519
+    from cometbft_tpu.utils import trace
+
+    store, final_state, genesis, _ = chain
+    monkeypatch.setattr(ed25519, "NATIVE_MAX", 0)
+    sink = os.path.join(str(tmp_path), "replay.jsonl")
+    executor = BlockExecutor(AppConns(KVStoreApp()))
+    engine = ReplayEngine(store, executor, verify_mode="batched", window=3)
+    trace.configure(sink)
+    try:
+        state, stats = engine.run(genesis.copy())
+        trace.flush()
+        with open(sink, encoding="utf-8") as f:
+            recs = [json.loads(line) for line in f]
+    finally:
+        trace.disable()
+    assert state.app_hash == final_state.app_hash and stats.blocks == 8
+    recs = [r for r in recs
+            if r["name"] not in ("trace.clock", "runtime.gc_pause")]
+    by_id = {r["id"]: r for r in recs if "id" in r}
+    (root,) = [r for r in recs if r["name"] == "blocksync.replay"]
+    assert root["parent"] is None
+    assert (root["from"], root["to"], root["depth"]) == (1, 8, 2)
+    assert {r["root"] for r in recs} == {root["id"]}  # one tree
+
+    def of(name):
+        return [r for r in recs if r["name"] == name]
+
+    # every window has its five spans, tied by `window` = first height
+    for name in ("blocksync.window_load", "blocksync.window_queue",
+                 "blocksync.window_fill", "blocksync.window_resolve",
+                 "blocksync.window_apply"):
+        assert sorted(r["window"] for r in of(name)) == [1, 4, 7], name
+    for r in of("blocksync.window_fill"):
+        assert by_id[r["parent"]]["name"] == "blocksync.window_queue"
+        assert by_id[r["parent"]]["window"] == r["window"]
+        assert r["columnar"] == r["commits"]  # decoded ed25519 commits
+    assert {r["window"]: (r["commits"], r["lanes"])
+            for r in of("blocksync.window_fill")} == {
+        1: (3, 12), 4: (4, 16), 7: (3, 12)}
+    assert {r["window"]: (r["blocks"], r["txs"])
+            for r in of("blocksync.window_apply")} == {
+        1: (3, 6), 4: (3, 6), 7: (2, 4)}
+    # apply: one state.apply_block per block, under its window
+    applies = of("state.apply_block")
+    assert sorted(r["height"] for r in applies) == list(range(1, 9))
+    for r in applies:
+        win = by_id[r["parent"]]
+        assert win["name"] == "blocksync.window_apply"
+        assert win["window"] <= r["height"] < win["window"] + 3
+        assert {"validate_ms", "finalize_ms", "commit_ms",
+                "save_events_ms"} <= r.keys()
+    # one dispatch per window, with its children, and a verdict wait
+    # whose `batch` is the submit that it resolves
+    submits = of("crypto.batch_verify")
+    assert [(r["path"], r["n"], r["bucket"]) for r in submits] == [
+        ("ladder", 12, 64), ("ladder", 16, 64), ("ladder", 12, 64)]
+    for r in submits:
+        kids = {k["name"] for k in recs if k.get("parent") == r["id"]}
+        assert kids == {"crypto.pack", "crypto.device_launch"}
+        assert by_id[r["parent"]]["name"] == "blocksync.window_queue"
+    waits = of("crypto.verdict_wait")
+    assert [w["batch"] for w in waits] == [r["id"] for r in submits]
+    for w in waits:
+        resolve = by_id[w["parent"]]
+        assert resolve["name"] == "blocksync.window_resolve"
+        queue = by_id[by_id[w["batch"]]["parent"]]
+        assert queue["window"] == resolve["window"]
+        assert w["path"] == "ladder" and not w["blame_rerun"]
+        assert w["since_submit_ms"] >= w["dur_ms"]
+    # per window, never per lane: 4 validators here, and the same
+    # spans a window whatever the validator count
+    per_window = [r for r in recs if r.get("window") == 4
+                  or by_id.get(r.get("parent"), {}).get("window") == 4]
+    assert len([r for r in per_window
+                if r["name"] != "state.apply_block"]) == 7
+
+
+def test_untraced_replay_emits_nothing(chain):
+    from cometbft_tpu.utils import trace
+
+    store, final_state, genesis, _ = chain
+    assert not trace.enabled
+    executor = BlockExecutor(AppConns(KVStoreApp()), backend="cpu")
+    engine = ReplayEngine(store, executor, window=3, backend="cpu")
+    state, _stats = engine.run(genesis.copy())
+    assert state.app_hash == final_state.app_hash
+    assert trace.tail() == [] and trace.path() is None
+    assert trace.span("blocksync.replay") is trace.span("state.apply_block")

@@ -19,6 +19,7 @@ described chip cannot be read back without one); all in this one file.
 not through an option of the program.
 """
 
+import re
 import time
 
 import jax
@@ -82,15 +83,32 @@ def chip(one_chip, no_persistent_cache, monkeypatch):
     return one_chip
 
 
-def _compile(fn, *args, **static):
+def _compile(fn, *args, scopes=(), kernels=(), **static):
     """Lower + compile a FRESH jit of fn (a fresh function identity: the
     program's own module-level jits may hold a CPU trace of the same
     shapes from another test of this worker). Returns the compiled
-    program after the checks every guard shares."""
+    program after the checks every guard shares. `scopes`: phases of
+    trace.KERNEL_SCOPES that must be in the op_name of operations of the
+    COMPILED program, which is what a profiler trace carries and
+    utils/traceview.device_join reads (ops/__init__.py says which jax
+    setting that hangs on). `kernels`: pallas name= that must be in a
+    kernel's op_name, inside its phase."""
+    from cometbft_tpu.utils.trace import KERNEL_SCOPES
+
     t0 = time.perf_counter()
     fresh = jax.jit(lambda *a: fn(*a, **static))
     compiled = fresh.lower(*args).compile()
     dt = time.perf_counter() - t0
+    names = set(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
+    for name in scopes:
+        assert name in KERNEL_SCOPES, name
+        assert any(f"/{name}/" in n for n in names), (
+            f"no operation of the compiled program under scope {name}")
+    for name in kernels:
+        assert name in KERNEL_SCOPES, name
+        assert any(n.endswith(f"/{name}/pallas_call") and
+                   any(p in KERNEL_SCOPES for p in n.split("/")[:-2])
+                   for n in names), f"no kernel named {name} in a phase"
     mem = compiled.memory_analysis()
     print(f"\n  {fn.__name__}: compiled in {dt:.1f}s, "
           f"temp {mem.temp_size_in_bytes / 1e6:.1f} MB, "
@@ -162,7 +180,9 @@ def test_cache_key_does_not_depend_on_the_caller(chip):
 
 @pytest.mark.parametrize("b", [1024, 10240])
 def test_decompress_pubkeys_compiles_for_v5e(chip, b):
-    c = _compile(EV.decompress_pubkeys, _S((b, 32), jnp.uint8, chip))
+    c = _compile(EV.decompress_pubkeys, _S((b, 32), jnp.uint8, chip),
+                 scopes=("ladder.decompress",),
+                 kernels=("curve_decompress",))
     assert _kernels(c) == 1
 
 
@@ -170,7 +190,10 @@ def test_decompress_pubkeys_compiles_for_v5e(chip, b):
 def test_ladder_compiles_for_v5e(chip, b):
     """verify_batch_cached_a: the production ladder entry (R decompress
     kernel + the fused ladder kernel)."""
-    c = _compile(EV.verify_batch_cached_a, *_ladder_args(b, chip))
+    c = _compile(EV.verify_batch_cached_a, *_ladder_args(b, chip),
+                 scopes=("ladder.scalar_reduce", "ladder.decompress",
+                         "ladder.double_scalar", "ladder.compare"),
+                 kernels=("curve_decompress", "curve_ladder_sub_mul8"))
     assert _kernels(c) == 2
 
 
@@ -242,6 +265,10 @@ def test_rlc_compiles_for_v5e(chip):
         *(_S(prep[k].shape, prep[k].dtype, chip)
           for k in ("stream", "stream_neg", "counts", "weights",
                     "c_digits")),
+        scopes=("rlc.expand_stream", "rlc.decompress", "rlc.accumulate",
+                "rlc.bucket_reduce", "rlc.window_combine",
+                "rlc.final_check"),
+        kernels=("curve_decompress", "msm_accumulate_weighted", "field_mul"),
         s_rounds=s_pad,
     )
     assert _kernels(c) >= 3  # A, R decompress + the accumulate kernel

@@ -7,11 +7,22 @@ import subprocess
 import sys
 import time
 
+import pytest
+
 from cometbft_tpu.utils import trace
 
 
 def _cleanup():
     trace.disable()
+
+
+def _read(sink):
+    """The sink's records less the tracer's own (configure() writes
+    trace.clock; a collection may add runtime.gc_pause anywhere)."""
+    with open(sink, encoding="utf-8") as f:
+        recs = [json.loads(line) for line in f]
+    return [r for r in recs
+            if r["name"] not in ("trace.clock", "runtime.gc_pause")]
 
 
 def test_tracer_disabled_is_noop_and_cheap():
@@ -54,10 +65,7 @@ def test_tracer_jsonl_schema_and_tail(tmp_path):
         with trace.span("state.apply_block", height=4, txs=7) as s:
             s.add(validate_ms=0.1)
         trace.flush()  # writes are buffered with bounded staleness
-        records = [
-            json.loads(line)
-            for line in open(sink, encoding="utf-8")
-        ]
+        records = _read(sink)
         assert len(records) == 2
         for rec in records:
             # every record carries the merge-safe envelope
@@ -68,8 +76,9 @@ def test_tracer_jsonl_schema_and_tail(tmp_path):
         assert sp["kind"] == "span" and sp["name"] == "state.apply_block"
         assert sp["dur_ms"] >= 0 and sp["validate_ms"] == 0.1
         # tail() (the dump_trace RPC backend) parses the same records
-        assert [r["name"] for r in trace.tail(10)] == [
-            "consensus.step", "state.apply_block",
+        assert [r["name"] for r in trace.tail(10)
+                if r["name"] != "runtime.gc_pause"] == [
+            "trace.clock", "consensus.step", "state.apply_block",
         ]
         assert trace.tail(1)[0]["name"] == "state.apply_block"
     finally:
@@ -77,7 +86,7 @@ def test_tracer_jsonl_schema_and_tail(tmp_path):
     # after disable, the sink is closed and writes are dropped
     assert trace.enabled is False
     trace.emit("late")
-    assert sum(1 for _ in open(sink, encoding="utf-8")) == 2
+    assert len(_read(sink)) == 2
 
 
 def test_tail_window_grows_past_initial_seek(tmp_path):
@@ -90,13 +99,16 @@ def test_tail_window_grows_past_initial_seek(tmp_path):
         pad = "x" * 220  # ~260 B/record -> 3000 records ≈ 780 KiB
         for i in range(3000):
             trace.event("grow", i=i, pad=pad)
+        trace.flush()  # records wait in memory until a flush
         assert os.path.getsize(sink) > 256 * 1024
-        got = trace.tail(2500)
+        got = [r for r in trace.tail(2600) if r["name"] == "grow"][-2500:]
         assert len(got) == 2500
         assert got[0]["i"] == 500 and got[-1]["i"] == 2999
         # n beyond the file returns every record, first line included
-        assert len(trace.tail(100_000)) == 3000
-        assert trace.tail(100_000)[0]["i"] == 0
+        everything = trace.tail(100_000)
+        assert everything[0]["name"] == "trace.clock"
+        grown = [r for r in everything if r["name"] == "grow"]
+        assert len(grown) == 3000 and grown[0]["i"] == 0
     finally:
         _cleanup()
 
@@ -139,7 +151,7 @@ def test_set_node_first_caller_wins(tmp_path):
         assert trace.node_id() == "aabb" * 10
         trace.event("after")
         trace.flush()
-        recs = [json.loads(line) for line in open(sink, encoding="utf-8")]
+        recs = _read(sink)
         assert "node" not in recs[0]
         assert recs[1]["node"] == "aabb" * 10
     finally:
@@ -163,5 +175,219 @@ def test_tracer_env_var_configures_subprocess(tmp_path):
         capture_output=True, text=True, timeout=60,
     )
     assert p.returncode == 0, p.stderr[-2000:]
-    recs = [json.loads(line) for line in open(sink, encoding="utf-8")]
+    recs = _read(sink)  # written at exit: nothing flushed them before
     assert recs and recs[0]["name"] == "boot" and recs[0]["ok"] == 1
+
+
+# ----------------------------------------------------------------------
+# ISSUE 24: the span tree (id / parent / root / t0_ns / t1_ns / self_ms),
+# records held in memory, the collector's pauses, the profiler's clock
+# ----------------------------------------------------------------------
+def _spans(sink):
+    return {r["name"]: r for r in _read(sink) if r["kind"] == "span"}
+
+
+def test_nested_spans_form_one_tree_with_self_time(tmp_path):
+    sink = os.path.join(str(tmp_path), "tree.jsonl")
+    trace.configure(sink)
+    try:
+        with trace.span("root", height=7) as root:
+            with trace.span("a") as a:
+                time.sleep(0.02)
+                trace.event("mark", k=1)
+                with trace.span("a.inner"):
+                    time.sleep(0.01)
+            with trace.span("b"):
+                time.sleep(0.01)
+            root.add(n=3)
+        trace.flush()
+        sp = _spans(sink)
+        ids = [sp[n]["id"] for n in ("root", "a", "a.inner", "b")]
+        assert len(set(ids)) == 4 and a.id == sp["a"]["id"]
+        assert sp["root"]["parent"] is None
+        assert sp["a"]["parent"] == sp["b"]["parent"] == sp["root"]["id"]
+        assert sp["a.inner"]["parent"] == sp["a"]["id"]
+        assert {r["root"] for r in sp.values()} == {sp["root"]["id"]}
+        for r in sp.values():
+            assert r["t0_ns"] <= r["t1_ns"]
+            assert abs((r["t1_ns"] - r["t0_ns"]) / 1e6 - r["dur_ms"]) < 1e-3
+            assert {"ts", "pid", "name", "kind", "dur_ms"} <= r.keys()
+        # children lie inside their parent, on one clock
+        assert sp["root"]["t0_ns"] <= sp["a"]["t0_ns"]
+        assert sp["a"]["t1_ns"] <= sp["b"]["t0_ns"] <= sp["root"]["t1_ns"]
+        # self time = duration less what the DIRECT children covered
+        assert sp["root"]["self_ms"] == pytest.approx(
+            sp["root"]["dur_ms"] - sp["a"]["dur_ms"] - sp["b"]["dur_ms"],
+            abs=0.01)
+        assert sp["a"]["self_ms"] == pytest.approx(
+            sp["a"]["dur_ms"] - sp["a.inner"]["dur_ms"], abs=0.01)
+        assert sp["a"]["self_ms"] >= 19 and sp["b"]["self_ms"] >= 9
+        assert sp["root"]["self_ms"] < 5
+        assert sp["root"]["height"] == 7 and sp["root"]["n"] == 3
+        # a bare record inside a span knows the span that caused it
+        mark = next(r for r in _read(sink) if r["name"] == "mark")
+        assert mark["parent"] == sp["a"]["id"]
+        assert mark["root"] == sp["root"]["id"]
+        # the clock pair that puts t0_ns on the wall clock
+        clock = next(r for r in trace.tail(1000)
+                     if r["name"] == "trace.clock")
+        wall = clock["time_ns"] + sp["root"]["t1_ns"] - clock["perf_ns"]
+        assert abs(wall / 1e9 - sp["root"]["ts"]) < 0.05
+    finally:
+        _cleanup()
+
+
+def test_spans_of_two_threads_keep_their_own_trees(tmp_path):
+    import threading
+
+    sink = os.path.join(str(tmp_path), "threads.jsonl")
+    trace.configure(sink)
+    try:
+        go = threading.Barrier(2, timeout=30)
+
+        def work(tag):
+            with trace.span("root", tag=tag):
+                go.wait()  # both roots open at once
+                for _ in range(50):
+                    with trace.span("leaf", tag=tag):
+                        pass
+
+        threads = [threading.Thread(target=work, args=(t,)) for t in "xy"]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+            assert not t.is_alive()
+        trace.flush()
+        recs = [r for r in _read(sink) if r["kind"] == "span"]
+        roots = {r["tag"]: r for r in recs if r["name"] == "root"}
+        leaves = [r for r in recs if r["name"] == "leaf"]
+        assert len(roots) == 2 and len(leaves) == 100
+        assert len({r["id"] for r in recs}) == 102
+        for leaf in leaves:  # never adopted by the other thread's root
+            assert leaf["parent"] == leaf["root"] == roots[leaf["tag"]]["id"]
+        for r in roots.values():
+            assert r["parent"] is None and r["root"] == r["id"]
+    finally:
+        _cleanup()
+
+
+def test_records_wait_in_memory_until_a_flush(tmp_path, monkeypatch):
+    sink = os.path.join(str(tmp_path), "held.jsonl")
+    trace.configure(sink)
+    try:
+        trace.flush()
+        size0 = os.path.getsize(sink)
+        with trace.span("root"):
+            for i in range(200):
+                with trace.span("leaf", i=i):
+                    pass
+            # nothing was serialised inside the span that encloses them
+            assert os.path.getsize(sink) == size0
+            # ... but tail() (the dump_trace RPC) sees them
+            assert [r["i"] for r in trace.tail(5)] == [195, 196, 197, 198, 199]
+            assert os.path.getsize(sink) > size0
+            # bounded: past MAX_BUFFERED waiting records, one that
+            # closes inside a span writes them out
+            monkeypatch.setattr(trace, "MAX_BUFFERED", 10)
+            monkeypatch.setattr(trace, "FLUSH_INTERVAL_S", 1e-6)
+            monkeypatch.setattr(trace, "NESTED_FLUSH_INTERVALS", 1e12)
+            size1 = os.path.getsize(sink)
+            for i in range(9):
+                with trace.span("leaf", i=i):
+                    pass
+            assert os.path.getsize(sink) == size1
+            with trace.span("leaf", i=9):
+                pass
+            assert os.path.getsize(sink) > size1
+        # a record that closes outside any span flushes on the interval
+        monkeypatch.setattr(trace, "MAX_BUFFERED", 1 << 20)
+        size2 = os.path.getsize(sink)
+        trace.event("bare")
+        assert os.path.getsize(sink) > size2
+        assert len([r for r in _read(sink) if r["name"] == "leaf"]) == 210
+    finally:
+        _cleanup()
+
+
+def test_gc_pause_is_a_child_of_the_span_it_interrupted(tmp_path):
+    import gc
+
+    sink = os.path.join(str(tmp_path), "gc.jsonl")
+    before = len(gc.callbacks)
+    trace.configure(sink)
+    try:
+        assert len(gc.callbacks) == before + 1
+        with trace.span("root"):
+            with trace.span("busy"):
+                gc.collect()  # a full collection
+        trace.flush()
+        with open(sink, encoding="utf-8") as f:
+            recs = [json.loads(line) for line in f]
+        sp = {r["name"]: r for r in recs}
+        pause = next(r for r in recs if r["name"] == "runtime.gc_pause"
+                     and r["generation"] == 2)
+        assert pause["parent"] == sp["busy"]["id"]
+        assert pause["root"] == sp["root"]["id"]
+        assert "collected" in pause and pause["t0_ns"] <= pause["t1_ns"]
+        # the interrupted span's self time leaves the pause out
+        assert sp["busy"]["self_ms"] == pytest.approx(
+            sp["busy"]["dur_ms"] - pause["dur_ms"], abs=0.01)
+        # a young collection that pauses under 1 ms leaves no record
+        assert all(r["generation"] == 2 or r["dur_ms"] >= 1.0
+                   for r in recs if r["name"] == "runtime.gc_pause")
+    finally:
+        _cleanup()
+    assert len(gc.callbacks) == before  # disable() removes the hook
+
+
+def test_a_span_under_the_profiler_is_in_the_xplane_with_its_id(tmp_path):
+    """While tracing is on and jax is imported, a span also enters
+    jax.profiler.TraceAnnotation(name, span_id=id): under a profiler
+    session it lies on the profiler's clock, joined to the sink by id."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+
+    from cometbft_tpu.utils import xplane
+
+    sink = os.path.join(str(tmp_path), "prof.jsonl")
+    prof = os.path.join(str(tmp_path), "prof")
+    trace.configure(sink)
+    try:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(prof, profiler_options=opts)
+        try:
+            with trace.span("types.verify_commit", height=3) as outer:
+                with trace.span("crypto.batch_verify", n=2) as inner:
+                    jnp.arange(8).sum().block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        trace.flush()
+    finally:
+        _cleanup()
+    found = glob.glob(os.path.join(prof, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert found
+    xp = xplane.load(found[-1])
+    assert xp["start_ns"] is not None
+    spans = {sp["span_id"]: sp for p in xp["planes"]
+             for sp in p.get("spans", [])}
+    assert spans[outer.id]["name"] == "types.verify_commit"
+    assert spans[inner.id]["name"] == "crypto.batch_verify"
+    o, i = spans[outer.id], spans[inner.id]
+    assert o["start_ns"] <= i["start_ns"]
+    assert i["start_ns"] + i["dur_ns"] <= o["start_ns"] + o["dur_ns"]
+    # the same two spans, by id, in the sink
+    by_id = {r["id"]: r for r in _read(sink) if r["kind"] == "span"}
+    assert by_id[inner.id]["parent"] == outer.id
+    # and on one clock: the sink's t0_ns, moved to the wall clock by
+    # trace.clock, lands on the annotation's start
+    with open(sink, encoding="utf-8") as f:
+        clock = next(r for r in map(json.loads, f)
+                     if r["name"] == "trace.clock")
+    wall = clock["time_ns"] + by_id[outer.id]["t0_ns"] - clock["perf_ns"]
+    assert abs(wall - (xp["start_ns"] + o["start_ns"])) < 5e6  # 5 ms

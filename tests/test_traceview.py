@@ -317,3 +317,157 @@ def test_cli_summary_timeline_critical_path(tmp_path):
     p = _analyze(["stall", root, "--json"], str(tmp_path))
     assert p.returncode == 0, p.stderr
     assert json.loads(p.stdout)["status"] == "ok"
+
+
+# ----------------------------------------------------------------------
+# ISSUE 24: the join of a span sink with a profiler trace, on a small
+# recorded fixture (tests/data/device_join.json, in the form
+# utils/xplane.load gives; ns since the session began)
+# ----------------------------------------------------------------------
+_DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+_SCOPES = ("rlc.decompress", "rlc.accumulate", "rlc.window_combine",
+           "ladder.double_scalar", "field_mul", "msm_accumulate_weighted",
+           "curve_ladder_sub_mul8")
+
+
+def _fixture():
+    with open(os.path.join(_DATA, "device_join.json")) as f:
+        xp = json.load(f)
+    recs = traceview.load_records(
+        os.path.join(_DATA, "device_join.spans.jsonl"))
+    return xp, recs
+
+
+def test_device_join_books_idle_time_to_the_innermost_program_span():
+    xp, recs = _fixture()
+    j = traceview.device_join(xp, recs, scopes=_SCOPES)
+    # device 0 is the busiest: [1000,6000) + [8000,9500) of [0,12000)
+    assert j["busiest"] == "/device:TPU:0" and len(j["devices"]) == 2
+    assert j["stretch_s"] == pytest.approx(12000e-9)
+    assert j["busy_s"] == pytest.approx(6500e-9)
+    assert j["idle_s"] == pytest.approx(5500e-9)
+    idle = dict(j["idle_by_span"])
+    root = "types.verify_commit"
+    # [0,1000): root to 100, commit_items to 900, root to 950, batch_verify
+    assert idle[f"{root} > types.commit_items"] == pytest.approx(800e-9)
+    assert idle[f"{root} > crypto.batch_verify"] == pytest.approx(50e-9)
+    # [6000,8000): root, verdict_wait [6200,7800), root
+    assert idle[f"{root} > crypto.verdict_wait"] == pytest.approx(1600e-9)
+    # [9500,12000): root to 10000, outside to 10500, then a span that the
+    # sink does not hold (still open when it was read): its name alone
+    assert idle[traceview.NO_SPAN] == pytest.approx(500e-9)
+    assert idle[root] == pytest.approx(
+        (100 + 50 + 200 + 200 + 500 + 1500) * 1e-9)
+    assert sum(idle.values()) == pytest.approx(5500e-9)
+    assert j["idle_named_share"] == pytest.approx(1 - 500 / 5500)
+    assert (j["spans_in_trace"], j["spans_joined"]) == (5, 4)
+    # without the sink the rows carry the span's name alone
+    bare = dict(traceview.device_join(xp, scopes=_SCOPES)["idle_by_span"])
+    assert bare["types.commit_items"] == pytest.approx(800e-9)
+    assert "types.verify_commit > types.commit_items" not in bare
+
+
+def test_device_join_books_device_time_to_kernel_scopes():
+    xp, recs = _fixture()
+    j = traceview.device_join(xp, recs, scopes=_SCOPES)
+    busy = dict(j["busy_by_scope"])
+    assert busy["rlc.decompress"] == pytest.approx(1000e-9)
+    assert busy["rlc.accumulate"] == pytest.approx(1000e-9)
+    # the loop has no op_name of its own: it takes the scope most of its
+    # children's time carries, and so does the child that has none; the
+    # loop keeps only the time its children leave (4000 - 1000 - 2000)
+    assert busy["rlc.window_combine"] == pytest.approx(4000e-9)
+    assert busy["ladder.double_scalar"] == pytest.approx(500e-9)  # device 1
+    assert busy[traceview.NO_SCOPE] == pytest.approx(500e-9)  # copy.4
+    assert sum(busy.values()) == pytest.approx(7000e-9)  # both devices
+    assert j["busy_scoped_share"] == pytest.approx(1 - 500 / 7000)
+    how = dict(j["busy_booked_by"])
+    assert how["own"] == pytest.approx(3500e-9)
+    assert how["children"] == pytest.approx(1000e-9)
+    assert how["enclosing"] == pytest.approx(2000e-9)
+    assert how["none"] == pytest.approx(500e-9)
+    ops = dict(j["ops_by_scope"]["rlc.window_combine"])
+    # a pallas kernel goes by its name= (the innermost registered part of
+    # its op_name), not by its HLO instruction (tpu_custom_call.3)
+    assert ops == {"fusion.7": pytest.approx(2000e-9),
+                   "field_mul": pytest.approx(1000e-9),
+                   "while.9": pytest.approx(1000e-9)}
+    assert dict(j["ops_by_scope"]["rlc.accumulate"]) == {
+        "msm_accumulate_weighted": pytest.approx(1000e-9)}
+    assert dict(j["ops_by_scope"]["ladder.double_scalar"]) == {
+        "curve_ladder_sub_mul8": pytest.approx(500e-9)}
+
+
+def test_device_join_of_a_stretch_and_its_rendering():
+    xp, recs = _fixture()
+    j = traceview.device_join(xp, recs, stretch=(2000.0, 8000.0),
+                              scopes=_SCOPES)
+    assert j["stretch_s"] == pytest.approx(6000e-9)
+    assert j["busy_s"] == pytest.approx(4000e-9)
+    assert dict(j["busy_by_scope"]) == {
+        "rlc.window_combine": pytest.approx(4000e-9)}
+    assert j["busy_scoped_share"] == 1.0
+    text = traceview.render_device_join(j)
+    assert "types.verify_commit > crypto.verdict_wait" in text
+    assert "rlc.window_combine" in text and "while.9" in text
+    with pytest.raises(ValueError):
+        traceview.device_join({"start_ns": None, "planes": []})
+
+
+def test_trace_analyze_device_command(tmp_path, capsys):
+    """tools/trace_analyze.py device: refuses without --xplane, reads a
+    sink, and says what it cannot find."""
+    import importlib.util
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "trace_analyze", os.path.join(repo, "tools", "trace_analyze.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    sink = os.path.join(_DATA, "device_join.spans.jsonl")
+    assert tool.main(["device", sink]) == 2
+    assert tool.main(["device", sink, "--xplane", str(tmp_path)]) == 2
+    err = capsys.readouterr().err
+    assert "needs --xplane" in err and "no *.xplane.pb" in err
+
+
+def test_xplane_reader_on_a_trace_recorded_on_a_v5e():
+    """tests/data/v5e_probe.xplane.pb: the profiler's own file from one
+    v5e chip (PR 24's first chip call): a tiny jitted program with two
+    named scopes, a scan over a pallas kernel named probe_mul3, under two
+    TraceAnnotations with span_id 7 and 8. The reader finds what
+    jax.profiler.ProfileData hides: each operation's op_name."""
+    from cometbft_tpu.utils import xplane
+
+    xp = xplane.load(os.path.join(_DATA, "v5e_probe.xplane.pb"))
+    assert xp["start_ns"] == 1790546504926573769  # wall clock, ns
+    planes = {p["name"]: p for p in xp["planes"]}
+    assert set(planes) == {"/device:TPU:0", "/host:CPU"}
+    dev = planes["/device:TPU:0"]
+    assert [m["op"] for m in dev["modules"]] == [
+        "jit_prog(2309410719311152149)"]
+    assert len(dev["ops"]) == 25
+    kernel = [o for o in dev["ops"] if o["op"] == "probe_mul3.3"]
+    assert len(kernel) == 8  # one a scan step, inside the while's event
+    assert kernel[0]["op_name"] == (
+        "jit(prog)/rlc.accumulate/while/body/closed_call/probe_mul3/"
+        "pallas_call")
+    loop = next(o for o in dev["ops"] if o["op"] == "while")
+    assert loop["op_name"] == ""
+    for k in kernel:
+        assert loop["start_ns"] <= k["start_ns"]
+        assert k["start_ns"] + k["dur_ns"] <= loop["start_ns"] + loop["dur_ns"]
+    spans = {sp["span_id"]: sp for sp in planes["/host:CPU"]["spans"]}
+    assert spans[7]["name"] == "types.verify_commit"
+    assert spans[8]["name"] == "crypto.batch_verify"
+    assert spans[7]["start_ns"] <= spans[8]["start_ns"]
+    # and the join reads it: the loop and its unnamed fusions go to the
+    # scope their kernel names
+    j = traceview.device_join(
+        xp, scopes=("rlc.decompress", "rlc.accumulate", "rlc.final_check"))
+    busy = dict(j["busy_by_scope"])
+    assert busy["rlc.accumulate"] == pytest.approx(
+        loop["dur_ns"] * 1e-9, rel=1e-6)
+    assert j["busiest"] == "/device:TPU:0"
+    assert {"types.verify_commit", "crypto.batch_verify"} <= {
+        k for k, _ in j["idle_by_span"]}

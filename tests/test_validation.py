@@ -90,3 +90,77 @@ def test_light_trusting_subset_overlap():
     # demanding >2/3 overlap: 40 > 40? no -> fails
     with pytest.raises(validation.ErrNotEnoughVotingPower):
         validation.verify_commit_light_trusting(CHAIN, vals_a, commit, (2, 3))
+
+
+# ----------------------------------------------------------------------
+# ISSUE 24: the span tree of one verify_commit
+# ----------------------------------------------------------------------
+def _traced_verify(tmp_path, n, light=False):
+    import json
+    import os
+
+    from cometbft_tpu.utils import trace
+
+    _, vals, bid, commit = _setup(n=n)
+    sink = os.path.join(str(tmp_path), f"commit-{n}-{light}.jsonl")
+    trace.configure(sink)
+    try:
+        fn = validation.verify_commit_light if light else validation.verify_commit
+        fn(CHAIN, vals, bid, 5, commit)
+        trace.flush()
+        with open(sink, encoding="utf-8") as f:
+            recs = [json.loads(line) for line in f]
+    finally:
+        trace.disable()
+    # the tracer's own records come and go with the collector
+    return [r for r in recs
+            if r["name"] not in ("trace.clock", "runtime.gc_pause")]
+
+
+@pytest.mark.parametrize("light", [False, True])
+def test_traced_verify_commit_is_one_tree_whatever_the_size(tmp_path, light):
+    small = _traced_verify(tmp_path, 8, light)
+    large = _traced_verify(tmp_path, 64, light)
+    # per call, never per lane: the same records at 8 and at 64 validators
+    assert [r["name"] for r in small] == [r["name"] for r in large]
+    by = {r["name"]: r for r in large}
+    assert set(by) == {
+        "types.verify_commit", "types.commit_items",
+        "types.verify_items_fill", "crypto.batch_verify",
+        "crypto.materialize", "crypto.native_verify",
+        "crypto.commit_partition"}
+    root = by["types.verify_commit"]
+    assert root["parent"] is None and root["height"] == 5
+    assert {r["root"] for r in large} == {root["id"]}
+    for name in ("types.commit_items", "types.verify_items_fill",
+                 "crypto.batch_verify", "crypto.commit_partition"):
+        assert by[name]["parent"] == root["id"], name
+    for name in ("crypto.materialize", "crypto.native_verify"):
+        assert by[name]["parent"] == by["crypto.batch_verify"]["id"], name
+    # light stops once +2/3 is reached: 43 of 64 equal powers
+    n = 43 if light else 64
+    assert root["n"] == by["types.commit_items"]["n"] == n
+    assert by["types.verify_items_fill"]["groups"] == 1
+    assert by["types.verify_items_fill"]["singles"] == 0
+    bv = by["crypto.batch_verify"]
+    assert (bv["path"], bv["n"], bv["bucket"]) == ("native", n, 64)
+    assert 0 <= by["types.commit_items"]["sign_bytes_ms"] \
+        <= by["types.commit_items"]["dur_ms"]
+    # the entry layer from inside: the root's self time is what its
+    # direct children leave
+    kids = sum(by[k]["dur_ms"] for k in (
+        "types.commit_items", "types.verify_items_fill",
+        "crypto.batch_verify"))
+    # (less a collection's pause, should one fall straight under the root)
+    assert 0 <= root["self_ms"] <= root["dur_ms"] - kids + 0.02
+    assert bool(root.get("light")) == light
+
+
+def test_untraced_verify_commit_emits_nothing(tmp_path):
+    from cometbft_tpu.utils import trace
+
+    assert not trace.enabled
+    assert trace.span("types.verify_commit", height=1) is trace.span("x")
+    _, vals, bid, commit = _setup()
+    validation.verify_commit(CHAIN, vals, bid, 5, commit)
+    assert trace.tail() == [] and trace.path() is None

@@ -6,6 +6,13 @@
     python tools/trace_analyze.py timeline      <paths...> [--height H]
     python tools/trace_analyze.py critical-path <paths...> [--height H]
     python tools/trace_analyze.py stall         <paths...>
+    python tools/trace_analyze.py device        <paths...> --xplane <file>
+
+`device` joins the span sinks with a profiler trace (`*.xplane.pb`, or a
+directory that jax.profiler wrote one under) taken while tracing was on:
+the busiest device's idle time by the innermost program span the host
+was in, and device time by kernel scope (trace.KERNEL_SCOPES), for the
+whole trace or `--stretch LO:HI` (milliseconds since the session began).
 
 `paths` are trace sink files or directories (an e2e workdir is
 expanded to every ``node*/data/trace.jsonl`` under it; default: the
@@ -26,10 +33,46 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from cometbft_tpu.utils import traceview  # noqa: E402
 
 
+def device(args) -> int:
+    import glob
+
+    from cometbft_tpu.utils import xplane
+    from cometbft_tpu.utils.trace import KERNEL_SCOPES
+
+    if not args.xplane:
+        print("trace_analyze: device needs --xplane", file=sys.stderr)
+        return 2
+    path = args.xplane
+    if os.path.isdir(path):
+        found = sorted(glob.glob(os.path.join(
+            path, "**", "*.xplane.pb"), recursive=True))
+        if not found:
+            print(f"trace_analyze: no *.xplane.pb under {path}",
+                  file=sys.stderr)
+            return 2
+        path = found[-1]
+    records = []
+    for sink in traceview.discover(args.paths) if args.paths else []:
+        records += traceview.load_records(sink)
+    stretch = None
+    if args.stretch:
+        lo, hi = (float(x) * 1e6 for x in args.stretch.split(":"))
+        stretch = (lo, hi)
+    try:
+        j = traceview.device_join(xplane.load(path), records, stretch,
+                                  scopes=KERNEL_SCOPES)
+    except ValueError as e:
+        print(f"trace_analyze: {e}", file=sys.stderr)
+        return 2
+    print(json.dumps(j, indent=2) if args.as_json
+          else traceview.render_device_join(j))
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("command", choices=(
-        "summary", "timeline", "critical-path", "stall"))
+        "summary", "timeline", "critical-path", "stall", "device"))
     ap.add_argument("paths", nargs="*", default=None,
                     help="trace sink files or node/workdir directories "
                          "(default: .)")
@@ -37,9 +80,17 @@ def main(argv=None) -> int:
                     help="height to analyze (default: last committed)")
     ap.add_argument("--limit", type=int, default=200,
                     help="timeline: show at most N records (0 = all)")
+    ap.add_argument("--xplane", default=None,
+                    help="device: the profiler trace to join with")
+    ap.add_argument("--stretch", default=None, metavar="LO:HI",
+                    help="device: only this stretch, in ms since the "
+                         "profiler session began")
     ap.add_argument("--json", action="store_true", dest="as_json",
                     help="emit the raw analysis dict as JSON")
     args = ap.parse_args(argv)
+
+    if args.command == "device":
+        return device(args)
 
     try:
         mt = traceview.merge(args.paths or ["."])

@@ -7,7 +7,11 @@ so a name emitted but not declared in `trace.SPAN_REGISTRY` is
 invisible to triage docs, and a declared name with no live call site is
 a stale promise. This lint extracts every literal first argument to
 trace.span()/trace.event()/trace.emit() across the package (plus tools/
-and bench.py) and checks both directions. Exits 1 on any mismatch.
+and bench.py, and the two names the tracer writes itself) and checks
+both directions. It holds `trace.KERNEL_SCOPES` to the same rule against
+the phase (jax.named_scope) and pallas_call names in ops/, which the join of a
+profiler trace (utils/traceview.device_join) keys device time on. Exits
+1 on any mismatch.
 
 Run directly (`python tools/trace_lint.py`) or via the tier-1 suite
 (tests/test_observability.py wraps main()).
@@ -34,6 +38,15 @@ EXCLUDE = {
 # including the `_trace` alias used by modules avoiding name clashes
 CALL_RE = re.compile(
     r"\b_?trace\.(?:span|event|emit)\(\s*[\"']([^\"']+)[\"']")
+# the tracer's own records (trace.clock, runtime.gc_pause)
+TRACER_RE = re.compile(r"\b(?:event|_Span)\(\s*[\"']([^\"']+)[\"']")
+# kernel scopes in ops/: jax.named_scope("x"),
+# pallas_call(..., name="x") and the name handed to
+# field._pallas_binop(kernel, "x", ...)
+SCOPE_RE = re.compile(
+    r"\bnamed_scope\(\s*[\"']([^\"']+)[\"']"
+    r"|\bname=[\"']([^\"']+)[\"']"
+    r"|_pallas_binop\(\s*\w+,\s*[\"']([^\"']+)[\"']")
 
 
 def _source_files():
@@ -48,40 +61,52 @@ def _source_files():
         yield bench
 
 
-def main() -> int:
-    sys.path.insert(0, REPO)
-    from cometbft_tpu.utils.trace import SPAN_REGISTRY
-
-    used: dict[str, list[str]] = {}
-    for path in _source_files():
-        if os.path.abspath(path) in {os.path.abspath(e) for e in EXCLUDE}:
-            continue
-        with open(path, encoding="utf-8") as f:
-            src = f.read()
-        for m in CALL_RE.finditer(src):
-            used.setdefault(m.group(1), []).append(
-                os.path.relpath(path, REPO))
-
-    undeclared = sorted(set(used) - set(SPAN_REGISTRY))
-    unused = sorted(set(SPAN_REGISTRY) - set(used))
-    ok = True
+def _agree(what: str, table: str, used: dict, declared) -> bool:
+    """Both directions between the names in use and a registry."""
+    undeclared = sorted(set(used) - set(declared))
+    unused = sorted(set(declared) - set(used))
     if undeclared:
-        ok = False
-        print("span names emitted but missing from trace.SPAN_REGISTRY:",
+        print(f"{what} in use but missing from trace.{table}:",
               file=sys.stderr)
         for n in undeclared:
             print(f"  {n}  ({', '.join(sorted(set(used[n])))})",
                   file=sys.stderr)
     if unused:
-        ok = False
-        print("span names declared in trace.SPAN_REGISTRY but never "
-              "emitted:", file=sys.stderr)
+        print(f"{what} declared in trace.{table} but never used:",
+              file=sys.stderr)
         for n in unused:
             print(f"  {n}", file=sys.stderr)
+    return not undeclared and not unused
+
+
+def main() -> int:
+    sys.path.insert(0, REPO)
+    from cometbft_tpu.utils.trace import KERNEL_SCOPES, SPAN_REGISTRY
+
+    tracer = os.path.join(PKG, "utils", "trace.py")
+    ops = os.path.join(PKG, "ops") + os.sep
+    used: dict[str, list[str]] = {}
+    scopes: dict[str, list[str]] = {}
+    for path in _source_files():
+        path = os.path.abspath(path)
+        if path in EXCLUDE and path != tracer:
+            continue
+        with open(path, encoding="utf-8") as f:
+            src = f.read()
+        rel = os.path.relpath(path, REPO)
+        for m in (TRACER_RE if path == tracer else CALL_RE).finditer(src):
+            used.setdefault(m.group(1), []).append(rel)
+        if path.startswith(ops):
+            for m in SCOPE_RE.finditer(src):
+                scopes.setdefault(next(filter(None, m.groups())),
+                                  []).append(rel)
+
+    ok = _agree("span names", "SPAN_REGISTRY", used, SPAN_REGISTRY)
+    ok &= _agree("kernel scopes", "KERNEL_SCOPES", scopes, KERNEL_SCOPES)
     if not ok:
         return 1
-    print(f"trace lint: {len(SPAN_REGISTRY)} registered span names, "
-          "all emitted and declared")
+    print(f"trace lint: {len(SPAN_REGISTRY)} registered span names and "
+          f"{len(KERNEL_SCOPES)} kernel scopes, all in use and declared")
     return 0
 
 
