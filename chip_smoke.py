@@ -72,6 +72,7 @@ class Probe:
             for name in names:
                 setattr(mod, name + "_jit",
                         self._recorded(name, getattr(mod, name + "_jit")))
+        self.workdir = workdir
         self.trace_path = os.path.join(workdir, "smoke_trace.jsonl")
         trace.configure(self.trace_path)
         self._trace_off = 0
@@ -394,89 +395,179 @@ def _median_call_s(jit_fn, a, kw, reps: int = 5) -> float:
     return statistics.median(ts)
 
 
-def phase_device_terms(probe: Probe, lanes):
-    """Device-side seconds per batch of each engine, measured on programs
-    whose inputs already sit on the device (median of 5 calls), beside the
-    constants the dispatch assumes. Nothing is re-derived here (ROADMAP
-    S2): the printout is what the next issue plans from."""
+def more_lanes(lanes, n: int, seed: int):
+    """`lanes` grown to n signed lanes with further commits of the same
+    size (a catch-up window's batch is 65 commits' lanes in one)."""
+    out, k = list(lanes), 0
+    while len(out) < n:
+        k += 1
+        vals, _bid, commit, _weird = build_commit(len(lanes), seed + 100 * k)
+        out += commit_lanes(vals, commit)
+    return out[:n]
+
+
+def _engine_timings(probe: Probe, lanes, engine: str, calls: int = 5):
+    """One engine as submit() launches it, warm: medians over `calls`
+    batches of submit() (host) and submit -> verdict in ms, and device ms a
+    batch from a profiler trace of the same calls (busy time under the
+    engine's kernel scopes, the reduction of tools/trace_analyze.py device).
+    A batch whose RLC layout declined ran on the ladder: it is redrawn."""
+    import glob
+
+    import jax
+
+    from cometbft_tpu.crypto import ed25519 as E
+    from cometbft_tpu.utils import traceview, xplane
+    from cometbft_tpu.utils.trace import KERNEL_SCOPES
+
+    def one():
+        bv = verifier(lanes, force_perlane=engine == "ladder")
+        t0 = time.perf_counter()
+        pend = bv.submit()
+        t1 = time.perf_counter()
+        ok, _bits = pend.result()
+        t2 = time.perf_counter()
+        if not ok:
+            raise SystemExit(f"FAIL: {engine} refused honest lanes")
+        return pend._path, (t1 - t0) * 1e3, (t2 - t0) * 1e3
+
+    # plain submit() picks by the model; to put RLC under the clock the
+    # script pins the pick for the length of this measurement
+    model_pick = E._rlc_beats_ladder
+    if engine == "rlc":
+        E._rlc_beats_ladder = lambda n, b: True
+    prof = os.path.join(probe.workdir, f"profile_{engine}_{len(lanes)}")
+    try:
+        one()  # compiles or loads, fills the pubkey cache
+        took = []
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0  # device operations and our spans only
+        # off the chip XLA:CPU books every thunk as a host event: 84 MB and
+        # half a minute for three calls, and no device plane to read
+        opts.host_tracer_level = 1 if probe.on_chip else 0
+        jax.profiler.start_trace(prof, profiler_options=opts)
+        try:
+            for _ in range(4 * calls):
+                took.append(one())
+                if sum(p == engine for p, _, _ in took) == calls:
+                    break
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        E._rlc_beats_ladder = model_pick
+    mine = [t for t in took if t[0] == engine]
+    if len(mine) < calls:
+        raise SystemExit(f"FAIL: {engine} took {len(mine)} of {len(took)} "
+                         f"batches of {len(lanes)} lanes")
+    out = {"submit_ms": statistics.median(t[1] for t in mine),
+           "submit_to_verdict_ms": statistics.median(t[2] for t in mine),
+           "declined": len(took) - len(mine), "device_ms": None}
+    pbs = sorted(glob.glob(os.path.join(prof, "**", "*.xplane.pb"),
+                           recursive=True))
+    try:
+        j = traceview.device_join(xplane.load(pbs[-1]), scopes=KERNEL_SCOPES)
+    except (IndexError, ValueError):
+        return out  # no device plane in the trace (off the chip)
+    by_scope = {k: v for k, v in j["busy_by_scope"]
+                if k.startswith(engine + ".")}
+    if by_scope:
+        out["device_ms"] = sum(by_scope.values()) / len(mine) * 1e3
+        out["by_scope_ms"] = {k: round(v / len(mine) * 1e3, 3)
+                              for k, v in by_scope.items()}
+    return out
+
+
+def phase_device_terms(probe: Probe, lanes, sizes, seed: int):
+    """The dispatch model's device terms, measured again: both engines as
+    submit() launches them, warm, at the live lane counts of the cells' two
+    buckets (`sizes`), and from the two sizes each engine's fixed and
+    per-lane term beside what crypto/ed25519.py assumes. Nothing is
+    re-derived here: the printout is what a change of the constants is
+    made from."""
     import numpy as np
 
     from cometbft_tpu.crypto import ed25519 as E
 
-    n = len(lanes)
-    b = E._bucket(n)
-    items = [(p.bytes(), m, s) for p, m, s in lanes]
-
-    mdl = E.dispatch_model(n, b)
-    host = mdl["host_terms"]
-    log(f"   link probe _link_mbps() = {mdl['link_mbps']:.1f} MB/s; host "
+    host = E._host_terms()
+    log(f"   link probe _link_mbps() = {E._link_mbps():.1f} MB/s; host "
         f"terms ladder {host['ladder_us']:.3f} rlc {host['rlc_us']:.3f} "
         f"us/sig (rlc threads {host['rlc_threads']}, native "
         f"{host['rlc_native']}), calibrated={host['calibrated']}")
     if not host["calibrated"]:
         raise SystemExit("FAIL: host dispatch terms fell back to the "
                          "written-down constants (calibration failed)")
-    log(f"   dispatch model at n={n}: t_ladder {mdl['t_ladder'] * 1e3:.2f} ms "
-        f"t_rlc {mdl['t_rlc'] * 1e3:.2f} ms -> plain submit() picks "
-        f"{'rlc' if n >= E.RLC_MIN and mdl['t_rlc'] < mdl['t_ladder'] else 'ladder'}"
-        f" when the device is real")
+    where = "device" if probe.on_chip else "NOT A DEVICE NUMBER (rehearsal)"
 
-    measured: dict[str, float | None] = {}
-    # ladder: always (force_perlane is the verifier's own argument)
-    ok, _ = verifier(lanes, force_perlane=True).submit().result()
-    assert ok
-    measured["ladder"] = _median_call_s(
-        *probe.latest("verify_batch_cached_a", b))
-    # the other engines: on the chip always; off it only where this
-    # process already compiled them (an XLA:CPU compile of the RLC graph
-    # takes minutes and proves nothing)
-    rlc_prog = probe.latest("rlc_verify_stream", b)
-    if probe.on_chip or rlc_prog is not None:
-        pend = None
-        for _ in range(8):
-            pend = verifier(lanes)._launch_rlc()
-            if pend is not None:
-                break
-        if pend is None:
-            raise SystemExit("FAIL: the RLC layout declined 8 draws running")
-        assert pend.result()[0]
-        measured["rlc"] = _median_call_s(
-            *probe.latest("rlc_verify_stream", b))
+    got: dict[str, dict[int, dict]] = {"ladder": {}, "rlc": {}}
+    for n in sizes:
+        big = more_lanes(lanes, n, seed)
+        b = E._bucket(n)
+        mdl = E.dispatch_model(n, b)
+        pick = ("rlc" if n >= E.RLC_MIN and E._rlc_beats_ladder(n, b)
+                else "ladder")
+        log(f"   n={n} bucket={b}: the model says ladder "
+            f"{mdl['ladder']['device'] * 1e3:.2f} ms, rlc "
+            f"{mdl['rlc']['device'] * 1e3:.2f} ms of device (t_ladder "
+            f"{mdl['t_ladder'] * 1e3:.2f}, t_rlc {mdl['t_rlc'] * 1e3:.2f}); "
+            f"plain submit() picks {pick} when the device is real")
+        # RLC: on the chip always; off it an XLA:CPU compile of the graph
+        # takes minutes and proves nothing
+        for eng in ("ladder", "rlc") if probe.on_chip else ("ladder",):
+            r = got[eng][n] = _engine_timings(probe, big, eng)
+            # the same program called again with its inputs on the device
+            name = ("verify_batch_cached_a" if eng == "ladder"
+                    else "rlc_verify_stream")
+            r["kernel_ms"] = _median_call_s(*probe.latest(name, b)) * 1e3
+            dev = (f"{r['device_ms']:.3f} ms in the profile "
+                   f"{r.get('by_scope_ms')}" if r["device_ms"] is not None
+                   else "not in a profile")
+            log(f"     {eng:<6} {where}: {dev}; blocked call "
+                f"{r['kernel_ms']:.3f} ms; submit() {r['submit_ms']:.3f} ms, "
+                f"submit -> verdict {r['submit_to_verdict_ms']:.3f} ms; "
+                f"{r['declined']} layouts declined")
+
+    n0, n1 = sizes
+    for eng, fixed, per_lane in (
+        ("ladder", E._DEV_LADDER_FIXED_MS, E._DEV_LADDER_US),
+        ("rlc", E._DEV_RLC_FIXED_MS, E._DEV_RLC_US),
+    ):
+        if len(got[eng]) < 2:
+            log(f"   {eng}: not measured; assumed {fixed} ms + n x "
+                f"{per_lane} us")
+            continue
+        t0, t1 = (got[eng][n]["device_ms"] or got[eng][n]["kernel_ms"]
+                  for n in sizes)
+        us = (t1 - t0) / (n1 - n0) * 1e3
+        log(f"   {eng}, {where}: fixed {t0 - n0 * us * 1e-3:.2f} ms + n x "
+            f"{us:.3f} us through ({n0}, {t0:.2f} ms) and ({n1}, {t1:.2f} "
+            f"ms); assumed {fixed} ms + n x {per_lane} us")
+
+    n, b = n0, E._bucket(n0)
     delta_prog = probe.latest("verify_batch_delta", b)
     if (probe.on_chip or delta_prog is not None) and b <= E.DELTA_MAX_BUCKET:
-        d = E._detect_delta(items)
+        d = E._detect_delta([(p.bytes(), m, s) for p, m, s in lanes[:n]])
         if d:
-            bv = verifier(lanes)
+            bv = verifier(lanes[:n])
             bv._materialize()
             _bits, all_ok = bv._launch_device_delta(d)
             assert bool(np.asarray(all_ok))
-            measured["delta"] = _median_call_s(
-                *probe.latest("verify_batch_delta", b))
+            s = _median_call_s(*probe.latest("verify_batch_delta", b))
+            log(f"   delta kernel, {where}: {s * 1e3:.3f} ms = "
+                f"{s / n * 1e6:.3f} us/sig; assumed _DEV_DELTA_US (an "
+                f"end-to-end figure) = {E._DEV_DELTA_US}")
         else:
             log("   delta: these sign bytes share too little structure")
     # the ladder end to end: packed, shipped, verified, fetched, 8 deep
-    bvs = [verifier(lanes, force_perlane=True) for _ in range(8)]
+    bvs = [verifier(lanes[:n], force_perlane=True) for _ in range(8)]
     E.collect_pending([bv.submit() for bv in bvs])
     t0 = time.perf_counter()
     res = E.collect_pending([bv.submit() for bv in bvs])
     e2e = (time.perf_counter() - t0) / len(bvs)
     assert all(ok for ok, _ in res)
-
-    where = "device" if probe.on_chip else "NOT A DEVICE NUMBER (rehearsal)"
-    log(f"   per {n}-lane batch (bucket {b}), {where}:")
-    for eng, const, label in (
-        ("ladder", E._DEV_LADDER_US, "_DEV_LADDER_US"),
-        ("rlc", E._DEV_RLC_US, "_DEV_RLC_US"),
-        ("delta", E._DEV_DELTA_US, "_DEV_DELTA_US (an end-to-end figure)"),
-    ):
-        s = measured.get(eng)
-        got = (f"{s * 1e3:.3f} ms = {s / n * 1e6:.3f} us/sig"
-               if s is not None else "not measured")
-        log(f"     {eng:<7} kernel {got}; assumed {label} = {const}")
-    log(f"     ladder end to end, 8 in flight: {e2e * 1e3:.3f} ms = "
+    log(f"   ladder end to end, 8 in flight, {where}: {e2e * 1e3:.3f} ms = "
         f"{e2e / n * 1e6:.3f} us/sig; assumed _DEV_PREHASH_US = "
         f"{E._DEV_PREHASH_US}")
-    return measured
+    return got
 
 
 def _make_store(path: str, n_blocks: int, n_vals: int, seed: int, **kw):
@@ -740,6 +831,9 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny sizes, no platform assertion")
+    ap.add_argument("--terms", action="store_true",
+                    help="only the device-terms phase: both engines at both "
+                         "of the cells' buckets, beside the dispatch's terms")
     args = ap.parse_args(argv)
     t_start = time.perf_counter()
 
@@ -783,17 +877,29 @@ def main(argv=None) -> int:
     try:
         probe = Probe(workdir, on_chip)
         n_mega = 48 if small else 10_000
+        # live lanes of the two buckets the cells use (10240, 65536); the
+        # rehearsal's two sizes share one bucket, so its fit says nothing
+        term_sizes = (24, 48) if small else (10_000, 65_000)
         if args.chips == 4:
             _, rec = run_phase(probe, "mesh-4",
                                lambda: phase_mesh(probe, n_mega, args.seed))
+            phases.append(rec)
+        elif args.terms:
+            vals, _bid, commit, _weird = build_commit(n_mega, args.seed)
+            _, rec = run_phase(
+                probe, "device-terms",
+                lambda: phase_device_terms(
+                    probe, commit_lanes(vals, commit), term_sizes, args.seed))
             phases.append(rec)
         else:
             lanes, rec = run_phase(
                 probe, "mega-commit",
                 lambda: phase_megacommit(n_mega, args.seed))
             phases.append(rec)
-            _, rec = run_phase(probe, "device-terms",
-                               lambda: phase_device_terms(probe, lanes))
+            _, rec = run_phase(
+                probe, "device-terms",
+                lambda: phase_device_terms(probe, lanes, term_sizes,
+                                           args.seed))
             phases.append(rec)
             blocks, n_vals, window = (12, 8, 4) if small else (256, 1000, 64)
             _, rec = run_phase(

@@ -44,22 +44,35 @@ SIG_SIZE = 64
 # it up to 16384 would waste 38% of lanes on the hottest batch shape.
 BUCKETS = (64, 256, 1024, 4096, 10240, 16384, 65536)
 
-# At and above this size the RLC/MSM engine (ops/msm.py) is considered
-# instead of the per-lane ladder kernel (one multi-scalar multiplication
-# instead of N ladders, reference crypto/ed25519/ed25519.go:207-240).
-# MEASURED head-to-head on the real chip (round 4, 10k batches, depth-8
-# pipeline): ladder 178k sigs/s, RLC 41.7k. Round 5's xprof
-# decomposition (PROFILE.md round-5) corrected the round-4 diagnosis:
-# RLC *device* time is 2.11 us/sig — 2x BETTER than the ladder — and
-# the loss is entirely the HOST prepare stage (signed digits + bucket
-# layout, ~20 us/sig of numpy on this 1-core box). The dispatch model
-# therefore carries host, device, and wire terms per path; since this
-# PR the RLC host term is the NATIVE packer (csrc/rlc_packer.inc,
-# measured 1.06 us/sig single-worker on a 10k batch — 19x the numpy
-# path), so RLC wins wherever wire isn't the binding stage.
+# At and above this size the RLC/MSM engine (ops/msm.py: one multi-scalar
+# multiplication instead of N ladders, reference
+# crypto/ed25519/ed25519.go:207-240) is a candidate beside the per-lane
+# ladder kernel. The dispatch model carries host, wire and device terms for
+# each engine; the slowest stage of an engine is its time, and the engine
+# with the smaller time takes the batch.
+#
+# The device terms are readings of ONE v5e (DEVICE_KIND; the chip tool's
+# machine, 2026-09-28, PR 25): each engine as submit() launches it, warm,
+# device time a batch from the profiler trace (5 batches, by kernel scope,
+# the reduction of tools/trace_analyze.py device), at the live lane counts
+# of the two buckets the benchmark's cells use. Each engine's term is the
+# line through its two readings, fixed + n * per-lane
+# (`python chip_smoke.py --terms` measures all four again):
+#   lanes (bucket)    ladder       RLC
+#   10,000 (10240)    20.48 ms     120.19 ms
+#   65,000 (65536)    130.87 ms    278.31 ms
+# RLC's fixed part is its two one-lane-wide tails (`rlc.final_check` 35.1
+# ms, `rlc.window_combine` 32.4 ms at either size) and the part of
+# `rlc.accumulate` and `rlc.expand_stream` that does not shrink with the
+# batch; its per-lane part alone is above the ladder's whole cost. So on
+# this chip the model picks RLC at no size in BUCKETS: every device batch
+# takes the ladder. The engine stays in the tree as a candidate the model
+# never picks (ROADMAP D2 holds the verdict for a `simplicity` PR).
 RLC_MIN = 4096
-_DEV_LADDER_US = 2.39  # measured device-resident pipelined (r5, PROFILE.md)
-_DEV_RLC_US = 2.11     # measured xprof device total (r5, PROFILE.md)
+_DEV_LADDER_FIXED_MS = 0.41  # v5e profile, 2026-09-28, PR 25 (table above)
+_DEV_LADDER_US = 2.007       # the same two readings
+_DEV_RLC_FIXED_MS = 91.44    # v5e profile, 2026-09-28, PR 25 (table above)
+_DEV_RLC_US = 2.875          # the same two readings
 # Host-side per-sig terms are CALIBRATED at first dispatch decision
 # (_host_terms: one small timed prepare / pack per engine) because they
 # move with the host — core count, toolchain presence, numpy build.
@@ -73,8 +86,8 @@ _HOST_LADDER_US = 1.6        # ladder submit packing (r4: ~15-22 ms/10k)
 # worker-pool MSM, calibrated like the terms above. Carried in the
 # model as a third dispatch path for the crossover accounting in
 # PROFILE.md round-20 — the measured verdict is NEGATIVE for signature
-# dispatch (hundreds of us/point vs the ladder's 2.39 us/sig device
-# floor); the engine earns its keep on its own workload (KZG openings,
+# dispatch (hundreds of us/point vs the ladder's ~2 us/sig device
+# term); the engine earns its keep on its own workload (KZG openings,
 # crypto/kzg.py), not here. r20 measured 393 us/point at n=256, 1 core.
 _HOST_MSM_US = 400.0
 _WIRE_LADDER_B = 96    # R||S||k per lane (73 on the delta fast path)
@@ -218,12 +231,12 @@ def dispatch_model(n: int, b: int) -> dict:
     host = _host_terms()
     ladder = {
         "wire": _WIRE_LADDER_B * b / bw,
-        "device": n * _DEV_LADDER_US * 1e-6,
+        "device": _DEV_LADDER_FIXED_MS * 1e-3 + n * _DEV_LADDER_US * 1e-6,
         "host": n * host["ladder_us"] * 1e-6,
     }
     rlc = {
         "wire": _WIRE_RLC_B * b / bw,
-        "device": n * _DEV_RLC_US * 1e-6,
+        "device": _DEV_RLC_FIXED_MS * 1e-3 + n * _DEV_RLC_US * 1e-6,
         "host": n * host["rlc_us"] * 1e-6,
     }
     out = {
@@ -239,7 +252,7 @@ def dispatch_model(n: int, b: int) -> dict:
         # G1 MSM on the native Pippenger engine. Host-only — nothing
         # ships to the device, so wire and device terms vanish — but
         # the per-point cost is hundreds of us against the ladder's
-        # 2.39 us/sig device floor, so the crossover never happens for
+        # ~2 us/sig device term, so the crossover never happens for
         # signature dispatch at any n (the honest negative result in
         # PROFILE.md round-20; the engine's win is KZG openings).
         msm = {
@@ -251,7 +264,8 @@ def dispatch_model(n: int, b: int) -> dict:
         out["t_msm"] = max(msm.values())
     eng = _mesh_engine()
     if eng is not None and eng.n_devices > 1:
-        # Sharded-mesh term: the batch's device time splits d ways but
+        # Sharded-mesh term: the per-lane part of the ladder's device
+        # time splits d ways (its fixed part is paid by every shard) but
         # the wire stage pays d separate shard stagings (each with the
         # calibrated fixed per-transfer cost) and every launch pays one
         # psum across the mesh. Host packing is the same 96 B/lane rsk
@@ -262,7 +276,9 @@ def dispatch_model(n: int, b: int) -> dict:
         terms = eng.dispatch_terms()
         mesh = {
             "wire": _WIRE_LADDER_B * b / bw + d * terms["put_fixed_s"],
-            "device": n * _DEV_LADDER_US * 1e-6 / d + terms["collective_s"],
+            "device": (_DEV_LADDER_FIXED_MS * 1e-3
+                       + n * _DEV_LADDER_US * 1e-6 / d
+                       + terms["collective_s"]),
             "host": ladder["host"],
         }
         out["mesh"] = mesh
@@ -298,9 +314,10 @@ def _mesh_beats_single(n: int, b: int) -> bool:
 # it (csrc/ed25519_ifma.inc), portable C++ otherwise.
 NATIVE_MAX = 1024
 
-# The device terms of the dispatch model (_DEV_LADDER_US, _DEV_RLC_US,
-# _DEV_DELTA_US, _DEV_PREHASH_US) and the wire-byte terms were measured
-# on ONE device kind, a TPU v5e, which jax reports as this device_kind.
+# The device terms of the dispatch model (_DEV_LADDER_*, _DEV_RLC_*: PR
+# 25's readings; _DEV_DELTA_US, _DEV_PREHASH_US: round 4's) and the
+# wire-byte terms were measured on ONE device kind, a TPU v5e, which
+# jax reports as this device_kind.
 # They are not re-derived per device: an accelerator of another kind is
 # an error (_accel_backed raises), not a v5e with different numbers.
 DEVICE_KIND = "TPU v5 lite"

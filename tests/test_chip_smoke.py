@@ -43,6 +43,20 @@ def test_chip_smoke_rehearsal_runs_every_phase():
     assert "refused at height" in p.stdout
 
 
+def test_chip_smoke_terms_runs_the_device_terms_phase_alone():
+    """--terms is the re-measurement of the dispatch's device terms: that
+    phase and no other, both sizes, the model's terms beside the fit."""
+    p = _run([SMOKE, "--rehearse", "--terms", "--seed", "5"])
+    assert p.returncode == 0, f"stdout={p.stdout[-3000:]}\nstderr={p.stderr[-3000:]}"
+    assert json.loads(p.stdout.strip().splitlines()[-1])["ok"] is True
+    assert p.stdout.count("== phase ") == 1
+    assert "   phase device-terms: ok in " in p.stdout
+    assert "n=24 bucket=64: the model says ladder" in p.stdout
+    assert "n=48 bucket=64: the model says ladder" in p.stdout
+    assert "NOT A DEVICE NUMBER (rehearsal): fixed " in p.stdout
+    assert "rlc: not measured; assumed 91.44 ms + n x 2.875 us" in p.stdout
+
+
 def test_chip_smoke_refuses_to_start_without_a_chip():
     """No accelerator and no --rehearse: non-zero before any work, and no
     result line on stdout."""

@@ -1,8 +1,9 @@
 """Batch-size-aware backend dispatch: native C++ RLC for commit-sized
-batches, TPU MSM for mega-batches, per-lane kernel as the blame/bitmap
-fallback (reference types/validation.go:26-53 + crypto/batch dispatch;
-sizing policy is ours — the reference has one CPU backend, we have
-three engines behind one seam)."""
+batches, the per-lane TPU kernel for mega-batches and for blame, the TPU
+RLC/MSM engine a candidate that the v5e's measured terms never pick
+(reference types/validation.go:26-53 + crypto/batch dispatch; sizing
+policy is ours — the reference has one CPU backend, we have three
+engines behind one seam)."""
 
 import numpy as np
 import pytest
@@ -357,58 +358,145 @@ def _pin_model(monkeypatch, link_mbps, rlc_us, ladder_us=1.6):
     return e
 
 
-def test_rlc_crossover_fast_link_native_packer(monkeypatch):
-    """The VERDICT Next #5 'Done' criterion: with the native packer's
-    measured host term (~1.1 us/sig) on a fast link, the 10k dispatch
-    must flip to RLC — its 2.11 us/sig device floor beats the ladder's
-    2.39, and neither host (1.1) nor wire (~1 ms at 1 GB/s) binds."""
-    e = _pin_model(monkeypatch, link_mbps=1000.0, rlc_us=1.1)
-    m = e.dispatch_model(10000, 10240)
-    assert m["t_rlc"] == pytest.approx(10000 * 2.11e-6)  # device-bound
-    assert e._rlc_beats_ladder(10000, 10240)
+def _binding(stages: dict) -> str:
+    return max(stages, key=stages.get)
 
 
-def test_rlc_crossover_numpy_host_still_loses(monkeypatch):
-    """Same link, numpy packer (20 us/sig): host term dominates
-    (200 ms vs the ladder's 23.9 ms device) — ladder keeps the batch.
-    This is the round-5 status quo the native packer exists to fix."""
-    e = _pin_model(monkeypatch, link_mbps=1000.0, rlc_us=20.0)
-    m = e.dispatch_model(10000, 10240)
-    assert m["t_rlc"] == pytest.approx(10000 * 20.0e-6)  # host-bound
-    assert not e._rlc_beats_ladder(10000, 10240)
+@pytest.mark.parametrize(
+    "link_mbps, rlc_us, n, b, winner, ladder_binds, rlc_binds",
+    [
+        # the round-6 'Done' case (native packer 1.1 us/sig, fast link):
+        # neither host nor wire binds, and the device terms the v5e
+        # measures give the batch to the ladder
+        pytest.param(1000.0, 1.1, 10000, 10240, "ladder", "device",
+                     "device", id="fast_link_native_packer"),
+        # numpy packer (20 us/sig): RLC is host-bound at 200 ms, further
+        # behind still
+        pytest.param(1000.0, 20.0, 10000, 10240, "ladder", "device", "host",
+                     id="numpy_host_still_loses"),
+        # a 30 MB/s link: the ladder's 96 B/lane wire (32.8 ms) binds it;
+        # RLC's 116 B/lane (39.6 ms) is still under its device time
+        pytest.param(30.0, 1.1, 10000, 10240, "ladder", "wire", "device",
+                     id="30mbps_wire_still_loses"),
+        # REAL link probe and REAL first-use calibration on this host (the
+        # CPU loopback): whatever they read, wire does not bind and the
+        # measured device terms decide
+        pytest.param(None, None, 10000, 10240, "ladder", None, None,
+                     marks=needs_native,
+                     id="loopback_with_real_calibration"),
+        # the two batches the benchmark's cells send, at the link the
+        # chip's machine probes (1.0-1.9 GB/s)
+        pytest.param(1000.0, 1.65, 10000, 10240, "ladder", "device",
+                     "device", id="megacommit_10000_of_10240"),
+        pytest.param(1000.0, 1.65, 65000, 65536, "ladder", "device",
+                     "device", id="catchup_65000_of_65536"),
+    ],
+)
+def test_engine_crossover(monkeypatch, link_mbps, rlc_us, n, b, winner,
+                          ladder_binds, rlc_binds):
+    """Which engine the stage model gives a mega-batch to, and which stage
+    binds each engine. Device terms are the v5e's readings (PR 25): RLC's
+    fixed part alone is above the ladder's whole batch, so RLC loses on the
+    device at every size, and a slow host or a slow link only adds to it."""
+    from cometbft_tpu.crypto import ed25519 as e
+
+    if link_mbps is None:
+        monkeypatch.setattr(e, "_HOST_TERMS", None)  # fresh calibration
+        assert e._host_terms()["calibrated"]
+    else:
+        _pin_model(monkeypatch, link_mbps, rlc_us, ladder_us=1.05)
+    m = e.dispatch_model(n, b)
+    for path, binds in (("ladder", ladder_binds), ("rlc", rlc_binds)):
+        if binds is None:  # a loaded host may bind; the loopback never
+            assert _binding(m[path]) != "wire"
+        else:
+            assert _binding(m[path]) == binds
+            assert m["t_" + path] == pytest.approx(m[path][binds])
+    assert e._rlc_beats_ladder(n, b) == (winner == "rlc")
 
 
-def test_rlc_crossover_30mbps_wire_still_loses(monkeypatch):
-    """1-core host on a 30 MB/s link: even with the native packer,
-    RLC's 116 B/lane wire (39.6 ms) exceeds the ladder's 96 B/lane
-    (32.8 ms) — the dispatch must still pick the ladder, so a slow link
-    is never regressed by this PR."""
-    e = _pin_model(monkeypatch, link_mbps=30.0, rlc_us=1.1)
-    m = e.dispatch_model(10000, 10240)
-    assert m["t_rlc"] == pytest.approx(116 * 10240 / 30e6)  # wire-bound
-    assert not e._rlc_beats_ladder(10000, 10240)
+# device milliseconds a batch, each engine as submit() launches it, warm, from
+# the profiler trace (my chip run, PR 25: `python chip_smoke.py --terms`)
+CHIP_READINGS_MS = {
+    ("ladder", 10000): 20.480,
+    ("ladder", 65000): 130.870,
+    ("rlc", 10000): 120.192,
+    ("rlc", 65000): 278.314,
+}
+
+
+@pytest.mark.parametrize("engine, n", sorted(CHIP_READINGS_MS))
+def test_device_terms_reproduce_the_chip_readings(monkeypatch, engine, n):
+    """fixed + n * per-lane of each engine is within 15% of what the v5e
+    read at the live lane counts of the cells' two buckets."""
+    e = _pin_model(monkeypatch, link_mbps=1000.0, rlc_us=1.65)
+    model_ms = e.dispatch_model(n, e._bucket(n))[engine]["device"] * 1e3
+    assert model_ms == pytest.approx(CHIP_READINGS_MS[engine, n], rel=0.15)
 
 
 @needs_native
-def test_rlc_selected_on_loopback_with_real_calibration(monkeypatch):
-    """End-to-end dispatch flip on the CPU-mesh loopback: REAL link
-    probe, REAL first-use calibration (no pinned constants). Skips only
-    if this host's packer misses the <= 2 us/sig target the PR pins in
-    PROFILE.md — on any box meeting it, loopback wire is ~free and the
-    RLC device floor must win the 10k decision."""
-    from cometbft_tpu.crypto import ed25519 as e
+@pytest.mark.parametrize("n", [10000, 65000])
+def test_mega_batch_on_an_accelerator_takes_the_ladder(monkeypatch, tmp_path,
+                                                       n):
+    """The decision itself, as submit() makes it on a host with the chip:
+    NATIVE_MAX as shipped, the real model, the real link probe and host
+    calibration; only the two jitted ladder programs are stand-ins. A
+    mega-batch filled by add_batch is counted and traced under `ladder`,
+    never reaches the RLC layout and never expands its columns."""
+    import json
 
-    if not native.rlc_available():
-        pytest.skip("no native RLC packer")
-    monkeypatch.setattr(e, "_HOST_TERMS", None)  # force fresh calibration
-    terms = e._host_terms()
-    assert terms["calibrated"]
-    if terms["rlc_us"] > 2.0:
-        pytest.skip(f"packer {terms['rlc_us']:.2f} us/sig > 2 target here")
-    assert e._rlc_beats_ladder(10000, 10240)
-    m = e.dispatch_model(10000, 10240)
-    # loopback: wire is not the binding stage for either path
-    assert m["rlc"]["wire"] < m["t_rlc"]
+    from cometbft_tpu.crypto import ed25519 as e
+    from cometbft_tpu.crypto import rlc
+    from cometbft_tpu.ops import ed25519_verify as ev
+    from cometbft_tpu.utils import trace
+    from cometbft_tpu.utils.metrics import crypto_metrics
+
+    def refuse(*_a, **_kw):
+        raise AssertionError("a mega-batch reached the RLC engine")
+
+    expanded = []
+    monkeypatch.setattr(e, "_ACCEL_BACKED", True)
+    monkeypatch.setattr(e, "_mesh_engine", lambda: None)
+    monkeypatch.setattr(e, "_A_CACHE", {})
+    monkeypatch.setattr(rlc, "prepare", refuse)
+    monkeypatch.setattr(e.Ed25519BatchVerifier, "_launch_rlc", refuse)
+    monkeypatch.setattr(e.Ed25519BatchVerifier, "_materialize",
+                        lambda self: expanded.append(self.count()))
+    monkeypatch.setattr(ev, "decompress_pubkeys_jit", lambda a: (a, a))
+    monkeypatch.setattr(
+        ev, "verify_batch_cached_a_jit",
+        lambda ok_a, neg_a, rsk, live: (np.asarray(live), np.asarray(True)))
+
+    r = np.random.default_rng(n)
+    sigs = r.integers(0, 256, (n, 64), np.uint8)
+    sigs[:, 63] = 0  # S < L on every lane
+    bv = e.Ed25519BatchVerifier(backend="tpu")
+    bv.add_batch(r.integers(0, 256, (n, 32), np.uint8), sigs,
+                 r.integers(0, 256, n * 100, np.uint8).tobytes(),
+                 np.full(n, 100, np.uint32))
+
+    sink = str(tmp_path / "spans.jsonl")
+    trace.configure(sink)
+    try:
+        pending = bv.submit()
+        ok, bits = pending.result()
+        trace.flush()
+        with open(sink, encoding="utf-8") as f:
+            spans = [json.loads(line) for line in f]
+    finally:
+        trace.disable()
+    assert ok and len(bits) == n and all(bits)
+    assert pending._path == "ladder"
+    picked = {k[0]: v for k, v in
+              crypto_metrics().path_selected_total.values().items()
+              if k[1] == "ed25519"}
+    assert picked == {"ladder": 1.0}
+    (batch,) = [sp for sp in spans if sp["name"] == "crypto.batch_verify"]
+    assert (batch["path"], batch["n"], batch["bucket"]) == (
+        "ladder", n, e._bucket(n))
+    names = {sp["name"] for sp in spans}
+    assert not names & {"crypto.rlc_prepare", "crypto.materialize"}
+    assert expanded == []
 
 
 def _pin_model_msm(monkeypatch, link_mbps, rlc_us, msm_us,
@@ -440,15 +528,17 @@ def test_msm_crossover_negative_at_every_batch_size(monkeypatch):
     """The round-20 crossover verdict, pinned with the measured terms
     (393 us/point at n=256 on the reference box): the ladder-vs-RLC-vs-
     MSM three-way pick NEVER selects MSM for signature dispatch — its
-    host fold is ~170x the ladder's 2.39 us/sig device floor, and
-    scaling n only scales both linearly. The engine's win is the KZG
-    opening workload (WORKLOADS.json das_pc_multiproof), not this one."""
+    host fold is ~190x the ladder's ~2 us/sig device term, and scaling
+    n only scales both linearly (under RLC's fixed part a tiny batch's
+    fold does fit, so the pick is against the better of the two). The
+    engine's win is the KZG opening workload (WORKLOADS.json
+    das_pc_multiproof), not this one."""
     e = _pin_model_msm(monkeypatch, link_mbps=1000.0, rlc_us=1.1,
                        msm_us=393.0)
     for n in (64, 256, 1024, 4096, 10240, 65536):
         m = e.dispatch_model(n, n)
         assert m["t_msm"] > m["t_ladder"], n
-        assert m["t_msm"] > m["t_rlc"], n
+        assert m["t_msm"] > min(m["t_ladder"], m["t_rlc"]), n
     # even a 100x-parallel fantasy engine loses above the smallest tier
     e2 = _pin_model_msm(monkeypatch, link_mbps=1000.0, rlc_us=1.1,
                         msm_us=3.93)
@@ -520,16 +610,19 @@ def test_mesh_term_absent_without_engine(monkeypatch):
 
 
 def test_mesh_flips_device_bound_batch(monkeypatch):
-    """Fast link, 8 chips: the ladder's 23.9 ms device stage splits to
-    ~3 ms and the mesh becomes HOST-bound at 16 ms — below both ladder
-    (23.9 device) and RLC (21.1 device), so dispatch must flip to mesh
-    exactly where splitting device time is what the batch needed."""
+    """Fast link, 8 chips: the per-lane part of the ladder's device
+    stage splits 8 ways (its fixed part does not) and the mesh becomes
+    HOST-bound at 16 ms — below the ladder's device stage and far below
+    RLC's, so dispatch must flip to mesh exactly where splitting device
+    time is what the batch needed."""
     e = _pin_model(monkeypatch, link_mbps=1000.0, rlc_us=1.1)
     monkeypatch.setattr(e, "_mesh_engine", lambda: _StubMesh())
     m = e.dispatch_model(10000, 10240)
     assert m["n_devices"] == 8
     assert m["mesh"]["device"] == pytest.approx(
-        10000 * e._DEV_LADDER_US * 1e-6 / 8 + 60e-6)
+        e._DEV_LADDER_FIXED_MS * 1e-3
+        + 10000 * e._DEV_LADDER_US * 1e-6 / 8 + 60e-6)
+    assert m["t_ladder"] == pytest.approx(m["ladder"]["device"])
     assert m["t_mesh"] == pytest.approx(10000 * 1.6e-6)  # host binds
     assert e._mesh_beats_single(10000, 10240)
 
