@@ -8,6 +8,8 @@ SHA256(pubkey_bytes)[:20] for ed25519 (reference crypto/ed25519/ed25519.go:180).
 from __future__ import annotations
 
 import hashlib
+import threading
+import time
 from abc import ABC, abstractmethod
 
 
@@ -71,3 +73,39 @@ class BatchVerifier(ABC):
 
     @abstractmethod
     def verify(self) -> tuple[bool, list[bool]]: ...
+
+
+class HostLeg:
+    """A host engine's verification in flight on a worker thread, behind
+    the pending interface of ed25519's device handles (prefetch() /
+    result()). The engines are one ctypes call each, which releases the
+    GIL: launched before the caller packs and launches its device batch,
+    they run under it and beside each other (types/validation.py). A
+    thread a leg, started here and joined by result(): no pool to keep
+    across a fork. `own_s` is the call's wall time on its thread."""
+
+    __slots__ = ("_thread", "_out", "_err", "own_s")
+
+    def __init__(self, fn):
+        self._out = self._err = None
+        self.own_s = 0.0
+        self._thread = threading.Thread(
+            target=self._run, args=(fn,), name="host-leg", daemon=True)
+        self._thread.start()
+
+    def _run(self, fn) -> None:
+        t0 = time.perf_counter()
+        try:
+            self._out = fn()
+        except BaseException as e:  # noqa: BLE001 — re-raised by result()
+            self._err = e
+        self.own_s = time.perf_counter() - t0
+
+    def prefetch(self) -> None:
+        pass  # nothing to fetch: the verdict is made on the host
+
+    def result(self):
+        self._thread.join()
+        if self._err is not None:
+            raise self._err
+        return self._out

@@ -32,7 +32,7 @@ import hmac
 import secrets
 
 from . import native as _native
-from .keys import PrivKey, PubKey
+from .keys import HostLeg, PrivKey, PubKey
 
 KEY_TYPE = "tendermint/PubKeySecp256k1"
 PRIV_KEY_SIZE = 32
@@ -241,6 +241,20 @@ def verify_many(items, nchunks: int = 0) -> list:
         if out is not None:
             return [ok and wf for ok, wf in zip(out, well_formed)]
     return [verify_python(p, m, s) for p, m, s in items]
+
+
+def submit_many(items) -> HostLeg:
+    """verify_many from a worker thread, launched now, behind the
+    pending interface of the batch verifiers: result() gives (all ok,
+    per-item verdicts). The seam a commit's secp256k1 partition takes
+    so that it runs under the commit's device leg."""
+    items = list(items)
+
+    def run():
+        bits = verify_many(items)
+        return all(bits), bits
+
+    return HostLeg(run)
 
 
 class Secp256k1PrivKey(PrivKey):

@@ -30,7 +30,7 @@ import hashlib
 import secrets
 
 from . import ristretto as R
-from .keys import BatchVerifier, PrivKey, PubKey, tmhash20
+from .keys import BatchVerifier, HostLeg, PrivKey, PubKey, tmhash20
 from .merlin import Transcript
 
 KEY_TYPE = "tendermint/PubKeySr25519"
@@ -198,14 +198,26 @@ class Sr25519BatchVerifier(BatchVerifier):
         return len(self._items)
 
     def verify(self) -> tuple[bool, list[bool]]:
-        if not self._items:
-            return False, []
-        if len(self._items) >= 4 and _verify_rlc(self._items):
-            return True, [True] * len(self._items)
-        # batch failed (or tiny): per-signature scan gives the bitmap
-        # (reference batch.go falls back the same way)
-        bits = [_verify_one(p, m, s) for p, m, s in self._items]
-        return all(bits), bits
+        return _verify_batch(self._items)
+
+    def submit(self) -> HostLeg:
+        """The same verdict from a worker thread, launched now: the
+        native batch is one ctypes call, so it runs under whatever the
+        caller does until result() (a commit's device leg). The items
+        are snapshotted: the verifier may be reused after submit()."""
+        items = list(self._items)
+        return HostLeg(lambda: _verify_batch(items))
+
+
+def _verify_batch(items) -> tuple[bool, list[bool]]:
+    if not items:
+        return False, []
+    if len(items) >= 4 and _verify_rlc(items):
+        return True, [True] * len(items)
+    # batch failed (or tiny): per-signature scan gives the bitmap
+    # (reference batch.go falls back the same way)
+    bits = [_verify_one(p, m, s) for p, m, s in items]
+    return all(bits), bits
 
 
 def _msm(pairs):

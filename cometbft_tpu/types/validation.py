@@ -39,19 +39,58 @@ def _curve_of(tag: str) -> str:
     return tag.rsplit("PubKey", 1)[-1].lower() or tag
 
 
-def _observe_partition(tag: str, path: str, n: int, dt: float) -> None:
+def _observe_partition(tag: str, path: str, dt: float) -> None:
     """Per-curve observability for one commit partition: the mixed
     mega-commit's breakdown (which curve burns the wall) shows up in
-    /metrics (crypto_verify_seconds{path=...,curve=...}) and the trace
-    tail without re-profiling."""
+    /metrics (crypto_verify_seconds{path=...,curve=...}) without
+    re-profiling. `dt` runs from the partition's launch to its verdict."""
     curve = _curve_of(tag)
     m = crypto_metrics()
     m.path_selected_total.inc(1.0, path, curve)
     m.verify_seconds.observe(dt, path, curve)
-    if _trace.enabled:
-        _trace.emit("crypto.commit_partition", "span",
-                    dur_ms=round(dt * 1e3, 3), curve=curve, path=path,
-                    n=n)
+
+
+class _Leg:
+    """One curve's share of a commit from its launch to its verdict:
+    the verifier or the in-flight handle, and the crypto.commit_partition
+    span, which its sibling legs overlap."""
+
+    __slots__ = ("tag", "path", "idxs", "bv", "pending", "verdict",
+                 "t0", "launch_s", "span")
+
+    def __init__(self, tag: str, path: str, idxs: list[int], bv=None):
+        self.tag = tag
+        self.path = path
+        self.idxs = idxs
+        self.bv = bv
+        self.pending = self.verdict = None
+        self.span = _trace.open_span(
+            "crypto.commit_partition", curve=_curve_of(tag), path=path,
+            n=len(idxs))
+        self.t0 = _time.perf_counter()
+        self.launch_s = 0.0
+
+    def launched(self) -> "_Leg":
+        self.launch_s = _time.perf_counter() - self.t0
+        return self
+
+    def resolve(self) -> tuple[bool, list[bool]]:
+        """(all ok, verdict per index of idxs), blocking as long as the
+        leg still needs; closes the span."""
+        t0 = _time.perf_counter()
+        if self.verdict is None:
+            self.verdict = (self.pending.result() if self.pending is not None
+                            else self.bv.verify())
+        now = _time.perf_counter()
+        waited = now - t0
+        # a leg on a worker thread knows its own time; one that ran on
+        # this thread took its launch and what its verdict blocked for
+        own = getattr(self.pending, "own_s", self.launch_s + waited)
+        self.span.add(own_ms=round(own * 1e3, 3),
+                      waited_ms=round(waited * 1e3, 3))
+        self.span.close()
+        _observe_partition(self.tag, self.path, now - self.t0)
+        return self.verdict
 
 
 class CommitError(Exception):
@@ -86,7 +125,8 @@ def _verify_items(items, backend: str):
     batch); key types without batch support (secp256k1) verify singly —
     matching the reference's batchSigIdxs dispatch
     (types/validation.go:274-311, crypto/batch/batch.go:11-35).
-    Raises ErrInvalidSignature naming the first invalid index.
+    Raises ErrInvalidSignature naming the lowest invalid index, on
+    whichever curve it lies.
     """
     if len(items) >= BATCH_VERIFY_THRESHOLD:
         from ..crypto.batch import create_batch_verifier
@@ -114,36 +154,21 @@ def _verify_items(items, backend: str):
                     singles.setdefault(tag, []).append(i)
             sp.add(groups=len(groups),
                    singles=sum(len(v) for v in singles.values()))
-        # Launch every batch group async FIRST (submit() returns an
-        # in-flight handle; on a multi-device mesh each group can land
-        # on a different chip), then verify the singles while the
-        # batches are on device, then resolve. Raise ordering is
-        # PRESERVED exactly: batch groups resolve and raise in group
-        # insertion order before any single verdict raises, which is
-        # what the serial code did — the singles' verdicts are computed
-        # early but deferred.
+        # Launch every leg first, await the verdicts after. The host
+        # legs go first: each is a hand-off to a worker thread (one
+        # ctypes call into the C++ engine, which releases the GIL), so
+        # they run under the ed25519 group's submit(), which packs on
+        # this thread, under its device program, and beside each other.
+        # The verdicts are awaited in reverse: the device's first, so a
+        # host leg's waited_ms is what that overlap did not hide. With
+        # backend "cpu" (the reference path) nothing is launched: each
+        # leg is judged on this thread, in the same order.
         from ..crypto.sched import current_context
 
         sched_ctx = current_context()
-        in_flight = []
-        for tag, (bv, idxs) in groups.items():
-            if bv is None or not idxs:
-                continue
-            t0 = _time.perf_counter()
-            pending = None
-            if sched_ctx is not None and tag == _ED_TAG:
-                # shared-scheduler seam (crypto/sched.py): the filled
-                # verifier coalesces with other tenants'/sources' work
-                # into one mega-dispatch; the handle is
-                # pending-compatible and the bitmap slice is bit-exact
-                pending = sched_ctx.submit(bv)
-            elif backend != "cpu" and hasattr(bv, "submit"):
-                pending = bv.submit()
-                pending.prefetch()
-            in_flight.append((tag, bv, idxs, t0, pending))
-        deferred = []
+        threaded = backend != "cpu"
+        legs: list[_Leg] = []
         for tag, idxs in singles.items():
-            t0 = _time.perf_counter()
             if tag == _SECP_TAG:
                 # no batch equation for secp256k1 (matching the
                 # reference's "no batch support"), but the whole
@@ -153,58 +178,74 @@ def _verify_items(items, backend: str):
                 from ..crypto import native as _native
                 from ..crypto import secp256k1 as _secp
 
-                path = ("native-multi"
-                        if _native.secp256k1_available()
-                        else "single")
-                verdicts = _secp.verify_many(
-                    [(items[i][0].bytes(), items[i][1], items[i][2])
-                     for i in idxs])
+                leg = _Leg(tag, "native-multi"
+                           if _native.secp256k1_available() else "single",
+                           idxs)
+                rows = [(items[i][0].bytes(), items[i][1], items[i][2])
+                        for i in idxs]
+                if threaded:
+                    leg.pending = _secp.submit_many(rows)
+                else:
+                    bits = _secp.verify_many(rows)
+                    leg.verdict = (all(bits), bits)
             else:
-                path = "single"
-                verdicts = [items[i][0].verify_signature(
+                leg = _Leg(tag, "single", idxs)
+                bits = [items[i][0].verify_signature(
                     items[i][1], items[i][2]) for i in idxs]
-            _observe_partition(tag, path, len(idxs),
-                               _time.perf_counter() - t0)
-            deferred.append((idxs, verdicts))
-        for tag, bv, idxs, t0, pending in in_flight:
+                leg.verdict = (all(bits), bits)
+            legs.append(leg.launched())
+        # ed25519 last: the one submit() that works on this thread
+        for tag in sorted(groups, key=lambda t: t == _ED_TAG):
+            bv, idxs = groups[tag]
+            if bv is None or not idxs:
+                continue
+            leg = _Leg(tag, "aggregate" if tag == _BLS_TAG else "batch",
+                       idxs, bv)
+            if sched_ctx is not None and tag == _ED_TAG:
+                # shared-scheduler seam (crypto/sched.py): the filled
+                # verifier coalesces with other tenants'/sources' work
+                # into one mega-dispatch; the handle is
+                # pending-compatible and the bitmap slice is bit-exact
+                leg.pending = sched_ctx.submit(bv)
+            elif threaded and hasattr(bv, "submit"):
+                leg.pending = bv.submit()
+                leg.pending.prefetch()
+            legs.append(leg.launched())
+        # every leg is judged whatever the others found, and blame goes
+        # to the LOWEST bad index of the commit, as the reference's
+        # per-signature loop over a mixed set gives it
+        bad: list[int] = []
+        for leg in reversed(legs):
             pc0 = None
-            if tag == _BLS_TAG:
+            if leg.tag == _BLS_TAG:
                 from ..crypto import bls as _bls
 
                 pc0 = _bls.pairing_checks()
-            if pending is not None:
-                ok, bits = pending.result()
-            else:
-                ok, bits = bv.verify()
-            dt = _time.perf_counter() - t0
-            if pc0 is not None:
+                t0 = _time.perf_counter()
+            ok, bits = leg.resolve()
+            if pc0 is not None and _trace.enabled:
                 # the whole BLS partition collapsed into aggregate
                 # pairing check(s): 1 on accept, +n rescan on blame
-                if _trace.enabled:
-                    _trace.emit("crypto.bls_aggregate", "span",
-                                dur_ms=round(dt * 1e3, 3), n=len(idxs),
-                                pairing_checks=_bls.pairing_checks() - pc0)
-                _observe_partition(tag, "aggregate", len(idxs), dt)
-            else:
-                _observe_partition(tag, "batch", len(idxs), dt)
+                _trace.emit("crypto.bls_aggregate", "span",
+                            dur_ms=round(
+                                (_time.perf_counter() - t0) * 1e3, 3),
+                            n=len(leg.idxs),
+                            pairing_checks=_bls.pairing_checks() - pc0)
             if ok:
                 continue
             if bits:
-                # device bitmap pinpoints failures directly — no rescan
-                for j, b in zip(idxs, bits):
-                    if not b:
-                        raise ErrInvalidSignature(f"invalid signature at index {j}")
+                # the bitmap pinpoints failures directly — no rescan
+                bad.extend(j for j, b in zip(leg.idxs, bits) if not b)
+                continue
             # batch could not localize: fall back to single verification
             # like the reference (:327). If every signature passes singly,
             # the commit is valid — accept.
-            for j in idxs:
-                pub, msg, sig, _ = items[j]
-                if not pub.verify_signature(msg, sig):
-                    raise ErrInvalidSignature(f"invalid signature at index {j}")
-        for idxs, verdicts in deferred:
-            for i, ok in zip(idxs, verdicts):
-                if not ok:
-                    raise ErrInvalidSignature(f"invalid signature at index {i}")
+            bad.extend(j for j in leg.idxs
+                       if not items[j][0].verify_signature(
+                           items[j][1], items[j][2]))
+        if bad:
+            raise ErrInvalidSignature(
+                f"invalid signature at index {min(bad)}")
     else:
         for i, (pub, msg, sig, _) in enumerate(items):
             if not pub.verify_signature(msg, sig):
@@ -306,7 +347,7 @@ def _verify_cert_commit(
         _trace.emit("crypto.bls_aggregate", "span",
                     dur_ms=round(dt * 1e3, 3), n=commit.signer_count(),
                     pairing_checks=_bls.pairing_checks() - pc0)
-    _observe_partition(_BLS_TAG, "aggregate", commit.signer_count(), dt)
+    _observe_partition(_BLS_TAG, "aggregate", dt)
     if not ok:
         _raise_cert_error(bv.error)
 

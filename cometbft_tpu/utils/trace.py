@@ -31,6 +31,12 @@ Design constraints:
   the thread carries that span as ``parent``/``root``. Work that is one
   unit but not nested in time shares a field instead (``window`` on the
   spans of a replay window, ``batch`` on the result() of a submit).
+  A leg launched at one place and awaited at another, with other
+  spans in between (a curve's share of a commit), is a span made by
+  `open_span()`: the same ``id``/``parent``/``root``/``t0_ns``/``t1_ns``
+  and ``dur_ms``, begun at the call and ended by its `close()`, but
+  never on the thread's stack: no span nests under it, it carries no
+  ``self_ms`` and leaves its parent's alone.
   configure() writes one ``trace.clock`` event pairing perf_counter_ns
   with time_ns, which puts every span on the wall clock.
 * Under a profiler session the same spans lie in the profiler's trace:
@@ -322,16 +328,20 @@ class _Span:
             return
         if stack:
             stack[-1]._child_ns += dur_ns
-        rec = {"ts": time.time(), "pid": _pid, "name": self.name,
-               "kind": "span"}
-        if _node:
-            rec["node"] = _node
-        rec.update(id=self.id, parent=self.parent, root=self.root,
-                   t0_ns=self.t0_ns, t1_ns=t1_ns,
-                   dur_ms=round(dur_ns / 1e6, 3),
-                   self_ms=round((dur_ns - self._child_ns) / 1e6, 3))
+        rec = _span_record(self, t1_ns)
+        rec["self_ms"] = round((dur_ns - self._child_ns) / 1e6, 3)
         rec.update(self.fields)
         _record(rec, bool(stack))
+
+
+def _span_record(sp, t1_ns: int) -> dict:
+    """What every span record carries, before its own fields."""
+    rec = {"ts": time.time(), "pid": _pid, "name": sp.name, "kind": "span"}
+    if _node:
+        rec["node"] = _node
+    rec.update(id=sp.id, parent=sp.parent, root=sp.root, t0_ns=sp.t0_ns,
+               t1_ns=t1_ns, dur_ms=round((t1_ns - sp.t0_ns) / 1e6, 3))
+    return rec
 
 
 class _NoopSpan:
@@ -339,6 +349,9 @@ class _NoopSpan:
     id = None
 
     def add(self, **fields) -> None:
+        pass
+
+    def close(self) -> None:
         pass
 
     def __enter__(self):
@@ -356,6 +369,41 @@ def span(name: str, **fields):
     if not enabled:
         return _NOOP
     return _Span(name, fields)
+
+
+class _OpenSpan:
+    """A span in flight: begun by open_span(), ended by close(), off
+    the thread's stack all the while (see the module docstring)."""
+
+    __slots__ = ("name", "fields", "id", "parent", "root", "t0_ns")
+
+    def __init__(self, name: str, fields: dict):
+        self.name = name
+        self.fields = fields
+        self.id = next(_ids)
+        stack = _stack()
+        self.parent = stack[-1].id if stack else None
+        self.root = stack[-1].root if stack else self.id
+        self.t0_ns = time.perf_counter_ns()
+
+    def add(self, **fields) -> None:
+        self.fields.update(fields)
+
+    def close(self) -> None:
+        t1_ns = time.perf_counter_ns()
+        if not enabled:
+            return
+        rec = _span_record(self, t1_ns)
+        rec.update(self.fields)
+        _record(rec, bool(_stack()))
+
+
+def open_span(name: str, **fields):
+    """Begins a span that stays open across other spans of its thread;
+    its `close()` queues the record. The shared no-op when disabled."""
+    if not enabled:
+        return _NOOP
+    return _OpenSpan(name, fields)
 
 
 def _on_gc(phase: str, info: dict) -> None:
@@ -501,7 +549,7 @@ SPAN_REGISTRY = {
     "crypto.device_launch": "jax.device_put of one dispatch's wire arrays plus the jitted call's return (bytes)",
     "crypto.native_verify": "one batch judged by the host C++ engine, blame rescan included (n/ok)",
     "crypto.verdict_wait": "result() blocking on one batch's verdict (path/n/batch = id of its crypto.batch_verify/since_submit_ms/blame_rerun)",
-    "crypto.commit_partition": "per-curve share of one commit verification",
+    "crypto.commit_partition": "one curve's leg of one commit, launch to verdict, a child of types.verify_commit that its sibling legs overlap (curve/path/n/own_ms = the leg's own time on the thread that ran it: the host engine's call on its worker thread, or submit() plus the blocked result() of a device batch/waited_ms = what result() blocked the caller for)",
     "crypto.bls_aggregate": "one BLS partition collapsed to aggregate pairing check(s) (n/pairing_checks)",
     "crypto.mesh_submit": "one sharded mega-batch across the verify mesh (n/b/n_devices/shard_lanes)",
     "crypto.stream_place": "one streamed commit placed on a mesh device (device/n/b)",

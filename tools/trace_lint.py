@@ -6,9 +6,9 @@ tools/trace_analyze.py) keys its reconstruction on literal span names,
 so a name emitted but not declared in `trace.SPAN_REGISTRY` is
 invisible to triage docs, and a declared name with no live call site is
 a stale promise. This lint extracts every literal first argument to
-trace.span()/trace.event()/trace.emit() across the package (plus tools/
-and bench.py, and the two names the tracer writes itself) and checks
-both directions. It holds `trace.KERNEL_SCOPES` to the same rule against
+trace.span()/trace.open_span()/trace.event()/trace.emit() across the
+package (plus tools/ and bench.py, and the two names the tracer writes
+itself) and checks both directions. It holds `trace.KERNEL_SCOPES` to the same rule against
 the phase (jax.named_scope) and pallas_call names in ops/, which the join of a
 profiler trace (utils/traceview.device_join) keys device time on. Exits
 1 on any mismatch.
@@ -34,10 +34,11 @@ EXCLUDE = {
     os.path.abspath(__file__),
 }
 
-# literal name in trace.span("x")/trace.event("x")/trace.emit("x", ...)
+# literal name in trace.span("x")/trace.open_span("x")/trace.event("x")/
+# trace.emit("x", ...)
 # including the `_trace` alias used by modules avoiding name clashes
 CALL_RE = re.compile(
-    r"\b_?trace\.(?:span|event|emit)\(\s*[\"']([^\"']+)[\"']")
+    r"\b_?trace\.(?:span|open_span|event|emit)\(\s*[\"']([^\"']+)[\"']")
 # the tracer's own records (trace.clock, runtime.gc_pause)
 TRACER_RE = re.compile(r"\b(?:event|_Span)\(\s*[\"']([^\"']+)[\"']")
 # kernel scopes in ops/: jax.named_scope("x"),
