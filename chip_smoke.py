@@ -371,6 +371,26 @@ def phase_megacommit(n: int, seed: int):
         log(f"   corrupted commit: verify_commit refused ({e})")
     else:
         raise SystemExit("FAIL: corrupted commit was accepted")
+    # the same bytes as a node gets them, off the wire: a fresh decode
+    # carries its columns, so verify_commit judges them with no CommitSig
+    # built, and must blame the same lane from the device's bitmap
+    from cometbft_tpu.types import Commit
+
+    for label, c, want in (("honest", commit, None),
+                           ("corrupted", bad, f"index {min(why)}")):
+        fresh = Commit.decode(c.encode())
+        try:
+            verify_commit(CHAIN, vals, bid, 1, fresh)
+            got = None
+        except ErrInvalidSignature as e:
+            got = str(e)
+        if (got is None) != (want is None) or (want and want not in got):
+            raise SystemExit(f"FAIL: decoded {label} commit: {got}")
+        if getattr(fresh.signatures, "_real", True) is not None:
+            raise SystemExit(f"FAIL: decoded {label} commit took the "
+                             f"per-slot path")
+    log("   decoded commits (columnar entry): honest accepted, corrupted "
+        "blamed on the same index")
     bad_lanes = commit_lanes(vals, bad)
     ok1, bits1 = verifier(bad_lanes).verify()
     ok2, bits2 = verifier(bad_lanes).verify()

@@ -201,7 +201,8 @@ class ReplayEngine:
         except the first block's was signed by `validators`; the first
         block's was signed by `lc_vals_first`.
         """
-        from ..types.validation import _check_commit_basics, ErrInvalidCommitSize
+        from ..types.validation import (
+            ErrInvalidCommitSize, _check_commit_basics, commit_lanes)
 
         bv = ed25519.Ed25519BatchVerifier(backend=self.backend)
         per_commit: list[tuple[int, int, list[int]]] = []
@@ -222,60 +223,24 @@ class ReplayEngine:
             cert_sigs += commit.signer_count()
 
         def queue_commit_columnar(commit, vals, height, all_sigs):
-            """Whole-commit queueing without per-CommitSig Python: the
-            native decode columns + the frozen set's ed25519 columns
-            feed one vectorized address check, one native sign-bytes
-            build, and one add_batch. Returns False (caller takes the
-            per-slot path) when any precondition is off — non-ed25519
-            keys, hand-built commit, odd flags/lengths — so behavior is
-            byte-identical where it matters and merely slower where it
-            is rare."""
+            """Whole-commit queueing without per-CommitSig Python:
+            validation.commit_lanes (the one copy of the columnar gates)
+            turns the decode columns and the frozen set's key columns
+            into lanes, and one add_batch queues them. Returns False
+            (caller takes the per-slot path) when it declines or the set
+            holds non-ed25519 keys — so behavior is byte-identical where
+            it matters and merely slower where it is rare."""
             nonlocal lane
-            cols = commit.verify_columns()
-            vcols = vals.ed25519_columns()
-            if cols is None or vcols is None:
+            if vals.ed25519_columns() is None:
                 return False
-            flags, addrs, addr_lens, sig_lens, sigs, _, _ = cols
-            addr_rows, pub_rows, powers = vcols
-            absent = flags == 1
-            # light (tip) semantics verify only COMMIT votes
-            # (reference VerifyCommitLight); full semantics verify
-            # every non-absent signature
-            live = ~absent if all_sigs else flags == 2
-            # structural gates: only ABSENT/COMMIT/NIL flags, 20-byte
-            # addresses and 64-byte signatures on verified lanes
-            if not (
-                (absent | (flags == 2) | (flags == 3)).all()
-                and (addr_lens[live] == 20).all()
-                and (sig_lens[live] == 64).all()
-                and (addr_lens[absent] == 0).all()
-            ):
-                return False
-            if not (addrs[live] == addr_rows[live]).all():
-                return False  # per-slot path localizes the mismatch
-            sb = commit.vote_sign_bytes_blob(chain_id)
-            if sb is None:
-                return False
-            msg_blob, lens = sb
-            if live.all():
-                bv.add_batch(pub_rows, sigs, msg_blob, lens)
-            else:
-                import numpy as _np
-
-                idx = _np.nonzero(live)[0]
-                offs = _np.zeros(len(lens) + 1, _np.int64)
-                _np.cumsum(lens, out=offs[1:])
-                parts = [
-                    msg_blob[offs[i]:offs[i + 1]] for i in idx
-                ]
-                bv.add_batch(
-                    pub_rows[idx], sigs[idx], b"".join(parts), lens[idx]
-                )
-            lane += int(live.sum())
-            commit_power = int(powers[flags == 2].sum())
+            lanes = commit_lanes(chain_id, vals, commit, all_sigs)
+            if isinstance(lanes, str):
+                return False  # per-slot path localizes what is off
+            lanes.add_ed25519(bv)
+            lane += lanes.n
             per_commit.append(
                 (height, vals.total_voting_power() * 2 // 3,
-                 (commit_power,))
+                 (lanes.power,))
             )
             return True
 

@@ -562,7 +562,7 @@ class Ed25519BatchVerifier(BatchVerifier):
         self._pub_buf += pub_rows.tobytes()
         self._sig_buf += sig_rows.tobytes()
         self._msg_buf += msg_blob
-        self._msg_lens.extend(int(x) for x in msg_lens)
+        self._msg_lens.extend(np.asarray(msg_lens).tolist())
         self._lazy.append((pub_rows, sig_rows, msg_blob, msg_lens))
         self._delta = None
 

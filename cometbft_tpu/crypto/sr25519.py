@@ -194,6 +194,12 @@ class Sr25519BatchVerifier(BatchVerifier):
         self._items.append((pub_key.bytes(), msg, sig))
         return True
 
+    def add_rows(self, rows) -> None:
+        """add() for a commit's worth of (pub, msg, sig) rows whose
+        caller has checked the key type and the 64-byte signatures (the
+        columnar commit path gates on both)."""
+        self._items.extend(rows)
+
     def count(self) -> int:
         return len(self._items)
 

@@ -12,6 +12,7 @@ truncated division), since priorities are consensus-visible state.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 from ..crypto import merkle
 from ..crypto.keys import PubKey
@@ -100,6 +101,19 @@ class Validator:
         )
 
 
+class KeyColumns(NamedTuple):
+    """ValidatorSet.key_columns(): the set in validator order.
+
+    addr_rows (n,20) u8, or None if some address is not 20 bytes;
+    powers (n,) i64; curves maps each key type tag to (validator
+    indices ascending, pubkey rows (k, width) u8, or None if the keys of
+    that type differ in width)."""
+
+    addr_rows: object
+    powers: object
+    curves: dict
+
+
 def _sort_key(v: Validator):
     # voting power desc, then address asc
     return (-v.voting_power, v.address)
@@ -170,37 +184,63 @@ class ValidatorSet:
             self.__dict__["_hash_memo"] = h
         return h
 
-    def ed25519_columns(self):
-        """(addr_rows (n,20) u8, pub_rows (n,32) u8, powers i64) numpy
-        columns for the batch-verify fast path, or None when any key is
-        not ed25519. Memoized — replay verifies the same frozen set for
-        thousands of consecutive commits."""
-        cols = self.__dict__.get("_ed_cols", False)
-        if cols is not False:
+    def _members_memo(self) -> dict:
+        """What is derived from the membership alone ((address, pubkey,
+        power) in order), shared with every copy(): state hands each
+        height a fresh copy (proposer rotation), made before anyone has
+        asked the source for its columns, so a memo kept per object would
+        be rebuilt per block. update_with_change_set gives this set a
+        membership, and a memo, of its own."""
+        memo = self.__dict__.get("_members")
+        if memo is None:
+            memo = self.__dict__["_members"] = {}
+        return memo
+
+    def key_columns(self) -> KeyColumns:
+        """The set as numpy columns, for whole-commit verification
+        without a trip through each Validator. Memoized per membership:
+        the same set judges thousands of consecutive commits."""
+        memo = self._members_memo()
+        cols = memo.get("key_cols")
+        if cols is not None:
             return cols
         import numpy as np
 
-        cols = None
-        try:
-            pubs = []
-            for v in self.validators:
-                pk = v.pub_key
-                if pk.type_tag() != "tendermint/PubKeyEd25519":
-                    raise ValueError
-                pubs.append(pk.bytes())
-            n = len(self.validators)
-            cols = (
-                np.frombuffer(
-                    b"".join(v.address for v in self.validators), np.uint8
-                ).reshape(n, 20),
-                np.frombuffer(b"".join(pubs), np.uint8).reshape(n, 32),
-                np.asarray([v.voting_power for v in self.validators],
-                           np.int64),
-            )
-        except ValueError:
-            cols = None
-        self.__dict__["_ed_cols"] = cols
+        n = len(self.validators)
+        by_tag: dict[str, tuple[list[int], list[bytes]]] = {}
+        for i, v in enumerate(self.validators):
+            idxs, pubs = by_tag.setdefault(v.pub_key.type_tag(), ([], []))
+            idxs.append(i)
+            pubs.append(v.pub_key.bytes())
+        curves = {}
+        for tag, (idxs, pubs) in by_tag.items():
+            width = len(pubs[0])
+            rows = None
+            if width and all(len(p) == width for p in pubs):
+                rows = np.frombuffer(b"".join(pubs), np.uint8).reshape(
+                    len(pubs), width)
+            curves[tag] = (np.asarray(idxs, np.int64), rows)
+        addrs = [v.address for v in self.validators]
+        cols = KeyColumns(
+            np.frombuffer(b"".join(addrs), np.uint8).reshape(n, 20)
+            if all(len(a) == 20 for a in addrs) else None,
+            np.asarray([v.voting_power for v in self.validators], np.int64),
+            curves,
+        )
+        memo["key_cols"] = cols
         return cols
+
+    def ed25519_columns(self):
+        """(addr_rows (n,20) u8, pub_rows (n,32) u8, powers i64): the
+        view of key_columns() an all-ed25519 set gives, or None when any
+        key is of another type."""
+        cols = self.key_columns()
+        if list(cols.curves) != ["tendermint/PubKeyEd25519"]:
+            return None
+        _, pub_rows = cols.curves["tendermint/PubKeyEd25519"]
+        if pub_rows is None or cols.addr_rows is None:
+            return None
+        return cols.addr_rows, pub_rows, cols.powers
 
     def all_bls(self) -> bool:
         """True when every validator key is BLS12-381 — the gate for
@@ -241,6 +281,7 @@ class ValidatorSet:
         memo = self.__dict__.get("_hash_memo")
         if memo is not None:  # same membership -> same hash
             vs.__dict__["_hash_memo"] = memo
+        vs.__dict__["_members"] = self._members_memo()
         return vs
 
     # --- proposer priority machinery ---
@@ -381,7 +422,7 @@ class ValidatorSet:
         self._total_power = None
         self._addr_index = None
         self.__dict__.pop("_hash_memo", None)
-        self.__dict__.pop("_ed_cols", None)
+        self.__dict__.pop("_members", None)
         self.total_voting_power()
         # scale into the priority window, then center (reference order)
         self.rescale_priorities(

@@ -533,6 +533,13 @@ class CryptoMetrics:
             "Dispatch decisions per verify path "
             "(native/rlc/ladder/delta/cpu) and curve",
             labels=("path", "curve"))
+        self.commit_path_total = reg.counter(
+            "crypto", "commit_path_total",
+            "verify_commit / verify_commit_light calls by how the "
+            "commit became lanes: columnar (from its decode columns) or "
+            "per_slot, with the gate that declined (no_columns/"
+            "no_native/shape/key_type/address)",
+            labels=("path", "reason"))
         self.verify_seconds = reg.histogram(
             "crypto", "verify_seconds",
             "Batch-verify wall time submit→result",

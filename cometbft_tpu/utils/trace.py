@@ -533,8 +533,8 @@ SPAN_REGISTRY = {
     "consensus.cert_aggregate": "one aggregate-precommit certificate verified from catchup gossip (height/round/signers/outcome/dur_ms)",
     "state.apply_block": "ApplyBlock with validate/finalize/commit/save stage breakdown",
     "types.verify_commit": "one verify_commit / verify_commit_light (height/n = signatures judged/light); self_ms is the entry layer from inside",
-    "types.commit_items": "the per-signature loop of one commit: CommitSig access, address check, sign bytes (n/sign_bytes_ms accumulated)",
-    "types.verify_items_fill": "_verify_items up to the first submit: grouping by key type and the add() loop (n/groups/singles)",
+    "types.commit_items": "one commit turned into lanes: gates, address check, sign bytes (n/sign_bytes_ms = time inside the sign-bytes build/path = columnar: from the decode columns by validation.commit_lanes, no CommitSig built, or per_slot: the per-signature loop/reason, per_slot only = the gate that declined: no_columns, no_native, shape, key_type, address)",
+    "types.verify_items_fill": "the lanes filled into their verifiers, up to the first submit: one add_batch plus the minority curves' rows on the columnar path, grouping by key type and the add() loop on the per-slot path (n/groups/singles)",
     "blocksync.block": "one fast-synced block: fetch→verify→apply breakdown",
     "blocksync.replay": "one ReplayEngine.run (from/to/depth/mode); self_ms is the engine loop",
     "blocksync.window_load": "blocks of one replay window read and decoded from the store (window = first height/blocks)",

@@ -79,6 +79,9 @@ def test_verify_adjacent_rejects_tampering(chain):
     sig0 = bad.commit.signatures[0]
     orig = sig0.signature
     sig0.signature = bytes(64)
+    # the commit came through Commit.decode: a slot mutated in place
+    # must drop the decode memos (Commit.invalidate_memos' contract)
+    bad.commit.invalidate_memos()
     with pytest.raises(ErrInvalidSignature):
         verify_adjacent(
             CHAIN, t.signed_header, bad, u.validators, PERIOD, NOW,
@@ -105,6 +108,7 @@ def test_verify_stream_and_corruption(chain):
     victim = stream[4].signed_header.commit.signatures[2]
     orig = victim.signature
     victim.signature = orig[:-1] + bytes([orig[-1] ^ 1])
+    stream[4].signed_header.commit.invalidate_memos()
     with pytest.raises(ErrInvalidSignature):
         verify_stream(CHAIN, trusted, stream, PERIOD, NOW, backend="cpu")
     victim.signature = orig
