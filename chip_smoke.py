@@ -37,7 +37,7 @@ import threading
 import time
 import urllib.request
 
-DEVICE_PATHS = ("ladder", "delta", "rlc", "mesh")
+DEVICE_PATHS = ("ladder", "mesh")
 CHAIN = "chip-smoke"
 
 
@@ -51,14 +51,12 @@ class Probe:
     crypto/ed25519.py imports at call time), which path each batch took
     (the program's own crypto.batch_verify trace spans)."""
 
-    VERIFY_FNS = ("decompress_pubkeys", "verify_batch_cached_a",
-                  "verify_batch_delta", "verify_batch", "rlc_verify_stream")
+    VERIFY_FNS = ("decompress_pubkeys", "verify_batch_cached_a")
 
     def __init__(self, workdir: str, on_chip: bool):
         import jax
 
         from cometbft_tpu.ops import ed25519_verify as EV
-        from cometbft_tpu.ops import msm as MSM
         from cometbft_tpu.utils import trace
 
         self.on_chip = on_chip
@@ -68,10 +66,9 @@ class Probe:
         jax.monitoring.register_event_duration_secs_listener(self._on_dur)
         self.programs: dict[tuple, tuple] = {}  # signature -> (jit, a, kw)
         self._checked: set[tuple] = set()
-        for mod, names in ((EV, self.VERIFY_FNS[:4]), (MSM, self.VERIFY_FNS[4:])):
-            for name in names:
-                setattr(mod, name + "_jit",
-                        self._recorded(name, getattr(mod, name + "_jit")))
+        for name in self.VERIFY_FNS:
+            setattr(EV, name + "_jit",
+                    self._recorded(name, getattr(EV, name + "_jit")))
         self.workdir = workdir
         self.trace_path = os.path.join(workdir, "smoke_trace.jsonl")
         trace.configure(self.trace_path)
@@ -201,7 +198,7 @@ def run_phase(probe: Probe, name: str, fn):
     # the counter also carries verify_commit's per-curve partition labels
     # and single-signature verifies; the dispatch's own labels are these
     counted = sum(v for k, v in diff.items() if k[1] == "ed25519"
-                  and k[0] in DEVICE_PATHS + ("native", "cpu", "device_sha"))
+                  and k[0] in DEVICE_PATHS + ("native", "cpu"))
     if spans != counted:
         raise SystemExit(f"FAIL: {spans} dispatch spans but the counter "
                          f"moved by {counted}")
@@ -427,11 +424,13 @@ def more_lanes(lanes, n: int, seed: int):
 
 
 def _engine_timings(probe: Probe, lanes, engine: str, calls: int = 5):
-    """One engine as submit() launches it, warm: medians over `calls`
-    batches of submit() (host) and submit -> verdict in ms, and device ms a
-    batch from a profiler trace of the same calls (busy time under the
-    engine's kernel scopes, the reduction of tools/trace_analyze.py device).
-    A batch whose RLC layout declined ran on the ladder: it is redrawn."""
+    """One device engine as submit() launches it, warm: medians over
+    `calls` batches of submit() (host) and submit -> verdict in ms, and
+    device ms a batch from a profiler trace of the same calls (busy time
+    under the ladder's kernel scopes, which the mesh's shards run too, the
+    reduction of tools/trace_analyze.py device; for the mesh the mean over
+    its devices). The ladder is pinned by force_perlane, the mesh launched
+    as submit() launches it where the model gives it the batch."""
     import glob
 
     import jax
@@ -440,153 +439,108 @@ def _engine_timings(probe: Probe, lanes, engine: str, calls: int = 5):
     from cometbft_tpu.utils import traceview, xplane
     from cometbft_tpu.utils.trace import KERNEL_SCOPES
 
+    mesh = E._mesh_engine()
+
     def one():
-        bv = verifier(lanes, force_perlane=engine == "ladder")
+        bv = verifier(lanes, force_perlane=True)
         t0 = time.perf_counter()
-        pend = bv.submit()
+        pend = bv._launch_mesh(mesh) if engine == "mesh" else bv.submit()
         t1 = time.perf_counter()
         ok, _bits = pend.result()
         t2 = time.perf_counter()
         if not ok:
             raise SystemExit(f"FAIL: {engine} refused honest lanes")
-        return pend._path, (t1 - t0) * 1e3, (t2 - t0) * 1e3
+        return (t1 - t0) * 1e3, (t2 - t0) * 1e3
 
-    # plain submit() picks by the model; to put RLC under the clock the
-    # script pins the pick for the length of this measurement
-    model_pick = E._rlc_beats_ladder
-    if engine == "rlc":
-        E._rlc_beats_ladder = lambda n, b: True
     prof = os.path.join(probe.workdir, f"profile_{engine}_{len(lanes)}")
+    one()  # compiles or loads, fills the pubkey cache
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0  # device operations and our spans only
+    # off the chip XLA:CPU books every thunk as a host event: 84 MB and
+    # half a minute for three calls, and no device plane to read
+    opts.host_tracer_level = 1 if probe.on_chip else 0
+    jax.profiler.start_trace(prof, profiler_options=opts)
     try:
-        one()  # compiles or loads, fills the pubkey cache
-        took = []
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0  # device operations and our spans only
-        # off the chip XLA:CPU books every thunk as a host event: 84 MB and
-        # half a minute for three calls, and no device plane to read
-        opts.host_tracer_level = 1 if probe.on_chip else 0
-        jax.profiler.start_trace(prof, profiler_options=opts)
-        try:
-            for _ in range(4 * calls):
-                took.append(one())
-                if sum(p == engine for p, _, _ in took) == calls:
-                    break
-        finally:
-            jax.profiler.stop_trace()
+        took = [one() for _ in range(calls)]
     finally:
-        E._rlc_beats_ladder = model_pick
-    mine = [t for t in took if t[0] == engine]
-    if len(mine) < calls:
-        raise SystemExit(f"FAIL: {engine} took {len(mine)} of {len(took)} "
-                         f"batches of {len(lanes)} lanes")
-    out = {"submit_ms": statistics.median(t[1] for t in mine),
-           "submit_to_verdict_ms": statistics.median(t[2] for t in mine),
-           "declined": len(took) - len(mine), "device_ms": None}
+        jax.profiler.stop_trace()
+    out = {"submit_ms": statistics.median(t[0] for t in took),
+           "submit_to_verdict_ms": statistics.median(t[1] for t in took),
+           "device_ms": None}
     pbs = sorted(glob.glob(os.path.join(prof, "**", "*.xplane.pb"),
                            recursive=True))
     try:
         j = traceview.device_join(xplane.load(pbs[-1]), scopes=KERNEL_SCOPES)
     except (IndexError, ValueError):
         return out  # no device plane in the trace (off the chip)
-    by_scope = {k: v for k, v in j["busy_by_scope"]
-                if k.startswith(engine + ".")}
+    by_scope = {k: v for k, v in j["busy_by_scope"] if k.startswith("ladder.")}
     if by_scope:
-        out["device_ms"] = sum(by_scope.values()) / len(mine) * 1e3
-        out["by_scope_ms"] = {k: round(v / len(mine) * 1e3, 3)
+        share = calls * (mesh.n_devices if engine == "mesh" else 1)
+        out["device_ms"] = sum(by_scope.values()) / share * 1e3
+        out["by_scope_ms"] = {k: round(v / share * 1e3, 3)
                               for k, v in by_scope.items()}
     return out
 
 
 def phase_device_terms(probe: Probe, lanes, sizes, seed: int):
-    """The dispatch model's device terms, measured again: both engines as
-    submit() launches them, warm, at the live lane counts of the cells' two
-    buckets (`sizes`), and from the two sizes each engine's fixed and
-    per-lane term beside what crypto/ed25519.py assumes. Nothing is
-    re-derived here: the printout is what a change of the constants is
-    made from."""
-    import numpy as np
-
+    """The dispatch model's device terms, measured again: the ladder (and,
+    where a mesh is up, the mesh) as submit() launches it, warm, at the
+    live lane counts of the cells' two buckets (`sizes`), and from the two
+    sizes each engine's fixed and per-lane term beside what
+    crypto/ed25519.py assumes. Nothing is re-derived here: the printout is
+    what a change of the constants is made from."""
     from cometbft_tpu.crypto import ed25519 as E
 
     host = E._host_terms()
     log(f"   link probe _link_mbps() = {E._link_mbps():.1f} MB/s; host "
-        f"terms ladder {host['ladder_us']:.3f} rlc {host['rlc_us']:.3f} "
-        f"us/sig (rlc threads {host['rlc_threads']}, native "
-        f"{host['rlc_native']}), calibrated={host['calibrated']}")
+        f"term ladder {host['ladder_us']:.3f} us/sig, "
+        f"calibrated={host['calibrated']}")
     if not host["calibrated"]:
         raise SystemExit("FAIL: host dispatch terms fell back to the "
                          "written-down constants (calibration failed)")
     where = "device" if probe.on_chip else "NOT A DEVICE NUMBER (rehearsal)"
+    mesh = E._mesh_engine()
+    engines = ("ladder", "mesh") if mesh is not None else ("ladder",)
 
-    got: dict[str, dict[int, dict]] = {"ladder": {}, "rlc": {}}
+    got: dict[str, dict[int, dict]] = {eng: {} for eng in engines}
     for n in sizes:
         big = more_lanes(lanes, n, seed)
         b = E._bucket(n)
         mdl = E.dispatch_model(n, b)
-        pick = ("rlc" if n >= E.RLC_MIN and E._rlc_beats_ladder(n, b)
-                else "ladder")
-        log(f"   n={n} bucket={b}: the model says ladder "
-            f"{mdl['ladder']['device'] * 1e3:.2f} ms, rlc "
-            f"{mdl['rlc']['device'] * 1e3:.2f} ms of device (t_ladder "
-            f"{mdl['t_ladder'] * 1e3:.2f}, t_rlc {mdl['t_rlc'] * 1e3:.2f}); "
-            f"plain submit() picks {pick} when the device is real")
-        # RLC: on the chip always; off it an XLA:CPU compile of the graph
-        # takes minutes and proves nothing
-        for eng in ("ladder", "rlc") if probe.on_chip else ("ladder",):
+        log(f"   n={n} bucket={b}: the model says " + ", ".join(
+            f"{eng} {mdl[eng]['device'] * 1e3:.2f} ms of device "
+            f"(t_{eng} {mdl['t_' + eng] * 1e3:.2f})" for eng in engines))
+        for eng in engines:
             r = got[eng][n] = _engine_timings(probe, big, eng)
-            # the same program called again with its inputs on the device
-            name = ("verify_batch_cached_a" if eng == "ladder"
-                    else "rlc_verify_stream")
-            r["kernel_ms"] = _median_call_s(*probe.latest(name, b)) * 1e3
+            # the ladder's program called again, its inputs on the device
+            r["kernel_ms"] = (_median_call_s(
+                *probe.latest("verify_batch_cached_a", b)) * 1e3
+                if eng == "ladder" else None)
             dev = (f"{r['device_ms']:.3f} ms in the profile "
                    f"{r.get('by_scope_ms')}" if r["device_ms"] is not None
                    else "not in a profile")
-            log(f"     {eng:<6} {where}: {dev}; blocked call "
-                f"{r['kernel_ms']:.3f} ms; submit() {r['submit_ms']:.3f} ms, "
-                f"submit -> verdict {r['submit_to_verdict_ms']:.3f} ms; "
-                f"{r['declined']} layouts declined")
+            blocked = (f"; blocked call {r['kernel_ms']:.3f} ms"
+                       if r["kernel_ms"] is not None else "")
+            log(f"     {eng:<6} {where}: {dev}{blocked}; submit() "
+                f"{r['submit_ms']:.3f} ms, submit -> verdict "
+                f"{r['submit_to_verdict_ms']:.3f} ms")
 
     n0, n1 = sizes
-    for eng, fixed, per_lane in (
-        ("ladder", E._DEV_LADDER_FIXED_MS, E._DEV_LADDER_US),
-        ("rlc", E._DEV_RLC_FIXED_MS, E._DEV_RLC_US),
-    ):
-        if len(got[eng]) < 2:
-            log(f"   {eng}: not measured; assumed {fixed} ms + n x "
-                f"{per_lane} us")
-            continue
+    for eng in engines:
+        fixed, per_lane = E._DEV_LADDER_FIXED_MS, E._DEV_LADDER_US
+        if eng == "mesh":
+            fixed += mesh.dispatch_terms()["collective_s"] * 1e3
+            per_lane /= mesh.n_devices
         t0, t1 = (got[eng][n]["device_ms"] or got[eng][n]["kernel_ms"]
                   for n in sizes)
+        if t0 is None or t1 is None:
+            log(f"   {eng}: not measured; assumed {fixed:.2f} ms + n x "
+                f"{per_lane:.3f} us")
+            continue
         us = (t1 - t0) / (n1 - n0) * 1e3
         log(f"   {eng}, {where}: fixed {t0 - n0 * us * 1e-3:.2f} ms + n x "
             f"{us:.3f} us through ({n0}, {t0:.2f} ms) and ({n1}, {t1:.2f} "
-            f"ms); assumed {fixed} ms + n x {per_lane} us")
-
-    n, b = n0, E._bucket(n0)
-    delta_prog = probe.latest("verify_batch_delta", b)
-    if (probe.on_chip or delta_prog is not None) and b <= E.DELTA_MAX_BUCKET:
-        d = E._detect_delta([(p.bytes(), m, s) for p, m, s in lanes[:n]])
-        if d:
-            bv = verifier(lanes[:n])
-            bv._materialize()
-            _bits, all_ok = bv._launch_device_delta(d)
-            assert bool(np.asarray(all_ok))
-            s = _median_call_s(*probe.latest("verify_batch_delta", b))
-            log(f"   delta kernel, {where}: {s * 1e3:.3f} ms = "
-                f"{s / n * 1e6:.3f} us/sig; assumed _DEV_DELTA_US (an "
-                f"end-to-end figure) = {E._DEV_DELTA_US}")
-        else:
-            log("   delta: these sign bytes share too little structure")
-    # the ladder end to end: packed, shipped, verified, fetched, 8 deep
-    bvs = [verifier(lanes[:n], force_perlane=True) for _ in range(8)]
-    E.collect_pending([bv.submit() for bv in bvs])
-    t0 = time.perf_counter()
-    res = E.collect_pending([bv.submit() for bv in bvs])
-    e2e = (time.perf_counter() - t0) / len(bvs)
-    assert all(ok for ok, _ in res)
-    log(f"   ladder end to end, 8 in flight, {where}: {e2e * 1e3:.3f} ms = "
-        f"{e2e / n * 1e6:.3f} us/sig; assumed _DEV_PREHASH_US = "
-        f"{E._DEV_PREHASH_US}")
+            f"ms); assumed {fixed:.2f} ms + n x {per_lane:.3f} us")
     return got
 
 
@@ -648,7 +602,6 @@ def phase_catchup(workdir: str, n_blocks: int, n_vals: int, window: int,
 
     # a second chain with one corrupted signature: refused at that height.
     # Two full windows, so its batches have the shapes already compiled
-    # (a partial window is a new RLC stream shape, ~110 s of compile)
     n2 = 2 * window
     h_bad, idx_bad = window + window // 4, n_vals // 3
     db2 = os.path.join(workdir, "blockstore_bad.db")
@@ -837,9 +790,8 @@ def phase_mesh(probe: Probe, n: int, seed: int):
     assert pend.result()[0]
     mdl = E.dispatch_model(n, b)
     log(f"   plain submit() of {n} lanes on this host chose: {pend._path} "
-        f"(model ms: ladder {mdl['t_ladder'] * 1e3:.2f} rlc "
-        f"{mdl['t_rlc'] * 1e3:.2f} mesh {mdl['t_mesh'] * 1e3:.2f}; link "
-        f"{mdl['link_mbps']:.0f} MB/s)")
+        f"(model ms: ladder {mdl['t_ladder'] * 1e3:.2f} mesh "
+        f"{mdl['t_mesh'] * 1e3:.2f}; link {mdl['link_mbps']:.0f} MB/s)")
 
 
 # ---------------------------------------------------------------------
@@ -852,8 +804,9 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny sizes, no platform assertion")
     ap.add_argument("--terms", action="store_true",
-                    help="only the device-terms phase: both engines at both "
-                         "of the cells' buckets, beside the dispatch's terms")
+                    help="only the device-terms phase: the ladder (with "
+                         "--chips 4 the mesh too) at both of the cells' "
+                         "buckets, beside the dispatch's terms")
     args = ap.parse_args(argv)
     t_start = time.perf_counter()
 
@@ -881,7 +834,7 @@ def main(argv=None) -> int:
            else "(the fixed in-checkout path)" if cache == CACHE_DIR
            else "(set by the caller)")
         + f", {len(os.listdir(cache)) if os.path.isdir(cache) else 0} entries")
-    if not (native.available() and native.rlc_available()):
+    if not native.available():
         raise SystemExit(f"FAIL: the host C++ engine is not available: "
                          f"{native.build_state()}")
     bs = native.build_state()
@@ -900,16 +853,16 @@ def main(argv=None) -> int:
         # live lanes of the two buckets the cells use (10240, 65536); the
         # rehearsal's two sizes share one bucket, so its fit says nothing
         term_sizes = (24, 48) if small else (10_000, 65_000)
-        if args.chips == 4:
-            _, rec = run_phase(probe, "mesh-4",
-                               lambda: phase_mesh(probe, n_mega, args.seed))
-            phases.append(rec)
-        elif args.terms:
+        if args.terms:
             vals, _bid, commit, _weird = build_commit(n_mega, args.seed)
             _, rec = run_phase(
                 probe, "device-terms",
                 lambda: phase_device_terms(
                     probe, commit_lanes(vals, commit), term_sizes, args.seed))
+            phases.append(rec)
+        elif args.chips == 4:
+            _, rec = run_phase(probe, "mesh-4",
+                               lambda: phase_mesh(probe, n_mega, args.seed))
             phases.append(rec)
         else:
             lanes, rec = run_phase(
@@ -933,14 +886,12 @@ def main(argv=None) -> int:
                                    4 if small else 16, args.seed))
             phases.append(rec)
         verify = [c for c in probe.compiles if probe.is_verify(c["fn"])]
-        declined = sum(p["gave"].get("rlc_declined", 0) for p in phases)
         log("summary: " + json.dumps({
             "phases": {p["name"]: round(p["s"], 1) for p in phases},
             "device_batches": sum(p["device_batches"] for p in phases),
             "verify_shapes_compiled": sum(not c["cache_hit"] for c in verify),
             "verify_shapes_cache_hits": sum(c["cache_hit"] for c in verify),
             "compile_s": round(sum(c["s"] for c in probe.compiles), 1),
-            "rlc_declined_batches": declined,
             "wall_s": round(time.perf_counter() - t_start, 1),
         }))
         if on_chip and not any(p["device_batches"] for p in phases):

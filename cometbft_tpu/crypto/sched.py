@@ -13,7 +13,7 @@ the crypto dispatch:
   per-class queues --drainer--> coalesced mega-batch (absorb() merges
   the filled verifiers lane-exactly, recording each request's
   [start, end) range) --> ONE dispatch through the existing
-  native/RLC/mesh path --> per-request verdict slices, bit-exact vs
+  native/ladder/mesh path --> per-request verdict slices, bit-exact vs
   what each consumer's own dispatch would have returned.
 
 Scheduling policy:

@@ -17,17 +17,8 @@ import numpy as np
 from . import ed25519_ref as ref
 
 
-def generate_signed_batch(
-    n: int, seed: int = 0, msg_len: int = 120, vote_shaped: bool = False
-):
-    """Returns list of (pubkey32, msg, sig64) with distinct keys/messages.
-
-    vote_shaped=True mirrors canonical precommit sign bytes (reference
-    types/canonical.go): a commit-invariant prefix (type, height, round,
-    block id), ~8 bytes of per-vote timestamp in the middle, and a
-    shared chain-id suffix. Replay and commit verification hash exactly
-    this shape, which is what the structured-wire fast path
-    (crypto/ed25519._detect_delta) exploits."""
+def generate_signed_batch(n: int, seed: int = 0, msg_len: int = 120):
+    """Returns list of (pubkey32, msg, sig64) with distinct keys/messages."""
     import jax
     import jax.numpy as jnp
 
@@ -36,14 +27,7 @@ def generate_signed_batch(
     rng = np.random.default_rng(seed)
     a_sc = [int.from_bytes(rng.bytes(32), "little") % ref.L for _ in range(n)]
     r_sc = [int.from_bytes(rng.bytes(32), "little") % ref.L for _ in range(n)]
-    if vote_shaped:
-        mid_len = 8
-        sfx_len = 16
-        pfx = rng.bytes(msg_len - mid_len - sfx_len)
-        sfx = rng.bytes(sfx_len)
-        msgs = [pfx + rng.bytes(mid_len) + sfx for _ in range(n)]
-    else:
-        msgs = [rng.bytes(msg_len) for _ in range(n)]
+    msgs = [rng.bytes(msg_len) for _ in range(n)]
 
     @jax.jit
     def fixed_base_compress(digs):

@@ -1331,9 +1331,8 @@ void ed25519_pack_rsk(u64 n, const u8 *sigs, const u8 *pubs, const u8 *msgs,
 
 }  // extern "C"
 
-// RLC/MSM host packer — native port of crypto/rlc.py prepare
-// (own extern "C" exports: rlc_pack, rlc_packer_threads)
-#include "rlc_packer.inc"
+// The worker pool the engines below split their batches over
+#include "worker_pool.inc"
 
 // SHA-256 + RFC-6962 merkle root engine (own extern "C" exports)
 #include "merkle_native.inc"
@@ -1343,7 +1342,7 @@ void ed25519_pack_rsk(u64 n, const u8 *sigs, const u8 *pubs, const u8 *msgs,
 
 // secp256k1 ECDSA verify engine — 5x52 field, wNAF Strauss–Shamir
 // (own extern "C" exports: secp256k1_verify, secp256k1_multi_verify;
-// uses sha256_oneshot from merkle_native.inc, pool from rlc_packer.inc)
+// uses sha256_oneshot from merkle_native.inc, pool from worker_pool.inc)
 #include "secp256k1.inc"
 
 // sr25519 batch verification — merlin/STROBE transcripts, ristretto
@@ -1352,15 +1351,15 @@ void ed25519_pack_rsk(u64 n, const u8 *sigs, const u8 *pubs, const u8 *msgs,
 #include "sr25519_native.inc"
 
 // BLS12-381 pairing engine — aggregate-signature track (own extern "C"
-// exports; uses sha256n from merkle_native.inc, pool from rlc_packer.inc)
+// exports; uses sha256n from merkle_native.inc, pool from worker_pool.inc)
 #include "bls12_381.inc"
 
 // GF(2^16) Reed-Solomon erasure codec — data-availability sampling
 // track (own extern "C" exports: rs_encode16, rs_reconstruct16,
-// rs_gf16_threads; uses the pool from rlc_packer.inc)
+// rs_gf16_threads; uses the pool from worker_pool.inc)
 #include "rs_gf16.inc"
 
 // BLS12-381 G1 Pippenger MSM — KZG polynomial-commitment opening
 // engine (own extern "C" exports: g1_msm, g1_msm_threads; uses the
-// G1 core from bls12_381.inc, pool from rlc_packer.inc)
+// G1 core from bls12_381.inc, pool from worker_pool.inc)
 #include "g1_msm.inc"

@@ -9,7 +9,7 @@ Pieces:
 
 - ``VerifiedCommitCache`` — height-keyed, single-flight, LRU-bounded.
   The first caller for a height runs ``verify_commit_light`` (through
-  mesh/native/RLC dispatch); every concurrent and later caller waits on
+  native/ladder/mesh dispatch); every concurrent and later caller waits on
   the in-flight entry or hits the cached verdict. Hit/miss counters
   prove the fan-out amortization.
 - ``LightServe`` — maintains the MMR header accumulator incrementally

@@ -265,7 +265,7 @@ class AdmissionPipeline:
         n_dup = len(batch) - len(live)
 
         # stage 1 — one batch signature verify for the window's signed
-        # envelopes, through the crypto dispatch (native/rlc/ladder)
+        # envelopes, through the crypto dispatch (native/ladder/mesh)
         n_sig_fail = 0
         t1 = time.perf_counter()
         if _txlife.enabled:

@@ -10,9 +10,9 @@ import os as _os
 import jax as _jax
 
 # Persistent XLA compilation cache: on the chip's compiler each verify
-# shape takes ~25-30 s and the RLC graph ~100-130 s (Mosaic kernels
-# dominate, whatever the bucket), so a cold process that warms what it
-# uses pays minutes; with the cache a second process pays none.
+# shape takes ~25-30 s (the Mosaic kernel dominates, whatever the
+# bucket), so a cold process that warms what it uses pays minutes; with
+# the cache a second process pays none.
 #
 # Where it lives is decided from outside: jax itself reads
 # JAX_COMPILATION_CACHE_DIR, and when that is set nothing here touches
@@ -45,10 +45,9 @@ if "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES" not in _os.environ:
 # kernel's serialized Mosaic module, and by default jax writes the Python
 # call stack of every op (ten frames of it) into that module's locations
 # — which the key's canonicalisation does not strip. The same shape first
-# reached through another caller (verify_commit, the replay engine, RLC's
-# blame fallback) then has another key and compiles again: on the chip a
-# warm run recompiled both ladder shapes while the RLC graph, always
-# first traced from the same stack, hit (PR 21). One frame: locations
+# reached through another caller (verify_commit, the replay engine, a
+# blame re-run) then has another key and compiles again: on the chip a
+# warm run recompiled both ladder shapes (PR 21). One frame: locations
 # keep the op's own line and nothing of its callers; the key is then a
 # function of the program alone
 # (tests/test_tpu_device.py::test_cache_key_does_not_depend_on_the_caller).
@@ -59,6 +58,6 @@ if "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES" not in _os.environ:
 # whose name stack the HLO converter drops, so op_name is the bare
 # primitive ("and", "add") and the phases below (jax.named_scope, the
 # names of utils/trace.KERNEL_SCOPES) never reach a profiler trace. With
-# tracebacks on, op_name is the whole path, "jit(rlc_verify_stream)/
-# rlc.accumulate/...", which utils/traceview.device_join reads.
+# tracebacks on, op_name is the whole path, "jit(verify_batch_cached_a)/
+# ladder.double_scalar/...", which utils/traceview.device_join reads.
 _jax.config.update("jax_traceback_in_locations_limit", 1)

@@ -155,13 +155,6 @@ def is_identity(p):
     return F.is_zero(X) & F.eq(Y, Z)
 
 
-def eq_points(p, q):
-    """Projective equality: X1 Z2 == X2 Z1 and Y1 Z2 == Y2 Z1."""
-    X1, Y1, Z1, _ = p
-    X2, Y2, Z2, _ = q
-    return F.eq(F.mul(X1, Z2), F.mul(X2, Z1)) & F.eq(F.mul(Y1, Z2), F.mul(Y2, Z1))
-
-
 def _abs_diff_zero(a, b):
     """(1, B) int32 mask: canonical(a) == canonical(b). Kernel-safe
     keepdims formulation (no reductions to 1-D shapes)."""

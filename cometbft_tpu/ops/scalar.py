@@ -1,10 +1,9 @@
 """Batched arithmetic mod L (the ed25519 group order) on device.
 
-L = 2^252 + 27742317777372353535851937790883648493. Three jobs, all
-vectorized over the signature batch with no host round-trips:
+L = 2^252 + 27742317777372353535851937790883648493. Two jobs, both
+vectorized over the signature batch with no host round-trips (the
+challenge scalar arrives already reduced: the host hashes it):
 
-- `reduce512`: SHA-512 digests (512-bit little-endian) -> canonical
-  scalars < L via Barrett reduction (HAC Alg 14.42) in base-2^12 limbs.
 - `recode_signed`: scalar -> 64 signed radix-16 digits in [-8, 7] for the
   windowed ladder, via the add-0x888...8 trick (adding 8 to every nibble
   with full carry propagation turns unsigned nibbles into signed digits).
@@ -12,14 +11,13 @@ vectorized over the signature batch with no host round-trips:
 
 Behavior parity: the reference's scalar handling lives inside
 curve25519-voi (reference: crypto/ed25519/ed25519.go:13 imports); the
-Barrett/limb formulation here is an original TPU design sharing the 12-bit
-limb machinery of ops/field.py.
+limb formulation here is an original TPU design sharing the 12-bit limb
+machinery of ops/field.py.
 
-Carry discipline: Barrett needs *exact* limb values (digits feed floor/
-compare steps), so after each convolution we run a few parallel masking
-rounds to shrink carries, then one sequential ripple pass for exactness.
-Sequential passes are O(nlimbs) scalar steps over (B,) vectors — cheap
-relative to the curve ladder, and only ~4 of them run per signature.
+Carry discipline: digits and the borrow chain need *exact* limb values,
+so a sum is finished by one sequential ripple pass. Sequential passes are
+O(nlimbs) scalar steps over (B,) vectors — cheap relative to the curve
+ladder.
 """
 
 from __future__ import annotations
@@ -36,7 +34,6 @@ L_INT = 2**252 + 27742317777372353535851937790883648493
 HALF_INT = int("8" * 64, 16)  # 0x888...8: adds 8 to each of 64 nibbles
 
 _K = 22  # L occupies 22 base-2^12 limbs (bit 252 lives in limb 21)
-_MU_INT = (1 << (BITS * 2 * _K)) // L_INT  # floor(b^44 / L), 23 limbs
 
 
 def _to_limbs(x: int, n: int) -> np.ndarray:
@@ -44,7 +41,6 @@ def _to_limbs(x: int, n: int) -> np.ndarray:
 
 
 L_LIMBS = jnp.asarray(_to_limbs(L_INT, _K)[:, None])
-_MU_LIMBS = jnp.asarray(_to_limbs(_MU_INT, 23)[:, None])
 _HALF_LIMBS = jnp.asarray(_to_limbs(HALF_INT, _K)[:, None])
 
 
@@ -69,19 +65,13 @@ def bytes_to_limbs(b, nlimbs: int):
     return jnp.stack(limbs)
 
 
-def _canon(x, extra_rounds: int = 2):
-    """Exact canonicalization: limbs in [0, 2^12), value preserved.
-
-    A few parallel rounds shrink carries to <= 1, then one unrolled
-    sequential ripple finishes exactly. Input limbs must be >= 0.
-    The final carry out of the top limb is returned as (1, B) (callers for
-    which it must be zero assert statically via value bounds). Rows stay
-    2D (kernel-safe: no stack/scatter).
+def _canon(x):
+    """Exact canonicalization: limbs in [0, 2^12), value preserved, by
+    one unrolled sequential ripple. Input limbs must be >= 0. The final
+    carry out of the top limb is returned as (1, B) (callers for which it
+    must be zero assert statically via value bounds). Rows stay 2D
+    (kernel-safe: no stack/scatter).
     """
-    for _ in range(extra_rounds):
-        m = x & MASK
-        hi = x >> BITS
-        x = m + jnp.concatenate([jnp.zeros_like(hi[:1]), hi[:-1]], axis=0)
     out = []
     c = jnp.zeros_like(x[0:1])
     for j in range(x.shape[0]):
@@ -89,28 +79,6 @@ def _canon(x, extra_rounds: int = 2):
         out.append(t & MASK)
         c = t >> BITS
     return jnp.concatenate(out, axis=0), c
-
-
-def _conv(a, b):
-    """Plain (no modular fold) limb convolution: (n,B) x (m,B) -> (n+m-1,B).
-
-    Shifted-row form (n full-width MACs) — small traced graph, VPU-shaped.
-    """
-    n, m = a.shape[0], b.shape[0]
-    wide = n + m - 1
-    batch = a.shape[1]
-    t = jnp.zeros((wide, batch), jnp.int32)
-    for i in range(n):
-        rows = a[i][None, :] * b
-        t = t + jnp.concatenate(
-            [
-                jnp.zeros((i, batch), jnp.int32),
-                rows,
-                jnp.zeros((wide - m - i, batch), jnp.int32),
-            ],
-            axis=0,
-        )
-    return t
 
 
 def _sub_borrow(a, b):
@@ -128,29 +96,6 @@ def _sub_borrow(a, b):
     return jnp.concatenate(out, axis=0), c
 
 
-def reduce512(digest_bytes):
-    """(B, 64) uint8 little-endian 512-bit values -> (22, B) canonical < L."""
-    x = bytes_to_limbs(digest_bytes, 43)  # already canonical
-    q1 = x[_K - 1:]  # floor(x / b^21): 22 limbs
-    q2 = _conv(q1, jnp.broadcast_to(_MU_LIMBS, (23, x.shape[1])))
-    # q1*mu < b^45: one extra row absorbs the conv carries (parallel canon
-    # rounds shift carries up one row and would drop the top one).
-    q2 = jnp.concatenate([q2, jnp.zeros((1, q2.shape[1]), jnp.int32)], axis=0)
-    q2, _ = _canon(q2)
-    q3 = q2[_K + 1:]  # floor(q2 / b^23)
-    r2 = _conv(q3, jnp.broadcast_to(L_LIMBS, (_K, x.shape[1])))[: _K + 1]
-    r2, _ = _canon(r2)
-    r1 = x[: _K + 1]
-    r, _ = _sub_borrow(r1, r2)  # r >= 0 mathematically; borrow ignored
-    lpad = jnp.concatenate(
-        [jnp.broadcast_to(L_LIMBS, (_K, r.shape[1])),
-         jnp.zeros((1, r.shape[1]), jnp.int32)], axis=0)
-    for _ in range(2):  # Barrett leaves r < 3L
-        d, borrow = _sub_borrow(r, lpad)
-        r = jnp.where(borrow == 0, d, r)
-    return r[:_K]
-
-
 def lt_l(s_bytes):
     """(B, 32) uint8 little-endian -> bool (B,): value < L (ZIP-215 S check)."""
     s = bytes_to_limbs(s_bytes, _K)
@@ -165,7 +110,7 @@ def recode_signed(limbs):
     full carry ripple) and subtracting 8 from every resulting nibble.
     """
     t = limbs + _HALF_LIMBS
-    t, _ = _canon(t, extra_rounds=0)  # sums <= 2*4095: one ripple suffices
+    t, _ = _canon(t)  # sums <= 2*4095: one ripple suffices
     digits = []
     for i in range(64):
         limb, pos = divmod(4 * i, BITS)

@@ -40,9 +40,10 @@ def make_mesh(devices=None, axis: str = "sig") -> Mesh:
 def make_mesh_2d(devices=None, hosts: int = 2) -> Mesh:
     """Hierarchical (host, sig) mesh for multi-host pods: the outer axis
     maps to hosts (collectives cross DCN), the inner to the chips of one
-    host (collectives ride ICI). Lay out the batch over BOTH axes and
-    reduce hierarchically so only one scalar per host crosses DCN — the
-    layout discipline from the scaling playbook (slow axis outermost)."""
+    host (collectives ride ICI). Lay out the batch over BOTH axes
+    (sharded_verify_rsk_fn(mesh, ("host", "sig"))) and reduce
+    hierarchically so only one scalar per host crosses DCN — the layout
+    discipline from the scaling playbook (slow axis outermost)."""
     devices = devices if devices is not None else jax.devices()
     n = len(devices)
     if n % hosts:
@@ -50,45 +51,6 @@ def make_mesh_2d(devices=None, hosts: int = 2) -> Mesh:
     return Mesh(
         np.asarray(devices).reshape(hosts, n // hosts), ("host", "sig")
     )
-
-
-def sharded_verify_fn(mesh: Mesh, axes: str | tuple[str, ...] = "sig"):
-    """Build a pjit-ed batched verifier sharded over one or more mesh axes.
-
-    Inputs: a_bytes (B,32)u8, r_bytes (B,32)u8, s_bytes (B,32)u8,
-    msg_words (B,64)u32, two_blocks (B,)bool, live (B,)bool; B must divide
-    by the product of the named mesh axes.
-    Returns (all_ok: bool scalar replicated, bits: (B,) bool sharded).
-
-    The invalid-lane count psums over the axes INNERMOST-FIRST: on a
-    hierarchical (host, sig) mesh the partial sums ride ICI within each
-    host and only one scalar per host crosses DCN.
-    """
-    axes_t = (axes,) if isinstance(axes, str) else tuple(axes)
-
-    def local(a, r, s, m, tb, live):
-        bits, _ = ed25519_verify.verify_batch(a, r, s, m, tb, live)
-        bad = jnp.sum((~bits & live).astype(jnp.int32))
-        for ax in reversed(axes_t):  # innermost (fast) axis first
-            bad = jax.lax.psum(bad, ax)
-        return bad == 0, bits
-
-    spec_b = P(axes_t if len(axes_t) > 1 else axes_t[0])
-    fn = _shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(spec_b,) * 6,
-        out_specs=(P(), spec_b),
-        check_vma=False,
-    )
-    return jax.jit(fn)
-
-
-def sharded_verify_fn_2d(mesh: Mesh):
-    """Verifier over a (host, sig) mesh (make_mesh_2d): batch sharded
-    across every chip of every host, hierarchical reduction (see
-    sharded_verify_fn)."""
-    return sharded_verify_fn(mesh, axes=("host", "sig"))
 
 
 def pad_to_shards(n: int, parts: int, bucket: int | None = None) -> int:
@@ -142,8 +104,7 @@ def sharded_verify_rsk_fn(mesh: Mesh, axes: str | tuple[str, ...] = "sig"):
     return jax.jit(fn)
 
 
-# Dispatch-term fallbacks when calibration is skipped
-# (COMETBFT_TPU_DISPATCH_CALIBRATE=0) or fails. put_fixed: each shard's
+# Dispatch-term fallbacks when calibration fails. put_fixed: each shard's
 # H2D staging pays a fixed per-transfer cost on top of the bytes (the
 # same fixed cost the single-chip path's array-packing work avoids;
 # ~100 us is the class of a local PCIe link, not a reading taken on
@@ -173,8 +134,7 @@ class MeshVerifyEngine:
       device i because device_put is async.
     """
 
-    def __init__(self, devices=None, hosts: int | None = None,
-                 calibrate: bool | None = None):
+    def __init__(self, devices=None, hosts: int | None = None):
         devices = list(devices if devices is not None else jax.devices())
         if not devices:
             raise ValueError("mesh engine needs at least one device")
@@ -198,10 +158,6 @@ class MeshVerifyEngine:
         self._a_cache: dict = {}  # (sha256(pub col), B) -> staged a_bytes
         self._rr = 0
         self._terms: dict | None = None
-        if calibrate is None:
-            calibrate = os.environ.get(
-                "COMETBFT_TPU_DISPATCH_CALIBRATE", "1") != "0"
-        self._want_calibrate = calibrate
         crypto_metrics().mesh_devices.set(float(self.n_devices))
 
     # -- dispatch terms ------------------------------------------------
@@ -219,22 +175,21 @@ class MeshVerifyEngine:
                 "collective_s": _COLLECTIVE_US_FALLBACK * 1e-6,
                 "calibrated": False,
             }
-            if self._want_calibrate:
-                try:
-                    buf = np.zeros((self.n_devices * 64, 96), np.uint8)
+            try:
+                buf = np.zeros((self.n_devices * 64, 96), np.uint8)
+                jax.block_until_ready(
+                    jax.device_put(buf, self._sharding))  # warm path
+                best = float("inf")
+                for _ in range(2):
+                    t0 = _time.perf_counter()
                     jax.block_until_ready(
-                        jax.device_put(buf, self._sharding))  # warm path
-                    best = float("inf")
-                    for _ in range(2):
-                        t0 = _time.perf_counter()
-                        jax.block_until_ready(
-                            jax.device_put(buf, self._sharding))
-                        best = min(best, _time.perf_counter() - t0)
-                    # per-device share of the fixed staging cost
-                    terms["put_fixed_s"] = best / self.n_devices
-                    terms["calibrated"] = True
-                except Exception:
-                    pass
+                        jax.device_put(buf, self._sharding))
+                    best = min(best, _time.perf_counter() - t0)
+                # per-device share of the fixed staging cost
+                terms["put_fixed_s"] = best / self.n_devices
+                terms["calibrated"] = True
+            except Exception:
+                pass
             self._terms = terms
         return self._terms
 

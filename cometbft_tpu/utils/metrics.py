@@ -531,7 +531,7 @@ class CryptoMetrics:
         self.path_selected_total = reg.counter(
             "crypto", "path_selected_total",
             "Dispatch decisions per verify path "
-            "(native/rlc/ladder/delta/cpu) and curve",
+            "(native/ladder/mesh/cpu/single) and curve",
             labels=("path", "curve"))
         self.commit_path_total = reg.counter(
             "crypto", "commit_path_total",
@@ -546,10 +546,10 @@ class CryptoMetrics:
             labels=("path", "curve"))
         self.gave_way_total = reg.counter(
             "crypto", "gave_way_total",
-            "Device work handed to another engine: RLC batches whose "
-            "host layout declined (rlc_declined, per batch; the ladder "
-            "takes them) and on-device-SHA lanes too long for the "
-            "kernel (oversize, per lane; the host reference takes them)",
+            "Device work handed to another engine. No path of the "
+            "tree hands work over since PR 28 removed the two that did "
+            "(the RLC layout's rlc_declined, on-device SHA's oversize): "
+            "every reason reads 0, which the benchmark's checks expect",
             labels=("reason",))
         self.msm_native_total = reg.counter(
             "crypto", "msm_native_total",

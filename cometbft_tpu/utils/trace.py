@@ -544,7 +544,6 @@ SPAN_REGISTRY = {
     "blocksync.window_apply": "the blocks of one verified window applied (window/blocks/txs); children state.apply_block",
     "crypto.batch_verify": "one batch-verify dispatch, the host time inside submit() (path/n/bucket); its children split it",
     "crypto.materialize": "lazy whole-commit columns expanded into per-item tuples for one dispatch (n = lanes expanded, 0 when add() already built them)",
-    "crypto.rlc_prepare": "host RLC layout for one dispatch: coefficients, digit stream, lane budget (n/declined)",
     "crypto.pack": "fixed-shape wire arrays of one dispatch built on the host (n/bucket)",
     "crypto.device_launch": "jax.device_put of one dispatch's wire arrays plus the jitted call's return (bytes)",
     "crypto.native_verify": "one batch judged by the host C++ engine, blame rescan included (n/ok)",
@@ -583,22 +582,14 @@ SPAN_REGISTRY = {
 # device_join books device time to a phase that survives an edit to
 # ops/ (HLO instruction numbers do not).
 KERNEL_SCOPES = {
-    "rlc.decompress": "RLC program: ZIP-215 decoding of A and R, affine niels table",
-    "rlc.expand_stream": "RLC program: dense contribution stream -> (S, WK) gather table",
-    "rlc.accumulate": "RLC program: S rounds of lane-parallel mixed adds into the bucket lanes (row gather + msm_accumulate_weighted on the chip)",
-    "rlc.bucket_reduce": "RLC program: bucket lanes folded to per-window sums",
-    "rlc.window_combine": "RLC program: Horner over the windows' sums",
-    "rlc.final_check": "RLC program: + [c]B, cofactor clearing, identity test, decode flags",
     "ladder.decompress": "per-lane program: ZIP-215 decoding of R (and of A in decompress_pubkeys)",
-    "ladder.sha512": "per-lane program: SHA-512(R||A||M) on the device (device_sha and delta wires)",
-    "ladder.scalar_reduce": "per-lane program: k mod L, signed-digit recoding of s and k, S < L",
+    "ladder.scalar_reduce": "per-lane program: signed-digit recoding of s and k (k arrives reduced mod L), S < L",
     "ladder.double_scalar": "per-lane program: [8]([s]B + [k](-A) - R), one fused kernel on the chip",
     "ladder.compare": "per-lane program: identity test, lane bitmap and its all-ok summary",
     "curve_decompress": "pallas kernel (ops/curve.py): fused sqrt candidate and checks",
     "curve_ladder_sub_mul8": "pallas kernel (ops/curve.py): the whole double-scalar ladder",
     "field_mul": "pallas kernel (ops/field.py): one 22-limb field multiply outside a fused kernel",
     "field_sq": "pallas kernel (ops/field.py): one field squaring outside a fused kernel",
-    "msm_accumulate_weighted": "pallas kernel (ops/msm.py): weighted bucket accumulation over the gathered stream",
 }
 
 

@@ -611,7 +611,7 @@ def _scope_of(op_name: str, scopes) -> tuple[str | None, str | None]:
     """(phase, kernel) of an operation's op_name: its outermost and its
     innermost component that is a registered kernel scope. The phase is
     what device time is booked to; a pallas kernel's name= lies inside
-    its phase ("jit(f)/rlc.accumulate/msm_accumulate_weighted/
+    its phase ("jit(f)/ladder.double_scalar/curve_ladder_sub_mul8/
     pallas_call") and names the operation in place of its HLO
     instruction ("tpu_custom_call.27"). kernel is None where the two are
     one."""

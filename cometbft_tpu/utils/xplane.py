@@ -3,7 +3,7 @@ protobuf), cut to what utils/traceview.device_join needs.
 
 jax.profiler.ProfileData reads the same file but hides the statistics
 kept on an event's METADATA, and that is where XLA puts an operation's
-`op_name` (the stat `tf_op`: "jit(f)/rlc.accumulate/while/body/..."),
+`op_name` (the stat `tf_op`: "jit(f)/ladder.double_scalar/while/body/..."),
 the path that carries the jax.named_scope and pallas_call names of
 trace.KERNEL_SCOPES. The generated `xplane_pb2` would read it, but jax
 ships none: the only copy here is inside the tensorflow package

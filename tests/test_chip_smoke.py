@@ -45,7 +45,8 @@ def test_chip_smoke_rehearsal_runs_every_phase():
 
 def test_chip_smoke_terms_runs_the_device_terms_phase_alone():
     """--terms is the re-measurement of the dispatch's device terms: that
-    phase and no other, both sizes, the model's terms beside the fit."""
+    phase and no other, both sizes, the model's terms beside the fit, and
+    no engine but the ladder on a host without a mesh."""
     p = _run([SMOKE, "--rehearse", "--terms", "--seed", "5"])
     assert p.returncode == 0, f"stdout={p.stdout[-3000:]}\nstderr={p.stderr[-3000:]}"
     assert json.loads(p.stdout.strip().splitlines()[-1])["ok"] is True
@@ -53,8 +54,8 @@ def test_chip_smoke_terms_runs_the_device_terms_phase_alone():
     assert "   phase device-terms: ok in " in p.stdout
     assert "n=24 bucket=64: the model says ladder" in p.stdout
     assert "n=48 bucket=64: the model says ladder" in p.stdout
-    assert "NOT A DEVICE NUMBER (rehearsal): fixed " in p.stdout
-    assert "rlc: not measured; assumed 91.44 ms + n x 2.875 us" in p.stdout
+    assert "ladder, NOT A DEVICE NUMBER (rehearsal): fixed " in p.stdout
+    assert "assumed 0.41 ms + n x 2.007 us" in p.stdout
 
 
 def test_chip_smoke_refuses_to_start_without_a_chip():
@@ -164,20 +165,3 @@ def test_a_mesh_that_cannot_be_built_is_an_error(monkeypatch):
             M.get_engine(accel_backed=False)
     finally:
         M.reset_engine()
-
-
-def test_rlc_declines_are_counted(monkeypatch):
-    """_launch_rlc returning None is legitimate, and now visible."""
-    from cometbft_tpu.crypto import ed25519 as E
-    from cometbft_tpu.crypto import rlc
-    from cometbft_tpu.utils.metrics import crypto_metrics
-
-    monkeypatch.setattr(rlc, "prepare", lambda *a, **kw: None)
-    bv = E.Ed25519BatchVerifier(backend="tpu")
-    priv = E.Ed25519PrivKey(b"\x07" * 32)
-    for i in range(3):
-        msg = b"m%d" % i
-        bv.add(priv.pub_key(), msg, priv.sign(msg))
-    assert bv._launch_rlc() is None
-    assert crypto_metrics().gave_way_total.values() == {
-        ("rlc_declined",): 1.0}

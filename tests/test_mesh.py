@@ -20,8 +20,7 @@ def test_sharded_verify_1d_and_2d_agree():
     from cometbft_tpu.parallel.mesh import (
         make_mesh,
         make_mesh_2d,
-        sharded_verify_fn,
-        sharded_verify_fn_2d,
+        sharded_verify_rsk_fn,
     )
 
     cpus = jax.devices("cpu")
@@ -30,12 +29,12 @@ def test_sharded_verify_1d_and_2d_agree():
     raw = _batch(64)
 
     mesh = make_mesh(cpus[:8])
-    fn = sharded_verify_fn(mesh)
+    fn = sharded_verify_rsk_fn(mesh)
     args = [jax.device_put(a, NamedSharding(mesh, P("sig"))) for a in raw]
     ok1, bits1 = jax.block_until_ready(fn(*args))
 
     mesh2 = make_mesh_2d(cpus[:8], hosts=2)
-    fn2 = sharded_verify_fn_2d(mesh2)
+    fn2 = sharded_verify_rsk_fn(mesh2, ("host", "sig"))
     args2 = [
         jax.device_put(a, NamedSharding(mesh2, P(("host", "sig"))))
         for a in raw
@@ -48,7 +47,7 @@ def test_sharded_verify_1d_and_2d_agree():
     # flip one signature byte: BOTH layouts must reject, and the psum'd
     # verdict must reflect the single bad lane on whichever shard holds it
     bad = [np.array(a, copy=True) for a in raw]
-    bad[2][17, 0] ^= 1  # s_raw of lane 17
+    bad[1][17, 32] ^= 1  # S of lane 17
     argsb = [jax.device_put(a, NamedSharding(mesh, P("sig"))) for a in bad]
     okb, bitsb = jax.block_until_ready(fn(*argsb))
     args2b = [

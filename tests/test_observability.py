@@ -229,20 +229,17 @@ def test_trace_lint_covers_the_span_tree_and_the_kernel_scopes(tmp_path):
 
     for name in ("trace.clock", "runtime.gc_pause", "types.verify_commit",
                  "types.commit_items", "types.verify_items_fill",
-                 "crypto.materialize", "crypto.rlc_prepare", "crypto.pack",
+                 "crypto.materialize", "crypto.pack",
                  "crypto.device_launch", "crypto.native_verify",
                  "crypto.verdict_wait", "blocksync.replay",
                  "blocksync.window_load", "blocksync.window_queue",
                  "blocksync.window_fill", "blocksync.window_resolve",
                  "blocksync.window_apply"):
         assert name in SPAN_REGISTRY, name
-    for name in ("rlc.decompress", "rlc.expand_stream", "rlc.accumulate",
-                 "rlc.bucket_reduce", "rlc.window_combine",
-                 "rlc.final_check", "ladder.decompress", "ladder.sha512",
-                 "ladder.scalar_reduce", "ladder.double_scalar",
-                 "ladder.compare", "curve_decompress",
-                 "curve_ladder_sub_mul8", "field_mul", "field_sq",
-                 "msm_accumulate_weighted"):
+    for name in ("ladder.decompress", "ladder.scalar_reduce",
+                 "ladder.double_scalar", "ladder.compare",
+                 "curve_decompress", "curve_ladder_sub_mul8", "field_mul",
+                 "field_sq"):
         assert name in KERNEL_SCOPES, name
     # the lint reads ops/ for scopes: a scope it does not know, and one
     # nothing uses, both fail it
@@ -253,12 +250,12 @@ def test_trace_lint_covers_the_span_tree_and_the_kernel_scopes(tmp_path):
     spec.loader.exec_module(lint)
     assert lint.main() == 0
     found = {next(filter(None, m.groups())) for m in lint.SCOPE_RE.finditer(
-        'with jax.named_scope("rlc.new_phase"):\n'
+        'with jax.named_scope("ladder.new_phase"):\n'
         '    pl.pallas_call(k, name="new_kernel")\n'
         '    _pallas_binop(_mul_kernel, "field_new", a)\n')}
-    assert found == {"rlc.new_phase", "new_kernel", "field_new"}
+    assert found == {"ladder.new_phase", "new_kernel", "field_new"}
     assert not lint._agree("kernel scopes", "KERNEL_SCOPES",
-                           {"rlc.new_phase": ["ops/msm.py"]}, KERNEL_SCOPES)
+                           {"ladder.new_phase": ["ops/curve.py"]}, KERNEL_SCOPES)
 
 
 def test_logger_levels_and_fields():

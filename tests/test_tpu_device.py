@@ -32,12 +32,10 @@ from jax.sharding import SingleDeviceSharding
 from cometbft_tpu.crypto import ed25519 as E
 from cometbft_tpu.ops import ed25519_verify as EV
 from cometbft_tpu.ops import field as F
-from cometbft_tpu.ops import msm as MSM
 from cometbft_tpu.parallel import mesh as M
 
 # one v5e chip holds 16 GB and the replay engine keeps two windows in
-# flight: a single program's temporaries may take a quarter (the RLC
-# graph asks 2.14 GB at 10240/16384 lanes and 4.16 GB at 65536)
+# flight: a single program's temporaries may take a quarter
 TEMP_BUDGET_BYTES = 4 << 30
 
 
@@ -83,7 +81,7 @@ def chip(one_chip, no_persistent_cache, monkeypatch):
     return one_chip
 
 
-def _compile(fn, *args, scopes=(), kernels=(), **static):
+def _compile(fn, *args, scopes=(), kernels=()):
     """Lower + compile a FRESH jit of fn (a fresh function identity: the
     program's own module-level jits may hold a CPU trace of the same
     shapes from another test of this worker). Returns the compiled
@@ -96,7 +94,7 @@ def _compile(fn, *args, scopes=(), kernels=(), **static):
     from cometbft_tpu.utils.trace import KERNEL_SCOPES
 
     t0 = time.perf_counter()
-    fresh = jax.jit(lambda *a: fn(*a, **static))
+    fresh = jax.jit(lambda *a: fn(*a))
     compiled = fresh.lower(*args).compile()
     dt = time.perf_counter() - t0
     names = set(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
@@ -220,55 +218,3 @@ def test_ladder_other_buckets_compile_for_v5e(chip, b):
     assert _kernels(c) == 2
     c = _compile(EV.decompress_pubkeys, _S((b, 32), jnp.uint8, chip))
     assert _kernels(c) == 1
-
-
-@pytest.mark.slow
-def test_delta_and_device_sha_compile_for_v5e(chip):
-    b = 4096
-    assert b <= E.DELTA_MAX_BUCKET
-    c = _compile(
-        EV.verify_batch_delta, _S((b,), jnp.bool_, chip), _point(b, chip),
-        _S((b, 32), jnp.uint8, chip), _S((b, 64 + 8 + 1), jnp.uint8, chip),
-        _S((EV.DELTA_META_LEN,), jnp.uint8, chip),
-    )
-    assert _kernels(c) == 2
-    b = 1024
-    c = _compile(
-        EV.verify_batch, _S((b, 32), jnp.uint8, chip),
-        _S((b, 32), jnp.uint8, chip), _S((b, 32), jnp.uint8, chip),
-        _S((b, 64), jnp.uint32, chip), _S((b,), jnp.bool_, chip),
-        _S((b,), jnp.bool_, chip),
-    )
-    assert _kernels(c) == 3
-
-
-@pytest.mark.slow
-def test_rlc_compiles_for_v5e(chip):
-    """rlc_verify_stream at 10240 with the shapes crypto/rlc.prepare
-    really emits (~100-130 s; the planning rehearsal's 2.14 GB of temp
-    is what TEMP_BUDGET_BYTES watches)."""
-    from cometbft_tpu.crypto import rlc
-
-    n, b = 10_000, 10_240
-    rnd = np.random.default_rng(1)
-    blobs = (rnd.bytes(32 * n), rnd.bytes(64 * n), rnd.bytes(100 * n),
-             np.full(n, 100, np.uint64))
-    prep = None
-    while prep is None:  # the layout declines some random draws
-        prep = rlc.prepare(None, np.zeros(n, bool), b, blobs=blobs)
-    s_pad = 8
-    while s_pad < prep["s_rounds"]:
-        s_pad *= 2
-    c = _compile(
-        MSM.rlc_verify_stream, _S((b, 32), jnp.uint8, chip),
-        _S((b, 32), jnp.uint8, chip), _S((b,), jnp.bool_, chip),
-        *(_S(prep[k].shape, prep[k].dtype, chip)
-          for k in ("stream", "stream_neg", "counts", "weights",
-                    "c_digits")),
-        scopes=("rlc.expand_stream", "rlc.decompress", "rlc.accumulate",
-                "rlc.bucket_reduce", "rlc.window_combine",
-                "rlc.final_check"),
-        kernels=("curve_decompress", "msm_accumulate_weighted", "field_mul"),
-        s_rounds=s_pad,
-    )
-    assert _kernels(c) >= 3  # A, R decompress + the accumulate kernel

@@ -325,8 +325,8 @@ def test_cli_summary_timeline_critical_path(tmp_path):
 # utils/xplane.load gives; ns since the session began)
 # ----------------------------------------------------------------------
 _DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
-_SCOPES = ("rlc.decompress", "rlc.accumulate", "rlc.window_combine",
-           "ladder.double_scalar", "field_mul", "msm_accumulate_weighted",
+_SCOPES = ("ladder.scalar_reduce", "ladder.decompress", "ladder.compare",
+           "ladder.double_scalar", "field_mul", "curve_decompress",
            "curve_ladder_sub_mul8")
 
 
@@ -371,12 +371,12 @@ def test_device_join_books_device_time_to_kernel_scopes():
     xp, recs = _fixture()
     j = traceview.device_join(xp, recs, scopes=_SCOPES)
     busy = dict(j["busy_by_scope"])
-    assert busy["rlc.decompress"] == pytest.approx(1000e-9)
-    assert busy["rlc.accumulate"] == pytest.approx(1000e-9)
+    assert busy["ladder.scalar_reduce"] == pytest.approx(1000e-9)
+    assert busy["ladder.decompress"] == pytest.approx(1000e-9)
     # the loop has no op_name of its own: it takes the scope most of its
     # children's time carries, and so does the child that has none; the
     # loop keeps only the time its children leave (4000 - 1000 - 2000)
-    assert busy["rlc.window_combine"] == pytest.approx(4000e-9)
+    assert busy["ladder.compare"] == pytest.approx(4000e-9)
     assert busy["ladder.double_scalar"] == pytest.approx(500e-9)  # device 1
     assert busy[traceview.NO_SCOPE] == pytest.approx(500e-9)  # copy.4
     assert sum(busy.values()) == pytest.approx(7000e-9)  # both devices
@@ -386,14 +386,14 @@ def test_device_join_books_device_time_to_kernel_scopes():
     assert how["children"] == pytest.approx(1000e-9)
     assert how["enclosing"] == pytest.approx(2000e-9)
     assert how["none"] == pytest.approx(500e-9)
-    ops = dict(j["ops_by_scope"]["rlc.window_combine"])
+    ops = dict(j["ops_by_scope"]["ladder.compare"])
     # a pallas kernel goes by its name= (the innermost registered part of
     # its op_name), not by its HLO instruction (tpu_custom_call.3)
     assert ops == {"fusion.7": pytest.approx(2000e-9),
                    "field_mul": pytest.approx(1000e-9),
                    "while.9": pytest.approx(1000e-9)}
-    assert dict(j["ops_by_scope"]["rlc.accumulate"]) == {
-        "msm_accumulate_weighted": pytest.approx(1000e-9)}
+    assert dict(j["ops_by_scope"]["ladder.decompress"]) == {
+        "curve_decompress": pytest.approx(1000e-9)}
     assert dict(j["ops_by_scope"]["ladder.double_scalar"]) == {
         "curve_ladder_sub_mul8": pytest.approx(500e-9)}
 
@@ -405,11 +405,11 @@ def test_device_join_of_a_stretch_and_its_rendering():
     assert j["stretch_s"] == pytest.approx(6000e-9)
     assert j["busy_s"] == pytest.approx(4000e-9)
     assert dict(j["busy_by_scope"]) == {
-        "rlc.window_combine": pytest.approx(4000e-9)}
+        "ladder.compare": pytest.approx(4000e-9)}
     assert j["busy_scoped_share"] == 1.0
     text = traceview.render_device_join(j)
     assert "types.verify_commit > crypto.verdict_wait" in text
-    assert "rlc.window_combine" in text and "while.9" in text
+    assert "ladder.compare" in text and "while.9" in text
     with pytest.raises(ValueError):
         traceview.device_join({"start_ns": None, "planes": []})
 
@@ -436,7 +436,10 @@ def test_xplane_reader_on_a_trace_recorded_on_a_v5e():
     v5e chip (PR 24's first chip call): a tiny jitted program with two
     named scopes, a scan over a pallas kernel named probe_mul3, under two
     TraceAnnotations with span_id 7 and 8. The reader finds what
-    jax.profiler.ProfileData hides: each operation's op_name."""
+    jax.profiler.ProfileData hides: each operation's op_name. (PR 28
+    renamed the probe's three scope names inside the file to names the
+    tree still has, every enclosing length re-encoded; times, operations
+    and spans are as recorded.)"""
     from cometbft_tpu.utils import xplane
 
     xp = xplane.load(os.path.join(_DATA, "v5e_probe.xplane.pb"))
@@ -450,7 +453,7 @@ def test_xplane_reader_on_a_trace_recorded_on_a_v5e():
     kernel = [o for o in dev["ops"] if o["op"] == "probe_mul3.3"]
     assert len(kernel) == 8  # one a scan step, inside the while's event
     assert kernel[0]["op_name"] == (
-        "jit(prog)/rlc.accumulate/while/body/closed_call/probe_mul3/"
+        "jit(prog)/ladder.double_scalar/while/body/closed_call/probe_mul3/"
         "pallas_call")
     loop = next(o for o in dev["ops"] if o["op"] == "while")
     assert loop["op_name"] == ""
@@ -464,9 +467,10 @@ def test_xplane_reader_on_a_trace_recorded_on_a_v5e():
     # and the join reads it: the loop and its unnamed fusions go to the
     # scope their kernel names
     j = traceview.device_join(
-        xp, scopes=("rlc.decompress", "rlc.accumulate", "rlc.final_check"))
+        xp, scopes=("ladder.decompress", "ladder.double_scalar",
+                    "ladder.compare"))
     busy = dict(j["busy_by_scope"])
-    assert busy["rlc.accumulate"] == pytest.approx(
+    assert busy["ladder.double_scalar"] == pytest.approx(
         loop["dur_ns"] * 1e-9, rel=1e-6)
     assert j["busiest"] == "/device:TPU:0"
     assert {"types.verify_commit", "crypto.batch_verify"} <= {

@@ -1,10 +1,9 @@
 #!/bin/sh
 # ASAN/UBSAN build + run of the native Ed25519 engine (SURVEY §5.2's
 # sanitizer leg for csrc; the Python suite covers the logic, this
-# catches memory errors the .so build would hide). Covers the RLC
-# packer entry points (rlc_pack / rlc_packer_threads) with tight
-# buffers: n==0, all-skip, max-bucket, and chunk-determinism shapes —
-# plus the secp256k1 verify engine (r/s boundary values, bad point
+# catches memory errors the .so build would hide). Covers the ed25519
+# engine's batch and wire-packing entry points, the secp256k1 verify
+# engine (r/s boundary values, bad point
 # encodings, multi-verify chunk determinism), the sr25519 unit
 # (ristretto decode rejects, merlin challenge, batch residue s >= L,
 # n==0 batches), the BLS12-381 pairing engine (PoP cycle,
@@ -19,14 +18,15 @@
 # bad-encoding rejects).
 set -e
 cd "$(dirname "$0")/.."
+out="${TMPDIR:-/tmp}"
 # -std=c++17: std::shared_mutex in the IFMA engine; g++ <= 10 defaults
 # to gnu++14 and would fail the build outright
 g++ -std=c++17 -O1 -g -fsanitize=address,undefined -fno-omit-frame-pointer -pthread \
-    cometbft_tpu/csrc/ed25519_native.cpp cometbft_tpu/csrc/asan_selftest.cpp -o /tmp/ed25519_asan
-/tmp/ed25519_asan
+    cometbft_tpu/csrc/ed25519_native.cpp cometbft_tpu/csrc/asan_selftest.cpp -o "$out/ed25519_asan"
+"$out/ed25519_asan"
 # second pass with -march=native: on IFMA-capable hosts this compiles
 # and sanitizes the AVX-512 vector engine (cometbft_tpu/csrc/ed25519_ifma.inc) too
 g++ -std=c++17 -O1 -g -march=native -fsanitize=address,undefined \
     -fno-omit-frame-pointer -pthread \
-    cometbft_tpu/csrc/ed25519_native.cpp cometbft_tpu/csrc/asan_selftest.cpp -o /tmp/ed25519_asan_nat
-/tmp/ed25519_asan_nat
+    cometbft_tpu/csrc/ed25519_native.cpp cometbft_tpu/csrc/asan_selftest.cpp -o "$out/ed25519_asan_nat"
+"$out/ed25519_asan_nat"

@@ -26,6 +26,13 @@ DECL_FILE = os.path.join(PKG, "utils", "metrics.py")
 
 MUTATORS = ("inc", "set", "add", "observe")
 
+# Registered for a reader outside the package, driven by nothing in it:
+# benchmark/harness/env.py:131 snapshots crypto gave_way_total and
+# check.py:192 expects its `oversize` reason at 0. PR 28 removed the two
+# paths that drove it (a `simplicity` PR may not edit benchmark/);
+# ROADMAP D9's `benchmark` PR drops the reads, then the metric and this.
+READ_BY_THE_BENCHMARK = {"CryptoMetrics.gave_way_total"}
+
 
 def _bundle_metrics():
     """{bundle_class_name: [(attr, n_labels), ...]} for every *Metrics
@@ -61,11 +68,6 @@ def _package_sources() -> str:
                 continue
             with open(path, encoding="utf-8") as f:
                 chunks.append(f.read())
-    # bench.py drives the crypto snapshot from outside the package
-    bench = os.path.join(REPO, "bench.py")
-    if os.path.exists(bench):
-        with open(bench, encoding="utf-8") as f:
-            chunks.append(f.read())
     return "\n".join(chunks)
 
 
@@ -81,7 +83,8 @@ def main() -> int:
                 + r")\("
             )
             if not pat.search(src):
-                dead.append(f"{bundle}.{attr}")
+                if f"{bundle}.{attr}" not in READ_BY_THE_BENCHMARK:
+                    dead.append(f"{bundle}.{attr}")
                 continue
             if not n_labels:
                 continue
