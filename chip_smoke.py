@@ -482,13 +482,40 @@ def _engine_timings(probe: Probe, lanes, engine: str, calls: int = 5):
     return out
 
 
+def _packer_timings(lanes, calls: int = 5):
+    """The host term beside the device terms: native.pack_rsk over these
+    lanes' columns as the packer splits them itself (chunks over the C++
+    worker pool) and pinned to one chunk (the calling thread alone).
+    {"pooled" | "one chunk": (median ms of `calls`, chunks it reported)}."""
+    import numpy as np
+
+    from cometbft_tpu.crypto import ed25519 as E
+    from cometbft_tpu.crypto import native
+
+    bv = verifier(lanes)
+    n = bv.count()
+    rsk = np.zeros((E._bucket(n), 96), np.uint8)
+    lens = np.asarray(bv._msg_lens, np.uint64)
+    out = {}
+    for label, nchunks in (("pooled", 0), ("one chunk", 1)):
+        took = []
+        for _ in range(calls):
+            t0 = time.perf_counter()
+            ran = native.pack_rsk(n, bv._sig_buf, bv._pub_buf, bv._msg_buf,
+                                  lens, rsk, nchunks)
+            took.append(time.perf_counter() - t0)
+        out[label] = (statistics.median(took) * 1e3, ran)
+    return out
+
+
 def phase_device_terms(probe: Probe, lanes, sizes, seed: int):
     """The dispatch model's device terms, measured again: the ladder (and,
     where a mesh is up, the mesh) as submit() launches it, warm, at the
     live lane counts of the cells' two buckets (`sizes`), and from the two
     sizes each engine's fixed and per-lane term beside what
-    crypto/ed25519.py assumes. Nothing is re-derived here: the printout is
-    what a change of the constants is made from."""
+    crypto/ed25519.py assumes; beside them the host term, the packer at the
+    calibration probe's lane count and at both sizes. Nothing is re-derived
+    here: the printout is what a change of the constants is made from."""
     from cometbft_tpu.crypto import ed25519 as E
 
     host = E._host_terms()
@@ -503,9 +530,17 @@ def phase_device_terms(probe: Probe, lanes, sizes, seed: int):
     engines = ("ladder", "mesh") if mesh is not None else ("ladder",)
 
     got: dict[str, dict[int, dict]] = {eng: {} for eng in engines}
+
+    def log_packer(ls):
+        log(f"   packer n={len(ls)}, host clock: " + ", ".join(
+            f"{k} {ms:.3f} ms in {ran} chunk(s)"
+            for k, (ms, ran) in _packer_timings(ls).items()))
+
+    log_packer(lanes[:1024])  # the lane count of the calibration probe
     for n in sizes:
         big = more_lanes(lanes, n, seed)
         b = E._bucket(n)
+        log_packer(big)
         mdl = E.dispatch_model(n, b)
         log(f"   n={n} bucket={b}: the model says " + ", ".join(
             f"{eng} {mdl[eng]['device'] * 1e3:.2f} ms of device "

@@ -62,8 +62,8 @@ _DEV_LADDER_FIXED_MS = 0.41  # v5e profile, 2026-09-28, PR 25 (table above)
 _DEV_LADDER_US = 2.007       # the same two readings
 # The host-side per-sig term is CALIBRATED at the first dispatch decision
 # (_host_terms: one small timed pack_rsk) because it moves with the host:
-# core count, toolchain presence. This is the fallback when that fails:
-_HOST_LADDER_US = 1.6        # ladder submit packing (r4: ~15-22 ms/10k)
+# core speed, toolchain presence. This is the fallback when that fails:
+_HOST_LADDER_US = 1.6        # pack_rsk on one thread (the v5e's host: 1.0-1.2)
 _WIRE_LADDER_B = 96          # R||S||k per lane
 
 _LINK_MBPS: float | None = None
@@ -99,9 +99,15 @@ _HOST_TERMS: dict | None = None
 
 def _calibrate_host_terms() -> dict:
     """Measure the per-sig host cost of the ladder's pack stage on THIS
-    host: one timed pack_rsk over 1024 lanes. Returns the fallback
-    constant when anything goes wrong: dispatch must keep picking sanely
-    on a box where the probe can't run."""
+    host: one timed pack_rsk over 1024 lanes, which the packer does in
+    one chunk on the calling thread. So the term is the ONE-THREAD rate:
+    what a batch pays a lane when the worker pool is taken, and an upper
+    bound on what a mega-batch pays when it is free (a fifth of it on
+    the v5e's 13-core host: `python chip_smoke.py --terms`). The ladder
+    and the mesh share the term, so it cannot turn their choice; their
+    device terms decide. Returns the
+    fallback constant when anything goes wrong: dispatch must keep
+    picking sanely on a box where the probe can't run."""
     from . import native
 
     terms = {"ladder_us": _HOST_LADDER_US, "calibrated": False}
@@ -120,7 +126,7 @@ def _calibrate_host_terms() -> dict:
                 okp = native.pack_rsk(n, sig_blob, pub_blob, msg_blob,
                                       msg_lens, out_rsk)
                 best = min(best, _time.perf_counter() - t0)
-            if okp:
+            if okp is not None:
                 terms["ladder_us"] = best / n * 1e6
         terms["calibrated"] = True
     except Exception:
@@ -574,8 +580,7 @@ class Ed25519BatchVerifier(BatchVerifier):
 
         n = self.count()
         b = _bucket(n)
-        with _trace.span("crypto.pack", n=n, bucket=b):
-            rsk, live, pub_blob = self._pack_rsk_live(n, b)
+        rsk, live, pub_blob = self._pack_rsk_live(n, b)
         # Streamed placement: when a multi-device mesh is up, each whole
         # single-chip batch lands on the next device round-robin, so d
         # independent commits verify concurrently with no collective at
@@ -618,7 +623,12 @@ class Ed25519BatchVerifier(BatchVerifier):
     def _pack_rsk_live(self, n: int, b: int):
         """Pack the (b,96) R||S||k rows + live mask shared by the
         single-chip prehashed ladder and the sharded mesh paths (k
-        hashed host-side; see _launch_device's docstring)."""
+        hashed host-side; see _launch_device's docstring). The native
+        packer spreads the lanes over the C++ worker pool when it is
+        free; `pool` on the span and `mode` on the counter say what it
+        did: run (pooled), busy (another engine held the pool: packed
+        on this thread), small (too few lanes to split), python (no
+        native library)."""
         import hashlib
 
         pub_blob = self._pub_buf  # zero-copy; hashed + copied by callers
@@ -627,25 +637,31 @@ class Ed25519BatchVerifier(BatchVerifier):
         live[:n] = True
         from . import native
 
-        packed = native.available() and native.pack_rsk(
-            n, self._sig_buf, pub_blob, self._msg_buf,
-            np.asarray(self._msg_lens, np.uint64), rsk,
-        )
-        if not packed:
-            self._materialize()
-            sig_blob = bytes(self._sig_buf)
-            rsk[:n, :64] = np.frombuffer(sig_blob, np.uint8).reshape(n, 64)
-            sha = hashlib.sha512
-            ks = b"".join(
-                (
-                    int.from_bytes(
-                        sha(sig[:32] + pub + msg).digest(), "little"
-                    )
-                    % _L
-                ).to_bytes(32, "little")
-                for pub, msg, sig in self._items
-            )
-            rsk[:n, 64:] = np.frombuffer(ks, np.uint8).reshape(n, 32)
+        with _trace.span("crypto.pack", n=n, bucket=b) as sp:
+            chunks = native.pack_rsk(
+                n, self._sig_buf, pub_blob, self._msg_buf,
+                np.asarray(self._msg_lens, np.uint64), rsk,
+            ) if native.available() else None
+            if chunks is None:
+                self._materialize()
+                sig_blob = bytes(self._sig_buf)
+                rsk[:n, :64] = np.frombuffer(
+                    sig_blob, np.uint8).reshape(n, 64)
+                sha = hashlib.sha512
+                ks = b"".join(
+                    (
+                        int.from_bytes(
+                            sha(sig[:32] + pub + msg).digest(), "little"
+                        )
+                        % _L
+                    ).to_bytes(32, "little")
+                    for pub, msg, sig in self._items
+                )
+                rsk[:n, 64:] = np.frombuffer(ks, np.uint8).reshape(n, 32)
+            mode = ("python" if chunks is None else "busy" if chunks == 0
+                    else "small" if chunks == 1 else "run")
+            sp.add(chunks=chunks or 1, pool=mode)
+        crypto_metrics().pack_total.inc(1.0, mode)
         return rsk, live, pub_blob
 
     def _launch_mesh(self, eng):

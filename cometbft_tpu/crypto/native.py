@@ -182,13 +182,14 @@ def _bind(lib) -> None:
         ctypes.c_uint64, ctypes.c_char_p, ctypes.c_char_p,
         ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint64), ctypes.c_char_p,
     ]
-    lib.ed25519_pack_rsk.restype = None
+    lib.ed25519_pack_rsk.restype = ctypes.c_int
     # void_p operands: callers pass numpy views over their accumulation
     # buffers zero-copy (bytes() snapshots of MB-scale blobs cost ~0.5 ms
     # on the submit hot path)
     lib.ed25519_pack_rsk.argtypes = [
         ctypes.c_uint64, ctypes.c_void_p, ctypes.c_void_p,
         ctypes.c_void_p, ctypes.POINTER(ctypes.c_uint64), ctypes.c_void_p,
+        ctypes.c_int,
     ]
     lib.keccak_f1600.restype = None
     lib.keccak_f1600.argtypes = [ctypes.c_void_p]
@@ -395,28 +396,31 @@ def batch_challenge_scalars(items) -> bytes | None:
 
 
 def pack_rsk(n: int, sig_blob, pub_blob, msg_blob,
-             msg_lens, out_rsk) -> bool:
+             msg_lens, out_rsk, nchunks: int = 0) -> int | None:
     """Assemble the R||S||k device wire rows (stride 96) for n lanes
     straight into `out_rsk` (a C-contiguous uint8 numpy array with at
     least n*96 leading bytes): signature copy + 8-wide challenge
-    hashing + mod-L in one native call. False when the lib is absent
-    (caller packs in Python). The blobs may be bytes, bytearray, or
-    uint8 numpy arrays — all passed zero-copy; `msg_lens` is a uint64
-    numpy array."""
+    hashing + mod-L in one native call, in chunks over the C++ worker
+    pool when its slot is free. Returns the number of chunks the lanes
+    went in (1: too few lanes to split; 0: another engine held the
+    pool, so the calling thread packed them all), None when the lib is
+    absent (caller packs in Python). `nchunks` > 0 pins the chunk count
+    (tests; the rows are the same for every count). The blobs may be
+    bytes, bytearray, or uint8 numpy arrays — all passed zero-copy;
+    `msg_lens` is a uint64 numpy array."""
     lib = get_lib()
     if lib is None or not hasattr(lib, "ed25519_pack_rsk"):
-        return False
+        return None
     import numpy as _np
 
     def _addr(buf):
         return _np.frombuffer(buf, _np.uint8).ctypes.data_as(ctypes.c_void_p)
 
-    lib.ed25519_pack_rsk(
+    return lib.ed25519_pack_rsk(
         n, _addr(sig_blob), _addr(pub_blob), _addr(msg_blob),
         msg_lens.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
-        out_rsk.ctypes.data_as(ctypes.c_void_p),
+        out_rsk.ctypes.data_as(ctypes.c_void_p), nchunks,
     )
-    return True
 
 
 # Kept only for benchmark/ (a `simplicity` PR may not edit it):

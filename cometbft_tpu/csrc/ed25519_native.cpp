@@ -14,6 +14,7 @@
 //
 // Exposed as a tiny C ABI consumed via ctypes (no pybind11 in image).
 
+#include <algorithm>
 #include <cstdint>
 #include <cstring>
 #include <cstdio>
@@ -844,6 +845,10 @@ static bool cached_neg_decompress(ge::P *negA, const u8 pub[32]) {
 // 8-way multi-buffer SHA-512 (AVX-512) for batch challenge hashing
 #include "sha512_mb.inc"
 
+// The worker pool: the wire packer below tries it, the engines included
+// at the end of this file split their batches over it
+#include "worker_pool.inc"
+
 // ------------------------------------------------------- public ABI ------
 extern "C" {
 
@@ -1250,25 +1255,25 @@ void sha512_digest(const u8 *msg, u64 len, u8 *out) {
 }
 
 
-// Batch challenge scalars: k_i = SHA-512(R_i || A_i || M_i) mod L,
-// written at out + i*out_stride. Eight equal-length preimages at a time
-// ride the AVX-512 multi-buffer SHA-512 (csrc/sha512_mb.inc) — the
-// scalar hash loop was ~12 ms of every 10k-lane submit on the
-// single-core host; commit sign bytes within a batch are uniformly
-// sized, so grouping by length almost always fills full groups. The
-// strided output serves both the k-blob export (stride 32) and the
-// in-place R||S||k wire assembly (stride 96).
-static void batch_k_strided(u64 n, const u8 *sigs, const u8 *pubs,
-                            const u8 *msgs, const u64 *msg_lens, u8 *out,
-                            u64 out_stride) {
-    u64 off = 0;
-    u64 i = 0;
+// Batch challenge scalars of lanes [lo, hi): k_i = SHA-512(R_i || A_i ||
+// M_i) mod L, written at out + i*out_stride; `off` is where lane lo's
+// message starts in msgs. Eight equal-length preimages at a time ride
+// the AVX-512 multi-buffer SHA-512 (csrc/sha512_mb.inc) where the host
+// has it; commit sign bytes within a batch are uniformly sized, so
+// grouping by length almost always fills full groups. The strided
+// output serves both the k-blob export (stride 32) and the in-place
+// R||S||k wire assembly (stride 96). A lane's bytes depend on nothing
+// but the lane, so any split into ranges gives the same output.
+static void batch_k_strided(u64 lo, u64 hi, u64 off, const u8 *sigs,
+                            const u8 *pubs, const u8 *msgs,
+                            const u64 *msg_lens, u8 *out, u64 out_stride) {
+    u64 i = lo;
     bool mb = sha512mb::usable();
-    while (i < n) {
+    while (i < hi) {
         u64 ml = msg_lens[i];
         u64 total = 64 + ml;
         u64 nblocks = (total + 17 + 127) / 128;
-        bool group = mb && i + 8 <= n && nblocks <= 8;
+        bool group = mb && i + 8 <= hi && nblocks <= 8;
         if (group) {
             for (int k = 1; k < 8; k++)
                 if (msg_lens[i + k] != ml) { group = false; break; }
@@ -1316,23 +1321,58 @@ static void batch_k_strided(u64 n, const u8 *sigs, const u8 *pubs,
 
 void ed25519_batch_k(u64 n, const u8 *sigs, const u8 *pubs, const u8 *msgs,
                      const u64 *msg_lens, u8 *out) {
-    batch_k_strided(n, sigs, pubs, msgs, msg_lens, out, 32);
+    batch_k_strided(0, n, 0, sigs, pubs, msgs, msg_lens, out, 32);
 }
+
+// Fewest lanes worth a chunk of their own: about a millisecond of
+// hashing on one core against tens of microseconds to wake a worker.
+static const u64 PACK_CHUNK_MIN_LANES = 1024;
 
 // Assemble the device wire buffer R||S||k for n lanes directly into the
 // caller's (stride 96) numpy array: one call replaces the Python-side
 // k-blob round trip plus two numpy copies on the hot submit path
-// (crypto/ed25519.py _launch_device).
-void ed25519_pack_rsk(u64 n, const u8 *sigs, const u8 *pubs, const u8 *msgs,
-                      const u64 *msg_lens, u8 *out_rsk) {
-    for (u64 i = 0; i < n; i++) memcpy(out_rsk + i * 96, sigs + i * 64, 64);
-    batch_k_strided(n, sigs, pubs, msgs, msg_lens, out_rsk + 64, 96);
+// (crypto/ed25519.py _pack_rsk_live). The lanes go in chunks over the
+// worker pool when its slot is free; when another engine's job holds it
+// (a mixed commit's host legs are launched before the pack) the caller's
+// thread packs them all, as it does for a batch too small to split:
+// the device launch never waits for the pool. nchunks <= 0 takes the
+// chunk count from the lane count; chunk starts are multiples of 8
+// lanes, so the 8-way hash groups lanes as one chunk would. The bytes
+// are the same for every chunk count. Returns the number of chunks, 0
+// when the slot was taken.
+int ed25519_pack_rsk(u64 n, const u8 *sigs, const u8 *pubs, const u8 *msgs,
+                     const u64 *msg_lens, u8 *out_rsk, int nchunks) {
+    auto pack = [&](u64 lo, u64 hi, u64 off) {
+        for (u64 i = lo; i < hi; i++)
+            memcpy(out_rsk + i * 96, sigs + i * 64, 64);
+        batch_k_strided(lo, hi, off, sigs, pubs, msgs, msg_lens,
+                        out_rsk + 64, 96);
+    };
+    u64 T = nchunks > 0 ? (u64)nchunks
+                        : std::min<u64>(wpool::pool_width(),
+                                        n / PACK_CHUNK_MIN_LANES);
+    if (T <= 1) {
+        pack(0, n, 0);
+        return 1;
+    }
+    // where each chunk's lanes and messages start: one serial pass
+    std::vector<u64> lane0(T + 1), off0(T);
+    u64 off = 0, i = 0;
+    for (u64 c = 0; c < T; c++) {
+        lane0[c] = (n * c / T) & ~(u64)7;
+        for (; i < lane0[c]; i++) off += msg_lens[i];
+        off0[c] = off;
+    }
+    lane0[T] = n;
+    bool ran = wpool::pool()->try_run((int)T, [&](int c) {
+        pack(lane0[c], lane0[c + 1], off0[c]);
+    });
+    if (ran) return (int)T;
+    pack(0, n, 0);
+    return 0;
 }
 
 }  // extern "C"
-
-// The worker pool the engines below split their batches over
-#include "worker_pool.inc"
 
 // SHA-256 + RFC-6962 merkle root engine (own extern "C" exports)
 #include "merkle_native.inc"

@@ -533,6 +533,14 @@ class CryptoMetrics:
             "Dispatch decisions per verify path "
             "(native/ladder/mesh/cpu/single) and curve",
             labels=("path", "curve"))
+        self.pack_total = reg.counter(
+            "crypto", "pack_total",
+            "Wire packs (R||S||k rows of one device batch) by what the "
+            "packer did: run (chunks over the C++ worker pool), busy "
+            "(another engine held the pool: packed on the caller's "
+            "thread), small (too few lanes to split), python (no native "
+            "library)",
+            labels=("mode",))
         self.commit_path_total = reg.counter(
             "crypto", "commit_path_total",
             "verify_commit / verify_commit_light calls by how the "

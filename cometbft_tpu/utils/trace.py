@@ -544,7 +544,7 @@ SPAN_REGISTRY = {
     "blocksync.window_apply": "the blocks of one verified window applied (window/blocks/txs); children state.apply_block",
     "crypto.batch_verify": "one batch-verify dispatch, the host time inside submit() (path/n/bucket); its children split it",
     "crypto.materialize": "lazy whole-commit columns expanded into per-item tuples for one dispatch (n = lanes expanded, 0 when add() already built them)",
-    "crypto.pack": "fixed-shape wire arrays of one dispatch built on the host (n/bucket)",
+    "crypto.pack": "R||S||k wire rows of one ladder or mesh dispatch built on the host (n/bucket/chunks = chunks the lanes went in/pool = run|busy|small|python: pooled, pool taken so packed inline, too few lanes, no native library)",
     "crypto.device_launch": "jax.device_put of one dispatch's wire arrays plus the jitted call's return (bytes)",
     "crypto.native_verify": "one batch judged by the host C++ engine, blame rescan included (n/ok)",
     "crypto.verdict_wait": "result() blocking on one batch's verdict (path/n/batch = id of its crypto.batch_verify/since_submit_ms/blame_rerun)",

@@ -333,6 +333,53 @@ DISPATCH_TABLE = {
 }
 
 
+def _stub_host(monkeypatch, host):
+    """One of HOSTS: NATIVE_MAX and MESH_MIN as shipped, the stage model
+    with the link and the host's pack term pinned to the chip host's class
+    (1 GB/s, 1.05 us a lane); the device launches are stand-ins, so
+    nothing compiles."""
+    from cometbft_tpu.ops import ed25519_verify as ev
+
+    accel, mesh_devices = HOSTS[host]
+    e = _pin_model(monkeypatch, link_mbps=1000.0, ladder_us=1.05)
+    mesh = _StubMesh(n_devices=mesh_devices) if mesh_devices else None
+    monkeypatch.setattr(e, "_ACCEL_BACKED", accel)
+    monkeypatch.setattr(e, "_mesh_engine", lambda: mesh)
+    monkeypatch.setattr(e, "_A_CACHE", {})
+    monkeypatch.setattr(ev, "decompress_pubkeys_jit", lambda a: (a, a))
+    monkeypatch.setattr(
+        ev, "verify_batch_cached_a_jit",
+        lambda ok_a, neg_a, rsk, live: (np.asarray(live), np.asarray(True)))
+    return e
+
+
+def _columns(n):
+    """add_batch() columns of n random lanes that pass the precheck."""
+    r = np.random.default_rng(n)
+    sigs = r.integers(0, 256, (n, 64), np.uint8)
+    sigs[:, 63] = 0  # S < L on every lane
+    return (r.integers(0, 256, (n, 32), np.uint8), sigs,
+            r.integers(0, 256, n * 100, np.uint8).tobytes(),
+            np.full(n, 100, np.uint32))
+
+
+def _traced(tmp_path, fn):
+    """(fn(), the span records it emitted)."""
+    import json
+
+    from cometbft_tpu.utils import trace
+
+    sink = str(tmp_path / "spans.jsonl")
+    trace.configure(sink)
+    try:
+        out = fn()
+        trace.flush()
+        with open(sink, encoding="utf-8") as f:
+            return out, [json.loads(line) for line in f]
+    finally:
+        trace.disable()
+
+
 @needs_native
 @pytest.mark.parametrize(
     "n, host, want",
@@ -347,30 +394,11 @@ def test_dispatch_table(monkeypatch, tmp_path, n, host, want):
     where the ledger reads it: the crypto.batch_verify span and
     crypto_path_selected_total. force_perlane pins the ladder on every
     host at every size."""
-    import json
-
-    from cometbft_tpu.ops import ed25519_verify as ev
-    from cometbft_tpu.utils import trace
     from cometbft_tpu.utils.metrics import crypto_metrics
 
-    accel, mesh_devices = HOSTS[host]
-    e = _pin_model(monkeypatch, link_mbps=1000.0, ladder_us=1.05)
-    mesh = _StubMesh(n_devices=mesh_devices) if mesh_devices else None
-    monkeypatch.setattr(e, "_ACCEL_BACKED", accel)
-    monkeypatch.setattr(e, "_mesh_engine", lambda: mesh)
-    monkeypatch.setattr(e, "_A_CACHE", {})
+    e = _stub_host(monkeypatch, host)
     monkeypatch.setattr(native, "batch_verify", lambda items: True)
-    monkeypatch.setattr(ev, "decompress_pubkeys_jit", lambda a: (a, a))
-    monkeypatch.setattr(
-        ev, "verify_batch_cached_a_jit",
-        lambda ok_a, neg_a, rsk, live: (np.asarray(live), np.asarray(True)))
-
-    r = np.random.default_rng(n)
-    sigs = r.integers(0, 256, (n, 64), np.uint8)
-    sigs[:, 63] = 0  # S < L on every lane
-    columns = (r.integers(0, 256, (n, 32), np.uint8), sigs,
-               r.integers(0, 256, n * 100, np.uint8).tobytes(),
-               np.full(n, 100, np.uint32))
+    columns = _columns(n)
 
     def submit(force_perlane):
         bv = e.Ed25519BatchVerifier(backend="tpu",
@@ -385,18 +413,44 @@ def test_dispatch_table(monkeypatch, tmp_path, n, host, want):
                    if k[1] == "ed25519" and v > before.get(k, 0.0)}
         return pending._path, counted
 
-    sink = str(tmp_path / "spans.jsonl")
-    trace.configure(sink)
-    try:
-        path, counted = submit(force_perlane=False)
-        trace.flush()
-        with open(sink, encoding="utf-8") as f:
-            spans = [json.loads(line) for line in f]
-    finally:
-        trace.disable()
+    (path, counted), spans = _traced(
+        tmp_path, lambda: submit(force_perlane=False))
     (batch,) = [sp for sp in spans if sp["name"] == "crypto.batch_verify"]
     assert (path, counted, batch["path"]) == (want, {want}, want)
     assert submit(force_perlane=True) == ("ladder", {"ladder"})
+
+
+@needs_native
+@pytest.mark.parametrize("host", ["chip", "mesh4"])
+def test_every_device_dispatch_has_its_pack_span(monkeypatch, tmp_path, host):
+    """The ladder's and the mesh's submit() both pack inside one
+    crypto.pack span, a child of crypto.batch_verify, which says how many
+    chunks the lanes went in and whether the worker pool took them; the
+    same call counts under crypto_pack_total{mode}."""
+    from cometbft_tpu.utils.metrics import crypto_metrics
+
+    e = _stub_host(monkeypatch, host)
+    n = 10000
+    bv = e.Ed25519BatchVerifier(backend="tpu")
+    bv.add_batch(*_columns(n))
+    before = dict(crypto_metrics().pack_total.values())
+    ok, spans = _traced(tmp_path, lambda: bv.submit().result()[0])
+    assert ok
+    (batch,) = [sp for sp in spans if sp["name"] == "crypto.batch_verify"]
+    kids = [sp for sp in spans if sp.get("parent") == batch["id"]]
+    want_path, want_kids = {
+        "chip": ("ladder", {"crypto.pack", "crypto.device_launch"}),
+        "mesh4": ("mesh", {"crypto.pack"}),  # the stub mesh has no span
+    }[host]
+    assert batch["path"] == want_path
+    assert {sp["name"] for sp in kids} == want_kids
+    (pack,) = [sp for sp in kids if sp["name"] == "crypto.pack"]
+    chunks = min(native.rs_threads(), n // 1024)
+    mode = "run" if chunks > 1 else "small"
+    assert (pack["n"], pack["bucket"], pack["chunks"], pack["pool"]) == (
+        n, 10240, chunks, mode)
+    after = crypto_metrics().pack_total.values()
+    assert after[(mode,)] == before.get((mode,), 0.0) + 1.0
 
 
 def test_model_has_two_device_engines(monkeypatch):

@@ -2,7 +2,8 @@
 # ASAN/UBSAN build + run of the native Ed25519 engine (SURVEY §5.2's
 # sanitizer leg for csrc; the Python suite covers the logic, this
 # catches memory errors the .so build would hide). Covers the ed25519
-# engine's batch and wire-packing entry points, the secp256k1 verify
+# engine's batch and wire-packing entry points (the packer inline and
+# in chunks over the worker pool), the secp256k1 verify
 # engine (r/s boundary values, bad point
 # encodings, multi-verify chunk determinism), the sr25519 unit
 # (ristretto decode rejects, merlin challenge, batch residue s >= L,
