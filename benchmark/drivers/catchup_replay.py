@@ -7,21 +7,26 @@ Parameters (configuration shapes + the cell's traffic block):
   txs_per_block     transactions the generator puts in each block (about
                     ten bytes each); what the app applies
   warmup_windows    windows of the first pass that are not measured (the
-                    first traces, lowers and loads the RLC program)
-  profile_windows   windows in the traced stretch, from the boundary that
-                    opens the measured window: one pass's worth, because the
-                    pipeline queues a window's batch while the one before is
-                    applied, and the last window of a pass queues nothing
+                    first traces, lowers and loads the ladder at the
+                    window's bucket where set-up has not)
+  profile_windows   windows of the traced stretch: a pass of its own, run
+                    under the profiler once the measured window has closed
+                    (one pass's worth, because the pipeline queues a
+                    window's batch while the one before is applied, and the
+                    last window of a pass queues nothing)
   device_from_lanes batches of this many lanes or more must take a device path
 
-Set-up generates the chain into a sqlite store from --seed and replays its
-first window once, on the per-lane ladder (the engine a declined RLC layout
-falls back to): that warms the ladder and leaves the app and state of height
-`window`. A pass replays from a copy of those to the tip; when it reaches the
-tip a new pass starts. So every measured window is a steady one, `window`
-embedded commits and the tip's (65,000 lanes at 1000 validators), as in a long
-catch-up; the one-commit-shorter first window from genesis is a second RLC
-program (about a minute to trace and lower in every run, PR 23) that a node
+Set-up generates the chain into a sqlite store from --seed, then does what a
+node that stops does (checkpoints the write-ahead log, closes the store,
+drops the generator's objects) and opens the finished file anew: the replayer
+reads a store that something else wrote, never through the connection and
+the heap that just wrote it (PERF.md, Findings PR 31). It then replays the
+first window once, on the forced per-lane ladder, which warms the one engine
+the windows use and leaves the app and state of height `window`. A pass
+replays from a copy of those to the tip; when it reaches the tip a new pass
+starts. So every measured window is a steady one, `window` embedded commits
+and the tip's (65,000 lanes at 1000 validators), as in a long catch-up; the
+one-commit-shorter first window from genesis is a second shape that a node
 meets once in 50,000 blocks, and the measured traffic leaves it out. The
 engine applies blocks in bursts of one window, so the rate is (blocks of whole
 windows) / (time between window boundaries), a boundary being the apply of a
@@ -34,13 +39,27 @@ own verdict and apply, as every window of a long catch-up does), of a pass,
 and of all windows; and the time the harness itself spends between two passes
 (a copy of the app and the state, a new engine). The mid-pass windows are
 also the per-layer series `window_s.mid_pass`.
+
+A process draws one of two speeds (PERF.md, Findings PRs 25 and 31): glibc's
+free() either keeps a pass's 117 MB of commit-decode buffers or gives the top
+of the heap back to the system and faults it in again in every pass, at the
+load and the fill of the pass's last window. The benchmark leaves the draw as
+a node meets it. Every run prints what tells the speeds apart, never as
+metrics: beside the windows by place, the medians of their CPU seconds
+(process and main thread) and of the change in the bytes the C allocator holds
+from the system, read at the boundaries the driver stamps anyway; a traced run
+reads `window_load_tail_ms.catchup`.
 """
 
 from __future__ import annotations
 
 import copy
+import ctypes
+import gc
 import os
 import re
+import sqlite3
+import statistics
 import time
 
 from benchmark.harness import check as C
@@ -55,18 +74,90 @@ class _Stop(Exception):
 
 
 def make_store(path: str, n_blocks: int, n_vals: int, txs: int, seed: int):
+    """The generator: the chain of --seed written into a sqlite store at
+    `path`. Returns the store as the generator leaves it (open, its last
+    writes in the write-ahead log), the final state, the genesis state, and
+    the store's key-value handle, which is what there is to close."""
     from cometbft_tpu.abci.kvstore import KVStoreApp
     from cometbft_tpu.storage import BlockStore, open_kv
     from cometbft_tpu.utils import factories as fx
 
-    store = BlockStore(open_kv(path))
+    kv = open_kv(path)
+    store = BlockStore(kv)
     # nonces for 10 commits a fill: 10 x 1000 lanes is the 10240 bucket
     pool = fx.RPool(n_vals, blocks_per_fill=10, seed=seed + 11)
     _, final, genesis, _ = fx.make_chain(
         n_blocks, n_validators=n_vals, chain_id=CHAIN, txs_per_block=txs,
         app=KVStoreApp(), block_store=store, seed=seed,
         verify_last_commit=False, r_pool=pool)
-    return store, final, genesis
+    return store, final, genesis, kv
+
+
+class _Mallinfo2(ctypes.Structure):
+    _fields_ = [(name, ctypes.c_size_t) for name in (
+        "arena", "ordblks", "smblks", "hblks", "hblkhd", "usmblks", "fsmblks",
+        "uordblks", "fordblks", "keepcost")]
+
+
+try:
+    _mallinfo2 = ctypes.CDLL(None).mallinfo2
+    _mallinfo2.restype = _Mallinfo2
+except (AttributeError, OSError):  # not glibc 2.33 or later
+    _mallinfo2 = None
+
+
+def _heap_mb() -> float:
+    """MB the C allocator holds from the system: its heaps and the chunks it
+    mapped one by one (under a microsecond a call). It falls when free()
+    trims the top of the heap or unmaps a chunk, and every byte that comes
+    back is a page fault and a zeroed page (the chip's machine counts no
+    faults in ru_minflt, so this is the reading that shows it): a slow
+    process swings by a window's 117 MB in every pass, a fast one holds
+    steady within some MB."""
+    if _mallinfo2 is None:
+        return 0.0
+    m = _mallinfo2()
+    return (m.arena + m.hblkhd) / 1e6
+
+
+def settle_store(path: str) -> None:
+    """What a node that stops leaves of its store: every frame of the
+    write-ahead log moved into the file and the log cut to nothing, through
+    a connection of its own while the writer's is idle."""
+    conn = sqlite3.connect(path)
+    try:
+        conn.execute("PRAGMA wal_checkpoint(TRUNCATE)").fetchone()
+    finally:
+        conn.close()
+
+
+def build_store(path: str, p: dict, seed: int) -> None:
+    """The store as a catching-up node finds it: written by the generator,
+    settled, closed. Beside it, as a stopped node's state store would hold
+    them, the genesis state in its own encoding (`path`.genesis) and the
+    final app hash (`path`.apphash); no object the generator made outlives
+    the call."""
+    store, final, genesis, kv = make_store(
+        path, p["blocks"], p["validators"], p["txs_per_block"], seed)
+    with open(path + ".genesis", "wb") as f:
+        f.write(genesis.encode())
+    with open(path + ".apphash", "wb") as f:
+        f.write(final.app_hash)
+    settle_store(path)
+    kv.close()
+
+
+def open_store(path: str):
+    """The finished file opened anew, as a restarted node opens it:
+    (store, genesis state, final app hash)."""
+    from cometbft_tpu.state.types import State
+    from cometbft_tpu.storage import BlockStore, open_kv
+
+    with open(path + ".genesis", "rb") as f:
+        genesis = State.decode(f.read())
+    with open(path + ".apphash", "rb") as f:
+        final_hash = f.read()
+    return BlockStore(open_kv(path)), genesis, final_hash
 
 
 class Driver:
@@ -74,6 +165,9 @@ class Driver:
         self.ctx = ctx
         self.p = ctx.cell.params
         self.boundaries: list[tuple[float, int]] = []  # (t, blocks applied)
+        # beside each boundary: (process CPU s, main-thread CPU s, MB the
+        # allocator holds from the system)
+        self.usage: list[tuple[float, float, float]] = []
         self.pass_of: list[int] = []  # the pass each boundary closed a window of
         self.between_passes: list[tuple[float, float]] = []  # (t, harness s)
         self.applied = 0
@@ -86,7 +180,6 @@ class Driver:
         self.t0 = self.t1 = 0.0
         self.deadline = None
         self.profile_units = 0
-        self._profiler = None
 
     # -- the engine, with the harness's executor under it ---------------
 
@@ -120,26 +213,21 @@ class Driver:
         self.hash_checked += 1
         self.hash_mismatch += state.app_hash != want
         self.boundaries.append((now, self.applied))
+        self.usage.append((time.process_time(), time.thread_time(),
+                           _heap_mb()))
         self.pass_of.append(len(self.passes))
         if self.deadline is None:
             if len(self.boundaries) == self.p["warmup_windows"]:
                 self._open_window(now)
             elif len(self.boundaries) == 1:
-                self.ctx.objects_tracked("first RLC window replayed")
-        else:
-            if self._profiler is not None and self._profiler.active:
-                self.profile_units += 1
-                if self.profile_units == self.p["profile_windows"]:
-                    self._profiler.stop()
-            if now >= self.deadline:
-                raise _Stop
+                self.ctx.objects_tracked("first window of a pass replayed")
+        elif now >= self.deadline:
+            raise _Stop
 
     def _open_window(self, now: float) -> None:
         self.t0 = now
         self.deadline = now + self.seconds
         self.ctx.window_opens(now)
-        if self._profiler is not None:
-            self._profiler.start()
 
     def _pass(self) -> None:
         t0 = time.perf_counter()
@@ -162,19 +250,23 @@ class Driver:
     def setup(self) -> None:
         p, seed = self.p, self.ctx.seed
         t0 = time.perf_counter()
+        from cometbft_tpu.abci.kvstore import KVStoreApp
+
         db = os.path.join(self.ctx.workdir, "blockstore.db")
-        self.store, final, self.genesis = make_store(
-            db, p["blocks"], p["validators"], p["txs_per_block"], seed)
-        self.final_hash = final.app_hash
+        build_store(db, p, seed)
         self.ctx.objects_tracked("data built")
+        t1 = time.perf_counter()
+        freed = gc.collect()
+        self.store, self.genesis, self.final_hash = open_store(db)
+        self.ctx.objects_tracked("store reopened")
         log(f"   generated {p['blocks']} blocks x {p['validators']} validators "
             f"into sqlite ({os.path.getsize(db) / 1e6:.1f} MB) in "
-            f"{time.perf_counter() - t0:.1f}s")
-        # the first window, on the per-lane ladder at the window's bucket,
-        # which a declined RLC layout falls back to; every pass starts from
-        # the app and state it leaves
+            f"{t1 - t0:.1f}s; closed, collected ({freed} unreachable objects) "
+            f"and opened anew in {time.perf_counter() - t1:.2f}s")
+        # the first window, on the forced per-lane ladder at the window's
+        # bucket: the one engine the windows use; every pass starts from the
+        # app and state it leaves
         t0 = time.perf_counter()
-        from cometbft_tpu.abci.kvstore import KVStoreApp
 
         self.app_w = KVStoreApp()
         self.mode = "aside"
@@ -197,7 +289,6 @@ class Driver:
         boundaries belong to set-up: the measured window opens at the last
         of them (ctx.window_opens is called there)."""
         self.seconds = seconds
-        self._profiler = self.ctx.profiler  # None unless --trace 1
         try:
             while True:
                 self._pass()
@@ -209,21 +300,35 @@ class Driver:
             if self.deadline is None:
                 raise
         self.t1 = time.perf_counter()
-        if self._profiler is not None and self._profiler.active:
-            self._profiler.stop()
 
     def profile_stretch(self) -> None:
-        pass  # the stretch is the first measured window, traced as it runs
+        """One pass more under the profiler, once the measured window has
+        closed: the profiler's start and its stop (seconds of work on the
+        calling thread) lie outside every span of the window. Its blocks
+        are not the run's."""
+        w, tip = self.p["window"], self.p["blocks"]
+        to = min(tip, w * (1 + self.p["profile_windows"]))
+        engine = self._engine(self.store, copy.deepcopy(self.app_w))
+        start = self.state_w.copy()
+        self.mode = "aside"
+        self.ctx.profiler.start()
+        try:
+            engine.run(start, to_height=to)
+        finally:
+            self.ctx.profiler.stop()
+            self.mode = "run"
+        self.profile_units = -(-(to - w) // w)
 
     def _rate(self):
         return whole_window_rate(self.boundaries, self.t0, self.seconds)
 
-    def _window_times(self) -> dict:
-        """Seconds between consecutive boundaries inside the run, by the
+    def _windows_by_place(self) -> dict:
+        """The windows between consecutive boundaries inside the run, by the
         window's place in its pass: `first` starts with an empty pipeline
         (and holds the harness's work between passes), `last` queues no
         next window, `mid_pass` does what every window of a long catch-up
-        does."""
+        does. Each a row (seconds, process CPU s, main-thread CPU s, MB
+        more held from the system)."""
         per_pass = (self.p["blocks"] - self.p["window"]) // self.p["window"]
         end = self.t0 + self.seconds
         out: dict = {"first": [], "mid_pass": [], "last": []}
@@ -235,11 +340,13 @@ class Driver:
                 continue
             kind = ("first" if place == 0 else
                     "last" if place == per_pass - 1 else "mid_pass")
-            out[kind].append(t_b - t_a)
+            out[kind].append((t_b - t_a, *(
+                b - a for a, b in zip(self.usage[i - 1], self.usage[i]))))
         return out
 
     def series(self) -> dict:
-        return {f"window_s.{k}": v for k, v in self._window_times().items()}
+        return {f"window_s.{k}": [row[0] for row in v]
+                for k, v in self._windows_by_place().items()}
 
     def attempted(self) -> int:
         return self._rate()[2]
@@ -257,10 +364,9 @@ class Driver:
         log(f"   whole windows inside the run: {windows} = {blocks} blocks in "
             f"{span:.2f}s; boundaries at {rel[:10]} ... {rel[-3:]}; "
             f"completed passes: {len(self.passes)}")
-        wt = self._window_times()
+        by_place = self._windows_by_place()
+        wt = {k: [row[0] for row in v] for k, v in by_place.items()}
         if all(wt.values()):
-            import statistics
-
             w = self.p["window"]
             med = {k: statistics.median(v) for k, v in wt.items()}
             per_pass = (self.p["blocks"] - w) // w
@@ -271,6 +377,14 @@ class Driver:
                 f"{w / med['mid_pass']:.1f} blocks/s, a pass of median windows "
                 f"{w * per_pass / a_pass:.1f} (what restarts cost), all "
                 f"windows {rate:.1f} (what pauses cost besides)")
+            cols = {k: [round(statistics.median(c), 3) for c in zip(*rows)][1:]
+                    for k, rows in by_place.items()}
+            held = [u[2] for (t, _), u in zip(self.boundaries, self.usage)
+                    if self.t0 <= t <= self.t0 + self.seconds]
+            log(f"   the same windows' medians of (process CPU s, main-thread "
+                f"CPU s, MB more held from the system): {cols}; the allocator "
+                f"held {min(held):.0f} to {max(held):.0f} MB at the boundaries "
+                f"inside the run")
         inside = [s for t, s in self.between_passes
                   if self.t0 <= t <= self.t0 + self.seconds]
         log(f"   harness work between passes (a copy of the app and the "

@@ -17,7 +17,7 @@ from __future__ import annotations
 def accept_all() -> None:
     from cometbft_tpu.crypto import ed25519 as E
 
-    for cls in (E.PendingBatch, E.PendingRLC, E.DonePending):
+    for cls in (E.PendingBatch, E.DonePending):
         orig = cls.result
 
         def result(self, _orig=orig):

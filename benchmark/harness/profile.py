@@ -14,6 +14,7 @@ from __future__ import annotations
 import bisect
 import glob
 import os
+import time
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
@@ -26,6 +27,7 @@ class Profiler:
     def __init__(self, out_dir: str):
         self.dir = out_dir
         self.active = False
+        self.t_on = self.t_off = None  # perf_counter at start(), after stop()
 
     def start(self) -> None:
         import jax
@@ -33,6 +35,7 @@ class Profiler:
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 1
+        self.t_on = time.perf_counter()
         jax.profiler.start_trace(self.dir, profiler_options=opts)
         self.active = True
 
@@ -41,6 +44,7 @@ class Profiler:
 
         jax.profiler.stop_trace()
         self.active = False
+        self.t_off = time.perf_counter()
 
     def xplane(self) -> str:
         found = sorted(glob.glob(os.path.join(

@@ -76,8 +76,8 @@ class Ctx:
     @contextlib.contextmanager
     def perlane_forced(self):
         """Every batch verifier made inside takes the per-lane ladder (its
-        own `force_perlane` argument): the engine a declined RLC layout
-        falls back to, which warm-up must have run once."""
+        own `force_perlane` argument), whatever the dispatch would pick: a
+        warm-up inside compiles and loads the ladder at its batch's bucket."""
         from cometbft_tpu.crypto import ed25519 as E
 
         orig = E.Ed25519BatchVerifier.__init__
@@ -148,7 +148,7 @@ def main(argv=None) -> int:
         f"{cell.driver_name}, seed {args.seed}, {args.seconds:g}s, trace "
         f"{args.trace}" + (f", FAULT {args.fault}" if args.fault else ""))
     log(f"   parameters: {json.dumps(cell.params, sort_keys=True)}")
-    if not (native.available() and native.rlc_available()):
+    if not native.available():
         raise SystemExit(f"FAIL: the host C++ engine is not available: "
                          f"{native.build_state()}")
     bs = native.build_state()
@@ -228,6 +228,12 @@ def main(argv=None) -> int:
         if args.trace:
             log("== traced stretch")
             driver.profile_stretch()
+            log(f"   profiler on from {profiler.t_on - driver.t1:+.2f}s to "
+                f"{profiler.t_off - driver.t1:+.2f}s after the window closed, "
+                f"{driver.profile_units} units: "
+                + ("outside every span of the measured window"
+                   if profiler.t_on >= driver.t1 else
+                   "INSIDE the measured window: its spans hold the profiler"))
             t_load = time.perf_counter()
             xplane = profiler.xplane()
             trace_data = load_xplane(xplane, keep_host=tuple(recorder.names()))
@@ -272,6 +278,10 @@ def main(argv=None) -> int:
         if args.fault:
             line["fault"] = args.fault
         log(f"   compiles, whole run: {json.dumps(watch.summary())}")
+        # each number compared beside its limit: last in the line, and the
+        # last lines on standard error
+        line["checks"] = {c.name: {"value": c.value, "limit": c.limit,
+                                   "ok": c.ok} for c in checks}
     finally:
         if recorder is not None:
             recorder.unwrap_all()
@@ -279,6 +289,10 @@ def main(argv=None) -> int:
             gc_watch.close()
         trace.disable()
         shutil.rmtree(workdir, ignore_errors=True)
+    for name, c in line["checks"].items():
+        print(f"check {name}: {c['value']} (limit {c['limit']}) "
+              f"{'ok' if c['ok'] else 'FAIL'}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

@@ -1,9 +1,15 @@
 """The comparisons that decide `correct`, fed by hand: a batch on a host path,
 a compile inside the window, a reader that finds nothing."""
 
+import json
+import os
+
+import pytest
+
 from benchmark.harness import check as C
 from benchmark.harness import readers
 from benchmark.harness.env import CompileWatch
+from benchmark.harness.spec import BENCH, load_benchmark
 
 
 def _delta(paths, gave=None, lanes=0, batches=0):
@@ -12,17 +18,17 @@ def _delta(paths, gave=None, lanes=0, batches=0):
 
 
 def test_a_big_batch_on_the_host_makes_the_run_incorrect():
-    d = _delta({("rlc", "ed25519"): 9.0, ("native", "ed25519"): 1.0,
+    d = _delta({("mesh", "ed25519"): 9.0, ("native", "ed25519"): 1.0,
                 ("batch", "ed25519"): 10.0})
     checks = C.path_checks(d, None, 1024, 10)
     assert not all(c.ok for c in checks)
-    good = _delta({("rlc", "ed25519"): 9.0, ("ladder", "ed25519"): 1.0,
+    good = _delta({("mesh", "ed25519"): 9.0, ("ladder", "ed25519"): 1.0,
                    ("batch", "ed25519"): 10.0})
     assert all(c.ok for c in C.path_checks(good, None, 1024, 10))
 
 
 def test_spans_name_the_hidden_batch_when_tracing_is_on():
-    good = _delta({("rlc", "ed25519"): 1.0})
+    good = _delta({("mesh", "ed25519"): 1.0})
     spans = [{"name": "crypto.batch_verify", "n": 10000, "path": "native"},
              {"name": "crypto.batch_verify", "n": 150, "path": "native"}]
     checks = C.path_checks(good, spans, 1024, 1)
@@ -40,11 +46,15 @@ def test_lanes_sent_to_the_host_at_result_are_a_fault():
     assert not all(c.ok for c in C.path_checks(d, None, 1024, 1))
 
 
-def test_a_verify_program_compiling_inside_the_window_is_a_fault():
+@pytest.mark.parametrize("program", [
+    "jit(verify_batch_cached_a)", "jit(decompress_pubkeys)",
+    "jit(local)",  # the mesh's, as jax.monitoring names it on four devices
+])
+def test_a_verify_program_compiling_inside_the_window_is_a_fault(program):
     w = CompileWatch.__new__(CompileWatch)
     w.events = [
-        {"fn": "jit(rlc_verify_stream)", "s": 6.0, "t": 5.0, "cache_hit": True},
-        {"fn": "jit(rlc_verify_stream)", "s": 6.0, "t": 50.0, "cache_hit": True},
+        {"fn": program, "s": 6.0, "t": 5.0, "cache_hit": True},
+        {"fn": program, "s": 6.0, "t": 50.0, "cache_hit": True},
         {"fn": "jit(_stack)", "s": 0.01, "t": 51.0, "cache_hit": False},
     ]
     assert all(c.ok for c in C.compile_checks(w, 10.0, 40.0))
@@ -140,3 +150,37 @@ def test_driver_series_statistic():
                                   "stat": "median"}, src) == 0.5
     assert readers.driver_series({"series": "window_s.first",
                                   "stat": "median"}, src) is None
+
+
+def test_the_tail_load_marker_reads_the_last_written_window_alone():
+    """window_load_tail_ms.catchup: the median of blocksync.window_load over
+    heights 193-256, whatever the other two loads of a pass take."""
+    with open(os.path.join(BENCH, "layer_metrics",
+                           "window_load_tail_ms.catchup.json")) as f:
+        spec = json.load(f)
+    spans = [{"name": "blocksync.window_load", "window": h, "dur_ms": ms}
+             for h, ms in ((65, 70.0), (129, 71.0), (193, 160.0), (65, 72.0),
+                           (129, 70.0), (193, 164.0), (193, 168.0))]
+    spans.append({"name": "blocksync.window_queue", "window": 193, "dur_ms": 1.0})
+    src = {"spans": spans}
+    assert readers.KINDS[spec["reader"]](spec["params"], src) == 164.0
+    assert readers.KINDS[spec["reader"]](spec["params"], {"spans": spans[:2]}) is None
+
+
+def test_every_layer_metric_has_its_file_and_every_file_its_entry():
+    bench = load_benchmark()
+    names = [m["name"] for m in bench["per_layer"]]
+    files = sorted(f[:-5] for f in os.listdir(os.path.join(BENCH, "layer_metrics")))
+    assert sorted(names) == files
+    cells = {w["name"] for w in bench["workloads"]}
+    for m in bench["per_layer"]:
+        with open(os.path.join(BENCH, "layer_metrics", m["name"] + ".json")) as f:
+            spec = json.load(f)
+        assert spec["reader"] in readers.KINDS, m["name"]
+        # a cell added as data (PR 26) is in BENCHMARK.json alone
+        assert set(spec["cells"]) <= set(m["workloads"]) <= cells, m["name"]
+        assert (spec["unit"], spec["layer"], spec["moves"]) == (
+            m["unit"], m["layer"], m["moves"]), m["name"]
+    # the engines PRs 25 and 28 removed have no reader left
+    assert not [n for n in names if n.startswith(("rlc_", "batch_materialize_",
+                                                  "gc_full_pause_"))]

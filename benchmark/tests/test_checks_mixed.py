@@ -170,7 +170,7 @@ def test_the_probe_leaves_a_wrong_verdict_to_the_comparisons(monkeypatch):
     from benchmark.harness import faults
     from cometbft_tpu.crypto import ed25519 as E
 
-    for cls in (E.PendingBatch, E.PendingRLC, E.DonePending):
+    for cls in (E.PendingBatch, E.DonePending):
         monkeypatch.setattr(cls, "result", cls.result)  # put back after
     faults.accept_all()
     checks = M.blame_probe(seed=12)

@@ -40,8 +40,16 @@ def names(kind, cell):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_rehearsal_end_to_end_line(cell):
-    line = last(run(cell, "--trace", "0", "--rehearse"))
+    p = run(cell, "--trace", "0", "--rehearse")
+    line = last(p)
     assert line["device"]["platform"] == "cpu" and line["rehearsal"] is True
+    # each number compared beside its limit: the line's last key, and the
+    # last lines on standard error
+    assert list(line)[-1] == "checks" and line["checks"]
+    assert all(c["ok"] for c in line["checks"].values())
+    tail = p.stderr.strip().splitlines()[-len(line["checks"]):]
+    assert [ln.split(":")[0] for ln in tail] == [
+        "check " + name for name in line["checks"]]
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
     assert set(line["metrics"]) == names("end_to_end", cell)
@@ -52,8 +60,12 @@ def test_rehearsal_end_to_end_line(cell):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_rehearsal_traced_line(cell):
-    line = last(run(cell, "--trace", "1", "--rehearse"))
+    p = run(cell, "--trace", "1", "--rehearse")
+    line = last(p)
     assert line["correct"] is True
+    # the profiler's start and stop (seconds on the calling thread) are in no
+    # span the per-layer metrics read
+    assert "outside every span of the measured window" in p.stdout
     # off the chip no batch takes a device path and nothing runs on a
     # device: the device-trace metrics and RLC's share find nothing to read
     got = set(line["metrics"])
@@ -67,6 +79,7 @@ def test_control_a_verifier_that_accepts_everything_is_not_correct(cell):
     line = last(run(cell, "--trace", "0", "--rehearse", "--fault",
                     "accept_all", seed=4))
     assert line["correct"] is False and line["fault"] == "accept_all"
+    assert not all(c["ok"] for c in line["checks"].values())
 
 
 def _reaches_the_device(cell):
