@@ -599,6 +599,9 @@ class Ed25519BatchVerifier(BatchVerifier):
         with _trace.span("crypto.device_launch",
                          bytes=rsk.nbytes + live.nbytes) as sp:
             cached = _A_CACHE.get(fp)
+            a_cache = "miss" if cached is None else "hit"
+            sp.add(a_cache=a_cache)
+            crypto_metrics().a_cache_total.inc(1.0, a_cache)
             if cached is None:
                 a_bytes = np.zeros((b, 32), np.uint8)
                 a_bytes[:n] = np.frombuffer(

@@ -73,7 +73,7 @@ _N_CLASSES = 4
 
 class _Request:
     __slots__ = ("bv", "tenant", "source", "prio", "n", "t_enqueue",
-                 "future")
+                 "t_taken", "batch", "future")
 
     def __init__(self, bv, tenant: str, source: str, prio: int):
         self.bv = bv
@@ -82,6 +82,10 @@ class _Request:
         self.prio = prio
         self.n = bv.count()
         self.t_enqueue = time.perf_counter()
+        # traced runs only: when _take_batch popped it, and the id of
+        # the crypto.sched_coalesce span it rode in
+        self.t_taken = self.t_enqueue
+        self.batch = None
         self.future: Future = Future()
 
 
@@ -90,10 +94,14 @@ class SchedPending:
     scheduler future, interchangeable with PendingBatch where consumers
     hold one — blocksync's window pipeline calls prefetch() on it."""
 
-    __slots__ = ("_future",)
+    __slots__ = ("_req",)
 
-    def __init__(self, future: Future):
-        self._future = future
+    def __init__(self, req: _Request):
+        self._req = req
+
+    @property
+    def _future(self) -> Future:
+        return self._req.future
 
     def prefetch(self) -> None:
         # dispatch and the device fetch happen on the drainer thread;
@@ -101,7 +109,18 @@ class SchedPending:
         return None
 
     def result(self, timeout: float | None = None) -> tuple[bool, list[bool]]:
-        return self._future.result(timeout)
+        if not _trace.enabled:
+            return self._future.result(timeout)
+        # the caller's wait, in its own tree (a child of its
+        # types.verify_commit); `batch` names the crypto.sched_coalesce
+        # the request rode in, on the drainer's thread
+        req = self._req
+        with _trace.span("crypto.verdict_wait", path="sched",
+                         n=req.n) as sp:
+            try:
+                return self._future.result(timeout)
+            finally:
+                sp.add(batch=req.batch)
 
 
 def _fail(fut: Future, exc: Exception) -> None:
@@ -159,6 +178,9 @@ class VerifyScheduler:
             "coalesced_requests": 0, "passthrough": 0,
         }
         self._tenant_sigs: dict[str, int] = {}
+        # how long _collect lingered, from seeing work queued to taking
+        # the batch now in flight (the drainer's own; 0 under drain_once)
+        self._lingered_s = 0.0
 
     # -- producer side ---------------------------------------------------
     def submit(self, bv, tenant: str = "default",
@@ -170,12 +192,12 @@ class VerifyScheduler:
         if req.n == 0:
             # match Ed25519BatchVerifier.verify() on an empty batch
             _resolve(req.future, (False, []))
-            return SchedPending(req.future)
+            return SchedPending(req)
         with self._cv:
             if self._closed:
                 _fail(req.future,
                       RuntimeError("verify scheduler closed"))
-                return SchedPending(req.future)
+                return SchedPending(req)
             if not self.manual and (self._stopped or self._thread is None):
                 # lazy start, admission-pipeline style: first submit
                 # after construction (or stop()) spins the drainer up
@@ -199,7 +221,7 @@ class VerifyScheduler:
             crypto_metrics().sched_queue_depth.set(
                 sum(len(d) for d in q), tenant)
             self._cv.notify()
-        return SchedPending(req.future)
+        return SchedPending(req)
 
     def set_tenant_weight(self, tenant: str, weight: float) -> None:
         with self._cv:
@@ -259,6 +281,7 @@ class VerifyScheduler:
                 self._cv.wait()
             if self._n_queued == 0 and self._stopped:
                 return None
+            t_seen = time.perf_counter() if _trace.enabled else None
             oldest = min(
                 d[0].t_enqueue
                 for q in self._queues.values() for d in q if d)
@@ -275,6 +298,8 @@ class VerifyScheduler:
             # tenant's request dispatches with zero added latency
             batch = self._take_batch()
             self._inflight = batch
+            if t_seen is not None:
+                self._lingered_s = time.perf_counter() - t_seen
             return batch
 
     def _queued_sigs(self) -> int:
@@ -327,77 +352,30 @@ class VerifyScheduler:
         for tenant in self._order:
             crypto_metrics().sched_queue_depth.set(
                 sum(len(d) for d in self._queues[tenant]), tenant)
+        if _trace.enabled:
+            now = time.perf_counter()
+            for req in batch:
+                req.t_taken = now
         return batch
 
     def _dispatch(self, batch: list[_Request]) -> None:
         """ONE crypto dispatch for the whole batch; per-request verdicts
         recovered from the mega-bitmap by recorded lane ranges."""
-        m = crypto_metrics()
-        n_req = len(batch)
         try:
-            if n_req == 1:
-                # pass-through: the lone request's verifier dispatches
-                # as-is — no absorb copy, no coalescing tax
-                req = batch[0]
-                self.stats["dispatches"] += 1
-                self.stats["passthrough"] += 1
-                m.sched_batch_sigs.observe(req.n)
-                t0 = time.perf_counter()
-                ok, bits = req.bv.verify()
-                if _trace.enabled:
-                    _trace.emit(
-                        "crypto.sched_coalesce", "span",
-                        dur_ms=round((time.perf_counter() - t0) * 1e3, 3),
-                        n_requests=1, sigs=req.n, tenants=req.tenant,
-                        sources=req.source,
-                        per_tenant_sigs={req.tenant: req.n})
-                _resolve(req.future, (ok, bits))
-                return
             # non-coalescable verifiers (certificate one-pairing checks,
             # ISSUE 17) dispatch individually inside this drain cycle;
-            # only ed25519-absorbing verifiers share the mega-batch
-            solo = [r for r in batch
-                    if not getattr(r.bv, "coalescable", True)]
-            batch = [r for r in batch
-                     if getattr(r.bv, "coalescable", True)]
-            for req in solo:
-                self.stats["dispatches"] += 1
-                self.stats["passthrough"] += 1
-                m.sched_batch_sigs.observe(req.n)
-                _resolve(req.future, req.bv.verify())
-            if not batch:
-                return
-            if len(batch) == 1:
-                req = batch[0]
-                self.stats["dispatches"] += 1
-                self.stats["passthrough"] += 1
-                m.sched_batch_sigs.observe(req.n)
-                _resolve(req.future, req.bv.verify())
-                return
-            mega = _ed.Ed25519BatchVerifier(backend=self.backend)
-            ranges: list[tuple[int, int]] = []
-            per_tenant: dict[str, int] = {}
+            # only ed25519-absorbing verifiers share the mega-batch, and
+            # a lone one of those dispatches as-is too
+            solo, merged = [], []
             for req in batch:
-                ranges.append(mega.absorb(req.bv))
-                per_tenant[req.tenant] = \
-                    per_tenant.get(req.tenant, 0) + req.n
-                m.sched_coalesced_total.inc(1.0, req.source)
-            self.stats["dispatches"] += 1
-            self.stats["coalesced_requests"] += n_req
-            m.sched_batch_sigs.observe(mega.count())
-            tenants = ",".join(sorted(per_tenant))
-            sources = ",".join(sorted({r.source for r in batch}))
-            t0 = time.perf_counter()
-            ok_all, bits_all = mega.verify()
-            dur_ms = round((time.perf_counter() - t0) * 1e3, 3)
-            if _trace.enabled:
-                _trace.emit("crypto.sched_coalesce", "span",
-                            dur_ms=dur_ms, n_requests=n_req,
-                            sigs=mega.count(), tenants=tenants,
-                            sources=sources, per_tenant_sigs=per_tenant)
-            for req, (start, end) in zip(batch, ranges):
-                bits = bits_all[start:end]
-                _resolve(req.future, (all(bits), bits))
+                (merged if getattr(req.bv, "coalescable", True)
+                 else solo).append(req)
+            if len(merged) == 1:
+                solo.append(merged.pop())
+            for req in solo:
+                self._pass_through(req)
+            if merged:
+                self._coalesce(merged)
         except Exception as exc:  # noqa: BLE001 — deliver, don't die
             for req in batch:
                 _fail(req.future, RuntimeError(
@@ -407,6 +385,71 @@ class VerifyScheduler:
             with self._cv:
                 self._inflight = []
 
+    def _pass_through(self, req: _Request) -> None:
+        """A lone request's verifier dispatches as-is: no absorb copy,
+        no coalescing tax."""
+        self.stats["dispatches"] += 1
+        self.stats["passthrough"] += 1
+        crypto_metrics().sched_batch_sigs.observe(req.n)
+        with _trace.span("crypto.sched_coalesce") as sp:
+            verdict = req.bv.verify()
+            self._answer([req], [verdict], sp, None)
+
+    def _coalesce(self, batch: list[_Request]) -> None:
+        m = crypto_metrics()
+        with _trace.span("crypto.sched_coalesce") as sp:
+            t0 = time.perf_counter() if _trace.enabled else None
+            mega = _ed.Ed25519BatchVerifier(backend=self.backend)
+            ranges = [mega.absorb(req.bv) for req in batch]
+            absorb_s = None if t0 is None else time.perf_counter() - t0
+            for req in batch:
+                m.sched_coalesced_total.inc(1.0, req.source)
+            self.stats["dispatches"] += 1
+            self.stats["coalesced_requests"] += len(batch)
+            m.sched_batch_sigs.observe(mega.count())
+            _, bits_all = mega.verify()
+            verdicts = []
+            for start, end in ranges:
+                bits = bits_all[start:end]
+                verdicts.append((all(bits), bits))
+            self._answer(batch, verdicts, sp, absorb_s)
+
+    def _answer(self, batch: list[_Request], verdicts: list, sp,
+                absorb_s: float | None) -> None:
+        """Resolve every request of one dispatch, then (traced runs)
+        give the dispatch's span its fields and write one
+        crypto.sched_wait a request: children of that span, stamped
+        with the instant their verdict was set."""
+        if not _trace.enabled:
+            for req, verdict in zip(batch, verdicts):
+                _resolve(req.future, verdict)
+            return
+        done = []
+        for req, verdict in zip(batch, verdicts):
+            req.batch = sp.id
+            _resolve(req.future, verdict)
+            done.append(time.perf_counter())
+        per_tenant: dict[str, int] = {}
+        for req in batch:
+            per_tenant[req.tenant] = per_tenant.get(req.tenant, 0) + req.n
+        sigs = sum(per_tenant.values())
+        sp.add(n_requests=len(batch), sigs=sigs,
+               lanes_bucket=_ed._bucket(sigs),
+               tenants=",".join(sorted(per_tenant)),
+               sources=",".join(sorted({r.source for r in batch})),
+               per_tenant_sigs=per_tenant,
+               collect_ms=round(self._lingered_s * 1e3, 3))
+        if absorb_s is not None:
+            sp.add(absorb_ms=round(absorb_s * 1e3, 3))
+        alone = len(batch) == 1
+        for req, t1 in zip(batch, done):
+            _trace.emit(
+                "crypto.sched_wait", "span",
+                dur_ms=round((t1 - req.t_enqueue) * 1e3, 3),
+                queued_ms=round((req.t_taken - req.t_enqueue) * 1e3, 3),
+                tenant=req.tenant, source=req.source, n=req.n,
+                batch=sp.id, alone=alone)
+
     # -- manual pump (tests, deterministic measurement) ------------------
     def drain_once(self) -> int:
         """Form and dispatch one batch from whatever is queued right
@@ -415,6 +458,7 @@ class VerifyScheduler:
         with self._cv:
             batch = self._take_batch()
             self._inflight = batch
+            self._lingered_s = 0.0
         if batch:
             self._dispatch(batch)
         return len(batch)

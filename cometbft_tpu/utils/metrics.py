@@ -541,6 +541,13 @@ class CryptoMetrics:
             "thread), small (too few lanes to split), python (no native "
             "library)",
             labels=("mode",))
+        self.a_cache_total = reg.counter(
+            "crypto", "a_cache_total",
+            "Ladder launches by whether the batch's pubkey column was "
+            "already decompressed on the device (crypto/ed25519.py "
+            "_A_CACHE, keyed by the whole column and the bucket): hit, "
+            "or miss (the column ships and decompresses again)",
+            labels=("result",))
         self.commit_path_total = reg.counter(
             "crypto", "commit_path_total",
             "verify_commit / verify_commit_light calls by how the "

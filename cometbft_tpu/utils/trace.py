@@ -545,14 +545,15 @@ SPAN_REGISTRY = {
     "crypto.batch_verify": "one batch-verify dispatch, the host time inside submit() (path/n/bucket); its children split it",
     "crypto.materialize": "lazy whole-commit columns expanded into per-item tuples for one dispatch (n = lanes expanded, 0 when add() already built them)",
     "crypto.pack": "R||S||k wire rows of one ladder or mesh dispatch built on the host (n/bucket/chunks = chunks the lanes went in/pool = run|busy|small|python: pooled, pool taken so packed inline, too few lanes, no native library)",
-    "crypto.device_launch": "jax.device_put of one dispatch's wire arrays plus the jitted call's return (bytes)",
+    "crypto.device_launch": "jax.device_put of one dispatch's wire arrays plus the jitted call's return (bytes/a_cache = hit: the batch's pubkey column was already decompressed on the device, miss: it ships and decompresses again; untraced nodes read crypto_a_cache_total{result})",
     "crypto.native_verify": "one batch judged by the host C++ engine, blame rescan included (n/ok)",
-    "crypto.verdict_wait": "result() blocking on one batch's verdict (path/n/batch = id of its crypto.batch_verify/since_submit_ms/blame_rerun)",
+    "crypto.verdict_wait": "result() blocking on one batch's verdict (path/n/batch = id of its crypto.batch_verify/since_submit_ms/blame_rerun); path = sched: a caller's wait on a shared-scheduler handle (SchedPending.result), batch = id of the crypto.sched_coalesce its request rode in",
     "crypto.commit_partition": "one curve's leg of one commit, launch to verdict, a child of types.verify_commit that its sibling legs overlap (curve/path/n/own_ms = the leg's own time on the thread that ran it: the host engine's call on its worker thread, or submit() plus the blocked result() of a device batch/waited_ms = what result() blocked the caller for)",
     "crypto.bls_aggregate": "one BLS partition collapsed to aggregate pairing check(s) (n/pairing_checks)",
     "crypto.mesh_submit": "one sharded mega-batch across the verify mesh (n/b/n_devices/shard_lanes)",
     "crypto.stream_place": "one streamed commit placed on a mesh device (device/n/b)",
-    "crypto.sched_coalesce": "one shared-scheduler dispatch: n_requests/sigs/tenants/sources/per_tenant_sigs (crypto/sched.py)",
+    "crypto.sched_coalesce": "one shared-scheduler dispatch on the drainer's thread, the merge, the verify and the answers; its crypto.batch_verify and the requests' crypto.sched_wait are its children (n_requests/sigs/lanes_bucket/tenants/sources/per_tenant_sigs/absorb_ms = the merge loop, absent on the pass-through/collect_ms = how long _collect lingered between seeing work and taking this batch) (crypto/sched.py)",
+    "crypto.sched_wait": "one request through the shared scheduler, written where its verdict is set: dur_ms = enqueue to verdict, queued_ms = enqueue to the moment _take_batch popped it (tenant/source/n/batch = id of the crypto.sched_coalesce it rode in/alone = true on the pass-through)",
     "mempool.admit_window": "one micro-batched admission window: n/dup/sig_fail/app_fail/admitted + stage ms",
     "tx.lifecycle": "one stage crossing of a sampled tx (tx/stage/mono; utils/txlife.py — hash-prefix sampled, correlated across nodes by tx)",
     "p2p.send": "consensus wire message handed to a peer (msg/height/round/peer)",

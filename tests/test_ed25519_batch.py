@@ -180,3 +180,42 @@ def test_pipelined_submit_and_collect():
     # individual result() agrees with collect_pending
     ok1b, bits1b = p1.result()
     assert (ok1b, bits1b) == (ok1, bits1)
+
+
+def test_a_cache_hits_on_the_same_column_and_misses_on_another_k(tmp_path):
+    """The device-resident pubkey cache is keyed by the WHOLE batch's
+    pubkey column: the second launch of one column hits, the same set
+    taken k = 2 times over (a merged batch of two requests) is another
+    key and misses. crypto.device_launch says which, and so does
+    crypto_a_cache_total for untraced nodes."""
+    import json
+
+    from cometbft_tpu.crypto import ed25519 as E
+    from cometbft_tpu.utils import trace
+    from cometbft_tpu.utils.metrics import crypto_metrics
+
+    items = _signed(12)
+
+    def launch(k):
+        bv = Ed25519BatchVerifier(backend="tpu", force_perlane=True)
+        for pub, msg, sig in items * k:
+            bv.add(Ed25519PubKey(pub), msg, sig)
+        ok, bits = bv.verify()
+        assert ok and len(bits) == 12 * k
+
+    E._A_CACHE.clear()
+    sink = str(tmp_path / "a_cache.jsonl")
+    trace.configure(sink)
+    try:
+        for k in (1, 1, 2, 1):
+            launch(k)
+        trace.flush()
+    finally:
+        trace.disable()
+        E._A_CACHE.clear()
+    with open(sink, encoding="utf-8") as f:
+        got = [r["a_cache"] for r in map(json.loads, f)
+               if r["name"] == "crypto.device_launch"]
+    assert got == ["miss", "hit", "miss", "hit"]
+    assert dict(crypto_metrics().a_cache_total.values()) == {
+        ("hit",): 2.0, ("miss",): 2.0}
