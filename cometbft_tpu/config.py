@@ -429,8 +429,14 @@ class SchedConfig:
     dispatching directly. The scheduler coalesces concurrent requests
     into mega-batches bounded by `max_coalesce_sigs` /
     `max_coalesce_delay_ms` and services tenants (chain_ids) by
-    deficit-round-robin weighted by `tenant_weight`. A lone request
-    passes straight through with no added latency."""
+    deficit-round-robin weighted by `tenant_weight`. A dispatch is a
+    merge and a launch on the drainer's thread; a completion thread
+    takes its verdict and answers its requests, and at most two
+    dispatches are unanswered at a time: under load the next batch is
+    packed while the last is on the device, and `max_coalesce_delay_ms`
+    is the window of an idle scheduler only. A lone request on an idle
+    scheduler passes straight through with no added latency;
+    `stop_timeout_s` covers the join of both threads."""
 
     enabled: bool = True
     max_coalesce_sigs: int = 16384

@@ -27,18 +27,38 @@ Scheduling policy:
   fits the deficit. A hot tenant's share of any contended mega-batch is
   therefore bounded by weight/(total weight) plus one request of slack
   — the classic DRR bound — no matter how fast it submits.
-* Coalescing window: the drainer collects until ``max_coalesce_sigs``
-  or until the OLDEST queued request has waited ``max_coalesce_delay_ms``,
-  whichever comes first. Single-waiter fast path: when exactly one
-  request is queued and nothing else arrives by the time the drainer
-  looks, it dispatches immediately — an idle tenant pays zero
-  coalescing tax, and a request on an otherwise-empty queue never
-  waits out the delay window.
+* Coalescing window, the rule for an IDLE scheduler: the drainer
+  collects until ``max_coalesce_sigs`` or until the OLDEST queued
+  request has waited ``max_coalesce_delay_ms``, whichever comes first.
+  Single-waiter fast path: when exactly one request is queued, nothing
+  else arrives by the time the drainer looks AND no batch is unanswered,
+  it dispatches immediately — a tenant alone on its scheduler pays zero
+  coalescing tax. Behind an unanswered batch (whichever engine verified
+  it) a lone request lingers to its deadline: that batch's callers are
+  about to come back.
+* Two batches unanswered, the window UNDER LOAD: the drainer does not
+  wait for a verdict. It merges, packs and launches (``submit()``),
+  hands the handle to the completion thread and returns to its queues,
+  so batch n+1 is packed while batch n is on the device. At most
+  ``_MAX_UNANSWERED`` batches are launched and unanswered; the cap holds
+  back the LAUNCH, not the collection: the queues keep filling, and the
+  moment a slot frees the drainer takes everything queued (priority and
+  DRR order, up to ``max_coalesce_sigs``). Strict priority and DRR order
+  what is QUEUED, so a consensus request can find two batches of at most
+  ``max_coalesce_sigs`` lanes ahead of it, at 2.0 us a lane (66 ms at
+  the default 16,384; 10 ms where 16 chains of 150 share a scheduler).
 
-Lifecycle mirrors the PR-9 admission pipeline: lazy drainer start on
-first submit, ``stop()`` drains what it can then fails queued AND
-in-flight futures with tenant context after ``stop_timeout_s``,
-``close()`` additionally refuses later submits immediately.
+Two threads, started lazily on first submit and joined together: the
+drainer (``verify-sched``: collect, merge, launch) and the completion
+thread it owns (``verify-sched-done``: ``result()`` in launch order,
+slices, answers, then the batch's slot). A ``backend="cpu"`` verifier
+and one that cannot be merged (``coalescable`` false) have no
+``submit()`` worth the name and are verified and answered on the
+drainer's thread; ``drain_once()`` (manual mode) does everything on the
+caller's. ``stop()`` drains what it can, then fails queued requests AND
+those of every launched, unanswered batch with tenant context after
+``stop_timeout_s``; ``close()`` additionally refuses later submits
+immediately.
 
 Multi-tenant wiring: ``acquire_shared()/release_shared()`` refcount one
 process-wide scheduler per backend so N independent chains (distinct
@@ -51,6 +71,7 @@ call signature.
 
 from __future__ import annotations
 
+import queue
 import threading
 import time
 from collections import deque
@@ -69,6 +90,9 @@ PRIORITY_CLASS = {
     "background": 3,
 }
 _N_CLASSES = 4
+# batches launched and not yet answered, at most (blocksync.ReplayEngine's
+# depth): one on the device, the next merged and packed behind it
+_MAX_UNANSWERED = 2
 
 
 class _Request:
@@ -89,6 +113,36 @@ class _Request:
         self.future: Future = Future()
 
 
+class _Batch:
+    """One _take_batch from the queues to its last answer: it holds one
+    of the _MAX_UNANSWERED slots, and stop() fails its requests if it
+    cannot wait for them."""
+
+    __slots__ = ("reqs", "ahead", "pending", "part", "ranges", "span_id",
+                 "t_launch")
+
+    def __init__(self, reqs: list[_Request], ahead: int):
+        self.reqs = reqs
+        # batches on the device and unanswered when this one was taken
+        self.ahead = ahead
+        # what submit() returned for `part` (the merged requests, lane
+        # ranges in `ranges`, or the lone one, ranges None); None while
+        # not launched and where everything was answered by the thread
+        # that dispatched it
+        self.pending = None
+        self.part = reqs
+        self.ranges = None
+        # traced runs: the dispatch's crypto.sched_coalesce, and when
+        # its submit() returned
+        self.span_id = None
+        self.t_launch = None
+
+    def on_device(self) -> bool:
+        # the host engine's batch comes back from submit() verified
+        return (self.pending is not None
+                and not isinstance(self.pending, _ed.DonePending))
+
+
 class SchedPending:
     """Pending-compatible handle (.result()/.prefetch()) over a
     scheduler future, interchangeable with PendingBatch where consumers
@@ -104,8 +158,10 @@ class SchedPending:
         return self._req.future
 
     def prefetch(self) -> None:
-        # dispatch and the device fetch happen on the drainer thread;
-        # there is nothing for the consumer to start early
+        # the launch happens on the drainer's thread and the device
+        # fetch on the completion thread, which asks for each verdict
+        # as soon as its batch is launched; there is nothing for the
+        # consumer to start early
         return None
 
     def result(self, timeout: float | None = None) -> tuple[bool, list[bool]]:
@@ -131,12 +187,28 @@ def _fail(fut: Future, exc: Exception) -> None:
             pass
 
 
+def _fail_batch(reqs: list[_Request], exc: Exception) -> None:
+    for req in reqs:
+        _fail(req.future, RuntimeError(
+            f"verify dispatch failed for tenant "
+            f"{req.tenant!r} ({req.source}): {exc}"))
+
+
 def _resolve(fut: Future, value) -> None:
     if not fut.done():
         try:
             fut.set_result(value)
         except Exception:  # noqa: BLE001 — lost the resolution race
             pass
+
+
+def _slices(verdict, ranges: list | None) -> list:
+    """Each request's own (ok, bits) out of its batch's verdict."""
+    if ranges is None:
+        return [verdict]
+    bits_all = verdict[1]
+    return [(all(bits_all[start:end]), bits_all[start:end])
+            for start, end in ranges]
 
 
 class VerifyScheduler:
@@ -169,7 +241,8 @@ class VerifyScheduler:
         self._thread: threading.Thread | None = None
         self._stopped = False
         self._closed = False
-        self._inflight: list[_Request] = []
+        # every batch taken and not yet answered, oldest first
+        self._inflight: list[_Batch] = []
         self._n_queued = 0
         # counters a workload can snapshot: dispatches is the number the
         # coalescing win is measured on (dispatch calls per 1k sigs)
@@ -178,8 +251,9 @@ class VerifyScheduler:
             "coalesced_requests": 0, "passthrough": 0,
         }
         self._tenant_sigs: dict[str, int] = {}
-        # how long _collect lingered, from seeing work queued to taking
-        # the batch now in flight (the drainer's own; 0 under drain_once)
+        # how long _collect held the batch it took last, from seeing
+        # work queued (a slot free or not) to taking it (the drainer's
+        # own; 0 under drain_once)
         self._lingered_s = 0.0
 
     # -- producer side ---------------------------------------------------
@@ -200,7 +274,8 @@ class VerifyScheduler:
                 return SchedPending(req)
             if not self.manual and (self._stopped or self._thread is None):
                 # lazy start, admission-pipeline style: first submit
-                # after construction (or stop()) spins the drainer up
+                # after construction (or stop()) spins the drainer up,
+                # and the drainer its completion thread
                 self._stopped = False
                 if self._thread is None:
                     self._thread = threading.Thread(
@@ -234,8 +309,9 @@ class VerifyScheduler:
 
     # -- lifecycle -------------------------------------------------------
     def stop(self) -> None:
-        """Stop the drainer; queued and in-flight requests it could not
-        finish within stop_timeout_s fail with tenant context."""
+        """Stop both threads (the drainer joins its completion thread);
+        queued requests and those of launched batches not answered
+        within stop_timeout_s fail with tenant context."""
         with self._cv:
             self._stopped = True
             self._cv.notify_all()
@@ -250,7 +326,12 @@ class VerifyScheduler:
                     orphans.extend(d)
                     d.clear()
             self._n_queued = 0
-            orphans.extend(self._inflight)
+            for b in self._inflight:
+                orphans.extend(b.reqs)
+            # a thread that outlived the join finds its slot gone and
+            # its requests answered; a restart begins with both free
+            self._inflight = []
+            self._cv.notify_all()
             for tenant in self._queues:
                 crypto_metrics().sched_queue_depth.set(0.0, tenant)
         for req in orphans:
@@ -266,41 +347,83 @@ class VerifyScheduler:
 
     # -- drainer ---------------------------------------------------------
     def _drain_loop(self) -> None:
-        while True:
-            batch = self._collect()
-            if batch is None:
-                return
-            if batch:
-                self._dispatch(batch)
+        # the hand-over: launched batches, in launch order
+        done_q: queue.SimpleQueue = queue.SimpleQueue()
+        done = threading.Thread(target=self._done_loop, args=(done_q,),
+                                daemon=True, name="verify-sched-done")
+        done.start()
+        try:
+            while (b := self._collect()) is not None:
+                self._dispatch(b)
+                if b.pending is None:
+                    self._finish(b)
+                else:
+                    done_q.put(b)
+        finally:
+            done_q.put(None)
+            done.join()
 
-    def _collect(self) -> list[_Request] | None:
-        """Wait for work, linger for the coalescing window, pop one
-        DRR-ordered batch. None = stopped with nothing queued."""
+    def _done_loop(self, done_q: queue.SimpleQueue) -> None:
+        while (b := done_q.get()) is not None:
+            self._finish(b)
+
+    def _finish(self, b: _Batch) -> None:
+        """The rest of a dispatched batch: the verdict and the answers
+        of what it launched, then its slot (the one place that frees
+        one)."""
+        if b.pending is not None:
+            self._complete(b)
         with self._cv:
-            while self._n_queued == 0 and not self._stopped:
+            if b in self._inflight:  # stop() may have let it go
+                self._inflight.remove(b)
+            self._cv.notify_all()
+
+    def _collect(self) -> _Batch | None:
+        """Wait for work and for a free slot, linger for the coalescing
+        window, pop one DRR-ordered batch into the slot. None = stopped
+        with nothing queued."""
+        with self._cv:
+            t_seen = None
+            while True:
+                if self._n_queued:
+                    if t_seen is None and _trace.enabled:
+                        t_seen = time.perf_counter()
+                    if len(self._inflight) < _MAX_UNANSWERED:
+                        break
+                    # the cap holds back the launch only: the queues
+                    # fill on meanwhile
+                elif self._stopped:
+                    return None
                 self._cv.wait()
-            if self._n_queued == 0 and self._stopped:
-                return None
-            t_seen = time.perf_counter() if _trace.enabled else None
             oldest = min(
                 d[0].t_enqueue
                 for q in self._queues.values() for d in q if d)
             deadline = oldest + self.max_coalesce_delay_s
+            # single-waiter fast path: one request queued and no batch
+            # unanswered falls straight through, so a tenant alone on
+            # its scheduler dispatches with zero added latency. Behind
+            # an unanswered batch a lone request is the first of the
+            # next cohort and lingers like any other; what waited out a
+            # full cap is past its deadline and leaves at once, all of it
             while (not self._stopped
-                   and self._n_queued > 1
+                   and (self._n_queued > 1 or self._inflight)
                    and self._queued_sigs() < self.max_coalesce_sigs):
                 left = deadline - time.perf_counter()
                 if left <= 0:
                     break
                 self._cv.wait(timeout=left)
-            # single-waiter fast path falls straight through: with one
-            # request queued the while above never runs, so an idle
-            # tenant's request dispatches with zero added latency
-            batch = self._take_batch()
-            self._inflight = batch
+            b = self._take()
             if t_seen is not None:
                 self._lingered_s = time.perf_counter() - t_seen
-            return batch
+            return b
+
+    def _take(self) -> _Batch:
+        """One batch off the queues and into a slot. Caller holds the
+        lock; every other unanswered batch is launched by now."""
+        b = _Batch(self._take_batch(),
+                   sum(x.on_device() for x in self._inflight))
+        self._inflight.append(b)
+        return b
 
     def _queued_sigs(self) -> int:
         return sum(r.n for q in self._queues.values() for d in q for r in d)
@@ -358,110 +481,139 @@ class VerifyScheduler:
                 req.t_taken = now
         return batch
 
-    def _dispatch(self, batch: list[_Request]) -> None:
-        """ONE crypto dispatch for the whole batch; per-request verdicts
-        recovered from the mega-bitmap by recorded lane ranges."""
+    def _dispatch(self, b: _Batch) -> None:
+        """ONE crypto dispatch for the whole batch, launched and left in
+        `b.pending` for _finish(); per-request verdicts are recovered
+        from the mega-bitmap by recorded lane ranges."""
         try:
             # non-coalescable verifiers (certificate one-pairing checks,
             # ISSUE 17) dispatch individually inside this drain cycle;
             # only ed25519-absorbing verifiers share the mega-batch, and
             # a lone one of those dispatches as-is too
             solo, merged = [], []
-            for req in batch:
+            for req in b.reqs:
                 (merged if getattr(req.bv, "coalescable", True)
                  else solo).append(req)
             if len(merged) == 1:
                 solo.append(merged.pop())
             for req in solo:
-                self._pass_through(req)
+                self._pass_through(req, b)
             if merged:
-                self._coalesce(merged)
+                self._coalesce(merged, b)
         except Exception as exc:  # noqa: BLE001 — deliver, don't die
-            for req in batch:
-                _fail(req.future, RuntimeError(
-                    f"verify dispatch failed for tenant "
-                    f"{req.tenant!r} ({req.source}): {exc}"))
-        finally:
-            with self._cv:
-                self._inflight = []
+            _fail_batch(b.reqs, exc)
 
-    def _pass_through(self, req: _Request) -> None:
+    def _pass_through(self, req: _Request, b: _Batch) -> None:
         """A lone request's verifier dispatches as-is: no absorb copy,
         no coalescing tax."""
         self.stats["dispatches"] += 1
         self.stats["passthrough"] += 1
         crypto_metrics().sched_batch_sigs.observe(req.n)
         with _trace.span("crypto.sched_coalesce") as sp:
-            verdict = req.bv.verify()
-            self._answer([req], [verdict], sp, None)
+            self._launch(b, req.bv, [req], None, sp, None)
 
-    def _coalesce(self, batch: list[_Request]) -> None:
+    def _coalesce(self, merged: list[_Request], b: _Batch) -> None:
         m = crypto_metrics()
         with _trace.span("crypto.sched_coalesce") as sp:
             t0 = time.perf_counter() if _trace.enabled else None
             mega = _ed.Ed25519BatchVerifier(backend=self.backend)
-            ranges = [mega.absorb(req.bv) for req in batch]
+            ranges = [mega.absorb(req.bv) for req in merged]
             absorb_s = None if t0 is None else time.perf_counter() - t0
-            for req in batch:
+            for req in merged:
                 m.sched_coalesced_total.inc(1.0, req.source)
             self.stats["dispatches"] += 1
-            self.stats["coalesced_requests"] += len(batch)
+            self.stats["coalesced_requests"] += len(merged)
             m.sched_batch_sigs.observe(mega.count())
-            _, bits_all = mega.verify()
-            verdicts = []
-            for start, end in ranges:
-                bits = bits_all[start:end]
-                verdicts.append((all(bits), bits))
-            self._answer(batch, verdicts, sp, absorb_s)
+            self._launch(b, mega, merged, ranges, sp, absorb_s)
 
-    def _answer(self, batch: list[_Request], verdicts: list, sp,
-                absorb_s: float | None) -> None:
+    def _launch(self, b: _Batch, bv, part: list[_Request],
+                ranges: list | None, sp, absorb_s: float | None) -> None:
+        """Inside the dispatch's span: launch `bv` for `part` without
+        waiting for its verdict. The span closes behind the launch, so
+        its fields are those known by now."""
+        crypto_metrics().sched_overlap_total.inc(1.0, str(b.ahead))
+        if _trace.enabled:
+            per_tenant: dict[str, int] = {}
+            for req in part:
+                req.batch = sp.id
+                per_tenant[req.tenant] = per_tenant.get(req.tenant, 0) + req.n
+            sigs = sum(per_tenant.values())
+            sp.add(n_requests=len(part), sigs=sigs,
+                   lanes_bucket=_ed._bucket(sigs),
+                   tenants=",".join(sorted(per_tenant)),
+                   sources=",".join(sorted({r.source for r in part})),
+                   per_tenant_sigs=per_tenant,
+                   collect_ms=round(self._lingered_s * 1e3, 3),
+                   inflight=b.ahead)
+            if absorb_s is not None:
+                sp.add(absorb_ms=round(absorb_s * 1e3, 3))
+        if not getattr(bv, "coalescable", True) or bv.backend == "cpu":
+            # the certificate's pairing check and the oracle have no
+            # submit() worth the name: verified and answered here
+            self._answer(part, _slices(bv.verify(), ranges), sp.id)
+            return
+        b.part, b.ranges, b.span_id = part, ranges, sp.id
+        b.pending = bv.submit()
+        if _trace.enabled:
+            b.t_launch = time.perf_counter()
+
+    def _complete(self, b: _Batch) -> None:
+        """The verdict of a launched batch (the fetch releases the
+        interpreter), its slices, its answers. What raises here fails
+        the requests of this batch only."""
+        try:
+            with _trace.span("crypto.sched_complete", batch=b.span_id,
+                             n_requests=len(b.part)) as sp:
+                t0 = time.perf_counter() if _trace.enabled else None
+                verdict = b.pending.result()
+                if t0 is not None:
+                    sp.add(wait_ms=round(
+                        (time.perf_counter() - t0) * 1e3, 3))
+                self._answer(b.part, _slices(verdict, b.ranges), b.span_id)
+                if b.t_launch is not None:
+                    sp.add(since_launch_ms=round(
+                        (time.perf_counter() - b.t_launch) * 1e3, 3))
+        except Exception as exc:  # noqa: BLE001 — deliver, don't die
+            _fail_batch(b.part, exc)
+
+    def _answer(self, part: list[_Request], verdicts: list,
+                span_id: int | None) -> None:
         """Resolve every request of one dispatch, then (traced runs)
-        give the dispatch's span its fields and write one
-        crypto.sched_wait a request: children of that span, stamped
-        with the instant their verdict was set."""
+        write one crypto.sched_wait a request: children of the
+        dispatch's crypto.sched_coalesce (`span_id`) whichever thread
+        writes them, stamped with the instant their verdict was set."""
         if not _trace.enabled:
-            for req, verdict in zip(batch, verdicts):
+            for req, verdict in zip(part, verdicts):
                 _resolve(req.future, verdict)
             return
         done = []
-        for req, verdict in zip(batch, verdicts):
-            req.batch = sp.id
+        for req, verdict in zip(part, verdicts):
             _resolve(req.future, verdict)
             done.append(time.perf_counter())
-        per_tenant: dict[str, int] = {}
-        for req in batch:
-            per_tenant[req.tenant] = per_tenant.get(req.tenant, 0) + req.n
-        sigs = sum(per_tenant.values())
-        sp.add(n_requests=len(batch), sigs=sigs,
-               lanes_bucket=_ed._bucket(sigs),
-               tenants=",".join(sorted(per_tenant)),
-               sources=",".join(sorted({r.source for r in batch})),
-               per_tenant_sigs=per_tenant,
-               collect_ms=round(self._lingered_s * 1e3, 3))
-        if absorb_s is not None:
-            sp.add(absorb_ms=round(absorb_s * 1e3, 3))
-        alone = len(batch) == 1
-        for req, t1 in zip(batch, done):
+        alone = len(part) == 1
+        # emit() lets a field of its own name stand for the stack's
+        tree = {} if span_id is None else {"parent": span_id,
+                                           "root": span_id}
+        for req, t1 in zip(part, done):
             _trace.emit(
                 "crypto.sched_wait", "span",
                 dur_ms=round((t1 - req.t_enqueue) * 1e3, 3),
                 queued_ms=round((req.t_taken - req.t_enqueue) * 1e3, 3),
                 tenant=req.tenant, source=req.source, n=req.n,
-                batch=sp.id, alone=alone)
+                batch=span_id, alone=alone, **tree)
 
     # -- manual pump (tests, deterministic measurement) ------------------
     def drain_once(self) -> int:
-        """Form and dispatch one batch from whatever is queued right
-        now; returns the number of requests dispatched. Only meaningful
-        in manual mode (no drainer thread to race with)."""
+        """Form, dispatch and answer one batch from whatever is queued
+        right now, on the caller's thread; returns the number of
+        requests dispatched. Only meaningful in manual mode (no drainer
+        thread to race with)."""
         with self._cv:
-            batch = self._take_batch()
-            self._inflight = batch
+            b = self._take()
             self._lingered_s = 0.0
-        if batch:
-            self._dispatch(batch)
-        return len(batch)
+        self._dispatch(b)
+        self._finish(b)
+        return len(b.reqs)
 
 
 # ----------------------------------------------------------------------

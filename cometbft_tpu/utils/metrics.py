@@ -591,6 +591,15 @@ class CryptoMetrics:
             "Verify requests that shared a coalesced mega-batch dispatch, "
             "per request source (consensus/blocksync/light/admission)",
             labels=("source",))
+        self.sched_overlap_total = reg.counter(
+            "crypto", "sched_overlap_total",
+            "Scheduler dispatches by how many earlier batches were on the "
+            "device and unanswered when the drainer took them "
+            "(crypto/sched.py keeps at most two unanswered; a host-engine "
+            "batch holds a slot until answered and is not counted here): "
+            "0 = no device batch was out, 1 = merged, packed and launched "
+            "while another was on the device",
+            labels=("inflight",))
         self.sched_batch_sigs = reg.histogram(
             "crypto", "sched_batch_sigs",
             "Signatures per coalesced scheduler dispatch",

@@ -236,7 +236,10 @@ def _record(rec: dict, nested: bool) -> None:
 
 
 def emit(name: str, kind: str = "event", **fields) -> None:
-    """Queue one record. No-op (single bool check) when disabled."""
+    """Queue one record. No-op (single bool check) when disabled. It
+    hangs under the thread's innermost open span, unless the caller
+    gives `parent` and `root` among its fields: a record written on
+    one thread for a span of another (crypto.sched_wait)."""
     if not enabled:
         return
     rec = {"ts": time.time(), "pid": _pid, "name": name, "kind": kind}
@@ -552,8 +555,9 @@ SPAN_REGISTRY = {
     "crypto.bls_aggregate": "one BLS partition collapsed to aggregate pairing check(s) (n/pairing_checks)",
     "crypto.mesh_submit": "one sharded mega-batch across the verify mesh (n/b/n_devices/shard_lanes)",
     "crypto.stream_place": "one streamed commit placed on a mesh device (device/n/b)",
-    "crypto.sched_coalesce": "one shared-scheduler dispatch on the drainer's thread, the merge, the verify and the answers; its crypto.batch_verify and the requests' crypto.sched_wait are its children (n_requests/sigs/lanes_bucket/tenants/sources/per_tenant_sigs/absorb_ms = the merge loop, absent on the pass-through/collect_ms = how long _collect lingered between seeing work and taking this batch) (crypto/sched.py)",
-    "crypto.sched_wait": "one request through the shared scheduler, written where its verdict is set: dur_ms = enqueue to verdict, queued_ms = enqueue to the moment _take_batch popped it (tenant/source/n/batch = id of the crypto.sched_coalesce it rode in/alone = true on the pass-through)",
+    "crypto.sched_coalesce": "one shared-scheduler dispatch on the drainer's thread: the merge and the launch (submit(); a cpu-backend or non-coalescable verifier verifies and answers inside it); it closes behind the launch, the verdict is the completion side's; its crypto.batch_verify and the requests' crypto.sched_wait are its children (n_requests/sigs/lanes_bucket/tenants/sources/per_tenant_sigs/absorb_ms = the merge loop, absent on the pass-through/collect_ms = from the drainer seeing work queued, a slot free or not, to taking this batch/inflight = earlier batches ON THE DEVICE and unanswered when the drainer took this one, 0 or 1: a host-engine batch ahead is not counted) (crypto/sched.py)",
+    "crypto.sched_complete": "one launched batch on the completion side (the verify-sched-done thread; the caller's under drain_once): result(), the slices, the answers (batch = id of its crypto.sched_coalesce/n_requests/wait_ms = inside result()/since_launch_ms = submit() returned to last answer set)",
+    "crypto.sched_wait": "one request through the shared scheduler, written where its verdict is set, as a child of the crypto.sched_coalesce it rode in whichever thread writes it: dur_ms = enqueue to verdict, queued_ms = enqueue to the moment _take_batch popped it (tenant/source/n/batch = id of that crypto.sched_coalesce/alone = true on the pass-through)",
     "mempool.admit_window": "one micro-batched admission window: n/dup/sig_fail/app_fail/admitted + stage ms",
     "tx.lifecycle": "one stage crossing of a sampled tx (tx/stage/mono; utils/txlife.py — hash-prefix sampled, correlated across nodes by tx)",
     "p2p.send": "consensus wire message handed to a peer (msg/height/round/peer)",

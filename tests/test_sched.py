@@ -9,23 +9,25 @@ lifecycle mirrors the admission pipeline: stop() fails queued and
 in-flight requests with tenant context, close() refuses later submits.
 """
 
+import json
 import threading
 import time
 
 import pytest
 
 from cometbft_tpu.crypto import sched as S
-from cometbft_tpu.crypto.ed25519 import Ed25519BatchVerifier, Ed25519PrivKey
+from cometbft_tpu.crypto.ed25519 import (
+    DonePending, Ed25519BatchVerifier, Ed25519PrivKey)
 from cometbft_tpu.types import validation
 from cometbft_tpu.utils import factories as fx
 
 _PRIVS = [Ed25519PrivKey.generate() for _ in range(8)]
 
 
-def _bv(n, bad=(), tag=b""):
-    """A filled cpu-backend verifier with n sigs; indices in `bad` carry
-    a corrupted signature."""
-    bv = Ed25519BatchVerifier(backend="cpu")
+def _bv(n, bad=(), tag=b"", backend="cpu"):
+    """A filled verifier (the cpu oracle unless told) with n sigs;
+    indices in `bad` carry a corrupted signature."""
+    bv = Ed25519BatchVerifier(backend=backend)
     for i in range(n):
         p = _PRIVS[i % len(_PRIVS)]
         msg = b"sched-msg-%d-" % i + tag
@@ -185,10 +187,19 @@ def test_deadline_bounds_coalescing_wait():
 
 # -- concurrency --------------------------------------------------------
 
-def test_concurrent_submit_stress_no_lost_or_duplicate_futures():
-    """16 producer threads x 12 submits each race the drainer; every
-    future resolves exactly once with its own request's verdict."""
-    s = S.VerifyScheduler(backend="cpu", max_coalesce_delay_ms=1.0,
+@pytest.mark.parametrize("backend", ["cpu", "tpu", "device"])
+def test_concurrent_submit_stress_no_lost_or_duplicate_futures(backend,
+                                                               monkeypatch):
+    """16 producer threads x 12 submits each race the drainer, which
+    answers what the oracle verified ("cpu"), and the completion thread,
+    which answers what submit() launched: on the host engine ("tpu": off
+    a chip every batch is under the dispatch's line) or on the device
+    ("device": the stubbed submit() below, its handles open, so two
+    batches are in flight at times); every future resolves exactly once
+    with its own request's verdict."""
+    if backend == "device":
+        gate, backend = _Gate(monkeypatch, opened=True), "tpu"
+    s = S.VerifyScheduler(backend=backend, max_coalesce_delay_ms=1.0,
                           max_coalesce_sigs=256)
     results = {}
     lock = threading.Lock()
@@ -199,7 +210,7 @@ def test_concurrent_submit_stress_no_lost_or_duplicate_futures():
             for i in range(12):
                 bad = (0,) if (tid + i) % 3 == 0 else ()
                 tag = b"c%d-%d" % (tid, i)
-                h = s.submit(_bv(2, bad=bad, tag=tag),
+                h = s.submit(_bv(2, bad=bad, tag=tag, backend=backend),
                              tenant="t%d" % (tid % 4), source="light")
                 ok, bits = h.result(timeout=30)
                 expect_ok = not bad
@@ -320,3 +331,471 @@ def test_verify_context_reject_still_blames_exact_index():
 def test_verify_context_none_sched_is_noop():
     with S.verify_context(None, "t", "light"):
         assert S.current_context() is None
+
+
+# -- a second batch in flight (ISSUE 35) ---------------------------------
+# No chip: Ed25519BatchVerifier.submit is stubbed with a handle that
+# resolves when the test opens it, so the test decides when each batch's
+# verdict lands. Nothing here waits without a limit.
+
+LIMIT_S = 30.0
+# wide enough that two submits in a row meet in one window on a busy box
+_WINDOW_MS = 50.0
+
+
+class _Handle:
+    """What the stubbed submit() returns for a batch on the device: its
+    true verdict (the cpu oracle's), given out by result() once the test
+    opens it."""
+
+    def __init__(self, n, verdict, opened):
+        self.n = n
+        self.verdict = verdict
+        self.t_launch = time.perf_counter()
+        self.error = None
+        self._open = threading.Event()
+        if opened:
+            self._open.set()
+
+    def open(self, error=None):
+        self.error = error
+        self._open.set()
+
+    def prefetch(self):
+        pass
+
+    def result(self):
+        if not self._open.wait(LIMIT_S):
+            raise TimeoutError("the test never opened this batch")
+        if self.error is not None:
+            raise self.error
+        return self.verdict
+
+
+class _HostHandle(_Handle, DonePending):
+    """The host engine's: verified when submit() returns (a DonePending
+    to the scheduler), its answer held back like the device's so that a
+    test can look while it is unanswered."""
+
+    def __init__(self, n, verdict, opened):
+        DonePending.__init__(self, *verdict)
+        _Handle.__init__(self, n, verdict, opened)
+
+
+class _Gate:
+    """Stubs Ed25519BatchVerifier.submit; `launched` holds the handles
+    of what went to the device and `host` those of what the host engine
+    verified, each in launch order."""
+
+    def __init__(self, monkeypatch, opened=False):
+        self.launched: list[_Handle] = []
+        self.host: list[_HostHandle] = []
+        self.fail_next_submit = None
+        # the dispatch's line: a batch of fewer lanes comes back from
+        # submit() verified, as the host engine's does (answered at
+        # once unless the test holds it)
+        self.host_under = 0
+        self.hold_host = False
+        self._cv = threading.Condition()
+        gate = self
+
+        def submit(bv):
+            if gate.fail_next_submit is not None:
+                exc, gate.fail_next_submit = gate.fail_next_submit, None
+                raise exc
+            oracle = Ed25519BatchVerifier(backend="cpu")
+            oracle.absorb(bv)
+            if bv.count() < gate.host_under:
+                h = _HostHandle(bv.count(), oracle.verify(),
+                                not gate.hold_host)
+                to = gate.host
+            else:
+                h = _Handle(bv.count(), oracle.verify(), opened)
+                to = gate.launched
+            with gate._cv:
+                to.append(h)
+                gate._cv.notify_all()
+            return h
+
+        monkeypatch.setattr(Ed25519BatchVerifier, "submit", submit)
+
+    def wait_launched(self, n, timeout=LIMIT_S, host=False):
+        with self._cv:
+            to = self.host if host else self.launched
+            return self._cv.wait_for(lambda: len(to) >= n, timeout)
+
+    def open_all(self):
+        with self._cv:
+            for h in self.launched + self.host:
+                h.open()
+
+
+def _tpu(n, bad=(), tag=b""):
+    return _bv(n, bad, tag, backend="tpu")
+
+
+def _records(path):
+    with open(path, encoding="utf-8") as f:
+        return [json.loads(line) for line in f]
+
+
+def _done_threads():
+    return {t for t in threading.enumerate()
+            if t.name == "verify-sched-done"}
+
+
+@pytest.fixture
+def gate(monkeypatch):
+    g = _Gate(monkeypatch)
+    yield g
+    g.open_all()  # no thread of a failed test stays in result()
+
+
+def _two_in_flight(s, gate):
+    """Batch 0 (a lone request on an idle scheduler) and batch 1 (two
+    requests that met behind it) launched, neither answered."""
+    h0 = s.submit(_tpu(3, bad=(2,), tag=b"f0"), tenant="a",
+                  source="consensus")
+    assert gate.wait_launched(1)
+    h1 = s.submit(_tpu(2, tag=b"f1"), tenant="b", source="consensus")
+    h2 = s.submit(_tpu(4, bad=(0, 3), tag=b"f2"), tenant="c",
+                  source="blocksync")
+    assert gate.wait_launched(2), "batch 1 waited for batch 0's verdict"
+    assert [h.n for h in gate.launched] == [3, 6]
+    assert not any(h._future.done() for h in (h0, h1, h2))
+    return h0, h1, h2
+
+
+_WANT_012 = [(False, [True, True, False]), (True, [True, True]),
+             (False, [False, True, True, False])]
+
+
+@pytest.mark.parametrize("first", [0, 1],
+                         ids=["oldest-lands-first", "newest-lands-first"])
+def test_second_batch_launches_over_the_first_and_the_cap_holds(gate, first):
+    """Batch n+1 is submitted before batch n resolves; never more than
+    two are unanswered, and what queues meanwhile leaves as ONE batch the
+    moment a slot frees; every answer is its request's own slice,
+    whichever verdict lands first."""
+    s = S.VerifyScheduler(backend="tpu", max_coalesce_delay_ms=_WINDOW_MS)
+    try:
+        handles = list(_two_in_flight(s, gate))
+        late = [(5, (4,)), (1, ()), (2, (0,))]
+        for i, (n, bad) in enumerate(late):
+            handles.append(s.submit(_tpu(n, bad, tag=b"late%d" % i),
+                                    tenant="t%d" % i, source="light"))
+            time.sleep(0.02)
+        # several windows pass: the cap holds them, not the window
+        assert not gate.wait_launched(3, timeout=0.25)
+        assert len(s._inflight) == S._MAX_UNANSWERED == 2
+        gate.launched[first].open()
+        # verdicts are asked for in launch order: batch 1 alone frees
+        # nothing, batch 0 frees one slot
+        assert gate.wait_launched(3, timeout=0.3) is (first == 0)
+        gate.launched[1 - first].open()
+        assert gate.wait_launched(3)
+        assert [h.n for h in gate.launched] == [3, 6, 8]
+        gate.launched[2].open()
+        got = [h.result(timeout=LIMIT_S) for h in handles]
+        assert got == _WANT_012 + [
+            (False, [True] * 4 + [False]), (True, [True]),
+            (False, [False, True])]
+        assert s.stats["dispatches"] == 3 and s.stats["passthrough"] == 1
+    finally:
+        gate.open_all()
+        s.close()
+
+
+@pytest.mark.parametrize("ahead", [None, "device", "host"],
+                         ids=["idle", "behind-a-device-batch",
+                              "behind-a-host-engine-batch"])
+def test_a_lone_request_lingers_only_behind_an_unanswered_batch(gate, ahead):
+    """The single-waiter fast path is for an IDLE scheduler: nothing
+    queued beside the request and no batch unanswered, whichever engine
+    verified it. Behind one the lone request waits out its window like
+    any other."""
+    delay_s = 0.4 if ahead else 20.0
+    gate.hold_host = True
+    s = S.VerifyScheduler(backend="tpu", max_coalesce_delay_ms=delay_s * 1e3)
+    try:
+        if ahead:
+            gate.host_under = 3 if ahead == "host" else 0
+            s.submit(_tpu(2, tag=b"ahead"), tenant="a", source="consensus")
+            assert gate.wait_launched(1, host=ahead == "host")
+            gate.host_under = 0
+        t0 = time.perf_counter()
+        h = s.submit(_tpu(3, tag=b"lone"), tenant="b", source="consensus")
+        assert gate.wait_launched(1 + (ahead == "device"), timeout=10.0)
+        took = gate.launched[-1].t_launch - t0
+        if ahead:
+            assert took >= delay_s * 0.95, f"left after {took:.3f}s"
+        else:
+            assert took < 10.0  # a 20 s window it did not wait out
+        gate.open_all()
+        assert h.result(timeout=LIMIT_S) == (True, [True] * 3)
+        assert s.stats["passthrough"] == 1 + bool(ahead)
+    finally:
+        gate.open_all()
+        s.close()
+
+
+@pytest.mark.parametrize("where", ["submit", "result"])
+def test_a_batch_that_raises_fails_its_own_requests_only(gate, where):
+    """An exception from submit() (on the drainer) or from result() (on
+    the completion thread) fails the requests of that batch with the
+    tenant named; the batch in flight beside it is answered, both
+    threads live on and the next batch is answered too."""
+    before = _done_threads()
+    s = S.VerifyScheduler(backend="tpu", max_coalesce_delay_ms=_WINDOW_MS)
+    try:
+        h0 = s.submit(_tpu(3, bad=(2,), tag=b"f0"), tenant="a",
+                      source="consensus")
+        assert gate.wait_launched(1)
+        if where == "submit":
+            gate.fail_next_submit = OSError("engine down")
+        doomed = [s.submit(_tpu(2, tag=b"x%d" % i), tenant="t%d" % i,
+                           source="consensus") for i in range(2)]
+        if where == "result":
+            assert gate.wait_launched(2) and gate.launched[1].n == 4
+            gate.launched[1].open(error=OSError("engine down"))
+        gate.launched[0].open()
+        for i, h in enumerate(doomed):
+            with pytest.raises(RuntimeError,
+                               match=f"'t{i}'.*consensus.*engine down"):
+                h.result(timeout=LIMIT_S)
+        assert h0.result(timeout=LIMIT_S) == _WANT_012[0]
+        after = s.submit(_tpu(3, bad=(1,), tag=b"after"), tenant="t0",
+                         source="consensus")
+        assert gate.wait_launched(2 + (where == "result"))
+        gate.open_all()
+        assert after.result(timeout=LIMIT_S) == (False, [True, False, True])
+        (done,) = _done_threads() - before
+        assert s._thread.is_alive() and done.is_alive()
+        deadline = time.monotonic() + LIMIT_S
+        while s._inflight and time.monotonic() < deadline:
+            time.sleep(0.005)  # the slot frees behind the last answer
+        assert s._inflight == []
+    finally:
+        gate.open_all()
+        s.close()
+
+
+@pytest.mark.parametrize("end", ["stop", "close"])
+def test_stop_fails_two_batches_in_flight_and_joins_both_threads(gate, end):
+    before = _done_threads()
+    s = S.VerifyScheduler(backend="tpu", max_coalesce_delay_ms=_WINDOW_MS,
+                          stop_timeout_s=0.2)
+    handles = _two_in_flight(s, gate)
+    queued = s.submit(_tpu(1, tag=b"q"), tenant="d", source="light")
+    drainer = s._thread
+    (done,) = _done_threads() - before
+    assert drainer.name == "verify-sched"
+    t0 = time.monotonic()
+    getattr(s, end)()
+    assert time.monotonic() - t0 < 5.0  # one stop_timeout_s for both joins
+    for h, (n, tenant, source) in zip(
+            handles + (queued,),
+            [(3, "a", "consensus"), (2, "b", "consensus"),
+             (4, "c", "blocksync"), (1, "d", "light")]):
+        with pytest.raises(RuntimeError) as ei:
+            h.result(timeout=LIMIT_S)
+        msg = str(ei.value)
+        assert "abandoned" in msg and f"{n}-sig {source}" in msg
+        assert repr(tenant) in msg
+    assert s._thread is None and s._inflight == []
+    # the verdicts land after all: nothing is answered twice, and both
+    # threads end behind them
+    gate.open_all()
+    for t in (drainer, done):
+        t.join(timeout=LIMIT_S)
+        assert not t.is_alive()
+    late = s.submit(_tpu(2, tag=b"late"), tenant="a", source="consensus")
+    if end == "close":
+        with pytest.raises(RuntimeError, match="closed"):
+            late.result(timeout=LIMIT_S)
+        assert s._thread is None
+    else:  # a submit after stop() starts both threads anew
+        assert gate.wait_launched(3)
+        gate.open_all()
+        assert late.result(timeout=LIMIT_S) == (True, [True, True])
+        assert s._thread is not drainer
+        assert len(_done_threads() - before) == 1
+        s.close()
+    assert _done_threads() - before == set()  # joined with the drainer
+
+
+def _overlap_counts():
+    from cometbft_tpu.utils.metrics import crypto_metrics
+
+    return dict(crypto_metrics().sched_overlap_total.values())
+
+
+def test_a_host_engine_batch_holds_a_slot_and_is_not_counted_as_overlap(
+        gate, monkeypatch, tmp_path):
+    """A batch that submit() returns verified (under the dispatch's
+    line) goes to the completion thread like any other, is answered in
+    launch order and holds its slot till then; but `inflight` counts the
+    batches on the DEVICE, so only what is launched over the device's
+    batch reads 1."""
+    gate.host_under = 4
+    answered = []
+    orig = S.VerifyScheduler._answer
+
+    def answer(self, part, verdicts, span_id):
+        answered.append((threading.current_thread().name,
+                         sum(r.n for r in part)))
+        orig(self, part, verdicts, span_id)
+
+    from cometbft_tpu.utils import trace
+
+    monkeypatch.setattr(S.VerifyScheduler, "_answer", answer)
+    before = _overlap_counts()
+    sink = str(tmp_path / "host.jsonl")
+    trace.configure(sink)
+    s = S.VerifyScheduler(backend="tpu", max_coalesce_delay_ms=_WINDOW_MS)
+    try:
+        dev = s.submit(_tpu(5, bad=(1,), tag=b"dev"), tenant="a",
+                       source="consensus")
+        assert gate.wait_launched(1)
+        bvs = [_tpu(1, tag=b"h0"), _tpu(2, bad=(0,), tag=b"h1")]
+        host = [s.submit(bv, tenant=t, source="light")
+                for bv, t in zip(bvs, "bc")]
+        assert gate.wait_launched(1, host=True)
+        lone = s.submit(_tpu(2, tag=b"lone"), tenant="b", source="light")
+        # verified, and behind the device's batch all the same: both
+        # slots are taken and the lone request waits for one
+        time.sleep(4 * _WINDOW_MS / 1e3)
+        assert not any(h._future.done() for h in [dev, lone] + host)
+        with s._cv:
+            assert [(b.on_device(), sum(r.n for r in b.reqs))
+                    for b in s._inflight] == [(True, 5), (False, 3)]
+        gate.launched[0].open()
+        assert dev.result(timeout=LIMIT_S) == (
+            False, [True, False, True, True, True])
+        assert [h.result(timeout=LIMIT_S) for h in host] == [
+            (True, [True]), (False, [False, True])]
+        assert lone.result(timeout=LIMIT_S) == (True, [True, True])
+        assert answered == [("verify-sched-done", 5),
+                            ("verify-sched-done", 3),
+                            ("verify-sched-done", 2)]
+        assert len(gate.launched) == 1 and s.stats["dispatches"] == 3
+        after = _overlap_counts()
+        # the lone request was taken behind the host engine's batch
+        # only: a DonePending ahead reads 0
+        assert {k: after[k] - before.get(k, 0.0) for k in after} == {
+            ("0",): 2.0, ("1",): 1.0}
+    finally:
+        gate.open_all()
+        s.close()
+        trace.flush()
+        trace.disable()
+    # the span's field says the same, dispatch by dispatch
+    assert [(r["sigs"], r["inflight"]) for r in _records(sink)
+            if r["name"] == "crypto.sched_coalesce"] == [
+        (5, 0), (3, 1), (2, 0)]
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_drain_once_is_synchronous_on_the_callers_thread(monkeypatch,
+                                                         backend):
+    """Manual mode: submit, result, answer, return; no thread exists."""
+    before = _done_threads()
+    gate = _Gate(monkeypatch, opened=True)
+    s = S.VerifyScheduler(backend=backend, manual=True)
+    hs = [s.submit(_bv(2, bad=(i,), tag=b"m%d" % i, backend=backend),
+                   tenant="t%d" % i, source="consensus") for i in range(2)]
+    assert s.drain_once() == 2
+    assert all(h._future.done() for h in hs)
+    assert [h.result(0)[1] for h in hs] == [[False, True], [True, False]]
+    assert len(gate.launched) == (backend == "tpu")
+    assert s._thread is None and _done_threads() == before
+    assert s._inflight == [] and s.drain_once() == 0
+
+
+@pytest.mark.parametrize("kind", ["cpu-backend", "not-coalescable"])
+def test_a_verifier_without_a_submit_is_answered_by_the_drainer(
+        gate, monkeypatch, kind):
+    """The oracle and a verifier that cannot be merged are verified and
+    answered on the drainer's thread, behind nothing: submit() is never
+    called for them and they leave no batch unanswered."""
+    answered = []
+    orig = S.VerifyScheduler._answer
+
+    def answer(self, part, verdicts, span_id):
+        answered.append(threading.current_thread().name)
+        orig(self, part, verdicts, span_id)
+
+    monkeypatch.setattr(S.VerifyScheduler, "_answer", answer)
+    backend = "cpu" if kind == "cpu-backend" else "tpu"
+    s = S.VerifyScheduler(backend=backend, max_coalesce_delay_ms=_WINDOW_MS)
+    try:
+        bvs = [_bv(2, bad=(i,), tag=b"s%d" % i, backend="cpu")
+               for i in range(2)]
+        if kind == "not-coalescable":
+            for bv in bvs:
+                bv.coalescable = False
+        hs = [s.submit(bv, tenant="t%d" % i, source="consensus")
+              for i, bv in enumerate(bvs)]
+        assert [h.result(timeout=LIMIT_S)[1] for h in hs] == [
+            [False, True], [True, False]]
+        assert set(answered) == {"verify-sched"}
+        assert gate.launched == [] and gate.host == []
+        deadline = time.monotonic() + LIMIT_S
+        while s._inflight and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert s._inflight == []
+    finally:
+        s.close()
+
+
+def test_the_spans_and_the_counter_say_which_launch_overlapped(gate,
+                                                               tmp_path):
+    """crypto.sched_coalesce closes behind the launch with every field
+    it had, plus `inflight`; the completion side writes one
+    crypto.sched_complete a batch and the requests' crypto.sched_wait as
+    children of their dispatch; crypto_sched_overlap_total counts by
+    `inflight`."""
+    from cometbft_tpu.utils import trace
+
+    before = _overlap_counts()
+    sink = str(tmp_path / "overlap.jsonl")
+    trace.configure(sink)
+    s = S.VerifyScheduler(backend="tpu", max_coalesce_delay_ms=_WINDOW_MS)
+    try:
+        handles = _two_in_flight(s, gate)
+        trace.flush()
+        # both dispatch spans are written while neither verdict is in
+        early = [r for r in _records(sink)
+                 if r["name"] == "crypto.sched_coalesce"]
+        assert [r["inflight"] for r in early] == [0, 1]
+        gate.open_all()
+        assert [h.result(timeout=LIMIT_S) for h in handles] == _WANT_012
+    finally:
+        gate.open_all()
+        s.close()
+        trace.flush()
+        trace.disable()
+    recs = _records(sink)
+    coalesce = [r for r in recs if r["name"] == "crypto.sched_coalesce"]
+    assert [(r["n_requests"], r["sigs"], r["inflight"], "absorb_ms" in r)
+            for r in coalesce] == [(1, 3, 0, False), (2, 6, 1, True)]
+    assert coalesce[1]["per_tenant_sigs"] == {"b": 2, "c": 4}
+    assert coalesce[1]["sources"] == "blocksync,consensus"
+    assert all(r["collect_ms"] >= 0.0 and r["lanes_bucket"] >= r["sigs"]
+               for r in coalesce)
+    done = [r for r in recs if r["name"] == "crypto.sched_complete"]
+    assert [(r["batch"], r["n_requests"]) for r in done] == [
+        (r["id"], r["n_requests"]) for r in coalesce]
+    assert all(r["wait_ms"] >= 0.0 and r["since_launch_ms"] >= r["wait_ms"]
+               for r in done)
+    # the completion thread's spans are trees of their own
+    assert all(r["parent"] is None for r in done)
+    waits = [r for r in recs if r["name"] == "crypto.sched_wait"]
+    assert sorted((w["tenant"], w["parent"], w["batch"], w["alone"])
+                  for w in waits) == [
+        ("a", coalesce[0]["id"], coalesce[0]["id"], True),
+        ("b", coalesce[1]["id"], coalesce[1]["id"], False),
+        ("c", coalesce[1]["id"], coalesce[1]["id"], False)]
+    after = _overlap_counts()
+    assert {k: after[k] - before.get(k, 0.0) for k in after} == {
+        ("0",): 1.0, ("1",): 1.0}

@@ -389,19 +389,23 @@ def test_tracing_off_nothing_of_it_runs(world):
     vals, entries = world
     assert not trace.enabled
     seen = []
-    orig = S.VerifyScheduler._answer
+    orig = S.VerifyScheduler._answer, S.VerifyScheduler._launch
 
-    def answer(self, batch, verdicts, sp, absorb_s):
-        seen.extend(batch)
+    def launch(self, flight, bv, batch, ranges, sp, absorb_s):
         assert sp.id is None and not absorb_s
-        orig(self, batch, verdicts, sp, absorb_s)
+        orig[1](self, flight, bv, batch, ranges, sp, absorb_s)
 
-    S.VerifyScheduler._answer = answer
+    def answer(self, batch, verdicts, span_id):
+        seen.extend(batch)
+        assert span_id is None
+        orig[0](self, batch, verdicts, span_id)
+
+    S.VerifyScheduler._answer, S.VerifyScheduler._launch = answer, launch
     sched = S.VerifyScheduler(backend="tpu")
     try:
         got = _run_callers(sched, vals, entries)
     finally:
-        S.VerifyScheduler._answer = orig
+        S.VerifyScheduler._answer, S.VerifyScheduler._launch = orig
         sched.close()
     assert len(got) == len(seen) == len(CHAINS) * ROUNDS
     assert all(r.batch is None and r.t_taken == r.t_enqueue for r in seen)
