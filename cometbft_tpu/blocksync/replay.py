@@ -341,6 +341,9 @@ class ReplayEngine:
         belongs to a different set; raises when block h is missing)."""
         w_end = min(h + self.window - 1, tip)
         blocks = []
+        # what ended the window: the window size, the chain's tip, a block
+        # of another validator set, a block the store lacks
+        end = "tip" if w_end == tip else "full"
         with _trace.span("blocksync.window_load", window=h) as sp:
             for hh in range(h, w_end + 1):
                 blk = self.store.load_block(hh)
@@ -348,11 +351,15 @@ class ReplayEngine:
                     if hh == h:
                         raise BlockValidationError(
                             f"missing block at height {h}")
+                    end = "missing"
                     break
                 if blk.header.validators_hash != vals_hash:
+                    end = "set_change"
                     break
                 blocks.append(blk)
-            sp.add(blocks=len(blocks))
+            sp.add(blocks=len(blocks), end=end)
+        if blocks:
+            blocksync_metrics().window_blocks.observe(len(blocks))
         return blocks
 
     def run(self, state, to_height: int | None = None) -> tuple[object, ReplayStats]:
@@ -448,19 +455,35 @@ class ReplayEngine:
                     continue
                 # pipeline drained mid-chain: validator set changed at
                 # the boundary (or speculation failed) — reload and
-                # queue against the post-apply state
-                cur_hash = state.validators.hash()
+                # queue against the post-apply state. Nothing is in
+                # flight from here to the return of the re-queued
+                # window's submit(): blocksync.set_change spans that
+                # stretch (a depth-1 engine comes here after every
+                # window, which is no boundary)
+                sc = _trace.open_span("blocksync.set_change", height=nh)
+                new_hash = state.validators.hash()
+                boundary = new_hash != cur_hash or spec_dead
+                if boundary:
+                    reason = ("set_change" if new_hash != cur_hash
+                              else "speculation_failed")
+                    blocksync_metrics().set_change_total.inc(1.0, reason)
+                    sc.add(reason=reason)
+                cur_hash = new_hash
                 spec_dead = False
-                nxt = self._load_window(nh, tip, cur_hash)
-                if not nxt:
-                    raise BlockValidationError(
-                        f"cannot form window at height {nh}"
+                try:
+                    nxt = self._load_window(nh, tip, cur_hash)
+                    if not nxt:
+                        raise BlockValidationError(
+                            f"cannot form window at height {nh}"
+                        )
+                    nxt_handle = self._queue_window(
+                        state.chain_id, state.validators,
+                        state.last_validators, state.last_block_id,
+                        state.initial_height, nxt,
                     )
-                nxt_handle = self._queue_window(
-                    state.chain_id, state.validators,
-                    state.last_validators, state.last_block_id,
-                    state.initial_height, nxt,
-                )
+                finally:
+                    if boundary:
+                        sc.close()
                 q.append((nxt, nxt_handle))
                 last_qed = nxt
             stats.elapsed_s = time.perf_counter() - t0

@@ -486,6 +486,8 @@ class BlockExecutor:
         return self.apply_block(state, block_id, block, last_commit_preverified=True)
 
     def _update_state(self, state: State, block_id: BlockID, block: Block, resp) -> State:
+        from ..utils import trace
+
         n_vals = state.next_validators.copy()
         changed = state.last_height_validators_changed
         if resp.validator_updates:
@@ -496,7 +498,14 @@ class BlockExecutor:
                         _pub_key_from_update(vu), vu.power
                     )
                 )
-            n_vals.update_with_change_set(changes)
+            # the set's hash is asked for by the next block's validation
+            # anyway (next_validators_hash): here it is part of what a
+            # change of the set costs
+            with trace.span("state.valset_update",
+                            height=block.header.height,
+                            changes=len(changes)):
+                n_vals.update_with_change_set(changes)
+                n_vals.hash()
             changed = block.header.height + 2
         n_vals.increment_proposer_priority(1)
         # no defensive copies for the rotated sets: every mutator in the

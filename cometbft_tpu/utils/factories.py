@@ -198,6 +198,10 @@ def make_chain(
     start_state=None,
     start_commit: Commit | None = None,
     start_height: int = 1,
+    powers: list[int] | None = None,
+    extra_txs=None,
+    spare_signers: list[ScalarSigner] | None = None,
+    stale_set_at: int | None = None,
 ):
     """Generate a fully-valid signed chain by actually running the executor.
 
@@ -222,6 +226,16 @@ def make_chain(
     start_state/start_commit/start_height continue a chain from a prior
     make_chain call's (state, last_commit) so arbitrarily long chains
     build in bounded-memory chunks into one shared block_store.
+
+    A chain whose validator set changes: `powers` are the genesis powers
+    (default 10 each); extra_txs(height, state) returns the transactions
+    block `height` carries behind its own, `state` being the one the block
+    is proposed on (the kvstore app turns a val_tx() into a validator
+    update, in force two heights on; ValsetChurn draws them from a seed);
+    spare_signers hold the keys of members that join later. Every commit
+    is signed by the set the state gives for its height, except at
+    stale_set_at, where the set of the height BEFORE signs (as
+    corrupt_sig, a chain that replay must refuse).
     """
     from ..abci.client import AppConns
     from ..abci.kvstore import KVStoreApp
@@ -229,8 +243,8 @@ def make_chain(
     from ..storage import BlockStore, MemKV
 
     signers = make_signers(n_validators, seed=seed)
-    vals = make_validator_set(signers)
-    by_addr = {s.address(): s for s in signers}
+    vals = make_validator_set(signers, powers)
+    by_addr = {s.address(): s for s in signers + (spare_signers or [])}
     app = app or KVStoreApp()
     store = block_store or BlockStore(MemKV())
     executor = BlockExecutor(AppConns(app), backend=backend)
@@ -240,6 +254,8 @@ def make_chain(
     last_commit = start_commit if start_commit is not None else Commit()
     for h in range(start_height, start_height + n_blocks):
         txs = [b"k%d-%d=v%d" % (h, i, i) for i in range(txs_per_block)]
+        if extra_txs is not None:
+            txs += extra_txs(h, state)
         proposer = state.validators.get_proposer()
         block = executor.create_proposal_block(
             h, state, last_commit, proposer.address, txs,
@@ -247,10 +263,13 @@ def make_chain(
         )
         bid = block_id_for(block)
         vals_h = state.validators  # the set that signs height h's commit
+        if stale_set_at == h:
+            vals_h = state.last_validators
         state = executor.apply_block(
             state, bid, block,
             last_commit_preverified=(
-                corrupt_sig is not None or not verify_last_commit
+                corrupt_sig is not None or stale_set_at is not None
+                or not verify_last_commit
             ),
         )
         commit = make_commit(
@@ -268,6 +287,55 @@ def make_chain(
         store.save_block(block, commit)
         last_commit = commit
     return store, state, genesis, signers
+
+
+def val_tx(pub_bytes: bytes, power: int) -> bytes:
+    """The kvstore app's validator-update transaction (upstream
+    abci/example/kvstore): power 0 removes the member."""
+    return b"val:%s=%d" % (pub_bytes.hex().encode(), power)
+
+
+class ValsetChurn:
+    """make_chain's extra_txs on the schedule of upstream's e2e manifests
+    (test/e2e/networks/ci.toml: `[validator_update.<height>]` tables ten
+    heights apart, first one that adds a validator, then one that restates
+    its members' powers), drawn from `seed`: every `every` heights a block
+    carries validator updates, by turns a join (a key of `spare`, never
+    seen before, at a drawn power, while the member lowest in the order
+    leaves with power 0, so the set keeps its size) and a re-powering
+    (`repowered` members take a drawn power). Powers are drawn as the e2e
+    generator draws them, uniformly from power_lo..power_hi. The updates of
+    block H change the set of H+2 (state.next_validators is the set they
+    are applied to)."""
+
+    def __init__(self, spare: list[ScalarSigner], *, seed: int, every: int,
+                 repowered: int, power_lo: int, power_hi: int):
+        self.spare = list(spare)
+        self.rng = np.random.default_rng([seed, 36])
+        self.every, self.repowered = every, repowered
+        self.power_lo, self.power_hi = power_lo, power_hi
+        self.joins = self.repowerings = 0
+
+    def power(self) -> int:
+        return int(self.rng.integers(self.power_lo, self.power_hi + 1))
+
+    def genesis_powers(self, n: int) -> list[int]:
+        return [self.power() for _ in range(n)]
+
+    def __call__(self, height: int, state) -> list[bytes]:
+        turn, rest = divmod(height, self.every)
+        if rest:
+            return []
+        members = state.next_validators.validators
+        if turn % 2 and self.spare:
+            self.joins += 1
+            return [val_tx(members[-1].pub_key.bytes(), 0),
+                    val_tx(self.spare.pop().pub_bytes, self.power())]
+        self.repowerings += 1
+        picked = self.rng.choice(len(members), size=self.repowered,
+                                 replace=False)
+        return [val_tx(members[int(i)].pub_key.bytes(), self.power())
+                for i in picked]
 
 
 def make_commit(

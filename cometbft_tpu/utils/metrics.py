@@ -442,6 +442,16 @@ class BlockSyncMetrics:
         self.bad_blocks_total = reg.counter(
             "blocksync", "bad_blocks_total",
             "Blocks that failed verification (request redone)")
+        self.set_change_total = reg.counter(
+            "blocksync", "set_change_total",
+            "Boundaries at which replay drained its pipeline and queued "
+            "anew: the validator set changed, or speculation failed",
+            labels=("reason",))
+        self.window_blocks = reg.histogram(
+            "blocksync", "window_blocks",
+            "Blocks in a replay window as loaded (a change of the "
+            "validator set ends one early)",
+            buckets=(1, 2, 4, 8, 16, 32, 64, 128))
         self.cert_verify_seconds = reg.histogram(
             "blocksync", "cert_verify_seconds",
             "Certificate (one-pairing) commit verification wall time "
