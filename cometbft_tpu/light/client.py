@@ -379,7 +379,7 @@ class LightClient:
         byz = []
         commit = other.signed_header.commit
         for idx, cs in enumerate(commit.signatures):
-            if cs.is_absent() or idx >= len(other.validators.validators):
+            if cs.is_absent() or idx >= len(other.validators):
                 continue
             addr = cs.validator_address
             i2, v = common.validators.get_by_address(addr)

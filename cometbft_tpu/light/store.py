@@ -17,12 +17,12 @@ def _key(h: int) -> bytes:
 
 def _encode_vals(vals: ValidatorSet) -> bytes:
     out = b""
-    for v in vals.validators:
+    for m, priority in zip(vals.members, vals.priorities()):
         out += pb.f_embedded(
             1,
-            pb.f_embedded(1, encode_pub_key(v.pub_key))
-            + pb.f_varint(2, v.voting_power)
-            + pb.f_varint(3, v.proposer_priority + (1 << 62)),  # offset-encode
+            pb.f_embedded(1, encode_pub_key(m.pub_key))
+            + pb.f_varint(2, m.voting_power)
+            + pb.f_varint(3, priority + (1 << 62)),  # offset-encode
         )
     return out
 

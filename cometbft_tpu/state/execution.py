@@ -219,11 +219,11 @@ def build_last_commit_info(block: Block, last_vals: ValidatorSet | None):
         return CommitInfo()
     commit = block.last_commit
     cols = commit.verify_columns() if hasattr(commit, "verify_columns") else None
-    if cols is not None and len(cols[0]) == len(last_vals.validators):
+    if cols is not None and len(cols[0]) == len(last_vals):
         present = (cols[0] != 1).tolist()  # flags != ABSENT
         votes = [
             (val.address, val.voting_power, p)
-            for val, p in zip(last_vals.validators, present)
+            for val, p in zip(last_vals.members, present)
         ]
         return CommitInfo(round=commit.round, votes=votes)
     votes = []
@@ -415,7 +415,9 @@ class BlockExecutor:
         if life:
             _txlife.stage_block(life, "apply", height=h_)
         fail_point()  # reference execution.go:258 (post-FinalizeBlock, pre-save)
-        new_state = self._update_state(state, block_id, block, resp)
+        new_state, rotation = self._update_state(state, block_id, block, resp)
+        t_update = _time.perf_counter()
+        state_metrics().valset_rotation_total.inc(1.0, rotation)
 
         # Commit with the mempool locked, then update it against the new
         # state (reference execution.go:379 Commit).
@@ -475,7 +477,9 @@ class BlockExecutor:
             span.add(
                 validate_ms=round((t_validate - t0) * 1e3, 3),
                 finalize_ms=round((t_finalize - t_validate) * 1e3, 3),
-                commit_ms=round((t_commit - t_finalize) * 1e3, 3),
+                update_state_ms=round((t_update - t_finalize) * 1e3, 3),
+                commit_ms=round((t_commit - t_update) * 1e3, 3),
+                rotation=rotation,
                 save_events_ms=round((t_end - t_commit) * 1e3, 3),
             )
         return new_state
@@ -485,7 +489,10 @@ class BlockExecutor:
         replay window mega-batch (all structural checks still run)."""
         return self.apply_block(state, block_id, block, last_commit_preverified=True)
 
-    def _update_state(self, state: State, block_id: BlockID, block: Block, resp) -> State:
+    def _update_state(self, state: State, block_id: BlockID, block: Block,
+                      resp) -> tuple[State, str]:
+        """The state after `block`, and which arithmetic rotated the
+        proposer (ValidatorSet.increment_proposer_priority's label)."""
         from ..utils import trace
 
         n_vals = state.next_validators.copy()
@@ -507,7 +514,7 @@ class BlockExecutor:
                 n_vals.update_with_change_set(changes)
                 n_vals.hash()
             changed = block.header.height + 2
-        n_vals.increment_proposer_priority(1)
+        rotation = n_vals.increment_proposer_priority(1)
         # no defensive copies for the rotated sets: every mutator in the
         # codebase (here and consensus enter_new_round) operates on a
         # private .copy() first, so ValidatorSet objects reachable from
@@ -526,7 +533,7 @@ class BlockExecutor:
             last_height_validators_changed=changed,
             last_results_hash=results_hash(resp.tx_results),
             app_hash=resp.app_hash,
-        )
+        ), rotation
 
 
 def make_genesis_state(

@@ -103,12 +103,12 @@ def decode_params(buf: bytes) -> ConsensusParams:
     )
 
 
-def _encode_validator(v: Validator) -> bytes:
+def _encode_validator(m, proposer_priority: int) -> bytes:
     return (
-        pb.f_bytes(1, v.address)
-        + pb.f_embedded(2, encode_pub_key(v.pub_key))
-        + pb.f_varint(3, v.voting_power)
-        + pb.f_varint(4, v.proposer_priority)
+        pb.f_bytes(1, m.address)
+        + pb.f_embedded(2, encode_pub_key(m.pub_key))
+        + pb.f_varint(3, m.voting_power)
+        + pb.f_varint(4, proposer_priority)
     )
 
 
@@ -126,8 +126,8 @@ def _decode_validator(buf: bytes) -> Validator:
 
 def encode_validator_set(vs: ValidatorSet) -> bytes:
     out = b""
-    for v in vs.validators:
-        out += pb.f_embedded(1, _encode_validator(v))
+    for m, priority in zip(vs.members, vs.priorities()):
+        out += pb.f_embedded(1, _encode_validator(m, priority))
     prop = vs.get_proposer()
     out += pb.f_bytes(2, prop.address)
     return out
@@ -141,12 +141,8 @@ def decode_validator_set(buf: bytes) -> ValidatorSet:
             vals.append(_decode_validator(pb.as_bytes(v)))
         elif f == 2:
             prop_addr = pb.as_bytes(v)
-    vs = ValidatorSet(vals, increment_first=False)
-    # restore exact priorities (ValidatorSet() copies, order by power)
-    if prop_addr:
-        _, p = vs.get_by_address(prop_addr)
-        vs.proposer = p
-    return vs
+    return ValidatorSet(vals, increment_first=False,
+                        proposer_address=prop_addr)
 
 
 @dataclass

@@ -660,7 +660,7 @@ def _verify_commit(chain_id, vals, block_id, height, commit, backend,
                 # only where a leg must judge a lane singly: the
                 # per-slot code builds it
                 slot = int(lanes.slots[lane])
-                return (vals.validators[slot].pub_key,
+                return (vals.members[slot].pub_key,
                         commit.vote_sign_bytes(chain_id, slot),
                         commit.signatures[slot].signature)
 

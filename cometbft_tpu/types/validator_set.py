@@ -14,6 +14,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import NamedTuple
 
+import numpy as np
+
 from ..crypto import merkle
 from ..crypto.keys import PubKey
 from ..encoding import proto as pb
@@ -67,6 +69,12 @@ def decode_pub_key(fields: dict) -> PubKey:
     raise ValueError("unknown public key oneof")
 
 
+def _simple_encode(pub_key: PubKey, voting_power: int) -> bytes:
+    """SimpleValidator proto (pubkey + power), the hashing encoding."""
+    return pb.f_embedded(1, encode_pub_key(pub_key)) + pb.f_varint(
+        2, voting_power)
+
+
 @dataclass
 class Validator:
     address: bytes
@@ -78,27 +86,21 @@ class Validator:
     def from_pub_key(cls, pk: PubKey, power: int) -> "Validator":
         return cls(pk.address(), pk, power)
 
-    def simple_encode(self) -> bytes:
-        """SimpleValidator proto (pubkey + power), the hashing encoding."""
-        return pb.f_embedded(1, encode_pub_key(self.pub_key)) + pb.f_varint(
-            2, self.voting_power
-        )
-
-    def compare_proposer_priority(self, other: "Validator") -> "Validator":
-        if self.proposer_priority > other.proposer_priority:
-            return self
-        if self.proposer_priority < other.proposer_priority:
-            return other
-        if self.address < other.address:
-            return self
-        if self.address > other.address:
-            return other
-        raise ValueError("validators with equal addresses")
-
     def copy(self) -> "Validator":
         return Validator(
             self.address, self.pub_key, self.voting_power, self.proposer_priority
         )
+
+
+class Member(NamedTuple):
+    """One row of a membership: who a validator is and what it weighs.
+    Every set of one membership (a set and all its copy()s) shares the
+    same Member objects; the proposer priority is the set's own
+    (ValidatorSet.priorities()), so a Member has none."""
+
+    address: bytes
+    pub_key: PubKey
+    voting_power: int
 
 
 class KeyColumns(NamedTuple):
@@ -114,101 +116,203 @@ class KeyColumns(NamedTuple):
     curves: dict
 
 
-def _sort_key(v: Validator):
+def _sort_key(v):
     # voting power desc, then address asc
     return (-v.voting_power, v.address)
 
 
-class ValidatorSet:
-    """Ordered validator set with proposer rotation."""
+# --- the rotation (reference IncrementProposerPriority :116): scale the
+# priorities into a window of 2 x total power, centre them on their
+# average, then `times` elections: everyone gains its power, the highest
+# priority (ties to the LOWER address) wins and pays the total power.
+# Two implementations of the same arithmetic. rotate_integer is Python
+# ints clipped to int64 at every step, as upstream's safeAddClip /
+# safeSubClip: right for any input, the fallback, and the oracle of
+# tests/test_validator_set.py. _rotate_column is int64 numpy over the
+# priorities column, and ValidatorSet._rotate takes it only where no
+# step can leave int64, so no clip can fire and the two agree.
 
-    def __init__(self, validators: list[Validator], increment_first: bool = True):
+
+def rotate_integer(prios: list[int], powers: list[int],
+                   addresses: list[bytes], total: int,
+                   times: int) -> tuple[list[int], int | None]:
+    """(priorities after, index of the last winner or None at times=0)."""
+    diff_max = PRIORITY_WINDOW_SIZE_FACTOR * total
+    diff = max(prios) - min(prios)
+    if 0 < diff_max < diff:
+        ratio = (diff + diff_max - 1) // diff_max
+        prios = [_trunc_div(p, ratio) for p in prios]
+    # Go big.Int Euclidean Div (floor for positive divisor)
+    avg = sum(prios) // len(prios)
+    prios = [_clip(p - avg) for p in prios]
+    winner = None
+    for _ in range(times):
+        prios = [_clip(p + w) for p, w in zip(prios, powers)]
+        winner = 0
+        for i in range(1, len(prios)):
+            if prios[i] > prios[winner] or (
+                    prios[i] == prios[winner]
+                    and addresses[i] < addresses[winner]):
+                winner = i
+        prios[winner] = _clip(prios[winner] - total)
+    return prios, winner
+
+
+def _rotate_column(p, powers, address_rank, total: int, times: int,
+                   spread: int):
+    """rotate_integer on an int64 column `p` (not written to), for a
+    caller that has shown nothing here can overflow. `spread` is max(p) -
+    min(p) as a Python int; address_rank() gives each member's place in
+    address order, asked for only at a tie."""
+    diff_max = PRIORITY_WINDOW_SIZE_FACTOR * total
+    if 0 < diff_max < spread:
+        ratio = (spread + diff_max - 1) // diff_max
+        q, r = np.divmod(p, ratio)
+        q += (r != 0) & (p < 0)  # floor -> toward zero, as _trunc_div
+        p = q
+    p = p - (int(p.sum()) // len(p))
+    winner = None
+    for _ in range(times):
+        p += powers
+        top = np.flatnonzero(p == p.max())
+        winner = int(top[0] if len(top) == 1
+                     else top[address_rank()[top].argmin()])
+        p[winner] -= total
+    return p, winner
+
+
+class ValidatorSet:
+    """Ordered validator set with proposer rotation.
+
+    A set is a membership and a column: `members`, the ordered (power
+    desc, address asc) Member rows, shared with every copy() together
+    with all that is derived from them (hash, total power, address
+    index, key_columns()); and the set's own proposer priorities, one
+    int64 column in member order. Rotating a copy costs a few vector
+    operations and builds no per-member object; update_with_change_set
+    is the one place that makes a new membership."""
+
+    def __init__(self, validators: list[Validator], increment_first: bool = True,
+                 proposer_address: bytes = b""):
+        """`proposer_address` restores the proposer a stored set was
+        saved with (the last winner, who is NOT the highest priority
+        after paying for its turn)."""
         if not validators:
             raise ValueError("validator set must not be empty")
-        vals = sorted((v.copy() for v in validators), key=_sort_key)
-        addrs = [v.address for v in vals]
-        if len(set(addrs)) != len(addrs):
-            raise ValueError("duplicate validator address")
-        self.validators: list[Validator] = vals
-        self.proposer: Validator | None = None
-        self._total_power: int | None = None
-        self._addr_index: dict[bytes, int] | None = None
         self._frozen = False
+        self._proposer: Member | None = None
+        self._set_membership(sorted(validators, key=_sort_key))
+        if len(self._address_index()) != len(self.members):
+            raise ValueError("duplicate validator address")
         self.total_voting_power()  # validates the cap
         if increment_first:
             self.increment_proposer_priority(1)
+        if proposer_address:
+            i = self._address_index().get(proposer_address)
+            self._proposer = None if i is None else self.members[i]
+
+    def _set_membership(self, vals: list[Validator]):
+        """This set becomes `vals` (in order): a membership, with a memo,
+        of its own, and their priorities as its column."""
+        self.members: tuple[Member, ...] = tuple(
+            Member(v.address, v.pub_key, v.voting_power) for v in vals)
+        try:
+            self._prio = np.array([v.proposer_priority for v in vals], np.int64)
+        except OverflowError:
+            raise ValueError("proposer priority outside int64") from None
+        self._view: list[Validator] | None = None
+        # what is derived from the membership alone, shared with every
+        # copy(): state hands each height a fresh copy (proposer
+        # rotation), made before anyone has asked the source for its
+        # columns, so a memo kept per object would be rebuilt per block
+        self._memo: dict = {}
 
     # --- queries ---
 
     def __len__(self) -> int:
-        return len(self.validators)
+        return len(self.members)
+
+    @property
+    def validators(self) -> list[Validator]:
+        """The set as Validator objects carrying this set's priorities:
+        a snapshot for readers that want rows (codecs, RPC, tests),
+        built on first use and dropped when the priorities move. Writing
+        to it does not change the set. Code on a block's path reads
+        `members` and `priorities()` and never asks for it."""
+        if self._view is None:
+            self._view = [
+                Validator(m.address, m.pub_key, m.voting_power, p)
+                for m, p in zip(self.members, self._prio.tolist())
+            ]
+        return self._view
+
+    def priorities(self) -> list[int]:
+        """Proposer priorities in member order."""
+        return self._prio.tolist()
+
+    def _validator(self, i: int) -> Validator:
+        if self._view is not None:
+            return self._view[i]
+        m = self.members[i]
+        return Validator(m.address, m.pub_key, m.voting_power,
+                         self._prio.item(i))
 
     def total_voting_power(self) -> int:
-        if self._total_power is None:
+        total = self._memo.get("total_power")
+        if total is None:
             total = 0
-            for v in self.validators:
-                total += v.voting_power
+            for m in self.members:
+                total += m.voting_power
                 if total > MAX_TOTAL_VOTING_POWER:
                     raise ValueError("total voting power exceeds cap")
-            self._total_power = total
-        return self._total_power
+            self._memo["total_power"] = total
+        return total
 
-    def get_by_address(self, addr: bytes) -> tuple[int, Validator | None]:
+    def _address_index(self) -> dict[bytes, int]:
         # O(1) address index (10k-validator light-trusting verification
         # does one lookup per signature; a linear scan would be O(N^2)).
-        if self._addr_index is None:
-            self._addr_index = {
-                v.address: i for i, v in enumerate(self.validators)
+        index = self._memo.get("address_index")
+        if index is None:
+            index = self._memo["address_index"] = {
+                m.address: i for i, m in enumerate(self.members)
             }
-        i = self._addr_index.get(addr, -1)
-        return (i, self.validators[i]) if i >= 0 else (-1, None)
+        return index
+
+    def get_by_address(self, addr: bytes) -> tuple[int, Validator | None]:
+        i = self._address_index().get(addr, -1)
+        return (i, self._validator(i)) if i >= 0 else (-1, None)
 
     def get_by_index(self, idx: int) -> Validator | None:
-        if 0 <= idx < len(self.validators):
-            return self.validators[idx]
+        if 0 <= idx < len(self.members):
+            return self._validator(idx)
         return None
 
     def has_address(self, addr: bytes) -> bool:
-        return self.get_by_address(addr)[0] >= 0
+        return addr in self._address_index()
 
     def hash(self) -> bytes:
         # memoized: the hash covers only (pubkey, power) — membership
-        # changes go through update_with_changeset (which invalidates);
+        # changes go through update_with_changeset (a new memo);
         # proposer-priority churn doesn't affect it. Replay hashes the
         # same set once per block otherwise (~ms each at 100 vals).
-        h = self.__dict__.get("_hash_memo")
+        h = self._memo.get("hash")
         if h is None:
-            h = merkle.hash_from_byte_slices(
-                [v.simple_encode() for v in self.validators]
+            h = self._memo["hash"] = merkle.hash_from_byte_slices(
+                [_simple_encode(m.pub_key, m.voting_power)
+                 for m in self.members]
             )
-            self.__dict__["_hash_memo"] = h
         return h
-
-    def _members_memo(self) -> dict:
-        """What is derived from the membership alone ((address, pubkey,
-        power) in order), shared with every copy(): state hands each
-        height a fresh copy (proposer rotation), made before anyone has
-        asked the source for its columns, so a memo kept per object would
-        be rebuilt per block. update_with_change_set gives this set a
-        membership, and a memo, of its own."""
-        memo = self.__dict__.get("_members")
-        if memo is None:
-            memo = self.__dict__["_members"] = {}
-        return memo
 
     def key_columns(self) -> KeyColumns:
         """The set as numpy columns, for whole-commit verification
         without a trip through each Validator. Memoized per membership:
         the same set judges thousands of consecutive commits."""
-        memo = self._members_memo()
-        cols = memo.get("key_cols")
+        cols = self._memo.get("key_cols")
         if cols is not None:
             return cols
-        import numpy as np
-
-        n = len(self.validators)
+        n = len(self.members)
         by_tag: dict[str, tuple[list[int], list[bytes]]] = {}
-        for i, v in enumerate(self.validators):
+        for i, v in enumerate(self.members):
             idxs, pubs = by_tag.setdefault(v.pub_key.type_tag(), ([], []))
             idxs.append(i)
             pubs.append(v.pub_key.bytes())
@@ -220,14 +324,14 @@ class ValidatorSet:
                 rows = np.frombuffer(b"".join(pubs), np.uint8).reshape(
                     len(pubs), width)
             curves[tag] = (np.asarray(idxs, np.int64), rows)
-        addrs = [v.address for v in self.validators]
+        addrs = [v.address for v in self.members]
         cols = KeyColumns(
             np.frombuffer(b"".join(addrs), np.uint8).reshape(n, 20)
             if all(len(a) == 20 for a in addrs) else None,
-            np.asarray([v.voting_power for v in self.validators], np.int64),
+            np.asarray([v.voting_power for v in self.members], np.int64),
             curves,
         )
-        memo["key_cols"] = cols
+        self._memo["key_cols"] = cols
         return cols
 
     def ed25519_columns(self):
@@ -246,14 +350,13 @@ class ValidatorSet:
         """True when every validator key is BLS12-381 — the gate for
         certificate-native folding. Memoized like ed25519_columns:
         consensus consults it once per commit on a frozen set."""
-        memo = self.__dict__.get("_all_bls")
-        if memo is None:
-            memo = bool(self.validators) and all(
-                v.pub_key.type_tag() == "tendermint/PubKeyBls12_381"
-                for v in self.validators
+        bls = self._memo.get("all_bls")
+        if bls is None:
+            bls = self._memo["all_bls"] = all(
+                m.pub_key.type_tag() == "tendermint/PubKeyBls12_381"
+                for m in self.members
             )
-            self.__dict__["_all_bls"] = memo
-        return memo
+        return bls
 
     def freeze(self) -> "ValidatorSet":
         """Seal the set against mutation. State snapshots share (alias)
@@ -265,84 +368,94 @@ class ValidatorSet:
         return self
 
     def _assert_mutable(self):
-        if getattr(self, "_frozen", False):
+        if self._frozen:
             raise RuntimeError(
                 "mutating a frozen ValidatorSet (aliased by a State "
                 "snapshot) — call .copy() first"
             )
 
     def copy(self) -> "ValidatorSet":
+        """A set of the same membership (shared, not copied) with its
+        own priorities, mutable whether or not this one is frozen."""
         vs = ValidatorSet.__new__(ValidatorSet)
-        vs.validators = [v.copy() for v in self.validators]
-        vs.proposer = self.proposer.copy() if self.proposer else None
-        vs._total_power = self._total_power
-        vs._addr_index = None
+        vs.members = self.members
+        vs._memo = self._memo
+        vs._prio = self._prio.copy()
+        vs._proposer = self._proposer
+        vs._view = None
         vs._frozen = False
-        memo = self.__dict__.get("_hash_memo")
-        if memo is not None:  # same membership -> same hash
-            vs.__dict__["_hash_memo"] = memo
-        vs.__dict__["_members"] = self._members_memo()
         return vs
 
     # --- proposer priority machinery ---
 
-    def _compute_avg_priority(self) -> int:
-        n = len(self.validators)
-        s = sum(v.proposer_priority for v in self.validators)
-        # Go big.Int Euclidean Div (floor for positive divisor)
-        return s // n
+    def _address_rank(self):
+        """Each member's place in address order, (n,) i64: what breaks a
+        tie for the highest priority."""
+        rank = self._memo.get("address_rank")
+        if rank is None:
+            addresses = [m.address for m in self.members]
+            order = sorted(range(len(addresses)), key=addresses.__getitem__)
+            rank = np.empty(len(order), np.int64)
+            rank[order] = np.arange(len(order))
+            self._memo["address_rank"] = rank
+        return rank
 
-    def _shift_by_avg(self):
-        avg = self._compute_avg_priority()
-        for v in self.validators:
-            v.proposer_priority = _clip(v.proposer_priority - avg)
-
-    def rescale_priorities(self, diff_max: int):
+    def _rotate(self, times: int) -> str:
+        """Rescale, centre, `times` elections (0 after a change of the
+        membership, which elects nobody); says which arithmetic ran."""
         self._assert_mutable()
-        if diff_max <= 0:
-            return
-        prios = [v.proposer_priority for v in self.validators]
-        diff = max(prios) - min(prios)
-        if diff < 0:
-            diff = -diff
-        ratio = (diff + diff_max - 1) // diff_max
-        if diff > diff_max:
-            for v in self.validators:
-                v.proposer_priority = _trunc_div(v.proposer_priority, ratio)
+        total = self.total_voting_power()
+        p = self._prio
+        lo, hi = p.min().item(), p.max().item()
+        reach = max(-lo, hi)
+        terms = self._memo.get("rotation")
+        if terms is None:
+            # the powers column (none where a power leaves int64: the
+            # guard below then fails for any election), and the most one
+            # election moves a priority by: its power up, the total down
+            powers = [m.voting_power for m in self.members]
+            step = abs(total) + max(map(abs, powers))
+            terms = self._memo["rotation"] = (
+                np.asarray(powers, np.int64) if step < 1 << 63 else None,
+                step)
+        powers, step = terms
+        # int64 holds every step: the sum of n priorities; a priority
+        # less the average (2 x reach at most), then `times` elections
+        if (len(p) * reach < 1 << 62
+                and 2 * reach + times * step < 1 << 63):
+            path = "column"
+            self._prio, winner = _rotate_column(
+                p, powers, self._address_rank, total, times, hi - lo)
+        else:
+            path = "integer"
+            prios, winner = rotate_integer(
+                p.tolist(), [m.voting_power for m in self.members],
+                [m.address for m in self.members], total, times)
+            self._prio = np.array(prios, np.int64)
+        self._view = None
+        if winner is not None:
+            self._proposer = self.members[winner]
+        return path
 
-    def _increment_once(self) -> Validator:
-        for v in self.validators:
-            v.proposer_priority = _clip(v.proposer_priority + v.voting_power)
-        mostest = self.validators[0]
-        for v in self.validators[1:]:
-            mostest = mostest.compare_proposer_priority(v)
-        mostest.proposer_priority = _clip(
-            mostest.proposer_priority - self.total_voting_power()
-        )
-        return mostest
-
-    def increment_proposer_priority(self, times: int):
-        self._assert_mutable()
+    def increment_proposer_priority(self, times: int) -> str:
+        """Rotates the proposer `times` turns on; returns which arithmetic
+        ran, `column` (int64 numpy) or `integer` (Python ints, where the
+        set's magnitudes could leave int64): same answers, the label is
+        for the counters."""
         if times <= 0:
             raise ValueError("times must be positive")
-        diff_max = PRIORITY_WINDOW_SIZE_FACTOR * self.total_voting_power()
-        self.rescale_priorities(diff_max)
-        self._shift_by_avg()
-        proposer = None
-        for _ in range(times):
-            proposer = self._increment_once()
-        self.proposer = proposer
+        return self._rotate(times)
 
-    def get_proposer(self) -> Validator:
-        if self.proposer is None:
-            self.proposer = self._find_proposer()
-        return self.proposer
-
-    def _find_proposer(self) -> Validator:
-        mostest = self.validators[0]
-        for v in self.validators[1:]:
-            mostest = mostest.compare_proposer_priority(v)
-        return mostest
+    def get_proposer(self) -> Member:
+        """The winner of the last rotation; on a set never rotated, the
+        highest priority (ties to the lower address)."""
+        if self._proposer is None:
+            prios = self._prio.tolist()
+            top = max(prios)
+            self._proposer = min(
+                (m for m, p in zip(self.members, prios) if p == top),
+                key=lambda m: m.address)
+        return self._proposer
 
     def copy_increment_proposer_priority(self, times: int) -> "ValidatorSet":
         vs = self.copy()
@@ -418,14 +531,10 @@ class ValidatorSet:
             if v.address in new_set:
                 v.proposer_priority = penalty
 
-        self.validators = sorted(updated, key=_sort_key)
-        self._total_power = None
-        self._addr_index = None
-        self.__dict__.pop("_hash_memo", None)
-        self.__dict__.pop("_members", None)
+        # (the proposer stays the last rotation's winner, as upstream's
+        # field does, even one that has just left: the next rotation,
+        # which every caller makes, elects from the new membership)
+        self._set_membership(sorted(updated, key=_sort_key))
         self.total_voting_power()
         # scale into the priority window, then center (reference order)
-        self.rescale_priorities(
-            PRIORITY_WINDOW_SIZE_FACTOR * self.total_voting_power()
-        )
-        self._shift_by_avg()
+        self._rotate(0)

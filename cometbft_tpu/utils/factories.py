@@ -358,7 +358,7 @@ def make_commit(
     commit = Commit(height=height, round=round_, block_id=block_id, signatures=[])
     sig_slots = []
     signers, msgs = [], []
-    for idx, val in enumerate(vals.validators):
+    for idx, val in enumerate(vals.members):
         if idx in absent:
             commit.signatures.append(CommitSig.absent())
             sig_slots.append(None)
@@ -376,7 +376,7 @@ def make_commit(
         msgs.append(None)  # filled after sign bytes known
     # sign bytes depend on the commit structure built above
     j = 0
-    for idx in range(len(vals.validators)):
+    for idx in range(len(vals)):
         if sig_slots[idx] is None:
             continue
         msgs[j] = commit.vote_sign_bytes(chain_id, idx)
@@ -386,7 +386,7 @@ def make_commit(
         nonces=r_pool.next() if r_pool is not None else None,
     )
     j = 0
-    for idx in range(len(vals.validators)):
+    for idx in range(len(vals)):
         if sig_slots[idx] is None:
             continue
         commit.signatures[idx].signature = sigs[j]

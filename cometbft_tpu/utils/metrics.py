@@ -404,6 +404,12 @@ class StateMetrics:
         self.block_verify_time = reg.histogram(
             "state", "block_verify_time",
             "Commit signature verification wall time (TPU kernel path)")
+        self.valset_rotation_total = reg.counter(
+            "state", "valset_rotation_total",
+            "Proposer rotations of applied blocks by the arithmetic that "
+            "ran: column (int64 numpy) or integer (Python ints, for a set "
+            "whose priorities or powers could leave int64)",
+            labels=("path",))
 
 
 class StoreMetrics:

@@ -36,7 +36,13 @@ from cometbft_tpu.types.validation import (  # noqa: E402
 )
 from cometbft_tpu.utils import factories as fx  # noqa: E402
 from cometbft_tpu.utils import trace  # noqa: E402
-from cometbft_tpu.utils.metrics import blocksync_metrics  # noqa: E402
+from cometbft_tpu.types.validator_set import (  # noqa: E402
+    MAX_TOTAL_VOTING_POWER,
+)
+from cometbft_tpu.utils.metrics import (  # noqa: E402
+    blocksync_metrics,
+    state_metrics,
+)
 
 CHAIN = "churn-chain"
 N, BLOCKS, POWER = 8, 24, 1_000_000
@@ -383,6 +389,56 @@ def test_one_set_change_span_and_one_counter_step_a_boundary(tmp_path, depth):
         h for h in range(1, BLOCKS + 1)
         if ref.val_updates(store.load_block(h).data.txs)]
     assert all(r["changes"] == 2 for r in ups)
+
+
+STAGES = ("validate_ms", "finalize_ms", "update_state_ms", "commit_ms",
+          "save_events_ms")
+
+
+@pytest.mark.parametrize("mode,depth", (("batched", 1), ("batched", 2),
+                                        ("full", None)))
+def test_every_apply_block_span_says_how_the_proposer_rotated(
+        tmp_path, mode, depth):
+    """update_state_ms (the next state: validator updates, the rotation)
+    stands beside the four older stages, the five within the span, and
+    the rotations counter says the same as the spans."""
+    store, final, genesis, _ = churn_chain(SEEDS[2])  # updates every 3rd block
+    counter = state_metrics().valset_rotation_total
+    before = counter.values().get(("column",), 0.0)  # making the chain counts
+    (state, _, _), recs = _traced(
+        tmp_path, lambda: replay(store, genesis, mode, 4, depth))
+    assert state.app_hash == final.app_hash
+    spans = _of(recs, "state.apply_block")
+    assert [r["height"] for r in spans] == list(range(1, BLOCKS + 1))
+    for r in spans:
+        assert r["rotation"] == "column", r
+        assert all(r[f] >= 0 for f in STAGES), r
+        # each stage is rounded to a microsecond, as the span is
+        assert sum(r[f] for f in STAGES) <= r["dur_ms"] + 0.003, r
+    # blocks with and without validator updates alike
+    changed = {r["parent"] for r in _of(recs, "state.valset_update")}
+    assert changed and changed < {r["id"] for r in spans}
+    assert counter.values() == {("column",): before + BLOCKS}
+
+
+def test_the_rotations_counter_names_a_set_that_fell_back(tmp_path):
+    """A set whose total power is at the cap has priorities that int64
+    cannot sum: it rotates on Python ints, the chain replays to the same
+    app hash, and state_metrics() shows the fallback without a trace."""
+    store, final, genesis, _ = fx.make_chain(
+        6, n_validators=8, chain_id=CHAIN, backend="cpu",
+        powers=[MAX_TOTAL_VOTING_POWER // 8] * 8)
+    before = state_metrics().valset_rotation_total.values()  # the generator's
+    (state, _, _), recs = _traced(
+        tmp_path, lambda: replay(store, genesis, "batched", 4, 2))
+    assert state.app_hash == final.app_hash
+    assert state.validators.hash() == final.validators.hash()
+    assert state.encode() == final.encode()
+    paths = [r["rotation"] for r in _of(recs, "state.apply_block")]
+    assert len(paths) == 6 and "integer" in paths
+    after = state_metrics().valset_rotation_total.values()
+    assert {k: after[k] - before.get(k, 0.0) for k in after} == {
+        (p,): float(paths.count(p)) for p in set(paths)}
 
 
 @pytest.mark.parametrize("depth", (1, 2))
