@@ -642,21 +642,12 @@ class ConsensusState:
             CertCommit(cert, len(self.validators)),
         )
         sched = getattr(self.executor, "verify_sched", None)
-        t0 = time.perf_counter()
         if sched is not None:
             verified, _ = sched.submit(
                 bv, self.executor.sched_tenant, "consensus"
             ).result()
         else:
             verified, _ = bv.verify()
-        if trace.enabled:
-            trace.emit(
-                "consensus.cert_aggregate", "span",
-                dur_ms=round((time.perf_counter() - t0) * 1e3, 3),
-                height=cert.height, round=cert.round,
-                signers=cert.signer_count(),
-                outcome="verified" if verified else "invalid",
-            )
         if not verified:
             m.cert_gossip_total.inc(1.0, "invalid")
             return  # bad peer certificate: drop (punishment at p2p layer)

@@ -38,7 +38,6 @@ import hashlib
 import struct
 import threading
 
-from ..utils import trace as _trace
 from ..utils.metrics import crypto_metrics
 from . import native as _native
 from .bls import (
@@ -311,9 +310,7 @@ def open_single(coeffs, z: int, srs: SRS | None = None,
     """(y, proof): evaluate and commit the quotient witness."""
     y = poly_eval(coeffs, z)
     q = poly_quotient(coeffs, z)
-    with _trace.span("crypto.msm_opening", n=len(q), cols=1):
-        pi = commit(q, srs, force_oracle=force_oracle)
-    return y, pi
+    return y, commit(q, srs, force_oracle=force_oracle)
 
 
 def _jac(pt) -> tuple | None:
@@ -416,10 +413,7 @@ def open_multi(col_coeffs, commitments, z: int,
             folded[d] = (folded[d] + w * cd) % R
         w = w * gamma % R
     q = poly_quotient(folded, z)
-    with _trace.span("crypto.msm_opening", n=len(q),
-                     cols=len(col_coeffs)):
-        pi = commit(q, srs, nchunks=nchunks, force_oracle=force_oracle)
-    return ys, pi
+    return ys, commit(q, srs, nchunks=nchunks, force_oracle=force_oracle)
 
 
 def verify_multi(commitments, z: int, ys, proof: bytes,

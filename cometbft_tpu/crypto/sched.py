@@ -96,8 +96,10 @@ _MAX_UNANSWERED = 2
 
 
 class _Request:
+    # t_done exists on a request answered by a traced run only: the
+    # instant its verdict was set (read with getattr)
     __slots__ = ("bv", "tenant", "source", "prio", "n", "t_enqueue",
-                 "t_taken", "batch", "future")
+                 "t_taken", "t_done", "batch", "future")
 
     def __init__(self, bv, tenant: str, source: str, prio: int):
         self.bv = bv
@@ -169,14 +171,21 @@ class SchedPending:
             return self._future.result(timeout)
         # the caller's wait, in its own tree (a child of its
         # types.verify_commit); `batch` names the crypto.sched_coalesce
-        # the request rode in, on the drainer's thread
+        # the request rode in, on the drainer's thread; `wake_ms` is
+        # what lies between the verdict set on the completion thread
+        # and this thread running again (none before the call began)
         req = self._req
         with _trace.span("crypto.verdict_wait", path="sched",
                          n=req.n) as sp:
+            t_call = time.perf_counter()
             try:
                 return self._future.result(timeout)
             finally:
                 sp.add(batch=req.batch)
+                t_done = getattr(req, "t_done", None)
+                if t_done is not None:
+                    sp.add(wake_ms=round((time.perf_counter() - max(
+                        t_done, t_call)) * 1e3, 3))
 
 
 def _fail(fut: Future, exc: Exception) -> None:
@@ -251,10 +260,6 @@ class VerifyScheduler:
             "coalesced_requests": 0, "passthrough": 0,
         }
         self._tenant_sigs: dict[str, int] = {}
-        # how long _collect held the batch it took last, from seeing
-        # work queued (a slot free or not) to taking it (the drainer's
-        # own; 0 under drain_once)
-        self._lingered_s = 0.0
 
     # -- producer side ---------------------------------------------------
     def submit(self, bv, tenant: str = "default",
@@ -381,13 +386,18 @@ class VerifyScheduler:
     def _collect(self) -> _Batch | None:
         """Wait for work and for a free slot, linger for the coalescing
         window, pop one DRR-ordered batch into the slot. None = stopped
-        with nothing queued."""
-        with self._cv:
-            t_seen = None
+        with nothing queued. The span opens before and closes after the
+        lock, so the tracer never flushes under it."""
+        with _trace.span("crypto.sched_collect") as sp, self._cv:
+            t_work = 0
             while True:
                 if self._n_queued:
-                    if t_seen is None and _trace.enabled:
-                        t_seen = time.perf_counter()
+                    if sp.id is not None:
+                        # traced: when this pass saw work queued; the
+                        # first such reading ends the idle wait, the
+                        # last (a slot is free) the wait for a slot
+                        t_slot = time.perf_counter_ns()
+                        t_work = t_work or t_slot
                     if len(self._inflight) < _MAX_UNANSWERED:
                         break
                     # the cap holds back the launch only: the queues
@@ -413,8 +423,11 @@ class VerifyScheduler:
                     break
                 self._cv.wait(timeout=left)
             b = self._take()
-            if t_seen is not None:
-                self._lingered_s = time.perf_counter() - t_seen
+            if t_work:
+                sp.add(idle_ms=round((t_work - sp.t0_ns) / 1e6, 3),
+                       slot_ms=round((t_slot - t_work) / 1e6, 3),
+                       linger_ms=round(
+                           (time.perf_counter_ns() - t_slot) / 1e6, 3))
             return b
 
     def _take(self) -> _Batch:
@@ -542,9 +555,7 @@ class VerifyScheduler:
                    lanes_bucket=_ed._bucket(sigs),
                    tenants=",".join(sorted(per_tenant)),
                    sources=",".join(sorted({r.source for r in part})),
-                   per_tenant_sigs=per_tenant,
-                   collect_ms=round(self._lingered_s * 1e3, 3),
-                   inflight=b.ahead)
+                   per_tenant_sigs=per_tenant, inflight=b.ahead)
             if absorb_s is not None:
                 sp.add(absorb_ms=round(absorb_s * 1e3, 3))
         if not getattr(bv, "coalescable", True) or bv.backend == "cpu":
@@ -586,18 +597,18 @@ class VerifyScheduler:
             for req, verdict in zip(part, verdicts):
                 _resolve(req.future, verdict)
             return
-        done = []
         for req, verdict in zip(part, verdicts):
+            # before the verdict, so that the caller it wakes finds it
+            req.t_done = time.perf_counter()
             _resolve(req.future, verdict)
-            done.append(time.perf_counter())
         alone = len(part) == 1
         # emit() lets a field of its own name stand for the stack's
         tree = {} if span_id is None else {"parent": span_id,
                                            "root": span_id}
-        for req, t1 in zip(part, done):
+        for req in part:
             _trace.emit(
                 "crypto.sched_wait", "span",
-                dur_ms=round((t1 - req.t_enqueue) * 1e3, 3),
+                dur_ms=round((req.t_done - req.t_enqueue) * 1e3, 3),
                 queued_ms=round((req.t_taken - req.t_enqueue) * 1e3, 3),
                 tenant=req.tenant, source=req.source, n=req.n,
                 batch=span_id, alone=alone, **tree)
@@ -610,7 +621,6 @@ class VerifyScheduler:
         thread to race with)."""
         with self._cv:
             b = self._take()
-            self._lingered_s = 0.0
         self._dispatch(b)
         self._finish(b)
         return len(b.reqs)

@@ -21,7 +21,6 @@ import math
 import struct
 from dataclasses import dataclass, field
 
-from ..utils import trace
 from .commit import DACommitment, proof_num_bytes
 
 
@@ -117,14 +116,9 @@ class Sampler:
     ) -> bool:
         """One opening proof checked end-to-end: geometry matches the
         header root, chunk hash sits at `index` under chunks_root."""
-        with trace.span(
-            "da.sample_verify", index=index, n=com.n
-        ) as sp:
-            ok = com.root() == da_root and com.verify_sample(
-                index, chunk, proof
-            )
-            sp.add(ok=ok)
-        return ok
+        return com.root() == da_root and com.verify_sample(
+            index, chunk, proof
+        )
 
     def run(self, height: int, da_root: bytes, fetch) -> SampleResult:
         ok = 0

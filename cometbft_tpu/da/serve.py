@@ -194,13 +194,10 @@ class DAServe:
         chunk = entry.shards[index]
         proof = entry.proofs[index]
         nbytes = proof_num_bytes(chunk, proof)
-        with trace.span(
-            "da.serve_sample", height=height, index=index, bytes=nbytes
-        ):
-            self.metrics.samples_served_total.inc()
-            self.metrics.proof_bytes.observe(nbytes)
-            with self._lock:
-                self._served += 1
+        self.metrics.samples_served_total.inc()
+        self.metrics.proof_bytes.observe(nbytes)
+        with self._lock:
+            self._served += 1
         return chunk, proof, entry.commitment
 
     def pc_sample(self, height: int, row: int, cols):
@@ -225,15 +222,11 @@ class DAServe:
                 self._pc_withheld_hits += 1
             return None
         nbytes = pcmod.multiproof_num_bytes(len(cols))
-        with trace.span(
-            "da.serve_sample", height=height, index=row,
-            cols=len(cols), bytes=nbytes, track="pc",
-        ):
-            ys, proof = entry.pc.open_row_cols(row, cols)
-            self.metrics.pc_samples_served_total.inc()
-            self.metrics.pc_proof_bytes.observe(nbytes)
-            with self._lock:
-                self._pc_served += 1
+        ys, proof = entry.pc.open_row_cols(row, cols)
+        self.metrics.pc_samples_served_total.inc()
+        self.metrics.pc_proof_bytes.observe(nbytes)
+        with self._lock:
+            self._pc_served += 1
         return ys, proof
 
     def pc_commitments(self, height: int):

@@ -35,7 +35,6 @@ from collections import OrderedDict, deque
 
 from ..crypto.sched import verify_context
 from ..types.validation import verify_commit_light
-from ..utils import trace
 from ..utils.metrics import light_metrics
 from .mmr import MMR, MMRProof
 from .types import LightBlock, SignedHeader
@@ -237,9 +236,7 @@ class LightServe:
                 # enabled mid-chain after statesync) — re-anchor by
                 # backfilling from the block store.
                 self._backfill_locked(expected, header.height)
-            with trace.span("light.mmr_append", height=header.height) as sp:
-                leaf = self.mmr.append(header.hash())
-                sp.add(leaf=leaf, size=self.mmr.leaf_count)
+            self.mmr.append(header.hash())
             payload = self._render_payload(header)
             self._payloads[header.height] = payload
             while len(self._payloads) > self.payload_retain:
@@ -256,9 +253,7 @@ class LightServe:
                 raise RuntimeError(
                     f"light serve cannot backfill height {h}: not in store"
                 )
-            with trace.span("light.mmr_append", height=h) as sp:
-                leaf = self.mmr.append(blk.header.hash())
-                sp.add(leaf=leaf, size=self.mmr.leaf_count)
+            self.mmr.append(blk.header.hash())
 
     def _render_payload(self, header) -> dict:
         """One shared dict per height — rendered once, pushed to every
@@ -300,13 +295,8 @@ class LightServe:
         return idx
 
     def _prove_locked(self, height: int) -> MMRProof:
-        idx = self._leaf_index(height)
-        with trace.span("light.serve_proof", height=height,
-                        size=self.mmr.leaf_count) as sp:
-            proof = self.mmr.prove(idx)
-            nbytes = proof.num_bytes()
-            sp.add(bytes=nbytes)
-        light_metrics().proof_bytes.observe(nbytes)
+        proof = self.mmr.prove(self._leaf_index(height))
+        light_metrics().proof_bytes.observe(proof.num_bytes())
         return proof
 
     def ancestry_proof(self, height: int) -> MMRProof:

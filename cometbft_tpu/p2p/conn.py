@@ -37,7 +37,6 @@ import time
 from dataclasses import dataclass
 
 from ..utils.metrics import p2p_metrics
-from ..utils import trace
 
 PACKET_DATA = 1
 PACKET_PING = 2
@@ -99,8 +98,6 @@ class _Channel:
         self.sending: memoryview | None = None
         self.sending_len = 0
         self.sent_pos = 0
-        self.send_npkts = 0
-        self.send_t0 = 0.0
         self.recently_sent = 0.0
         # persistent reassembly buffer: grown geometrically, reused
         # across messages (replaces the per-message list + b"".join)
@@ -118,9 +115,9 @@ class _Channel:
             return self.sending is not None or bool(self.send_queue)
 
     def next_packet(self):
-        """-> (payload memoryview, eof, done) or None. `done` is
-        (msg_bytes, n_packets, t0, queue_depth) when this packet
-        completes a message, else None. The payload is a slice over the
+        """-> (payload memoryview, eof, depth) or None. `depth` is the
+        send queue's depth when this packet completes a message, else
+        None. The payload is a slice over the
         original queued buffer — no copy; it stays valid after `sending`
         is dropped because the slice keeps the buffer alive."""
         with self.lock:
@@ -131,20 +128,16 @@ class _Channel:
                 self.sending = memoryview(msg)
                 self.sending_len = len(msg)
                 self.sent_pos = 0
-                self.send_npkts = 0
-                self.send_t0 = time.perf_counter()
             chunk = self.sending[self.sent_pos:
                                  self.sent_pos + self.payload_cap]
             self.sent_pos += len(chunk)
-            self.send_npkts += 1
             eof = self.sent_pos >= self.sending_len
-            done = None
+            depth = None
             if eof:
                 self.sending = None
-                done = (self.sending_len, self.send_npkts, self.send_t0,
-                        len(self.send_queue))
+                depth = len(self.send_queue)
             self.recently_sent += len(chunk)
-            return chunk, eof, done
+            return chunk, eof, depth
 
 
 class MConnection:
@@ -226,7 +219,7 @@ class MConnection:
                 pkt = ch.next_packet()
                 if pkt is None:
                     continue
-                chunk, eof, done = pkt
+                chunk, eof, depth = pkt
                 struct.pack_into("<BHB", hdr, 0, PACKET_DATA, ch.desc.id,
                                  1 if eof else 0)
                 if self._write_views is not None:
@@ -237,18 +230,9 @@ class MConnection:
                 p2p_metrics().message_send_bytes_total.inc(
                     frame_len, f"{ch.desc.id:#04x}"
                 )
-                if done is not None:
-                    msg_bytes, npkts, t0, depth = done
+                if depth is not None:
                     p2p_metrics().send_queue_depth.set(
                         depth, f"{ch.desc.id:#04x}")
-                    if trace.enabled:
-                        trace.emit(
-                            "p2p.zero_copy_send", "span",
-                            dur_ms=round(
-                                (time.perf_counter() - t0) * 1e3, 3),
-                            chan=ch.desc.id, bytes=msg_bytes,
-                            packets=npkts,
-                        )
                 self._send_limit.spend(frame_len, self._stopped)
         except Exception as e:  # noqa: BLE001
             if not self._stopped.is_set():

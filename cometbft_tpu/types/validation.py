@@ -276,21 +276,7 @@ def _judge(groups, singles, secp_rows, item, backend: str) -> None:
     # per-signature loop over a mixed set gives it
     bad: list[int] = []
     for leg in reversed(legs):
-        pc0 = None
-        if leg.tag == _BLS_TAG:
-            from ..crypto import bls as _bls
-
-            pc0 = _bls.pairing_checks()
-            t0 = _time.perf_counter()
         ok, bits = leg.resolve()
-        if pc0 is not None and _trace.enabled:
-            # the whole BLS partition collapsed into aggregate
-            # pairing check(s): 1 on accept, +n rescan on blame
-            _trace.emit("crypto.bls_aggregate", "span",
-                        dur_ms=round(
-                            (_time.perf_counter() - t0) * 1e3, 3),
-                        n=len(leg.idxs),
-                        pairing_checks=_bls.pairing_checks() - pc0)
         if ok:
             continue
         if bits:
@@ -382,7 +368,6 @@ def _verify_cert_commit(
     """Shared core for verify_commit/verify_commit_light on a
     CertCommit: structural checks, then one pairing (scheduler-routed
     when a verify_context is active)."""
-    from ..crypto import bls as _bls
     from ..crypto.sched import current_context
 
     _check_commit_basics(vals, commit, height, block_id)
@@ -393,17 +378,11 @@ def _verify_cert_commit(
     bv = CertCommitVerifier(chain_id, vals, commit)
     ctx = current_context()
     t0 = _time.perf_counter()
-    pc0 = _bls.pairing_checks()
     if ctx is not None:
         ok, _bits = ctx.submit(bv).result()
     else:
         ok, _bits = bv.verify()
-    dt = _time.perf_counter() - t0
-    if _trace.enabled:
-        _trace.emit("crypto.bls_aggregate", "span",
-                    dur_ms=round(dt * 1e3, 3), n=commit.signer_count(),
-                    pairing_checks=_bls.pairing_checks() - pc0)
-    _observe_partition(_BLS_TAG, "aggregate", dt)
+    _observe_partition(_BLS_TAG, "aggregate", _time.perf_counter() - t0)
     if not ok:
         _raise_cert_error(bv.error)
 

@@ -17,17 +17,30 @@ Design constraints:
   un-guarded ``with trace.span(...)`` sites stay cheap too.
 * One JSON object per line. Every record carries ``ts`` (epoch seconds
   at emit, i.e. a span's END), ``pid`` (merge safety across e2e nodes),
+  ``tid`` (threading.get_native_id() of the thread that wrote it),
   ``name`` and ``kind`` ("span" or "event"); spans add ``dur_ms``;
   callers attach free-form fields. Once `set_node()` ran, records also
   carry ``node`` — the cross-process join key the traceview merger
-  aligns sinks on.
+  aligns sinks on. The first record a thread writes into a sink is
+  preceded by one ``trace.thread`` event (``tid``, ``thread`` = its
+  name): a name costs one record a thread, not a field a record.
 * A span made by `span()` is a node of a tree: ``id`` (unique in the
   process), ``parent`` (the span open on this thread when it began,
   null at a root), ``root`` (the id of its tree's root: the spans of one
   verify_commit, one ReplayEngine.run share it), ``t0_ns``/``t1_ns``
   from time.perf_counter_ns(), and ``self_ms``: its duration less what
   its direct children covered (each child adds its duration to its
-  parent at exit). A record written by emit() while a span is open on
+  parent at exit). A ROOT span also carries ``cpu_ms``: what its thread
+  spent on a CPU inside it (time.thread_time_ns() at both ends: native
+  code it called included, another thread's turn at the interpreter and
+  any sleep excluded). Only roots pay for that clock: it is the
+  kernel's, a reading costs a quarter of a microsecond on Linux but
+  5.6 us under a sandboxed kernel (gVisor, the benchmark's chip host),
+  where it also steps by 10 ms, so that one span reads 0 or 10 and only
+  a thread's sum over a window is a number (PERF.md, Findings PR 38).
+  `tools/trace_analyze.py threads` sets that sum beside the thread's
+  wall time and its time in the spans WAIT_SPANS names.
+  A record written by emit() while a span is open on
   the thread carries that span as ``parent``/``root``. Work that is one
   unit but not nested in time shares a field instead (``window`` on the
   spans of a replay window, ``batch`` on the result() of a submit).
@@ -36,7 +49,8 @@ Design constraints:
   `open_span()`: the same ``id``/``parent``/``root``/``t0_ns``/``t1_ns``
   and ``dur_ms``, begun at the call and ended by its `close()`, but
   never on the thread's stack: no span nests under it, it carries no
-  ``self_ms`` and leaves its parent's alone.
+  ``self_ms`` and leaves its parent's alone; it may end on another
+  thread than it began on, so it carries no ``cpu_ms`` even at a root.
   configure() writes one ``trace.clock`` event pairing perf_counter_ns
   with time_ns, which puts every span on the wall clock.
 * Under a profiler session the same spans lie in the profiler's trace:
@@ -52,7 +66,11 @@ Design constraints:
   `tail()`, `disable()` and at exit — never at every record (per-record
   flushing costs a syscall per consensus wire message once the p2p
   hooks are on), and as a rule not inside the span whose self time the
-  work would be booked to. A SIGKILLed node loses at most the last
+  work would be booked to. A flush takes the waiting records under the
+  buffer's lock and serialises and writes them outside it, under a lock
+  of the file's own: a record on another thread never waits for a
+  json.dumps, and one that finds a flush under way leaves its own to
+  the next. A SIGKILLed node loses at most the last
   interval's records (the last few under a long root span).
 * Fork safety: ``pid`` is re-stamped and the sink reopened via an
   at-fork hook, so a process forked after configure() never stamps the
@@ -78,11 +96,20 @@ import time
 enabled = False
 _path: str | None = None
 _fh = None
-# re-entrant: a collection that starts while a flush serialises records
-# runs _on_gc, which queues its own record, on the same thread
+# the waiting records and the sink's identity (_fh, _path, _epoch).
+# Re-entrant: a collection that starts while a record is queued runs
+# _on_gc, which queues its own, on the same thread
 _lock = threading.RLock()
+# the file: one flush serialises and writes at a time, outside _lock.
+# Taken BEFORE _lock, and never waited for by a record (_record tries
+# it), so a thread that closes a span is never held for a json.dumps.
+# Not re-entrant: a collection inside a flush must not start a second
+_io_lock = threading.Lock()
 _pid = os.getpid()
 _node = ""
+# configure() calls so far: a thread announces itself (trace.thread)
+# once in every sink it writes to
+_epoch = 0
 
 # bounded write staleness: records wait in memory at most this long
 # before a record that closes outside any span flushes them (see module
@@ -98,36 +125,42 @@ _last_flush = 0.0
 _buf: list[dict] = []
 
 _ids = itertools.count(1)
-_tls = threading.local()
 _annotation = None  # jax.profiler.TraceAnnotation once jax is imported
 
 # a collection is recorded when it is a full one or pauses longer
 GC_PAUSE_MIN_NS = 1_000_000
 
 
-def _stack() -> list:
-    try:
-        return _tls.stack
-    except AttributeError:
-        _tls.stack = []
-        return _tls.stack
+class _Thread(threading.local):
+    """What the tracer keeps for each thread."""
+
+    def __init__(self):
+        self.stack: list = []  # its open spans, innermost last
+        self.tid = threading.get_native_id()
+        self.announced = 0  # the _epoch whose sink holds its trace.thread
+        self.gc_span = None
+
+
+_tls = _Thread()
 
 
 def configure(path: str) -> None:
     """Open (append) the JSONL sink at `path` and enable tracing."""
-    global enabled, _path, _fh, _pid, _last_flush
-    with _lock:
-        if _fh is not None:
-            _write_locked()
-            _fh.close()
-        d = os.path.dirname(path)
-        if d:
-            os.makedirs(d, exist_ok=True)
-        _fh = open(path, "a", encoding="utf-8", buffering=1 << 16)
-        _path = path
-        _pid = os.getpid()
-        _last_flush = 0.0
-        enabled = True
+    global enabled, _path, _fh, _pid, _last_flush, _epoch
+    with _io_lock:
+        _write()
+        with _lock:
+            if _fh is not None:
+                _fh.close()
+            d = os.path.dirname(path)
+            if d:
+                os.makedirs(d, exist_ok=True)
+            _fh = open(path, "a", encoding="utf-8", buffering=1 << 16)
+            _path = path
+            _pid = os.getpid()
+            _last_flush = 0.0
+            _epoch += 1
+            enabled = True
     if _on_gc not in gc.callbacks:
         gc.callbacks.append(_on_gc)
     event("trace.clock", perf_ns=time.perf_counter_ns(),
@@ -136,15 +169,16 @@ def configure(path: str) -> None:
 
 def disable() -> None:
     global enabled, _path, _fh, _node
-    with _lock:
+    with _io_lock:
         enabled = False
-        if _fh is not None:
-            _write_locked()
-            _fh.close()
-        _fh = None
-        _path = None
-        _node = ""
-        del _buf[:]
+        _write()
+        with _lock:
+            if _fh is not None:
+                _fh.close()
+            _fh = None
+            _path = None
+            _node = ""
+            del _buf[:]
     if _on_gc in gc.callbacks:
         gc.callbacks.remove(_on_gc)
 
@@ -182,11 +216,15 @@ def _before_fork() -> None:
 def _after_fork_in_child() -> None:
     # A forked child must stamp its OWN pid and must not share the
     # parent's buffered file object (interleaved partial writes). The
-    # lock is replaced too: another thread may have held it at fork
-    # time, which would deadlock the child forever.
-    global _pid, _fh, _lock, _last_flush
+    # locks are replaced too: another thread may have held one at fork
+    # time, which would deadlock the child forever. The one thread that
+    # lives on has a new native id and says so in the child's records.
+    global _pid, _fh, _lock, _io_lock, _last_flush
     _lock = threading.RLock()
+    _io_lock = threading.Lock()
     _pid = os.getpid()
+    _tls.tid = threading.get_native_id()
+    _tls.announced = 0
     _buf.clear()
     # first emit in the child flushes at once: multiprocessing children
     # exit via os._exit(), which skips atexit and buffered-file shutdown
@@ -208,31 +246,61 @@ if hasattr(os, "register_at_fork"):  # POSIX only; harmless otherwise
                         after_in_child=_after_fork_in_child)
 
 
-def _write_locked() -> None:
-    """Serialise the waiting records into the sink; `_lock` is held."""
+# one encoder for every record (json.dumps with these arguments builds
+# one a call)
+_encode = json.JSONEncoder(separators=(",", ":"), default=str).encode
+
+
+def _serialise(batch: list[dict]) -> str:
+    return "".join([_encode(rec) + "\n" for rec in batch])
+
+
+def _write() -> None:
+    """One flush; `_io_lock` is held. The waiting records leave the
+    buffer under `_lock`; they are serialised and written outside it."""
     global _last_flush, _buf
-    _last_flush = time.monotonic()
-    batch, _buf = _buf, []  # a record queued meanwhile waits its turn
+    with _lock:
+        fh = _fh
+        _last_flush = time.monotonic()
+        batch, _buf = _buf, []  # a record queued meanwhile waits its turn
+    if fh is None:
+        return
     if batch:
-        dumps = json.dumps
-        _fh.write("".join(
-            dumps(rec, separators=(",", ":"), default=str) + "\n"
-            for rec in batch))
-    _fh.flush()
+        fh.write(_serialise(batch))
+    fh.flush()
+
+
+def _envelope(name: str, kind: str) -> dict:
+    """What every record carries, before anything of its own."""
+    rec = {"ts": time.time(), "pid": _pid, "tid": _tls.tid, "name": name,
+           "kind": kind}
+    if _node:
+        rec["node"] = _node
+    return rec
 
 
 def _record(rec: dict, nested: bool) -> None:
-    """Queue one finished record; flush when the module docstring's
-    staleness rule says so."""
+    """Queue one finished record, behind its thread's trace.thread if
+    this sink has none yet; flush when the module docstring's staleness
+    rule says so and no other flush is under way."""
     with _lock:
         if _fh is None:  # raced with disable()
             return
+        if _tls.announced != _epoch:
+            _tls.announced = _epoch
+            _buf.append(dict(_envelope("trace.thread", "event"),
+                             thread=threading.current_thread().name))
         _buf.append(rec)
         due = time.monotonic() - _last_flush
-        if due >= FLUSH_INTERVAL_S and (
-                not nested or len(_buf) >= MAX_BUFFERED
-                or due >= NESTED_FLUSH_INTERVALS * FLUSH_INTERVAL_S):
-            _write_locked()
+        if due < FLUSH_INTERVAL_S or (
+                nested and len(_buf) < MAX_BUFFERED
+                and due < NESTED_FLUSH_INTERVALS * FLUSH_INTERVAL_S):
+            return
+    if _io_lock.acquire(blocking=False):
+        try:
+            _write()
+        finally:
+            _io_lock.release()
 
 
 def emit(name: str, kind: str = "event", **fields) -> None:
@@ -242,10 +310,8 @@ def emit(name: str, kind: str = "event", **fields) -> None:
     one thread for a span of another (crypto.sched_wait)."""
     if not enabled:
         return
-    rec = {"ts": time.time(), "pid": _pid, "name": name, "kind": kind}
-    if _node:
-        rec["node"] = _node
-    stack = _stack()
+    rec = _envelope(name, kind)
+    stack = _tls.stack
     if stack:
         rec["parent"] = stack[-1].id
         rec["root"] = stack[-1].root
@@ -254,10 +320,10 @@ def emit(name: str, kind: str = "event", **fields) -> None:
 
 
 def flush() -> None:
-    """Force the waiting records to disk (readers that bypass tail())."""
-    with _lock:
-        if _fh is not None:
-            _write_locked()
+    """Force the waiting records to disk (readers that bypass tail());
+    waits for a flush that another thread has under way."""
+    with _io_lock:
+        _write()
 
 
 atexit.register(flush)  # records still waiting when the process ends
@@ -280,7 +346,7 @@ def _find_annotation():
 
 class _Span:
     __slots__ = ("name", "fields", "id", "parent", "root", "t0_ns",
-                 "_child_ns", "_ann")
+                 "c0_ns", "_child_ns", "_ann")
 
     def __init__(self, name: str, fields: dict):
         self.name = name
@@ -301,7 +367,7 @@ class _Span:
         return False
 
     def _open(self, annotate: bool) -> None:
-        stack = _stack()
+        stack = _tls.stack
         if stack:
             self.parent = stack[-1].id
             self.root = stack[-1].root
@@ -314,14 +380,20 @@ class _Span:
             self._ann = ann(self.name, span_id=self.id)
             self._ann.__enter__()
         self.t0_ns = time.perf_counter_ns()
+        # a root reads its thread's CPU clock, inside the wall clock's
+        # two readings, so cpu_ms never passes dur_ms
+        if self.parent is None:
+            self.c0_ns = time.thread_time_ns()
 
     def _close(self, min_ns: int) -> None:
         """Pops the span and queues its record, unless it lasted less
         than `min_ns` (then it leaves no mark on its parent either)."""
+        if self.parent is None:
+            cpu_ns = time.thread_time_ns() - self.c0_ns
         t1_ns = time.perf_counter_ns()
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
-        stack = _stack()
+        stack = _tls.stack
         if stack and stack[-1] is self:
             stack.pop()
         elif self in stack:  # closed out of order: drop it and the
@@ -333,15 +405,15 @@ class _Span:
             stack[-1]._child_ns += dur_ns
         rec = _span_record(self, t1_ns)
         rec["self_ms"] = round((dur_ns - self._child_ns) / 1e6, 3)
+        if self.parent is None:
+            rec["cpu_ms"] = round(cpu_ns / 1e6, 3)
         rec.update(self.fields)
         _record(rec, bool(stack))
 
 
 def _span_record(sp, t1_ns: int) -> dict:
     """What every span record carries, before its own fields."""
-    rec = {"ts": time.time(), "pid": _pid, "name": sp.name, "kind": "span"}
-    if _node:
-        rec["node"] = _node
+    rec = _envelope(sp.name, "span")
     rec.update(id=sp.id, parent=sp.parent, root=sp.root, t0_ns=sp.t0_ns,
                t1_ns=t1_ns, dur_ms=round((t1_ns - sp.t0_ns) / 1e6, 3))
     return rec
@@ -384,7 +456,7 @@ class _OpenSpan:
         self.name = name
         self.fields = fields
         self.id = next(_ids)
-        stack = _stack()
+        stack = _tls.stack
         self.parent = stack[-1].id if stack else None
         self.root = stack[-1].root if stack else self.id
         self.t0_ns = time.perf_counter_ns()
@@ -398,7 +470,7 @@ class _OpenSpan:
             return
         rec = _span_record(self, t1_ns)
         rec.update(self.fields)
-        _record(rec, bool(_stack()))
+        _record(rec, bool(_tls.stack))
 
 
 def open_span(name: str, **fields):
@@ -423,7 +495,7 @@ def _on_gc(phase: str, info: dict) -> None:
                 "runtime.gc_pause", {"generation": info["generation"]})
             sp._open(full)
         return
-    sp = getattr(_tls, "gc_span", None)
+    sp = _tls.gc_span
     if sp is not None:
         _tls.gc_span = None
         sp.fields["collected"] = info.get("collected", 0)
@@ -528,12 +600,12 @@ class TailReader:
 # change, not a local edit.
 SPAN_REGISTRY = {
     "trace.clock": "written by configure(): perf_ns (time.perf_counter_ns, the clock of t0_ns/t1_ns) paired with time_ns (the wall clock of a profiler session's start)",
+    "trace.thread": "written once a thread and sink, ahead of the first record the thread writes: tid (threading.get_native_id(), on every record) and thread (its name); tools/trace_analyze.py threads names its rows by it",
     "runtime.gc_pause": "one garbage collection that was full or paused over 1 ms, a child of the span it interrupted (generation/collected)",
     "node.boot": "node identity: moniker + full node id, once per process start",
     "consensus.step": "span closing the consensus step being left (height/round/dur_ms/next)",
     "consensus.finalize_commit": "block decided at height/round, with tx count",
     "consensus.propose_speculative": "one speculative proposal assembly overlapping the previous height's commit gap (height/txs/bytes)",
-    "consensus.cert_aggregate": "one aggregate-precommit certificate verified from catchup gossip (height/round/signers/outcome/dur_ms)",
     "state.valset_update": "a block's validator updates applied to the set of two heights on, and the new set hashed (height/changes)",
     "state.apply_block": "ApplyBlock with validate/finalize/commit/save stage breakdown (validate_ms/finalize_ms/update_state_ms = the next state built: the validator updates and the proposer rotation/commit_ms = the app's Commit and the mempool's update/save_events_ms: five stages in order, which sum to dur_ms less the clock reads; rotation = column|integer: the arithmetic that rotated the proposer, ValidatorSet._rotate)",
     "types.verify_commit": "one verify_commit / verify_commit_light (height/n = signatures judged/light); self_ms is the entry layer from inside",
@@ -552,32 +624,38 @@ SPAN_REGISTRY = {
     "crypto.pack": "R||S||k wire rows of one ladder or mesh dispatch built on the host (n/bucket/chunks = chunks the lanes went in/pool = run|busy|small|python: pooled, pool taken so packed inline, too few lanes, no native library)",
     "crypto.device_launch": "jax.device_put of one dispatch's wire arrays plus the jitted call's return (bytes/a_cache = hit: the batch's pubkey column was already decompressed on the device, miss: it ships and decompresses again; untraced nodes read crypto_a_cache_total{result})",
     "crypto.native_verify": "one batch judged by the host C++ engine, blame rescan included (n/ok)",
-    "crypto.verdict_wait": "result() blocking on one batch's verdict (path/n/batch = id of its crypto.batch_verify/since_submit_ms/blame_rerun); path = sched: a caller's wait on a shared-scheduler handle (SchedPending.result), batch = id of the crypto.sched_coalesce its request rode in",
+    "crypto.verdict_wait": "result() blocking on one batch's verdict (path/n/batch = id of its crypto.batch_verify/since_submit_ms/blame_rerun); path = sched: a caller's wait on a shared-scheduler handle (SchedPending.result), batch = id of the crypto.sched_coalesce its request rode in, wake_ms = from the instant the request's verdict was set (on the completion thread) to result() returning on the caller's, 0 where the verdict was in before the call",
     "crypto.commit_partition": "one curve's leg of one commit, launch to verdict, a child of types.verify_commit that its sibling legs overlap (curve/path/n/own_ms = the leg's own time on the thread that ran it: the host engine's call on its worker thread, or submit() plus the blocked result() of a device batch/waited_ms = what result() blocked the caller for)",
-    "crypto.bls_aggregate": "one BLS partition collapsed to aggregate pairing check(s) (n/pairing_checks)",
     "crypto.mesh_submit": "one sharded mega-batch across the verify mesh (n/b/n_devices/shard_lanes)",
     "crypto.stream_place": "one streamed commit placed on a mesh device (device/n/b)",
-    "crypto.sched_coalesce": "one shared-scheduler dispatch on the drainer's thread: the merge and the launch (submit(); a cpu-backend or non-coalescable verifier verifies and answers inside it); it closes behind the launch, the verdict is the completion side's; its crypto.batch_verify and the requests' crypto.sched_wait are its children (n_requests/sigs/lanes_bucket/tenants/sources/per_tenant_sigs/absorb_ms = the merge loop, absent on the pass-through/collect_ms = from the drainer seeing work queued, a slot free or not, to taking this batch/inflight = earlier batches ON THE DEVICE and unanswered when the drainer took this one, 0 or 1: a host-engine batch ahead is not counted) (crypto/sched.py)",
+    "crypto.sched_collect": "the drainer between two dispatches, one a batch taken: from the entry of _collect to the return of _take, opened before and closed after the scheduler's lock (idle_ms = nothing queued/slot_ms = work queued and both slots of _MAX_UNANSWERED taken/linger_ms = the coalescing window and the pop that ends it: the three sum to dur_ms; the one the drainer is stopped in carries none of them) (crypto/sched.py)",
+    "crypto.sched_coalesce": "one shared-scheduler dispatch on the drainer's thread: the merge and the launch (submit(); a cpu-backend or non-coalescable verifier verifies and answers inside it); it closes behind the launch, the verdict is the completion side's; its crypto.batch_verify and the requests' crypto.sched_wait are its children (n_requests/sigs/lanes_bucket/tenants/sources/per_tenant_sigs/absorb_ms = the merge loop, absent on the pass-through/inflight = earlier batches ON THE DEVICE and unanswered when the drainer took this one, 0 or 1: a host-engine batch ahead is not counted) (crypto/sched.py)",
     "crypto.sched_complete": "one launched batch on the completion side (the verify-sched-done thread; the caller's under drain_once): result(), the slices, the answers (batch = id of its crypto.sched_coalesce/n_requests/wait_ms = inside result()/since_launch_ms = submit() returned to last answer set)",
     "crypto.sched_wait": "one request through the shared scheduler, written where its verdict is set, as a child of the crypto.sched_coalesce it rode in whichever thread writes it: dur_ms = enqueue to verdict, queued_ms = enqueue to the moment _take_batch popped it (tenant/source/n/batch = id of that crypto.sched_coalesce/alone = true on the pass-through)",
     "mempool.admit_window": "one micro-batched admission window: n/dup/sig_fail/app_fail/admitted + stage ms",
     "tx.lifecycle": "one stage crossing of a sampled tx (tx/stage/mono; utils/txlife.py — hash-prefix sampled, correlated across nodes by tx)",
     "p2p.send": "consensus wire message handed to a peer (msg/height/round/peer)",
-    "p2p.zero_copy_send": "one multiplexed message fully packetized via memoryview slicing (chan/bytes/packets)",
     "p2p.recv": "consensus wire message received from a peer (msg/height/round/peer)",
-    "light.mmr_append": "one committed header folded into the MMR accumulator (height/leaf/size/dur_ms)",
-    "light.serve_proof": "one MMR ancestry proof generated for a light client (height/size/bytes)",
     "da.encode": "one committed payload erasure-coded + committed (height/bytes/shards/shard_bytes)",
-    "da.serve_sample": "one extended-chunk opening served to a sampling client (height/index)",
-    "da.sample_verify": "one sample proof verified against the header's da_root (index/n/ok)",
     "da.pc_commit": "one payload committed on the 2D KZG track: per-column commitments + parity extension (height/rows/cols/bytes)",
-    "crypto.msm_opening": "one KZG opening-proof quotient committed via G1 MSM (n/cols)",
     "replication.feed_send": "one committed height's frame fanned out on the replication feed (height/subs/bytes)",
     "replication.replica_apply": "one feed frame applied into replica serving state (height/da/dur_ms)",
     "consensus.conflicting_vote": "conflicting signed votes from one validator at one HRS (height/round/type/vote_a/vote_b hex) — the watchtower's equivocation feed",
     "watchtower.audit": "one audited feed frame: every check run against a height (node/height/checks/dur_ms)",
     "watchtower.verdict": "one watchtower finding (check/node/height/safety/detail) — safety verdicts fail an audited e2e run",
 }
+
+# The spans in which a thread does nothing but wait, for a verdict or
+# for work: their self time is a wait the program chose. What is left of
+# a thread's wall time inside its root spans, after those and after what
+# it spent on a CPU (the roots' cpu_ms), is the interpreter's or the
+# OS's: another thread's turn, a descheduled core, the C++ pool working
+# for it. tools/trace_analyze.py threads splits a thread's time by it;
+# tools/trace_lint.py holds it to registered names.
+WAIT_SPANS = (
+    "crypto.verdict_wait",
+    "crypto.sched_collect",
+)
 
 
 # Kernel-scope registry: every phase of ops/ (a jax.named_scope) and

@@ -33,6 +33,7 @@ laptop against sinks scp'd out of a broken testnet.
 
 from __future__ import annotations
 
+import bisect
 import json
 import os
 from collections import Counter, defaultdict
@@ -576,6 +577,9 @@ def merge(paths) -> MergedTrace:
 # ----------------------------------------------------------------------
 NO_SPAN = "host:outside any span"
 NO_SCOPE = "(no scope)"
+# the annotations inside which a device program is launched: an idle gap
+# of the device is the business of the thread that runs them
+LAUNCH_SPANS = ("crypto.device_launch", "crypto.mesh_submit")
 
 
 def _union(intervals) -> list[list[float]]:
@@ -589,9 +593,11 @@ def _union(intervals) -> list[list[float]]:
 
 
 def _innermost_segments(spans: list[dict]) -> list[tuple]:
-    """[(t0, t1, span)] over the stretches where some span is open, the
-    span being the innermost one: the shortest that covers the stretch
-    (spans of one thread nest, so the shortest is the deepest)."""
+    """[(t0, t1, span)] over the stretches where some span of ONE thread
+    is open, the span being the innermost one: the shortest that covers
+    the stretch (spans of one thread nest, so the shortest is the
+    deepest; spans of several threads do not, and device_join calls
+    this a thread at a time)."""
     edges = sorted({t for sp in spans
                     for t in (sp["start_ns"], sp["start_ns"] + sp["dur_ns"])})
     starts = sorted(spans, key=lambda sp: sp["start_ns"])
@@ -680,6 +686,49 @@ def _device_time_by_scope(ops: list[dict], lo: float, hi: float,
     return ({"scope": dict(by_scope), "op": dict(by_op)}, dict(how))
 
 
+def _host_device_skew(launches: list[dict], planes: list[dict]):
+    """(ns, pairs): the least by which the device's clock runs ahead of
+    the host's, and how many launches that rests on; (None, 0) where no
+    launch finds a program run. Each launch annotation looks for its
+    run among one device's (the "XLA Modules" line) around itself,
+    never by count:
+
+    * the last run that began BEFORE it, behind the launch before it, if
+      the device had been idle for longer than the run is early (a run
+      that begins where another ends was queued behind that one: the
+      second program of a launch, a batch behind a batch): the clocks
+      disagree by at least that lead;
+    * else the first run that began inside the launch's interval, up to
+      the next launch: it follows its launch as it should.
+
+    A run answers one launch; the skew is the largest lead."""
+    starts = [sp["start_ns"] for sp in launches]
+    for p in planes:
+        runs = sorted(p.get("modules", ()), key=lambda m: m["start_ns"])
+        at = [m["start_ns"] for m in runs]
+        taken: set[int] = set()
+        lead, pairs = 0.0, 0
+        for i, s in enumerate(starts):
+            before = starts[i - 1] if i else float("-inf")
+            k = bisect.bisect_left(at, s) - 1  # the last run before s
+            if k >= 0 and k not in taken and at[k] > before and (
+                    k == 0 or at[k] - (at[k - 1] + runs[k - 1]["dur_ns"])
+                    > s - at[k]):
+                lead = max(lead, s - at[k])
+            else:
+                k += 1
+                while k in taken:
+                    k += 1
+                nxt = starts[i + 1] if i + 1 < len(starts) else float("inf")
+                if k >= len(at) or at[k] >= nxt:
+                    continue
+            taken.add(k)
+            pairs += 1
+        if pairs:
+            return lead, pairs
+    return None, 0
+
+
 def device_join(xp: dict, records: list[dict] | None = None,
                 stretch: tuple[float, float] | None = None,
                 scopes=()) -> dict:
@@ -687,14 +736,35 @@ def device_join(xp: dict, records: list[dict] | None = None,
 
     For the stretch [lo, hi) in ns since the session began (default:
     first to last event kept): the busiest device's idle time by the
-    innermost PROGRAM span the host was in, and every device's busy time
-    by kernel scope (`scopes`: trace.KERNEL_SCOPES). With `records` an
-    idle row is labelled by the span's whole ancestry ("root > ... >
-    leaf", joined to the sink by span id), which tells a
-    crypto.batch_verify under verify_commit from one under a replay
-    window; without, by the span's name alone."""
+    innermost PROGRAM span of the thread that LAUNCHES, and every
+    device's busy time by kernel scope (`scopes`: trace.KERNEL_SCOPES).
+    An idle gap is booked to one thread: that of the launch which ended
+    it (the last annotation of LAUNCH_SPANS that began before the
+    device operation closing the gap; the trailing gap goes to the last
+    launch's thread, a gap before every launch to the first's), by that
+    thread's innermost span over the gap, and to "outside any span"
+    where that thread was in none: what a caller's thread was in says
+    nothing of why the device is empty. Threads are the host plane's
+    lines (a span's `line`), else the sink's `tid`; a trace without a
+    launch annotation is booked as one thread. With `records` an idle
+    row is labelled by the span's whole ancestry ("root > ... > leaf",
+    joined to the sink by span id), which tells a crypto.batch_verify
+    under verify_commit from one under a replay window, and a thread
+    by its name (trace.thread); without, by the span's name alone.
+
+    The two clocks: `host_device_skew_ms` is the least the device's
+    clock must run ahead of the host's, read from `skew_pairs` launches
+    that found their program's run (_host_device_skew); the device's
+    timeline is moved later by it before anything is booked. Where no
+    launch finds a run it is None and nothing moves."""
     dev = [p for p in xp["planes"] if p.get("ops")]
     spans = [sp for p in xp["planes"] for sp in p.get("spans", [])]
+    launched = sorted((sp for sp in spans if sp["name"] in LAUNCH_SPANS),
+                      key=lambda sp: sp["start_ns"])
+    skew, skew_pairs = _host_device_skew(launched, dev)
+    if skew:
+        dev = [dict(p, ops=[dict(o, start_ns=o["start_ns"] + skew)
+                            for o in p["ops"]]) for p in dev]
     times = [t for p in dev for o in p["ops"]
              for t in (o["start_ns"], o["start_ns"] + o["dur_ns"])]
     times += [t for sp in spans
@@ -729,6 +799,8 @@ def device_join(xp: dict, records: list[dict] | None = None,
 
     by_id = {r["id"]: r for r in records or ()
              if r.get("kind") == "span" and "id" in r}
+    thread_names = {r["tid"]: r.get("thread") for r in records or ()
+                    if r.get("name") == "trace.thread"}
 
     def label(sp: dict) -> str:
         names, rec = [sp["name"]], by_id.get(sp["span_id"])
@@ -738,27 +810,52 @@ def device_join(xp: dict, records: list[dict] | None = None,
                 names.append(rec["name"])
         return " > ".join(reversed(names))
 
+    def thread_of(sp: dict):
+        if sp.get("line") is not None:
+            return sp["line"]
+        return by_id.get(sp["span_id"], {}).get("tid")
+
+    # one thread's spans nest; without a launch to go by, every span is
+    # booked as if one thread had written it
+    by_thread: dict = defaultdict(list)
+    for sp in spans:
+        by_thread[thread_of(sp) if launched else None].append(sp)
+    segs = {th: _innermost_segments(sps) for th, sps in by_thread.items()}
+    ends = {th: [t1 for _t0, t1, _sp in sg] for th, sg in segs.items()}
+    launch_starts = [sp["start_ns"] for sp in launched]
+
     idle: dict[str, float] = defaultdict(float)
+    idle_thread: dict = defaultdict(float)
     if busiest is not None:
         edges = [lo] + [t for iv in busiest[2] for t in iv] + [hi]
-        gaps = [(a, b) for a, b in zip(edges[0::2], edges[1::2]) if b > a]
-        segs = _innermost_segments(spans)
-        i = 0
-        for g0, g1 in gaps:
+        for g0, g1 in zip(edges[0::2], edges[1::2]):
+            if g1 <= g0:
+                continue
+            th = None
+            if launched:
+                k = bisect.bisect_right(launch_starts, g1) - 1
+                th = thread_of(launched[max(k, 0)])
+            idle_thread[th] += g1 - g0
             covered = 0.0
-            while i < len(segs) and segs[i][1] <= g0:
-                i += 1
-            j = i
-            while j < len(segs) and segs[j][0] < g1:
-                t0, t1, sp = segs[j]
+            first = bisect.bisect_right(ends.get(th, ()), g0)
+            for t0, t1, sp in segs.get(th, ())[first:]:
+                if t0 >= g1:
+                    break
                 part = min(t1, g1) - max(t0, g0)
                 if part > 0:
                     idle[label(sp)] += part
                     covered += part
-                j += 1
             idle[NO_SPAN] += (g1 - g0) - covered
     idle_ns = sum(idle.values())
     busy_ns = sum(scope_ns.values())
+
+    def thread_label(th) -> str:
+        if not launched:
+            return "every thread"
+        tids = {by_id[sp["span_id"]].get("tid") for sp in by_thread[th]
+                if sp["span_id"] in by_id} - {None}
+        tid = tids.pop() if len(tids) == 1 else None
+        return thread_names.get(tid) or f"thread {tid or th}"
 
     def top(d: dict, n: int = 0) -> list:
         rows = sorted(d.items(), key=lambda kv: -kv[1])
@@ -774,6 +871,12 @@ def device_join(xp: dict, records: list[dict] | None = None,
         "idle_by_span": top(idle),
         "idle_named_share": (1.0 - idle.get(NO_SPAN, 0.0) / idle_ns
                              if idle_ns else None),
+        "idle_by_thread": top({thread_label(th): v
+                               for th, v in idle_thread.items()}),
+        "launches": len(launched),
+        "threads": len(by_thread),
+        "host_device_skew_ms": None if skew is None else skew / 1e6,
+        "skew_pairs": skew_pairs,
         "busy_by_scope": top(scope_ns),
         "busy_scoped_share": (1.0 - scope_ns.get(NO_SCOPE, 0.0) / busy_ns
                               if busy_ns else None),
@@ -793,11 +896,25 @@ def render_device_join(j: dict) -> str:
                  j["stretch_s"], len(j["devices"]), j["busiest"],
                  j["busy_s"], j["idle_s"],
                  100 * j["idle_s"] / j["stretch_s"] if j["stretch_s"] else 0)]
-    lines.append("program spans in the trace: %d, of them in the sink: %d"
-                 % (j["spans_in_trace"], j["spans_joined"]))
+    lines.append("program spans in the trace: %d on %d thread(s), of them "
+                 "in the sink: %d; launches: %d" % (
+                     j["spans_in_trace"], j["threads"], j["spans_joined"],
+                     j["launches"]))
+    lines.append(
+        "host_device_skew_ms: none (no launch found its program's run; "
+        "nothing moved)" if j["host_device_skew_ms"] is None else
+        "host_device_skew_ms: %.3f (the most a program began before the "
+        "annotation that launched it, of %d launches that found their "
+        "run; the device's timeline is moved later by it)" % (
+            j["host_device_skew_ms"], j["skew_pairs"]))
     if j["idle_named_share"] is not None:
-        lines.append("idle time of the busiest device by the innermost "
-                     "program span the host was in (%.1f%% inside a span):"
+        lines.append("idle time of the busiest device by the thread whose "
+                     "launch ended the gap:")
+        for name, s in j["idle_by_thread"]:
+            lines.append("  %9.4f s  %5.1f%%  %s" % (
+                s, 100 * s / j["idle_s"], name))
+        lines.append("... and by the innermost program span that thread "
+                     "was in (%.1f%% inside a span):"
                      % (100 * j["idle_named_share"]))
         for name, s in j["idle_by_span"]:
             lines.append("  %9.4f s  %5.1f%%  %s" % (
@@ -814,6 +931,70 @@ def render_device_join(j: dict) -> str:
                             for op, t in j["ops_by_scope"][scope])
             lines.append("  %9.4f s  %5.1f%%  %-20s %s" % (
                 s, 100 * s / busy, scope, ops))
+    return "\n".join(lines)
+
+
+# ----------------------------------------------------------------------
+# a thread's wall time by what it was doing (ISSUE 38): the sink alone,
+# no profiler
+# ----------------------------------------------------------------------
+def thread_table(records: list[dict], wait_spans=()) -> list[dict]:
+    """One row a thread that wrote spans (`tid`, named by its
+    trace.thread record), over the window its spans cover, first t0_ns
+    to last t1_ns: `in_spans_ms`, the wall time inside its root spans,
+    of which `cpu_ms` on a CPU (the roots' cpu_ms) and `wait_ms` inside
+    the spans named in `wait_spans` (trace.WAIT_SPANS: the thread only
+    waits there, and chose to); `other_ms` is what is left of
+    in_spans_ms: off a CPU anywhere else, which is another thread's turn
+    at the interpreter, the OS, or native code's threads working for
+    this one. `outside_ms` is the rest of the window. Only spans made by
+    trace.span() count (they carry self_ms). The few percent a wait span
+    spends on a CPU (its wake-ups) are in both columns and missing from
+    other_ms."""
+    names = {(r.get("pid"), r["tid"]): r.get("thread") for r in records
+             if r.get("name") == "trace.thread" and "tid" in r}
+    rows: dict = {}
+    for r in records:
+        if r.get("kind") != "span" or "self_ms" not in r or "tid" not in r:
+            continue
+        key = (r.get("pid"), r["tid"])
+        row = rows.get(key)
+        if row is None:
+            row = rows[key] = {
+                "pid": key[0], "tid": key[1], "thread": names.get(key),
+                "spans": 0, "t0_ns": r["t0_ns"], "t1_ns": r["t1_ns"],
+                "in_spans_ms": 0.0, "cpu_ms": 0.0, "wait_ms": 0.0}
+        row["spans"] += 1
+        row["t0_ns"] = min(row["t0_ns"], r["t0_ns"])
+        row["t1_ns"] = max(row["t1_ns"], r["t1_ns"])
+        row["in_spans_ms"] += r["self_ms"]
+        row["cpu_ms"] += r.get("cpu_ms", 0.0)
+        if r["name"] in wait_spans:
+            row["wait_ms"] += r["self_ms"]
+    out = []
+    for row in sorted(rows.values(), key=lambda x: x["t0_ns"]):
+        row["other_ms"] = row["in_spans_ms"] - row["cpu_ms"] - row["wait_ms"]
+        row["window_ms"] = (row.pop("t1_ns") - row.pop("t0_ns")) / 1e6
+        row["outside_ms"] = row["window_ms"] - row["in_spans_ms"]
+        out.append({k: round(v, 3) if isinstance(v, float) else v
+                    for k, v in row.items()})
+    return out
+
+
+def render_thread_table(rows: list[dict]) -> str:
+    lines = ["a thread's window (first to last span) by what it was in; "
+             "percent of the window",
+             "%-24s %9s %7s %10s | %8s %8s %8s %8s" % (
+                 "thread", "tid", "spans", "window s", "on CPU",
+                 "waits", "interp/OS", "no span")]
+    for r in rows:
+        w = r["window_ms"] or 1.0
+        lines.append("%-24s %9s %7d %10.3f | %7.1f%% %7.1f%% %7.1f%% "
+                     "%7.1f%%" % (
+                         r["thread"] or "?", r["tid"], r["spans"],
+                         r["window_ms"] / 1e3, 100 * r["cpu_ms"] / w,
+                         100 * r["wait_ms"] / w, 100 * r["other_ms"] / w,
+                         100 * r["outside_ms"] / w))
     return "\n".join(lines)
 
 

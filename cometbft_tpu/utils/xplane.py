@@ -25,11 +25,17 @@ load(path) returns
                "ops":     [{"op", "op_name", "start_ns", "dur_ns"}, ...],
                "modules": [{"op", "start_ns", "dur_ns"}, ...]},
               {"name": "/host:CPU",
-               "spans":   [{"name", "span_id", "start_ns", "dur_ns"}]}]}
+               "spans":   [{"name", "span_id", "line", "start_ns",
+                            "dur_ns"}]}]}
 with start_ns relative to the session's begin, one clock for all planes.
 Device planes keep their "XLA Ops" and "XLA Modules" lines; host planes
 keep only events that carry a `span_id` (the program's trace.span()s,
-entered as jax.profiler.TraceAnnotation(name, span_id=id)).
+entered as jax.profiler.TraceAnnotation(name, span_id=id)), each with
+the `line` it lay on (the line's id, its name where it has none): a host
+plane has one line a thread, so a reader tells the threads apart without
+the span sink. The id is the profiler's own and not the native thread id;
+the sink's `tid` and `trace.thread` name a line's thread, joined by
+`span_id`.
 """
 
 from __future__ import annotations
@@ -151,9 +157,11 @@ def _plane(buf) -> dict | None:
     has_span_ids = "span_id" in stat_names.values()
     out: dict = {"name": name}
     for ln in lines:
-        lname, t_line, events = "", 0, []
-        for no, _w, v in _fields(ln):
-            if no == 2:
+        lname, line_id, t_line, events = "", 0, 0, []
+        for no, wire, v in _fields(ln):
+            if no == 1 and wire == 0:
+                line_id = v
+            elif no == 2:
                 lname = _text(v)
             elif no == 3:
                 t_line = v
@@ -190,7 +198,8 @@ def _plane(buf) -> dict | None:
                         span_id = value
                 if span_id is None:
                     continue
-                rec.update(name=short, span_id=span_id)
+                rec.update(name=short, span_id=span_id,
+                           line=line_id or lname)
             kept.append(rec)
         key = ("spans" if not is_dev else
                "ops" if lname == OPS_LINE else "modules")

@@ -199,7 +199,8 @@ def test_traced_replay_gives_one_tree_with_per_window_spans(
         trace.disable()
     assert state.app_hash == final_state.app_hash and stats.blocks == 8
     recs = [r for r in recs
-            if r["name"] not in ("trace.clock", "runtime.gc_pause")]
+            if r["name"] not in ("trace.clock", "trace.thread",
+                                 "runtime.gc_pause")]
     by_id = {r["id"]: r for r in recs if "id" in r}
     (root,) = [r for r in recs if r["name"] == "blocksync.replay"]
     assert root["parent"] is None

@@ -258,6 +258,47 @@ def test_trace_lint_covers_the_span_tree_and_the_kernel_scopes(tmp_path):
                            {"ladder.new_phase": ["ops/curve.py"]}, KERNEL_SCOPES)
 
 
+def test_trace_lint_holds_the_threads_names_and_the_wait_spans(monkeypatch,
+                                                               capsys):
+    """ISSUE 38: trace.thread and crypto.sched_collect are registered
+    and in use, the eight spans nothing read are gone from the registry
+    and from the tree (the lint is green both ways), and WAIT_SPANS may
+    name registered spans only."""
+    import importlib.util
+
+    from cometbft_tpu.utils import trace
+
+    assert {"trace.thread", "crypto.sched_collect"} <= set(
+        trace.SPAN_REGISTRY)
+    for name in ("consensus.cert_aggregate", "crypto.bls_aggregate",
+                 "crypto.msm_opening", "p2p.zero_copy_send",
+                 "light.mmr_append", "light.serve_proof",
+                 "da.serve_sample", "da.sample_verify"):
+        assert name not in trace.SPAN_REGISTRY, name
+    for name in ("crypto.commit_partition", "crypto.mesh_submit",
+                 "da.encode", "da.pc_commit", "watchtower.audit"):
+        assert name in trace.SPAN_REGISTRY, name
+    assert "crypto.sched_collect" in trace.WAIT_SPANS
+    assert set(trace.WAIT_SPANS) <= set(trace.SPAN_REGISTRY)
+    assert "collect_ms" not in trace.SPAN_REGISTRY["crypto.sched_coalesce"]
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "trace_lint", os.path.join(repo, "tools", "trace_lint.py"))
+    lint = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(lint)
+    assert lint.main() == 0
+    # the tracer's own three records are call sites the lint sees
+    assert {m.group(1) for m in lint.TRACER_RE.finditer(
+        'event("trace.clock", a=1)\n_envelope("trace.thread", "event")\n'
+        '_Span("runtime.gc_pause", {})')} == {
+        "trace.clock", "trace.thread", "runtime.gc_pause"}
+    capsys.readouterr()
+    monkeypatch.setattr(trace, "WAIT_SPANS",
+                        trace.WAIT_SPANS + ("crypto.no_such_wait",))
+    assert lint.main() == 1
+    assert "crypto.no_such_wait" in capsys.readouterr().err
+
+
 def test_logger_levels_and_fields():
     records = []
     cmtlog.set_sink(lambda level, msg, fields: records.append((level, msg, fields)))

@@ -40,9 +40,10 @@ def test_dump_trace_name_and_kind_filters(tmp_path):
             trace.event("p2p.send", msg="vote", height=h)
             trace.emit("state.apply_block", "span", height=h, dur_ms=1.0)
         res = dump_trace(None, {"n": "50"})
-        # configure() wrote trace.clock first
-        assert [r["name"] for r in res["records"]][0] == "trace.clock"
-        assert len(res["records"]) == 1 + 9
+        # configure() wrote trace.clock first, behind its thread's name
+        assert [r["name"] for r in res["records"]][:2] == [
+            "trace.thread", "trace.clock"]
+        assert len(res["records"]) == 2 + 9
         res = dump_trace(None, {"n": "50", "name": "p2p.recv"})
         assert [r["name"] for r in res["records"]] == ["p2p.recv"] * 3
         # substring match catches both directions of the wire hooks
@@ -361,8 +362,9 @@ def test_dump_trace_limit_param_and_cap(tmp_path):
         # explicit limit wins over the legacy alias
         assert len(dump_trace(None, {"limit": "3", "n": "9"})["records"]) == 3
         # clamped, not an error
-        # clamped, not an error (configure()'s trace.clock leads)
-        assert len(dump_trace(None, {"limit": "100000"})["records"]) == 151
+        # clamped, not an error (configure()'s trace.thread and
+        # trace.clock lead)
+        assert len(dump_trace(None, {"limit": "100000"})["records"]) == 152
         assert len(dump_trace(None, {"limit": "0"})["records"]) == 1
     finally:
         trace.disable()

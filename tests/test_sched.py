@@ -781,7 +781,8 @@ def test_the_spans_and_the_counter_say_which_launch_overlapped(gate,
             for r in coalesce] == [(1, 3, 0, False), (2, 6, 1, True)]
     assert coalesce[1]["per_tenant_sigs"] == {"b": 2, "c": 4}
     assert coalesce[1]["sources"] == "blocksync,consensus"
-    assert all(r["collect_ms"] >= 0.0 and r["lanes_bucket"] >= r["sigs"]
+    # how long the drainer held a batch back is crypto.sched_collect's
+    assert all("collect_ms" not in r and r["lanes_bucket"] >= r["sigs"]
                for r in coalesce)
     done = [r for r in recs if r["name"] == "crypto.sched_complete"]
     assert [(r["batch"], r["n_requests"]) for r in done] == [
@@ -799,3 +800,118 @@ def test_the_spans_and_the_counter_say_which_launch_overlapped(gate,
     after = _overlap_counts()
     assert {k: after[k] - before.get(k, 0.0) for k in after} == {
         ("0",): 1.0, ("1",): 1.0}
+
+
+# -- the drainer's account and the caller's wake (ISSUE 38) ---------------
+
+def _traced_collects(sink):
+    recs = _records(sink)
+    names = {r["tid"]: r["thread"] for r in recs
+             if r["name"] == "trace.thread"}
+    collects = [r for r in recs if r["name"] == "crypto.sched_collect"]
+    assert {names[r["tid"]] for r in collects} == {"verify-sched"}
+    return recs, collects
+
+
+def test_sched_collect_says_what_the_drainer_waited_for(gate, tmp_path):
+    """One crypto.sched_collect a batch taken: idle_ms (nothing queued),
+    slot_ms (work queued, both slots taken), linger_ms (the window) sum
+    to its dur_ms. slot_ms reads over 0 only for the batch that found
+    two unanswered; a request behind one batch in flight lingers its
+    window."""
+    from cometbft_tpu.utils import trace
+
+    hold_s = 0.2
+    sink = str(tmp_path / "collect.jsonl")
+    trace.configure(sink)
+    s = S.VerifyScheduler(backend="tpu", max_coalesce_delay_ms=_WINDOW_MS)
+    try:
+        handles = list(_two_in_flight(s, gate))
+        time.sleep(hold_s)  # the drainer idles: nothing is queued
+        handles.append(s.submit(_tpu(2, tag=b"late"), tenant="d",
+                                source="light"))
+        time.sleep(hold_s)  # ... and now waits for a slot
+        assert len(gate.launched) == 2
+        gate.open_all()
+        assert gate.wait_launched(3)
+        gate.open_all()
+        assert [h.result(timeout=LIMIT_S) for h in handles] == _WANT_012 + [
+            (True, [True, True])]
+    finally:
+        gate.open_all()
+        s.close()
+        trace.flush()
+        trace.disable()
+    recs, collects = _traced_collects(sink)
+    taken = [c for c in collects if "idle_ms" in c]
+    # each took what the dispatch behind it merged
+    assert [r["n_requests"] for r in recs
+            if r["name"] == "crypto.sched_coalesce"] == [1, 2, 1]
+    for c in taken:
+        assert "queued" not in c and "n_requests" not in c
+        assert c["idle_ms"] + c["slot_ms"] + c["linger_ms"] == pytest.approx(
+            c["dur_ms"], rel=0.05, abs=0.5)
+    lone, pair, late = taken
+    # an idle scheduler's lone request: no slot to wait for, no window
+    assert lone["slot_ms"] == 0.0 and lone["linger_ms"] < 0.9 * _WINDOW_MS
+    # behind one batch in flight: a slot is free, the window is kept
+    assert pair["slot_ms"] == 0.0
+    assert 0.9 * _WINDOW_MS <= pair["linger_ms"] < _WINDOW_MS + 500.0
+    # behind two: idle until it arrived, then held for a slot, then gone
+    # at once (its window ran out while it was held)
+    assert late["idle_ms"] >= 0.9 * hold_s * 1e3
+    assert late["slot_ms"] >= 0.9 * hold_s * 1e3
+    assert late["linger_ms"] < 0.9 * _WINDOW_MS
+    # the one the drainer was stopped in took nothing and says nothing
+    assert len(collects) == 4 and "idle_ms" not in collects[-1]
+    # in time, a collect lies between two dispatches of its thread
+    own = sorted((r for r in recs if r["name"] in (
+        "crypto.sched_collect", "crypto.sched_coalesce")),
+        key=lambda r: r["t0_ns"])
+    assert [r["name"].rsplit("_", 1)[1] for r in own] == [
+        "collect", "coalesce"] * 3 + ["collect"]
+    assert all(a["t1_ns"] <= b["t0_ns"] for a, b in zip(own, own[1:]))
+    assert len({r["tid"] for r in own}) == 1
+
+
+def test_wake_ms_runs_from_the_verdict_or_from_the_call(gate, tmp_path):
+    """crypto.verdict_wait path=sched: wake_ms is what lies between the
+    request's verdict being set and result() returning on the caller's
+    thread; a verdict that was in before the call is no wake."""
+    from cometbft_tpu.utils import trace
+
+    sink = str(tmp_path / "wake.jsonl")
+    trace.configure(sink)
+    s = S.VerifyScheduler(backend="tpu", max_coalesce_delay_ms=_WINDOW_MS)
+    try:
+        h = s.submit(_tpu(2, tag=b"w0"), tenant="a", source="consensus")
+        assert gate.wait_launched(1)
+        got = []
+        t = threading.Thread(
+            target=lambda: got.append(h.result(timeout=LIMIT_S)))
+        t.start()
+        time.sleep(0.1)  # the caller waits; then the verdict lands
+        gate.open_all()
+        t.join(LIMIT_S)
+        assert got == [(True, [True, True])]
+        # the verdict of the second is in long before anyone asks
+        h2 = s.submit(_tpu(1, tag=b"w1"), tenant="b", source="consensus")
+        assert gate.wait_launched(2)
+        gate.open_all()
+        deadline = time.monotonic() + LIMIT_S
+        while not h2._future.done() and time.monotonic() < deadline:
+            time.sleep(0.005)
+        time.sleep(0.1)
+        assert h2.result(timeout=LIMIT_S) == (True, [True])
+    finally:
+        gate.open_all()
+        s.close()
+        trace.flush()
+        trace.disable()
+    waits = [r for r in _records(sink) if r["name"] == "crypto.verdict_wait"
+             and r.get("path") == "sched"]
+    assert len(waits) == 2
+    blocked, late = waits
+    assert blocked["dur_ms"] >= 90.0
+    assert 0.0 <= blocked["wake_ms"] < blocked["dur_ms"] - 80.0
+    assert 0.0 <= late["wake_ms"] <= late["dur_ms"] < 50.0

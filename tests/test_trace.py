@@ -18,11 +18,12 @@ def _cleanup():
 
 def _read(sink):
     """The sink's records less the tracer's own (configure() writes
-    trace.clock; a collection may add runtime.gc_pause anywhere)."""
+    trace.clock, a thread's first record follows its trace.thread, a
+    collection may add runtime.gc_pause anywhere)."""
     with open(sink, encoding="utf-8") as f:
         recs = [json.loads(line) for line in f]
-    return [r for r in recs
-            if r["name"] not in ("trace.clock", "runtime.gc_pause")]
+    return [r for r in recs if r["name"] not in (
+        "trace.clock", "trace.thread", "runtime.gc_pause")]
 
 
 def test_tracer_disabled_is_noop_and_cheap():
@@ -78,7 +79,8 @@ def test_tracer_jsonl_schema_and_tail(tmp_path):
         # tail() (the dump_trace RPC backend) parses the same records
         assert [r["name"] for r in trace.tail(10)
                 if r["name"] != "runtime.gc_pause"] == [
-            "trace.clock", "consensus.step", "state.apply_block",
+            "trace.thread", "trace.clock", "consensus.step",
+            "state.apply_block",
         ]
         assert trace.tail(1)[0]["name"] == "state.apply_block"
     finally:
@@ -106,7 +108,8 @@ def test_tail_window_grows_past_initial_seek(tmp_path):
         assert got[0]["i"] == 500 and got[-1]["i"] == 2999
         # n beyond the file returns every record, first line included
         everything = trace.tail(100_000)
-        assert everything[0]["name"] == "trace.clock"
+        assert [r["name"] for r in everything[:2]] == [
+            "trace.thread", "trace.clock"]
         grown = [r for r in everything if r["name"] == "grow"]
         assert len(grown) == 3000 and grown[0]["i"] == 0
     finally:
@@ -391,3 +394,251 @@ def test_a_span_under_the_profiler_is_in_the_xplane_with_its_id(tmp_path):
                      if r["name"] == "trace.clock")
     wall = clock["time_ns"] + by_id[outer.id]["t0_ns"] - clock["perf_ns"]
     assert abs(wall - (xp["start_ns"] + o["start_ns"])) < 5e6  # 5 ms
+
+
+# ----------------------------------------------------------------------
+# ISSUE 38: threads in the span tree (tid, trace.thread, a root's
+# cpu_ms) and a flush that holds no other thread
+# ----------------------------------------------------------------------
+def _all(sink):
+    with open(sink, encoding="utf-8") as f:
+        return [json.loads(line) for line in f]
+
+
+def _spin(seconds):
+    """Keeps this thread at the interpreter until it has been on a CPU
+    that long (by its own CPU clock: a busy box only stretches it)."""
+    end = time.thread_time() + seconds
+    n = 0
+    while time.thread_time() < end:
+        n += 1
+    return n
+
+
+def test_every_record_names_its_thread_and_a_thread_is_named_once(tmp_path):
+    import threading
+
+    sink = os.path.join(str(tmp_path), "tid.jsonl")
+    trace.configure(sink)
+    try:
+        def work():
+            with trace.span("root"):
+                trace.event("mark")
+                sp = trace.open_span("leg")
+                sp.close()
+
+        t = threading.Thread(target=work, name="worker-7")
+        t.start()
+        t.join(30)
+        work()
+        trace.flush()
+        recs = _all(sink)
+    finally:
+        _cleanup()
+    assert all(isinstance(r["tid"], int) for r in recs)
+    named = [r for r in recs if r["name"] == "trace.thread"]
+    assert sorted(r["thread"] for r in named) == ["MainThread", "worker-7"]
+    tids = {r["thread"]: r["tid"] for r in named}
+    assert tids["MainThread"] == threading.get_native_id() != tids["worker-7"]
+    # a thread's name lies ahead of every other record of that thread
+    first = {}
+    for i, r in enumerate(recs):
+        first.setdefault(r["tid"], (i, r["name"]))
+    assert {name for _i, name in first.values()} == {"trace.thread"}
+    for tid in tids.values():
+        assert sorted(r["name"] for r in recs if r["tid"] == tid
+                      and r["name"] in ("root", "mark", "leg")) == [
+            "leg", "mark", "root"]
+    # a new sink gets the names anew
+    sink2 = os.path.join(str(tmp_path), "tid2.jsonl")
+    trace.configure(sink2)
+    try:
+        trace.event("again")
+        trace.flush()
+        assert [r["name"] for r in _all(sink2)] == [
+            "trace.thread", "trace.clock", "again"]
+    finally:
+        _cleanup()
+
+
+@pytest.mark.parametrize("kind", ["sleeps", "spins"])
+def test_cpu_ms_is_what_the_thread_ran(tmp_path, kind):
+    """cpu_ms never passes dur_ms; a span that sleeps 20 ms was on a CPU
+    for next to none of it, one that spins 20 ms of CPU reads them (and
+    on an idle box little more as its duration)."""
+    sink = os.path.join(str(tmp_path), f"cpu-{kind}.jsonl")
+    trace.configure(sink)
+    try:
+        with trace.span("work"):
+            if kind == "sleeps":
+                time.sleep(0.02)
+            else:
+                _spin(0.02)
+        leg = trace.open_span("leg")
+        leg.close()
+        trace.flush()
+        recs = _all(sink)
+    finally:
+        _cleanup()
+    work = next(r for r in recs if r["name"] == "work")
+    assert 0.0 <= work["cpu_ms"] <= work["dur_ms"]
+    assert work["dur_ms"] >= 19.0
+    if kind == "sleeps":
+        assert work["cpu_ms"] < 5.0
+    else:
+        assert 19.0 <= work["cpu_ms"] <= work["dur_ms"]
+    # a span that may end on another thread has no CPU time to give
+    leg = next(r for r in recs if r["name"] == "leg")
+    assert "cpu_ms" not in leg and leg["parent"] is None and "tid" in leg
+
+
+def test_only_a_root_reads_the_cpu_clock(tmp_path, monkeypatch):
+    """The thread's CPU clock costs microseconds a reading under a
+    sandboxed kernel, so a span pays for it only at a root: two
+    readings a tree, whatever lies under it; a root's cpu_ms holds its
+    children's time, a collector's pause included."""
+    import gc
+
+    reads = []
+    clock = time.thread_time_ns
+
+    def counted():
+        reads.append(1)
+        return clock()
+
+    sink = os.path.join(str(tmp_path), "roots.jsonl")
+    trace.configure(sink)
+    try:
+        gc.disable()  # no pause but the one asked for
+        monkeypatch.setattr(trace.time, "thread_time_ns", counted)
+        with trace.span("parent"):
+            with trace.span("child"):
+                _spin(0.03)
+            time.sleep(0.01)
+            with trace.span("interrupted"):
+                gc.collect()
+        monkeypatch.setattr(trace.time, "thread_time_ns", clock)
+        trace.flush()
+        recs = _all(sink)
+    finally:
+        gc.enable()
+        _cleanup()
+    assert len(reads) == 2
+    sp = {r["name"]: r for r in recs if r["kind"] == "span"}
+    assert set(sp) == {"parent", "child", "interrupted", "runtime.gc_pause"}
+    assert [n for n, r in sp.items() if "cpu_ms" in r] == ["parent"]
+    assert sp["runtime.gc_pause"]["parent"] == sp["interrupted"]["id"]
+    assert 29.0 <= sp["parent"]["cpu_ms"] <= sp["parent"]["dur_ms"] - 9.0
+    # self time is the wall clock's, as it was
+    assert sp["parent"]["self_ms"] == pytest.approx(
+        sp["parent"]["dur_ms"] - sp["child"]["dur_ms"]
+        - sp["interrupted"]["dur_ms"], abs=0.01)
+
+
+def test_a_pause_outside_every_span_is_a_root_with_cpu_ms(tmp_path):
+    import gc
+
+    sink = os.path.join(str(tmp_path), "pause.jsonl")
+    trace.configure(sink)
+    try:
+        gc.collect()
+        trace.flush()
+        recs = _all(sink)
+    finally:
+        _cleanup()
+    pause = next(r for r in recs if r["name"] == "runtime.gc_pause")
+    assert pause["parent"] is None
+    assert 0.0 <= pause["cpu_ms"] <= pause["dur_ms"]
+
+
+def test_two_threads_that_spin_under_one_lock_each_ran_half_their_wall(
+        tmp_path):
+    """What the thread did not run is not in cpu_ms: two threads that
+    take turns (a lock here, the interpreter in the scheduler's cell)
+    each read about half their wall time as on-CPU."""
+    import threading
+
+    sink = os.path.join(str(tmp_path), "turns.jsonl")
+    trace.configure(sink)
+    try:
+        lock = threading.Condition()
+        turn = [0]
+        go = threading.Barrier(2, timeout=30)
+
+        def work(me):
+            go.wait()
+            with trace.span("turns", tag=me):
+                for _ in range(20):
+                    with lock:
+                        assert lock.wait_for(lambda: turn[0] == me, 30)
+                        _spin(0.005)
+                        turn[0] = 1 - me
+                        lock.notify_all()
+
+        threads = [threading.Thread(target=work, args=(t,)) for t in (0, 1)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+            assert not t.is_alive()
+        trace.flush()
+        recs = [r for r in _all(sink) if r["name"] == "turns"]
+    finally:
+        _cleanup()
+    assert len(recs) == 2 and recs[0]["tid"] != recs[1]["tid"]
+    for r in recs:
+        # 20 turns of 5 ms of CPU each, the other thread's 19 or 20
+        # between them: it ran its own half and no more
+        assert r["dur_ms"] >= 190.0
+        assert 99.0 <= r["cpu_ms"] <= 0.65 * r["dur_ms"], r
+
+
+def test_a_record_from_another_thread_does_not_wait_for_a_slow_flush(
+        tmp_path, monkeypatch):
+    """A flush takes the waiting records under the buffer's lock and
+    serialises them outside it: while one thread is inside a slow
+    json.dumps, another closes spans without waiting, its records go
+    out with the next flush, and flush() itself waits its turn."""
+    import threading
+
+    sink = os.path.join(str(tmp_path), "slow.jsonl")
+    trace.configure(sink)
+    try:
+        serialising = threading.Event()
+        release = threading.Event()
+        orig = trace._serialise
+
+        def slow(batch):
+            serialising.set()
+            assert release.wait(30)
+            return orig(batch)
+
+        trace.event("first")
+        monkeypatch.setattr(trace, "_serialise", slow)
+        flusher = threading.Thread(target=trace.flush)
+        flusher.start()
+        assert serialising.wait(30)
+        # the flush is under way and stays so
+        monkeypatch.setattr(trace, "FLUSH_INTERVAL_S", 0.0)
+        t0 = time.perf_counter()
+        for i in range(50):
+            with trace.span("meanwhile", i=i):
+                pass
+        took = time.perf_counter() - t0
+        assert flusher.is_alive() and not release.is_set()
+        assert took < 1.0, f"50 spans took {took:.3f}s beside a flush"
+        monkeypatch.setattr(trace, "_serialise", orig)
+        waiter = threading.Thread(target=trace.flush)
+        waiter.start()
+        waiter.join(0.2)
+        assert waiter.is_alive()  # flush() keeps its guarantee: it waits
+        release.set()
+        flusher.join(30)
+        waiter.join(30)
+        assert not flusher.is_alive() and not waiter.is_alive()
+        recs = _read(sink)
+        assert [r["name"] for r in recs] == ["first"] + ["meanwhile"] * 50
+        assert [r["i"] for r in recs[1:]] == list(range(50))
+    finally:
+        release.set()
+        _cleanup()

@@ -475,3 +475,182 @@ def test_xplane_reader_on_a_trace_recorded_on_a_v5e():
     assert j["busiest"] == "/device:TPU:0"
     assert {"types.verify_commit", "crypto.batch_verify"} <= {
         k for k, _ in j["idle_by_span"]}
+
+
+# ----------------------------------------------------------------------
+# ISSUE 38: threads. A recorded two-thread fixture beside the one-thread
+# one (tests/data/device_join_threads.json and .spans.jsonl): a caller
+# whose short types.commit_items is open during the device's gap, and the
+# drainer, which launches: in no span when the gap begins, then in
+# crypto.pack. 1 ns of the profiler's trace is 1 us in the sink.
+# ----------------------------------------------------------------------
+def _threads_fixture():
+    with open(os.path.join(_DATA, "device_join_threads.json")) as f:
+        xp = json.load(f)
+    recs = traceview.load_records(
+        os.path.join(_DATA, "device_join_threads.spans.jsonl"))
+    return xp, recs
+
+
+def test_device_join_books_an_idle_gap_to_the_thread_that_launches():
+    xp, recs = _threads_fixture()
+    j = traceview.device_join(xp, recs, scopes=_SCOPES)
+    # busy [3000,5000) + [9000,10000) of [0,12000)
+    assert j["busy_s"] == pytest.approx(3000e-9)
+    assert j["idle_s"] == pytest.approx(9000e-9)
+    assert (j["threads"], j["launches"]) == (2, 2)
+    assert dict(j["idle_by_thread"]) == {
+        "verify-sched": pytest.approx(9000e-9)}
+    idle = dict(j["idle_by_span"])
+    bv = "crypto.sched_coalesce > crypto.batch_verify"
+    assert idle == {
+        # [0,2000) before the first launch, [10000,12000) behind the last
+        "crypto.sched_collect": pytest.approx(4000e-9),
+        "crypto.sched_coalesce": pytest.approx(200e-9),
+        bv: pytest.approx(800e-9),
+        f"{bv} > crypto.device_launch": pytest.approx(1000e-9),
+        # the gap [5000,9000): the launching thread in no span to 6000,
+        # then mostly in the pack
+        traceview.NO_SPAN: pytest.approx(1000e-9),
+        f"{bv} > crypto.pack": pytest.approx(2000e-9),
+    }
+    # nothing goes to the caller's span that happened to be shortest
+    assert not any(k.startswith("types.") for k in idle)
+    assert j["idle_named_share"] == pytest.approx(1 - 1000 / 9000)
+    text = traceview.render_device_join(j)
+    assert "verify-sched" in text and "host_device_skew_ms: 0.000" in text
+    # without the sink the threads are still the trace's lines
+    bare = traceview.device_join(xp, scopes=_SCOPES)
+    assert dict(bare["idle_by_span"])["crypto.pack"] == pytest.approx(2000e-9)
+    assert dict(bare["idle_by_thread"]) == {
+        "thread 22": pytest.approx(9000e-9)}
+    # ... and without a launch to go by, the old one-thread reading: the
+    # shortest span over the stretch, whichever thread's
+    for sp in xp["planes"][1]["spans"]:
+        if sp["name"] == "crypto.device_launch":
+            sp["name"] = "crypto.batch_verify"
+    old = dict(traceview.device_join(xp, recs, scopes=_SCOPES)["idle_by_span"])
+    assert old["types.verify_commit > types.commit_items"] == pytest.approx(
+        800e-9)
+
+
+def test_the_one_thread_fixture_reads_as_before_and_reports_no_skew():
+    xp, recs = _fixture()
+    j = traceview.device_join(xp, recs, scopes=_SCOPES)
+    assert (j["threads"], j["launches"]) == (1, 0)
+    assert j["host_device_skew_ms"] is None
+    assert dict(j["idle_by_thread"]) == {
+        "every thread": pytest.approx(5500e-9)}
+    assert "no launch found" in traceview.render_device_join(j)
+    # one thread that launches reads the same to the digit
+    for p in xp["planes"]:
+        for sp in p.get("spans", ()):
+            if sp["name"] == "crypto.batch_verify":
+                sp["name"] = "crypto.device_launch"
+    k = traceview.device_join(xp, recs, scopes=_SCOPES)
+    assert k["launches"] == 1 and k["idle_s"] == j["idle_s"]
+    assert sorted(v for _n, v in k["idle_by_span"]) == sorted(
+        v for _n, v in j["idle_by_span"])
+
+
+def test_host_device_skew_moves_the_devices_timeline():
+    """A program that begins before the annotation that launched it: the
+    clocks disagree by at least that, and the device's timeline moves."""
+    xp, recs = _threads_fixture()
+    dev = xp["planes"][0]
+    for ev in dev["ops"] + dev["modules"]:
+        ev["start_ns"] -= 1000.0  # launches 2500, 8300; runs 2000, 8000
+    j = traceview.device_join(xp, recs, scopes=_SCOPES)
+    assert j["host_device_skew_ms"] == pytest.approx(500e-6)
+    assert j["skew_pairs"] == 2
+    # moved later by 500: busy [2500,4500) + [8500,9500)
+    assert j["busy_s"] == pytest.approx(3000e-9)
+    assert dict(j["idle_by_span"])["crypto.sched_collect"] == pytest.approx(
+        (2000 + 2500) * 1e-9)
+    # a second program behind each launch's first (a column decompressed)
+    # begins where that one ends: queued, it answers no launch, and the
+    # launches still find their own
+    for m in list(dev["modules"]):
+        dev["modules"].append(
+            dict(m, start_ns=m["start_ns"] + m["dur_ns"] + 1.0))
+    k = traceview.device_join(xp, recs, scopes=_SCOPES)
+    assert k["host_device_skew_ms"] == pytest.approx(500e-6)
+    assert k["skew_pairs"] == 2
+    # a run queued behind another just before a launch is not that
+    # launch's, however near: 8000 begins where [5000,7999) ends, so the
+    # second launch (8300) takes the first run inside its own interval
+    dev["modules"] = [dict(op="p", start_ns=2000.0, dur_ns=2000.0),
+                      dict(op="p", start_ns=5000.0, dur_ns=2999.0),
+                      dict(op="p", start_ns=8000.0, dur_ns=1000.0),
+                      dict(op="p", start_ns=9000.5, dur_ns=1000.0)]
+    k = traceview.device_join(xp, recs, scopes=_SCOPES)
+    assert k["host_device_skew_ms"] == pytest.approx(500e-6)
+    # no run at all: no skew, nothing moves
+    dev["modules"] = []
+    k = traceview.device_join(xp, recs, scopes=_SCOPES)
+    assert k["host_device_skew_ms"] is None and k["skew_pairs"] == 0
+    # as recorded (less the 1000): busy [2000,4000) + [8000,9000)
+    assert k["busy_s"] == pytest.approx(3000e-9)
+    assert dict(k["idle_by_span"])["crypto.sched_collect"] == pytest.approx(
+        (2000 + 2700) * 1e-9)
+
+
+def test_host_device_skew_on_the_trace_recorded_on_a_v5e():
+    """The probe's program began 0.96 ms before the annotation around
+    its call (PERF.md section 7); the reader keeps each span's line."""
+    from cometbft_tpu.utils import xplane
+
+    xp = xplane.load(os.path.join(_DATA, "v5e_probe.xplane.pb"))
+    spans = [sp for p in xp["planes"] for sp in p.get("spans", ())]
+    assert len({sp["line"] for sp in spans}) == 1 and spans[0]["line"]
+    # the probe wrote no crypto.device_launch: as recorded nothing pairs
+    assert traceview.device_join(xp)["host_device_skew_ms"] is None
+    for sp in spans:
+        if sp["name"] == "crypto.batch_verify":
+            sp["name"] = "crypto.device_launch"
+    j = traceview.device_join(xp)
+    assert j["host_device_skew_ms"] == pytest.approx(0.964, abs=0.001)
+    assert (j["threads"], j["launches"], j["skew_pairs"]) == (1, 1, 1)
+
+
+def test_trace_analyze_threads_on_a_recorded_two_thread_sink(capsys):
+    import importlib.util
+
+    from cometbft_tpu.utils.trace import WAIT_SPANS
+
+    sink = os.path.join(_DATA, "device_join_threads.spans.jsonl")
+    rows = traceview.thread_table(traceview.load_records(sink), WAIT_SPANS)
+    by = {r["thread"]: r for r in rows}
+    assert set(by) == {"caller-0", "verify-sched"}
+    d, c = by["verify-sched"], by["caller-0"]
+    # the drainer: two collects, two dispatches and their children (the
+    # crypto.sched_wait it wrote for a caller has no self time and no
+    # part); on a CPU by its four roots, waiting in its collects
+    assert (d["tid"], d["spans"]) == (502, 9)
+    assert d["window_ms"] == 12.0 and d["in_spans_ms"] == 9.4
+    assert (d["cpu_ms"], d["wait_ms"], d["other_ms"]) == (2.6, 4.7, 2.1)
+    assert d["outside_ms"] == 2.6
+    # the caller: its wait for the verdict is chosen, the rest of what
+    # it did not run is the interpreter's
+    assert (c["tid"], c["spans"], c["in_spans_ms"]) == (501, 3, 12.0)
+    assert (c["cpu_ms"], c["wait_ms"], c["other_ms"]) == (1.5, 5.0, 5.5)
+    for r in rows:
+        assert r["cpu_ms"] + r["wait_ms"] + r["other_ms"] == pytest.approx(
+            r["in_spans_ms"])
+    # without the tuple no wall time is a chosen wait
+    assert traceview.thread_table(
+        traceview.load_records(sink))[0]["wait_ms"] == 0.0
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "trace_analyze", os.path.join(repo, "tools", "trace_analyze.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    assert tool.main(["threads", sink]) == 0
+    text = capsys.readouterr().out
+    assert "verify-sched" in text and "caller-0" in text
+    assert tool.main(["threads", sink, "--json"]) == 0
+    assert json.loads(capsys.readouterr().out) == rows
+    # a sink of the tree before ISSUE 38 has no thread to tell
+    assert tool.main(["threads", os.path.join(
+        _DATA, "device_join.spans.jsonl")]) == 2
+    assert "no span with a thread" in capsys.readouterr().err

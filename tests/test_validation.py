@@ -114,7 +114,8 @@ def _traced_verify(tmp_path, n, light=False):
         trace.disable()
     # the tracer's own records come and go with the collector
     return [r for r in recs
-            if r["name"] not in ("trace.clock", "runtime.gc_pause")]
+            if r["name"] not in ("trace.clock", "trace.thread",
+                                 "runtime.gc_pause")]
 
 
 @pytest.mark.parametrize("light", [False, True])

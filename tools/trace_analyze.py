@@ -7,12 +7,21 @@
     python tools/trace_analyze.py critical-path <paths...> [--height H]
     python tools/trace_analyze.py stall         <paths...>
     python tools/trace_analyze.py device        <paths...> --xplane <file>
+    python tools/trace_analyze.py threads       <paths...>
 
 `device` joins the span sinks with a profiler trace (`*.xplane.pb`, or a
 directory that jax.profiler wrote one under) taken while tracing was on:
-the busiest device's idle time by the innermost program span the host
-was in, and device time by kernel scope (trace.KERNEL_SCOPES), for the
-whole trace or `--stretch LO:HI` (milliseconds since the session began).
+the busiest device's idle time by the thread whose launch ended each
+gap and by the innermost program span that thread was in, the least the
+two clocks disagree by (`host_device_skew_ms`), and device time by
+kernel scope (trace.KERNEL_SCOPES), for the whole trace or `--stretch
+LO:HI` (milliseconds since the session began).
+
+`threads` needs the sinks alone: one row a thread, over the window its
+spans cover: on a CPU (its root spans' cpu_ms), inside the spans where
+it only waits (trace.WAIT_SPANS), the rest of its roots (another
+thread's turn at the interpreter, the OS, or native code's threads
+working for it), and outside every span.
 
 `paths` are trace sink files or directories (an e2e workdir is
 expanded to every ``node*/data/trace.jsonl`` under it; default: the
@@ -33,6 +42,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from cometbft_tpu.utils import traceview  # noqa: E402
 
 
+def _records(paths) -> list[dict]:
+    out = []
+    for sink in traceview.discover(paths):
+        out += traceview.load_records(sink)
+    return out
+
+
 def device(args) -> int:
     import glob
 
@@ -51,9 +67,7 @@ def device(args) -> int:
                   file=sys.stderr)
             return 2
         path = found[-1]
-    records = []
-    for sink in traceview.discover(args.paths) if args.paths else []:
-        records += traceview.load_records(sink)
+    records = _records(args.paths or [])
     stretch = None
     if args.stretch:
         lo, hi = (float(x) * 1e6 for x in args.stretch.split(":"))
@@ -69,10 +83,24 @@ def device(args) -> int:
     return 0
 
 
+def threads(args) -> int:
+    from cometbft_tpu.utils.trace import WAIT_SPANS
+
+    rows = traceview.thread_table(_records(args.paths or ["."]), WAIT_SPANS)
+    if not rows:
+        print("trace_analyze: no span with a thread (tid, self_ms) in "
+              f"{args.paths or ['.']!r}", file=sys.stderr)
+        return 2
+    print(json.dumps(rows, indent=2) if args.as_json
+          else traceview.render_thread_table(rows))
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("command", choices=(
-        "summary", "timeline", "critical-path", "stall", "device"))
+        "summary", "timeline", "critical-path", "stall", "device",
+        "threads"))
     ap.add_argument("paths", nargs="*", default=None,
                     help="trace sink files or node/workdir directories "
                          "(default: .)")
@@ -91,6 +119,8 @@ def main(argv=None) -> int:
 
     if args.command == "device":
         return device(args)
+    if args.command == "threads":
+        return threads(args)
 
     try:
         mt = traceview.merge(args.paths or ["."])

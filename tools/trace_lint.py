@@ -7,8 +7,9 @@ so a name emitted but not declared in `trace.SPAN_REGISTRY` is
 invisible to triage docs, and a declared name with no live call site is
 a stale promise. This lint extracts every literal first argument to
 trace.span()/trace.open_span()/trace.event()/trace.emit() across the
-package (plus tools/ and bench.py, and the two names the tracer writes
-itself) and checks both directions. It holds `trace.KERNEL_SCOPES` to the same rule against
+package (plus tools/ and bench.py, and the three names the tracer writes
+itself) and checks both directions; `trace.WAIT_SPANS` may name
+registered spans only. It holds `trace.KERNEL_SCOPES` to the same rule against
 the phase (jax.named_scope) and pallas_call names in ops/, which the join of a
 profiler trace (utils/traceview.device_join) keys device time on. Exits
 1 on any mismatch.
@@ -39,8 +40,9 @@ EXCLUDE = {
 # including the `_trace` alias used by modules avoiding name clashes
 CALL_RE = re.compile(
     r"\b_?trace\.(?:span|open_span|event|emit)\(\s*[\"']([^\"']+)[\"']")
-# the tracer's own records (trace.clock, runtime.gc_pause)
-TRACER_RE = re.compile(r"\b(?:event|_Span)\(\s*[\"']([^\"']+)[\"']")
+# the tracer's own records (trace.clock, trace.thread, runtime.gc_pause)
+TRACER_RE = re.compile(
+    r"\b(?:event|_envelope|_Span)\(\s*[\"']([^\"']+)[\"']")
 # kernel scopes in ops/: jax.named_scope("x"),
 # pallas_call(..., name="x") and the name handed to
 # field._pallas_binop(kernel, "x", ...)
@@ -82,7 +84,8 @@ def _agree(what: str, table: str, used: dict, declared) -> bool:
 
 def main() -> int:
     sys.path.insert(0, REPO)
-    from cometbft_tpu.utils.trace import KERNEL_SCOPES, SPAN_REGISTRY
+    from cometbft_tpu.utils.trace import (
+        KERNEL_SCOPES, SPAN_REGISTRY, WAIT_SPANS)
 
     tracer = os.path.join(PKG, "utils", "trace.py")
     ops = os.path.join(PKG, "ops") + os.sep
@@ -104,6 +107,11 @@ def main() -> int:
 
     ok = _agree("span names", "SPAN_REGISTRY", used, SPAN_REGISTRY)
     ok &= _agree("kernel scopes", "KERNEL_SCOPES", scopes, KERNEL_SCOPES)
+    unknown = sorted(set(WAIT_SPANS) - set(SPAN_REGISTRY))
+    if unknown:
+        print("trace.WAIT_SPANS names spans that trace.SPAN_REGISTRY "
+              f"does not: {', '.join(unknown)}", file=sys.stderr)
+        ok = False
     if not ok:
         return 1
     print(f"trace lint: {len(SPAN_REGISTRY)} registered span names and "
