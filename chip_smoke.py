@@ -548,13 +548,17 @@ def phase_device_terms(probe: Probe, lanes, sizes, seed: int):
         for eng in engines:
             r = got[eng][n] = _engine_timings(probe, big, eng)
             # the ladder's program called again, its inputs on the device
-            r["kernel_ms"] = (_median_call_s(
-                *probe.latest("verify_batch_cached_a", b)) * 1e3
-                if eng == "ladder" else None)
+            # and what an A-cache miss adds before it: the column's
+            # decompression and the 128 doublings of the cached pair
+            r["kernel_ms"], r["miss_ms"] = (
+                [_median_call_s(*probe.latest(fn, b)) * 1e3
+                 for fn in ("verify_batch_cached_a", "decompress_pubkeys")]
+                if eng == "ladder" else (None, None))
             dev = (f"{r['device_ms']:.3f} ms in the profile "
                    f"{r.get('by_scope_ms')}" if r["device_ms"] is not None
                    else "not in a profile")
-            blocked = (f"; blocked call {r['kernel_ms']:.3f} ms"
+            blocked = (f"; blocked call {r['kernel_ms']:.3f} ms, an A-cache "
+                       f"miss's decompress_pubkeys {r['miss_ms']:.3f} ms"
                        if r["kernel_ms"] is not None else "")
             log(f"     {eng:<6} {where}: {dev}{blocked}; submit() "
                 f"{r['submit_ms']:.3f} ms, submit -> verdict "
@@ -564,8 +568,9 @@ def phase_device_terms(probe: Probe, lanes, sizes, seed: int):
     for eng in engines:
         fixed, per_lane = E._DEV_LADDER_FIXED_MS, E._DEV_LADDER_US
         if eng == "mesh":
-            fixed += mesh.dispatch_terms()["collective_s"] * 1e3
-            per_lane /= mesh.n_devices
+            fixed = (E._DEV_MESH_FIXED_MS
+                     + mesh.dispatch_terms()["collective_s"] * 1e3)
+            per_lane = E._DEV_MESH_US / mesh.n_devices
         t0, t1 = (got[eng][n]["device_ms"] or got[eng][n]["kernel_ms"]
                   for n in sizes)
         if t0 is None or t1 is None:

@@ -29,8 +29,8 @@ from .keys import BatchVerifier, PrivKey, PubKey, tmhash20
 
 _L = ref.L  # ed25519 group order (host-side challenge reduction)
 
-# (sha256(pubkey column), bucket) -> device-resident (ok_a, neg_a) from
-# ops.ed25519_verify.decompress_pubkeys; see _launch_device.
+# (sha256(pubkey column), bucket) -> device-resident (ok_a, (-A, [2^128](-A)))
+# from ops.ed25519_verify.decompress_pubkeys; see _launch_device.
 _A_CACHE: dict = {}
 _A_CACHE_SIZE = 4
 
@@ -48,18 +48,25 @@ BUCKETS = (64, 256, 1024, 4096, 10240, 16384, 65536)
 # for each device engine; the slowest stage of an engine is its time.
 #
 # The device terms are readings of ONE v5e (DEVICE_KIND; the chip tool's
-# machine, 2026-09-28, PR 25): the ladder as submit() launches it, warm,
-# device time a batch from the profiler trace (5 batches, by kernel scope,
-# the reduction of tools/trace_analyze.py device), at the live lane counts
-# of the two buckets the benchmark's cells use. The term is the line
-# through the two readings, fixed + n * per-lane
-# (`python chip_smoke.py --terms` measures both again):
-#   lanes (bucket)    ladder
-#   10,000 (10240)    20.48 ms
-#   65,000 (65536)    130.87 ms
-# The RLC/MSM engine read 120.19 / 278.31 ms there and was removed by PR 28.
-_DEV_LADDER_FIXED_MS = 0.41  # v5e profile, 2026-09-28, PR 25 (table above)
-_DEV_LADDER_US = 2.007       # the same two readings
+# machine): the ladder as submit() launches it, warm, device time a batch
+# from the profiler trace (5 batches, by kernel scope, the reduction of
+# tools/trace_analyze.py device), at the live lane counts of the two
+# buckets the benchmark's cells use. A term is the line through its two
+# readings, fixed + n * per-lane (`python chip_smoke.py --terms` measures
+# both again; with `--chips 4` the mesh's too):
+#   lanes (bucket)    one point, 64 windows    the cached pair, 32 windows
+#                     (2026-09-28, PR 25)      (2026-10-01, PR 39)
+#   10,000 (10240)    20.48 ms                 15.45 ms
+#   65,000 (65536)    130.87 ms                98.69 ms
+# The single chip's ladder is given the pair (A, [2^128]A) that _A_CACHE
+# keeps; the mesh's shards keep no decompressed column and run the
+# one-point program, so the mesh's term keeps that program's line (over
+# the device count, plus the collective). The RLC/MSM engine read 120.19 /
+# 278.31 ms there and was removed by PR 28.
+_DEV_LADDER_FIXED_MS = 0.32  # v5e profile, 2026-10-01, PR 39 (the pair)
+_DEV_LADDER_US = 1.513       # the same two readings
+_DEV_MESH_FIXED_MS = 0.41    # v5e profile, 2026-09-28, PR 25 (one point)
+_DEV_MESH_US = 2.007         # the same two readings; a lane of ONE chip
 # The host-side per-sig term is CALIBRATED at the first dispatch decision
 # (_host_terms: one small timed pack_rsk) because it moves with the host:
 # core speed, toolchain presence. This is the fallback when that fails:
@@ -175,8 +182,8 @@ def dispatch_model(n: int, b: int) -> dict:
         terms = eng.dispatch_terms()
         mesh = {
             "wire": _WIRE_LADDER_B * b / bw + d * terms["put_fixed_s"],
-            "device": (_DEV_LADDER_FIXED_MS * 1e-3
-                       + n * _DEV_LADDER_US * 1e-6 / d
+            "device": (_DEV_MESH_FIXED_MS * 1e-3
+                       + n * _DEV_MESH_US * 1e-6 / d
                        + terms["collective_s"]),
             "host": ladder["host"],
         }
@@ -201,9 +208,9 @@ def _mesh_beats_single(n: int, b: int) -> bool:
 # it (csrc/ed25519_ifma.inc), portable C++ otherwise.
 NATIVE_MAX = 1024
 
-# The device terms of the dispatch model (_DEV_LADDER_*: PR 25's
-# readings) and the wire-byte term were measured on ONE device kind, a
-# TPU v5e, which jax reports as this device_kind.
+# The device terms of the dispatch model (_DEV_LADDER_*, _DEV_MESH_*: PR
+# 39's and PR 25's readings) and the wire-byte term were measured on ONE
+# device kind, a TPU v5e, which jax reports as this device_kind.
 # They are not re-derived per device: an accelerator of another kind is
 # an error (_accel_backed raises), not a v5e with different numbers.
 DEVICE_KIND = "TPU v5 lite"
@@ -612,12 +619,12 @@ class Ed25519BatchVerifier(BatchVerifier):
                 _A_CACHE[fp] = cached
                 while len(_A_CACHE) > _A_CACHE_SIZE:
                     _A_CACHE.pop(next(iter(_A_CACHE)))
-            ok_a, neg_a = cached
+            ok_a, a_points = cached
             if dev is not None and _trace.enabled:
                 _trace.emit("crypto.stream_place", "event",
                             device=str(getattr(dev, "id", dev)), n=n, b=b)
             bits, all_ok = verify_batch_cached_a_jit(
-                ok_a, neg_a, *jax.device_put((rsk, live), dev)
+                ok_a, a_points, *jax.device_put((rsk, live), dev)
             )
         # Snapshot per-batch state: the verifier may be reused/mutated
         # after submit() without corrupting in-flight results.

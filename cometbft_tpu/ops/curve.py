@@ -264,13 +264,13 @@ def compress(p):
     return enc.at[:, 31].set(enc[:, 31] | ((x[0] & 1) << 7).astype(jnp.uint8))
 
 
-# --- Constant base table: affine niels of [i]B for i in 0..8 ---
-def _host_base_niels() -> np.ndarray:
+# --- Constant base tables: affine niels of [i * 2^shift]B for i in 0..8 ---
+def _host_base_niels(shift: int = 0) -> np.ndarray:
     out = np.zeros((9, 3, F.NLIMBS), np.int32)
     out[0, 0] = F.from_int(1)  # identity: y+x=1, y-x=1, 2dxy=0
     out[0, 1] = F.from_int(1)
     for i in range(1, 9):
-        x, y = ref._ext_to_affine(ref._ext_scalar_mul(i, ref.B_POINT))
+        x, y = ref._ext_to_affine(ref._ext_scalar_mul(i << shift, ref.B_POINT))
         out[i, 0] = F.from_int((y + x) % P)
         out[i, 1] = F.from_int((y - x) % P)
         out[i, 2] = F.from_int((2 * ref.D * x * y) % P)
@@ -278,6 +278,12 @@ def _host_base_niels() -> np.ndarray:
 
 
 BASE_NIELS = jnp.asarray(_host_base_niels())  # (9, 3, 22)
+# [i * 2^128]B: the base table of the upper 32 digits when the ladder is
+# given the pair (A, [2^128]A) and runs 32 windows (ladder_sub_mul8)
+BASE_NIELS_HI = jnp.asarray(_host_base_niels(128))
+# points a ladder -> its base tables, (9 * points, 3, 22): table j is rows
+# [9j, 9j + 9)
+_BASE_TABLES = {1: BASE_NIELS, 2: jnp.concatenate([BASE_NIELS, BASE_NIELS_HI])}
 
 
 def lane_table(p):
@@ -342,25 +348,30 @@ def _base_madd(r, ws_row, base_rows=None):
     return madd(r, _apply_sign_affine(ws_row < 0, ypx, ymx, t2d))
 
 
-def _window_step(r, tbl_rows, ws_row, wk_row, base_rows=None):
-    """One radix-16 window: 4 doublings + base madd + lane add.
+def _window_step(r, base, lane):
+    """One radix-16 window: 4 doublings, then one base madd and one lane
+    add for each point the ladder was given.
 
-    r: extended point of (22, B) arrays; tbl_rows: callable(entry, comp)
-    -> (22, B) lane-table component; ws_row/wk_row: (1, B) signed digits.
-    Pure value-form — runs identically inside the Pallas kernel and on
-    the XLA (CPU) path.
+    r: extended point of (22, B) arrays. base: [(base_rows, ws_row), ...]
+    and lane: [(tbl_rows, wk_row), ...], one pair a point: a callable
+    (entry, comp) -> table component (base_rows None = BASE_NIELS) and
+    that table's (1, B) signed digits of this window. Pure value-form:
+    runs identically inside the Pallas kernel and on the XLA (CPU) path.
     """
     r = dbl_no_t(r)
     r = dbl_no_t(r)
     r = dbl_no_t(r)
     r = dbl(r)
-    r = _base_madd(r, ws_row, base_rows)
-    # lane-table niels add (4th component z2 carries no sign)
-    lypx, lymx, lt2d, lz2 = _select_rows(
-        tbl_rows, 4, jnp.abs(wk_row), wk_row.shape[1]
-    )
-    ypx, ymx, t2d = _apply_sign_affine(wk_row < 0, lypx, lymx, lt2d)
-    return add_niels(r, (ypx, ymx, t2d, lz2))
+    for base_rows, ws_row in base:
+        r = _base_madd(r, ws_row, base_rows)
+    for tbl_rows, wk_row in lane:
+        # lane-table niels add (4th component z2 carries no sign)
+        lypx, lymx, lt2d, lz2 = _select_rows(
+            tbl_rows, 4, jnp.abs(wk_row), wk_row.shape[1]
+        )
+        ypx, ymx, t2d = _apply_sign_affine(wk_row < 0, lypx, lymx, lt2d)
+        r = add_niels(r, (ypx, ymx, t2d, lz2))
+    return r
 
 
 def _kernel_identity(batch: int):
@@ -370,58 +381,84 @@ def _kernel_identity(batch: int):
     return (z, one, one, z)
 
 
-def _ladder_sub_kernel(ax, ay, az, at, rx, ry, rz, rt, ws_ref, wk_ref,
-                       base_ref, bias_ref, consts_ref, xo, yo, zo, tbl):
+def _ladder_sub_kernel(npts, *refs):
     """THE fused Pallas kernel: per tile it builds the 9-entry lane table
-    of A in VMEM, runs all 64 shared-doubling windows (fori_loop — one
-    traced window body), subtracts R and multiplies by the cofactor, all
-    without leaving VMEM. One launch per ladder instead of ~350: on this
-    runtime each pallas launch carries ~0.4 ms of serial overhead, which
-    dominated the round-2 per-window formulation.
+    of each of its `npts` points in VMEM, runs all 64 / npts
+    shared-doubling windows (fori_loop: one traced window body),
+    subtracts R and multiplies by the cofactor, all without leaving VMEM.
+    One launch per ladder instead of ~350: on this runtime each pallas
+    launch carries ~0.4 ms of serial overhead, which dominated the
+    round-2 per-window formulation.
+
+    Point j is [16^(j * 64 / npts)]A and takes digit rows j * 64 / npts
+    and up of ws_ref / wk_ref, and base table j of base_ref.
 
     Outputs: X, Y, Z of [8]([s]B + [k]A - R); the identity test runs at
     the XLA level (freeze has no multiplies).
     """
     global _KCONSTS
+    a_refs, refs = refs[: 4 * npts], refs[4 * npts :]
+    (rx, ry, rz, rt, ws_ref, wk_ref, base_ref, bias_ref, consts_ref,
+     xo, yo, zo, tbl) = refs
     nl = F.NLIMBS
+    windows = 64 // npts
     with F.kernel_mode(bias_ref[...]):
         _KCONSTS = {"d2": consts_ref[0:nl, :]}
         try:
-            a_pt = (ax[...], ay[...], az[...], at[...])
-            batch = a_pt[0].shape[1]
-
-            # Lane table of [e]A, e in 0..8, niels form, in VMEM scratch.
+            a_pts = [tuple(a[...] for a in a_refs[4 * j : 4 * j + 4])
+                     for j in range(npts)]
+            batch = a_pts[0][0].shape[1]
             ident_n = (
                 _row0_const(1, nl, batch),
                 _row0_const(1, nl, batch),
                 jnp.zeros((nl, batch), jnp.int32),
                 _row0_const(2, nl, batch),
             )
-            n1 = to_niels(a_pt)
-            entries = [ident_n, n1]
-            pk = a_pt
+
+            def rows_of(ref, j, ncomps):
+                """(entry, comp) -> that (22, .) slice of table j in ref."""
+                def rows(e, c):
+                    row = ((j * 9 + e) * ncomps + c) * nl
+                    return ref[row : row + nl, :]
+
+                return rows
+
+            # Lane tables of [e]A_j, e in 0..8, niels form, in VMEM scratch:
+            # ONE traced chain for all the points, side by side along the
+            # lanes (a chain a point costs the same multiplications and
+            # 5 s of lowering a process, warm cache or not).
+            wide = a_pts[0] if npts == 1 else tuple(
+                jnp.concatenate(cs, axis=1) for cs in zip(*a_pts))
+            n1 = to_niels(wide)
+            entries = [n1]
+            pk = wide
             for _ in range(7):
                 pk = add_niels(pk, n1)
                 entries.append(to_niels(pk))
-            for e, niels in enumerate(entries):
-                for c in range(4):
-                    tbl[(e * 4 + c) * nl : (e * 4 + c + 1) * nl, :] = niels[c]
-
-            def tbl_rows(e, c):
-                base = (e * 4 + c) * nl
-                return tbl[base : base + nl, :]
-
-            def base_rows(e, c):
-                base = (e * 3 + c) * nl
-                return base_ref[base : base + nl, :]
+            for j in range(npts):
+                lanes = slice(j * batch, (j + 1) * batch)
+                for e, niels in enumerate([ident_n] + entries):
+                    for c in range(4):
+                        row = ((j * 9 + e) * 4 + c) * nl
+                        tbl[row : row + nl, :] = (
+                            niels[c] if e == 0 or npts == 1
+                            else niels[c][:, lanes])
 
             def body(i, r):
-                w = 63 - i
-                ws = ws_ref[pl_dslice(w, 1), :]
-                wk = wk_ref[pl_dslice(w, 1), :]
-                return _window_step(r, tbl_rows, ws, wk, base_rows)
+                w = windows - 1 - i
+                # no `+ 0` for the first point: given one point the traced
+                # program is the 64-window one to the operation
+                rows = [pl_dslice(w + j * windows if j else w, 1)
+                        for j in range(npts)]
+                return _window_step(
+                    r,
+                    [(rows_of(base_ref, j, 3), ws_ref[rows[j], :])
+                     for j in range(npts)],
+                    [(rows_of(tbl, j, 4), wk_ref[rows[j], :])
+                     for j in range(npts)],
+                )
 
-            r = lax.fori_loop(0, 64, body, _kernel_identity(batch))
+            r = lax.fori_loop(0, windows, body, _kernel_identity(batch))
             r = add(r, neg((rx[...], ry[...], rz[...], rt[...])))
             for _ in range(3):
                 r = dbl_no_t(r)
@@ -434,79 +471,134 @@ def _ladder_sub_kernel(ax, ay, az, at, rx, ry, rz, rt, ws_ref, wk_ref,
 pl_dslice = None  # bound lazily (pallas import is TPU-path-only)
 
 
-def _ladder_sub_mul8_pallas(s_digits, k_digits, a_point, r_point):
+def _ladder_sub_mul8_pallas(s_digits, k_digits, a_points, r_point):
     global pl_dslice
+    import functools
+
     import jax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     pl_dslice = pl.dslice
+    npts = len(a_points)
     batch = s_digits.shape[1]
     tile = min(batch, F._PALLAS_TILE)
     nl = F.NLIMBS
-    base_flat = jnp.asarray(BASE_NIELS).reshape(9 * 3 * nl, 1)
+    base_flat = _BASE_TABLES[npts].reshape(npts * 9 * 3 * nl, 1)
     bias = jnp.asarray(F._SUB_BIAS)
     consts = jnp.asarray(_CONSTS_NP)
 
     point_spec = pl.BlockSpec((nl, tile), lambda i: (0, i), memory_space=pltpu.VMEM)
     dig_spec = pl.BlockSpec((64, tile), lambda i: (0, i), memory_space=pltpu.VMEM)
     base_spec = pl.BlockSpec(
-        (9 * 3 * nl, 1), lambda i: (0, 0), memory_space=pltpu.VMEM
+        (npts * 9 * 3 * nl, 1), lambda i: (0, 0), memory_space=pltpu.VMEM
     )
     bias_spec = pl.BlockSpec((nl, 1), lambda i: (0, 0), memory_space=pltpu.VMEM)
     consts_spec = pl.BlockSpec(
         (3 * nl, 1), lambda i: (0, 0), memory_space=pltpu.VMEM
     )
     out = pl.pallas_call(
-        _ladder_sub_kernel,
+        functools.partial(_ladder_sub_kernel, npts),
         out_shape=[jax.ShapeDtypeStruct((nl, batch), jnp.int32)] * 3,
         grid=(batch // tile,),
-        in_specs=[point_spec] * 8 + [dig_spec, dig_spec, base_spec,
-                                     bias_spec, consts_spec],
+        in_specs=[point_spec] * (4 * npts + 4) + [
+            dig_spec, dig_spec, base_spec, bias_spec, consts_spec],
         out_specs=[point_spec] * 3,
-        scratch_shapes=[pltpu.VMEM((9 * 4 * nl, tile), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((npts * 9 * 4 * nl, tile), jnp.int32)],
         name="curve_ladder_sub_mul8",
-    )(*a_point, *r_point, s_digits, k_digits, base_flat, bias, consts)
+    )(*(c for p in a_points for c in p), *r_point, s_digits, k_digits,
+      base_flat, bias, consts)
     return tuple(out)
 
 
-def ladder_sub_mul8(s_digits, k_digits, a_point, r_point):
-    """(X, Y, Z) of [8]([s]B + [k]a_point - r_point) — the whole ZIP-215
-    verification equation left side. On TPU this is ONE fused kernel."""
+def ladder_sub_mul8(s_digits, k_digits, a_points, r_point):
+    """(X, Y, Z) of [8]([s]B + [k]A - r_point): the whole ZIP-215
+    verification equation's left side. On TPU this is ONE fused kernel.
+
+    a_points says how long the ladder is: (A,) runs 64 windows; the pair
+    (A, [2^128]A), which a caller that keeps A on the device also keeps
+    (mul_2_128), runs 32 windows of two lane adds and two base madds
+    each: half the doublings for the same group element, whatever A."""
     if F._use_pallas(s_digits):
-        return _ladder_sub_mul8_pallas(s_digits, k_digits, a_point, r_point)
-    r = ladder(s_digits, k_digits, a_point)
+        return _ladder_sub_mul8_pallas(s_digits, k_digits, a_points, r_point)
+    r = ladder(s_digits, k_digits, a_points)
     r = add(r, neg(r_point))
     m = mul8(r)
     return (m[0], m[1], m[2])
 
 
-def ladder(s_digits, k_digits, a_point):
-    """[s]B + [k]a_point with shared doublings, signed radix-16 digits.
+def ladder(s_digits, k_digits, a_points):
+    """[s]B + [k]A with shared doublings, signed radix-16 digits.
 
     s_digits, k_digits: (64, B) int32 in [-8, 7], little-endian (digit i
-    weighs 16^i) — from ops.scalar.recode_signed. a_point: batched extended
-    point. Scans digits from most to least significant. XLA value-form
-    (the TPU path runs the fused kernel via ladder_sub_mul8 instead).
+    weighs 16^i), from ops.scalar.recode_signed. a_points: (A,) or
+    (A, [2^128]A), batched extended points (ladder_sub_mul8). Scans
+    digits from most to least significant, each point over its own
+    64 / len(a_points) rows. XLA value-form (the TPU path runs the fused
+    kernel via ladder_sub_mul8 instead).
     """
+    npts = len(a_points)
     batch = s_digits.shape[1]
-    tbl = lane_table(a_point)
-    xs = (jnp.flip(s_digits, axis=0), jnp.flip(k_digits, axis=0))
+    tbls = [lane_table(p) for p in a_points]
+    bases = _BASE_TABLES[npts].reshape(npts, 9, 3, F.NLIMBS)
 
-    def tbl_rows_factory(tblv):
-        def tbl_rows(e, c):
-            return tblv[e, c]
+    base_rows = [lambda e, c, t=t: t[e, c][:, None] for t in bases]
+    tbl_rows = [lambda e, c, t=t: t[e, c] for t in tbls]
 
-        return tbl_rows
+    def by_point(digits):  # (64, B) -> (windows, npts, B), top window first
+        return jnp.flip(digits.reshape(npts, 64 // npts, batch), 1).swapaxes(0, 1)
 
     def body(r, w):
         ws, wk = w
-        r = _window_step(r, tbl_rows_factory(tbl), ws[None, :], wk[None, :])
+        r = _window_step(
+            r,
+            [(rows, ws[j][None, :]) for j, rows in enumerate(base_rows)],
+            [(rows, wk[j][None, :]) for j, rows in enumerate(tbl_rows)],
+        )
         return r, None
 
-    r0 = identity(batch)
-    r, _ = lax.scan(body, r0, xs)
+    r, _ = lax.scan(body, identity(batch), (by_point(s_digits), by_point(k_digits)))
     return r
+
+
+def mul_2_128(p):
+    """[2^128]p by 128 doublings (exact for every point, torsion
+    included): the second point of ladder_sub_mul8's pair. On TPU one
+    fused kernel, a tile at a time like the decompression."""
+    if F._use_pallas(p[0]):
+        return _mul_2_128_pallas(p)
+    xyz, _ = lax.scan(lambda q, _: (dbl_no_t((*q, None))[:3], None),
+                      p[:3], None, length=127)
+    return dbl((*xyz, None))
+
+
+def _mul_2_128_kernel(x, y, z, bias_ref, xo, yo, zo, to):
+    with F.kernel_mode(bias_ref[...]):
+        xyz = lax.fori_loop(
+            0, 127, lambda i, q: dbl_no_t((*q, None))[:3],
+            (x[...], y[...], z[...]))
+        r = dbl((*xyz, None))
+    xo[...], yo[...], zo[...], to[...] = r
+
+
+def _mul_2_128_pallas(p):
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch = p[0].shape[1]
+    tile = min(batch, F._PALLAS_TILE)
+    nl = F.NLIMBS
+    point_spec = pl.BlockSpec((nl, tile), lambda i: (0, i), memory_space=pltpu.VMEM)
+    bias_spec = pl.BlockSpec((nl, 1), lambda i: (0, 0), memory_space=pltpu.VMEM)
+    return tuple(pl.pallas_call(
+        _mul_2_128_kernel,
+        out_shape=[jax.ShapeDtypeStruct((nl, batch), jnp.int32)] * 4,
+        grid=(batch // tile,),
+        in_specs=[point_spec] * 3 + [bias_spec],
+        out_specs=[point_spec] * 4,
+        name="curve_mul_2_128",
+    )(*p[:3], jnp.asarray(F._SUB_BIAS)))
 
 
 def fixed_base(s_digits):

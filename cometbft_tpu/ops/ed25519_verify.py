@@ -45,7 +45,8 @@ def verify_batch_prehashed(a_bytes, r_bytes, s_bytes, k_bytes, live):
         ok_a, a_pt = C.decompress(a_bytes)
         ok_r, r_pt = C.decompress(r_bytes)
     with jax.named_scope("ladder.double_scalar"):
-        X, Y, Z = C.ladder_sub_mul8(s_digits, k_digits, C.neg(a_pt), r_pt)
+        X, Y, Z = C.ladder_sub_mul8(
+            s_digits, k_digits, (C.neg(a_pt),), r_pt)
     with jax.named_scope("ladder.compare"):
         ok_eq = F.is_zero(X) & F.eq(Y, Z)
         bits = ok_a & ok_r & ok_eq & s_ok & live
@@ -62,25 +63,31 @@ verify_batch_prehashed_jit = jax.jit(verify_batch_prehashed)
 
 
 def decompress_pubkeys(a_bytes):
-    """(B, 32) uint8 pubkey encodings -> (ok, negated extended point).
+    """(B, 32) uint8 pubkey encodings -> (ok, (-A, [2^128](-A))), the
+    points negated and extended.
 
     The A half of the verification equation, split out so callers can
     keep a validator set's decompressed points resident on device: in
     commit replay the SAME pubkey column verifies every height, so the
     32 bytes/lane of A never need to re-cross the host->device link and
     the sqrt-decompression (one of the two per-lane exponentiations)
-    runs once per validator-set change instead of once per commit."""
+    runs once per validator-set change instead of once per commit. What
+    is kept is the pair that halves the ladder (C.ladder_sub_mul8): 128
+    doublings a lane here, once a column, for 128 fewer in every batch."""
     with jax.named_scope("ladder.decompress"):
         ok_a, a_pt = C.decompress(a_bytes)
-        return ok_a, C.neg(a_pt)
+        neg_a = C.neg(a_pt)
+    with jax.named_scope("ladder.a_hi"):
+        return ok_a, (neg_a, C.mul_2_128(neg_a))
 
 
 decompress_pubkeys_jit = jax.jit(decompress_pubkeys)
 
 
-def verify_batch_cached_a(ok_a, neg_a, rsk, live):
+def verify_batch_cached_a(ok_a, a_points, rsk, live):
     """verify_batch_prehashed with the pubkey stage precomputed by
-    decompress_pubkeys (device-resident across submits).
+    decompress_pubkeys (device-resident across submits); the ladder is
+    as long as a_points says (32 windows for decompress_pubkeys' pair).
 
     rsk: (B, 96) uint8 — R || S || k packed in one array so the
     per-commit host->device traffic is a single contiguous transfer
@@ -95,7 +102,7 @@ def verify_batch_cached_a(ok_a, neg_a, rsk, live):
     with jax.named_scope("ladder.decompress"):
         ok_r, r_pt = C.decompress(r_bytes)
     with jax.named_scope("ladder.double_scalar"):
-        X, Y, Z = C.ladder_sub_mul8(s_digits, k_digits, neg_a, r_pt)
+        X, Y, Z = C.ladder_sub_mul8(s_digits, k_digits, a_points, r_pt)
     with jax.named_scope("ladder.compare"):
         ok_eq = F.is_zero(X) & F.eq(Y, Z)
         bits = ok_a & ok_r & ok_eq & s_ok & live

@@ -668,11 +668,13 @@ WAIT_SPANS = (
 # ops/ (HLO instruction numbers do not).
 KERNEL_SCOPES = {
     "ladder.decompress": "per-lane program: ZIP-215 decoding of R (and of A in decompress_pubkeys)",
+    "ladder.a_hi": "decompress_pubkeys: [2^128](-A), once a cached column (an A-cache miss), so the ladder runs 32 windows",
     "ladder.scalar_reduce": "per-lane program: signed-digit recoding of s and k (k arrives reduced mod L), S < L",
     "ladder.double_scalar": "per-lane program: [8]([s]B + [k](-A) - R), one fused kernel on the chip",
     "ladder.compare": "per-lane program: identity test, lane bitmap and its all-ok summary",
     "curve_decompress": "pallas kernel (ops/curve.py): fused sqrt candidate and checks",
     "curve_ladder_sub_mul8": "pallas kernel (ops/curve.py): the whole double-scalar ladder",
+    "curve_mul_2_128": "pallas kernel (ops/curve.py): 128 doublings of a point, fused",
     "field_mul": "pallas kernel (ops/field.py): one 22-limb field multiply outside a fused kernel",
     "field_sq": "pallas kernel (ops/field.py): one field squaring outside a fused kernel",
 }

@@ -47,6 +47,8 @@ def test_chip_smoke_terms_runs_the_device_terms_phase_alone():
     """--terms is the re-measurement of the dispatch's device terms: that
     phase and no other, both sizes, the model's terms beside the fit, and
     no engine but the ladder on a host without a mesh."""
+    from cometbft_tpu.crypto import ed25519 as E
+
     p = _run([SMOKE, "--rehearse", "--terms", "--seed", "5"])
     assert p.returncode == 0, f"stdout={p.stdout[-3000:]}\nstderr={p.stderr[-3000:]}"
     assert json.loads(p.stdout.strip().splitlines()[-1])["ok"] is True
@@ -55,7 +57,8 @@ def test_chip_smoke_terms_runs_the_device_terms_phase_alone():
     assert "n=24 bucket=64: the model says ladder" in p.stdout
     assert "n=48 bucket=64: the model says ladder" in p.stdout
     assert "ladder, NOT A DEVICE NUMBER (rehearsal): fixed " in p.stdout
-    assert "assumed 0.41 ms + n x 2.007 us" in p.stdout
+    assert (f"assumed {E._DEV_LADDER_FIXED_MS:.2f} ms + n x "
+            f"{E._DEV_LADDER_US:.3f} us") in p.stdout
 
 
 def test_chip_smoke_refuses_to_start_without_a_chip():

@@ -130,11 +130,12 @@ def _pin_model(monkeypatch, link_mbps, ladder_us=1.6):
     return e
 
 
-# device milliseconds a batch, the ladder as submit() launches it, warm, from
-# the profiler trace (my chip run, PR 25: `python chip_smoke.py --terms`)
+# device milliseconds a batch, the ladder as submit() launches it (given the
+# cached pair, 32 windows), warm, from the profiler trace (my chip run, PR
+# 39: `python chip_smoke.py --terms`; 20.480 / 130.870 with one point, PR 25)
 CHIP_READINGS_MS = {
-    ("ladder", 10000): 20.480,
-    ("ladder", 65000): 130.870,
+    ("ladder", 10000): 15.454,
+    ("ladder", 65000): 98.692,
 }
 
 
@@ -169,10 +170,12 @@ def test_mega_batch_on_an_accelerator_takes_the_ladder(monkeypatch, tmp_path,
     monkeypatch.setattr(e, "_A_CACHE", {})
     monkeypatch.setattr(e.Ed25519BatchVerifier, "_materialize",
                         lambda self: expanded.append(self.count()))
-    monkeypatch.setattr(ev, "decompress_pubkeys_jit", lambda a: (a, a))
+    monkeypatch.setattr(ev, "decompress_pubkeys_jit",
+                        lambda a: (a, (a, a)))
     monkeypatch.setattr(
         ev, "verify_batch_cached_a_jit",
-        lambda ok_a, neg_a, rsk, live: (np.asarray(live), np.asarray(True)))
+        lambda ok_a, a_points, rsk, live: (np.asarray(live),
+                                           np.asarray(True)))
 
     r = np.random.default_rng(n)
     sigs = r.integers(0, 256, (n, 64), np.uint8)
@@ -244,18 +247,18 @@ def test_mesh_term_absent_without_engine(monkeypatch):
 def test_mesh_flips_device_bound_batch(monkeypatch):
     """Fast link, 8 chips: the per-lane part of the ladder's device
     stage splits 8 ways (its fixed part does not) and the mesh becomes
-    HOST-bound at 16 ms — below the ladder's device stage, so dispatch
-    must flip to mesh exactly where splitting device time is what the
-    batch needed."""
-    e = _pin_model(monkeypatch, link_mbps=1000.0)
+    HOST-bound at 12 ms — below the ladder's device stage (15.45 ms
+    since PR 39's pair), so dispatch must flip to mesh exactly where
+    splitting device time is what the batch needed."""
+    e = _pin_model(monkeypatch, link_mbps=1000.0, ladder_us=1.2)
     monkeypatch.setattr(e, "_mesh_engine", lambda: _StubMesh())
     m = e.dispatch_model(10000, 10240)
     assert m["n_devices"] == 8
     assert m["mesh"]["device"] == pytest.approx(
-        e._DEV_LADDER_FIXED_MS * 1e-3
-        + 10000 * e._DEV_LADDER_US * 1e-6 / 8 + 60e-6)
+        e._DEV_MESH_FIXED_MS * 1e-3
+        + 10000 * e._DEV_MESH_US * 1e-6 / 8 + 60e-6)
     assert m["t_ladder"] == pytest.approx(m["ladder"]["device"])
-    assert m["t_mesh"] == pytest.approx(10000 * 1.6e-6)  # host binds
+    assert m["t_mesh"] == pytest.approx(10000 * 1.2e-6)  # host binds
     assert e._mesh_beats_single(10000, 10240)
 
 
@@ -346,10 +349,12 @@ def _stub_host(monkeypatch, host):
     monkeypatch.setattr(e, "_ACCEL_BACKED", accel)
     monkeypatch.setattr(e, "_mesh_engine", lambda: mesh)
     monkeypatch.setattr(e, "_A_CACHE", {})
-    monkeypatch.setattr(ev, "decompress_pubkeys_jit", lambda a: (a, a))
+    monkeypatch.setattr(ev, "decompress_pubkeys_jit",
+                        lambda a: (a, (a, a)))
     monkeypatch.setattr(
         ev, "verify_batch_cached_a_jit",
-        lambda ok_a, neg_a, rsk, live: (np.asarray(live), np.asarray(True)))
+        lambda ok_a, a_points, rsk, live: (np.asarray(live),
+                                           np.asarray(True)))
     return e
 
 

@@ -236,10 +236,10 @@ def test_trace_lint_covers_the_span_tree_and_the_kernel_scopes(tmp_path):
                  "blocksync.window_fill", "blocksync.window_resolve",
                  "blocksync.window_apply"):
         assert name in SPAN_REGISTRY, name
-    for name in ("ladder.decompress", "ladder.scalar_reduce",
+    for name in ("ladder.decompress", "ladder.a_hi", "ladder.scalar_reduce",
                  "ladder.double_scalar", "ladder.compare",
-                 "curve_decompress", "curve_ladder_sub_mul8", "field_mul",
-                 "field_sq"):
+                 "curve_decompress", "curve_ladder_sub_mul8",
+                 "curve_mul_2_128", "field_mul", "field_sq"):
         assert name in KERNEL_SCOPES, name
     # the lint reads ops/ for scopes: a scope it does not know, and one
     # nothing uses, both fail it

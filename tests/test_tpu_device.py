@@ -132,8 +132,10 @@ def _S(shape, dtype, sh):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
 
-def _ladder_args(b, sh):
-    return (_S((b,), jnp.bool_, sh), _point(b, sh),
+def _ladder_args(b, sh, points=2):
+    """verify_batch_cached_a's arguments: the pair decompress_pubkeys
+    returns (32 windows), or one point (64, what the mesh's shards run)."""
+    return (_S((b,), jnp.bool_, sh), (_point(b, sh),) * points,
             _S((b, 96), jnp.uint8, sh), _S((b,), jnp.bool_, sh))
 
 
@@ -178,16 +180,18 @@ def test_cache_key_does_not_depend_on_the_caller(chip):
 
 @pytest.mark.parametrize("b", [1024, 10240])
 def test_decompress_pubkeys_compiles_for_v5e(chip, b):
+    """A's decompression and the 128 doublings of the cached pair."""
     c = _compile(EV.decompress_pubkeys, _S((b, 32), jnp.uint8, chip),
-                 scopes=("ladder.decompress",),
-                 kernels=("curve_decompress",))
-    assert _kernels(c) == 1
+                 scopes=("ladder.decompress", "ladder.a_hi"),
+                 kernels=("curve_decompress", "curve_mul_2_128"))
+    assert _kernels(c) == 2
 
 
 @pytest.mark.parametrize("b", [1024, 10240])
 def test_ladder_compiles_for_v5e(chip, b):
     """verify_batch_cached_a: the production ladder entry (R decompress
-    kernel + the fused ladder kernel)."""
+    kernel + the fused ladder kernel, given the cached pair: two lane
+    tables in the VMEM scratch)."""
     c = _compile(EV.verify_batch_cached_a, *_ladder_args(b, chip),
                  scopes=("ladder.scalar_reduce", "ladder.decompress",
                          "ladder.double_scalar", "ladder.compare"),
@@ -211,10 +215,22 @@ def test_sharded_verifier_compiles_for_four_chips(topo, chip):
     assert compiled.memory_analysis().temp_size_in_bytes < TEMP_BUDGET_BYTES
 
 
+@pytest.mark.parametrize("b", [2560] + [
+    pytest.param(b, marks=pytest.mark.slow) for b in (1024, 4096, 16384)])
+def test_one_point_ladder_compiles_at_the_mesh_shard_widths(chip, b):
+    """Given one point the ladder is the 64-window program the mesh's
+    shards run (verify_batch_prehashed keeps no column on the device):
+    a quarter of each bucket from MESH_MIN up."""
+    assert b * 4 in E.BUCKETS and b * 4 >= E.MESH_MIN
+    c = _compile(EV.verify_batch_cached_a, *_ladder_args(b, chip, points=1),
+                 kernels=("curve_decompress", "curve_ladder_sub_mul8"))
+    assert _kernels(c) == 2
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("b", [4096, 16384, 65536])
 def test_ladder_other_buckets_compile_for_v5e(chip, b):
     c = _compile(EV.verify_batch_cached_a, *_ladder_args(b, chip))
     assert _kernels(c) == 2
     c = _compile(EV.decompress_pubkeys, _S((b, 32), jnp.uint8, chip))
-    assert _kernels(c) == 1
+    assert _kernels(c) == 2
