@@ -13,6 +13,7 @@ Behavior parity with reference internal/state/execution.go:
 from __future__ import annotations
 
 from dataclasses import replace
+from time import perf_counter
 
 from ..crypto import merkle
 from ..crypto.ed25519 import Ed25519PubKey
@@ -142,7 +143,7 @@ def validate_block(
     block: Block,
     backend: str = "tpu",
     last_commit_preverified: bool = False,
-) -> None:
+) -> float:
     """Full block validation against current state
     (reference internal/state/validation.go).
 
@@ -150,6 +151,9 @@ def validate_block(
     the LastCommit (structure, size, hashes, and median-time checks still
     run) — used by the batched replay path, which has already verified
     those exact signatures in a window mega-batch.
+
+    Returns the seconds spent recomputing data_hash from the block's
+    transactions (state.apply_block's data_hash_ms).
     """
     h = block.header
     if h.chain_id != state.chain_id:
@@ -175,7 +179,10 @@ def validate_block(
         raise BlockValidationError("wrong app_hash")
     if h.last_results_hash != state.last_results_hash:
         raise BlockValidationError("wrong last_results_hash")
-    if h.data_hash != block.data.hash():
+    t_data = perf_counter()
+    data_hash = block.data.hash()
+    data_hash_s = perf_counter() - t_data
+    if h.data_hash != data_hash:
         raise BlockValidationError("wrong data_hash")
     if h.last_commit_hash != block.last_commit.hash():
         raise BlockValidationError("wrong last_commit_hash")
@@ -208,6 +215,7 @@ def validate_block(
         raise BlockValidationError("invalid proposer address")
     if h.da_root and len(h.da_root) != 32:
         raise BlockValidationError("invalid da_root length")
+    return data_hash_s
 
 
 def build_last_commit_info(block: Block, last_vals: ValidatorSet | None):
@@ -376,7 +384,7 @@ class BlockExecutor:
 
         with verify_context(self.verify_sched, self.sched_tenant,
                             "consensus"):
-            validate_block(
+            data_hash_s = validate_block(
                 state,
                 block,
                 backend=self.backend,
@@ -444,6 +452,7 @@ class BlockExecutor:
         if life:
             _txlife.stage_block(life, "commit", height=h_)
         fail_point()  # reference execution.go:301 (post-Commit, pre-save)
+        state_save_s = 0.0
         if self.state_store is not None:
             self.state_store.save(new_state)
             self.state_store.save_finalize_response(
@@ -454,6 +463,8 @@ class BlockExecutor:
             self.state_store.save_abci_responses(
                 block.header.height, _W.enc_finalize_resp(resp)
             )
+            state_save_s = _time.perf_counter() - t_commit
+            state_metrics().state_save_seconds.observe(state_save_s)
         if self.event_bus is not None:
             # fire events (reference execution.go:313 fireEvents)
             self.event_bus.publish_new_block(block, resp)
@@ -481,6 +492,10 @@ class BlockExecutor:
                 commit_ms=round((t_commit - t_update) * 1e3, 3),
                 rotation=rotation,
                 save_events_ms=round((t_end - t_commit) * 1e3, 3),
+                # inside validate_ms; inside save_events_ms
+                data_hash_ms=round(data_hash_s * 1e3, 3),
+                state_save_ms=round(state_save_s * 1e3, 3),
+                tx_bytes=sum(map(len, block.data.txs)),
             )
         return new_state
 

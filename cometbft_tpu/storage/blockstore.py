@@ -141,8 +141,12 @@ class BlockStore:
                 self._save_meta(sets)
             self._db.write_batch(sets)
 
+    def load_block_bytes(self, height: int) -> bytes | None:
+        """The stored bytes of a block, undecoded: load_block's read."""
+        return self._db.get(_key_block(height))
+
     def load_block(self, height: int) -> Block | None:
-        raw = self._db.get(_key_block(height))
+        raw = self.load_block_bytes(height)
         # our own stored bytes are canonical by construction: stash them
         # so BlockID/part-set work skips the re-encode
         return Block.decode(raw, trusted_bytes=True) if raw else None

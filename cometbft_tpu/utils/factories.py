@@ -19,6 +19,7 @@ import numpy as np
 
 from ..crypto import ed25519_ref as ref
 from ..crypto.ed25519 import Ed25519PubKey
+from ..encoding import proto as pb
 from ..types import (
     Block,
     BlockID,
@@ -231,7 +232,10 @@ def make_chain(
     (default 10 each); extra_txs(height, state) returns the transactions
     block `height` carries behind its own, `state` being the one the block
     is proposed on (the kvstore app turns a val_tx() into a validator
-    update, in force two heights on; ValsetChurn draws them from a seed);
+    update, in force two heights on; ValsetChurn draws them from a seed).
+    txs_per_block=0 with extra_txs gives blocks that carry what extra_txs
+    returns and nothing else: LoadtimeTxs makes the transactions of
+    upstream's QA load that way (400 of 1,024 bytes a block).
     spare_signers hold the keys of members that join later. Every commit
     is signed by the set the state gives for its height, except at
     stale_set_at, where the set of the height BEFORE signs (as
@@ -336,6 +340,74 @@ class ValsetChurn:
                                  replace=False)
         return [val_tx(members[int(i)].pub_key.bytes(), self.power())
                 for i in picked]
+
+
+class LoadtimeTxs:
+    """make_chain's extra_txs for blocks that carry upstream's QA load
+    (docs/qa: the testnets are loaded by test/loadtime with transactions of
+    `size` bytes through `connections` connections at `rate` tx/s each).
+    A transaction is what test/loadtime/payload NewBytes makes: the key
+    prefix `a=` and the hex of a protobuf Payload (connections = 1, rate =
+    2, size = 3, time = 4 as a google.protobuf.Timestamp, id = 5, padding =
+    6), the padding sized so that the whole transaction is `size` bytes
+    (hex doubles the payload, so `size` is even; a few small sizes that no
+    padding reaches are refused). The kvstore app takes it as the key `a`:
+    the store holds one entry however many arrive.
+
+    Drawn from `seed`: a 16-byte id a block (loadtime draws one a run of
+    the tool) and the padding's bytes. Transaction i of block `height`
+    carries the time at which a generator of `rate` tx/s sends it in the
+    height's own second: `height` s + i / rate s past the genesis time.
+    txs(height) is a function of (seed, height) alone."""
+
+    KEY_PREFIX = b"a="
+    GENESIS_S = 1_700_000_000
+
+    def __init__(self, seed: int, per_block: int = 400, size: int = 1024,
+                 connections: int = 1, rate: int = 400):
+        if size % 2:
+            raise ValueError("a loadtime transaction is hex: size is even")
+        self.seed, self.per_block, self.size = seed, per_block, size
+        self.connections, self.rate = connections, rate
+
+    def tx(self, height: int, i: int, run_id: bytes, rng) -> bytes:
+        nanos = (i % self.rate) * 1_000_000_000 // self.rate
+        when = Timestamp(self.GENESIS_S + height + i // self.rate, nanos)
+        head = (pb.f_varint(1, self.connections) + pb.f_varint(2, self.rate)
+                + pb.f_varint(3, self.size) + pb.f_embedded(4, when.encode())
+                + pb.f_bytes(5, run_id))
+        # what is left of (size - 2) / 2 payload bytes behind the padding's
+        # tag holds the padding and its length's varint
+        room = (self.size - len(self.KEY_PREFIX)) // 2 - len(head) - 1
+        n = next((n for n in (room - 1, room - 2, room - 3)
+                  if n >= 1 and n + len(pb.uvarint(n)) == room), None)
+        if n is None:
+            raise ValueError(f"no padding makes this transaction {self.size} "
+                             f"bytes")
+        payload = head + pb.f_bytes(6, rng.bytes(n))
+        return self.KEY_PREFIX + payload.hex().encode()
+
+    def txs(self, height: int) -> list[bytes]:
+        rng = np.random.default_rng([self.seed, 40, height])
+        run_id = rng.bytes(16)
+        return [self.tx(height, i, run_id, rng)
+                for i in range(self.per_block)]
+
+    def __call__(self, height: int, state) -> list[bytes]:
+        return self.txs(height)
+
+    @classmethod
+    def parse(cls, tx: bytes) -> dict:
+        """A transaction back to its payload's fields (what
+        test/loadtime/payload FromBytes reads)."""
+        if not tx.startswith(cls.KEY_PREFIX):
+            raise ValueError("not a loadtime transaction")
+        d = pb.fields_to_dict(bytes.fromhex(tx[len(cls.KEY_PREFIX):].decode()))
+        return {"connections": int(d.get(1, 0)), "rate": int(d.get(2, 0)),
+                "size": int(d.get(3, 0)),
+                "time": Timestamp.decode(pb.as_bytes(d.get(4, b""))),
+                "id": pb.as_bytes(d.get(5, b"")),
+                "padding": pb.as_bytes(d.get(6, b""))}
 
 
 def make_commit(

@@ -410,6 +410,12 @@ class StateMetrics:
             "ran: column (int64 numpy) or integer (Python ints, for a set "
             "whose priorities or powers could leave int64)",
             labels=("path",))
+        self.state_save_seconds = reg.histogram(
+            "state", "state_save_seconds",
+            "Wall time of ApplyBlock's writes to the state store: the "
+            "state with both validator sets, the results' hash and the "
+            "encoded FinalizeBlockResponse (observed only with a state "
+            "store)", buckets=TX_STAGE_BUCKETS)
 
 
 class StoreMetrics:
@@ -427,6 +433,10 @@ class StoreMetrics:
 
 
 class BlockSyncMetrics:
+    # a window of empty 1000-validator blocks is 20 MB, one of blocks
+    # that carry 400 transactions of 1 KB 33 MB
+    WINDOW_BYTES_BUCKETS = tuple(1 << k for k in range(16, 30, 2))
+
     def __init__(self, reg: Registry | None = None):
         reg = reg or DEFAULT_REGISTRY
         self.syncing = reg.gauge("blocksync", "syncing",
@@ -458,6 +468,13 @@ class BlockSyncMetrics:
             "Blocks in a replay window as loaded (a change of the "
             "validator set ends one early)",
             buckets=(1, 2, 4, 8, 16, 32, 64, 128))
+        self.window_bytes = reg.histogram(
+            "blocksync", "window_bytes",
+            "Stored block bytes read for a replay window",
+            buckets=BlockSyncMetrics.WINDOW_BYTES_BUCKETS)
+        self.txs_applied_total = reg.counter(
+            "blocksync", "txs_applied_total",
+            "Transactions of the blocks that replay verified and applied")
         self.cert_verify_seconds = reg.histogram(
             "blocksync", "cert_verify_seconds",
             "Certificate (one-pairing) commit verification wall time "

@@ -607,18 +607,18 @@ SPAN_REGISTRY = {
     "consensus.finalize_commit": "block decided at height/round, with tx count",
     "consensus.propose_speculative": "one speculative proposal assembly overlapping the previous height's commit gap (height/txs/bytes)",
     "state.valset_update": "a block's validator updates applied to the set of two heights on, and the new set hashed (height/changes)",
-    "state.apply_block": "ApplyBlock with validate/finalize/commit/save stage breakdown (validate_ms/finalize_ms/update_state_ms = the next state built: the validator updates and the proposer rotation/commit_ms = the app's Commit and the mempool's update/save_events_ms: five stages in order, which sum to dur_ms less the clock reads; rotation = column|integer: the arithmetic that rotated the proposer, ValidatorSet._rotate)",
+    "state.apply_block": "ApplyBlock with validate/finalize/commit/save stage breakdown (validate_ms/finalize_ms/update_state_ms = the next state built: the validator updates and the proposer rotation/commit_ms = the app's Commit and the mempool's update/save_events_ms: five stages in order, which sum to dur_ms less the clock reads; data_hash_ms = inside validate_ms, the transactions' hashes and their Merkle root; state_save_ms = inside save_events_ms, the three state-store writes: the state with both validator sets, the results' hash, the encoded FinalizeBlockResponse, 0 without a state store; tx_bytes = bytes of the block's transactions; rotation = column|integer: the arithmetic that rotated the proposer, ValidatorSet._rotate)",
     "types.verify_commit": "one verify_commit / verify_commit_light (height/n = signatures judged/light); self_ms is the entry layer from inside",
     "types.commit_items": "one commit turned into lanes: gates, address check, sign bytes (n/sign_bytes_ms = time inside the sign-bytes build/path = columnar: from the decode columns by validation.commit_lanes, no CommitSig built, or per_slot: the per-signature loop/reason, per_slot only = the gate that declined: no_columns, no_native, shape, key_type, address)",
     "types.verify_items_fill": "the lanes filled into their verifiers, up to the first submit: one add_batch plus the minority curves' rows on the columnar path, grouping by key type and the add() loop on the per-slot path (n/groups/singles)",
     "blocksync.block": "one fast-synced block: fetch→verify→apply breakdown",
     "blocksync.replay": "one ReplayEngine.run (from/to/depth/mode); self_ms is the engine loop",
-    "blocksync.window_load": "blocks of one replay window read and decoded from the store (window = first height/blocks/end = full|set_change|tip|missing: what ended it)",
+    "blocksync.window_load": "blocks of one replay window read and decoded from the store (window = first height/blocks/end = full|set_change|tip|missing: what ended it/bytes = stored block bytes read/read_ms = inside the key-value gets/decode_ms = inside Block.decode)",
     "blocksync.window_queue": "every signature check of one replay window queued and submitted (window/blocks)",
     "blocksync.window_fill": "the commits of one window filled into the batch verifier (window/commits/lanes/columnar = commits on the columnar path)",
     "blocksync.window_resolve": "one window's verdict awaited and its +2/3 tallies checked (window/sigs)",
     "blocksync.set_change": "one boundary at which replay drained and queued anew, nothing in flight: from the end of the last apply before it to the return of the re-queued window's submit() (height/reason = set_change|speculation_failed)",
-    "blocksync.window_apply": "the blocks of one verified window applied (window/blocks/txs); children state.apply_block",
+    "blocksync.window_apply": "the blocks of one verified window applied (window/blocks/txs/tx_bytes = bytes of those transactions); children state.apply_block",
     "crypto.batch_verify": "one batch-verify dispatch, the host time inside submit() (path/n/bucket); its children split it",
     "crypto.materialize": "lazy whole-commit columns expanded into per-item tuples for one dispatch (n = lanes expanded, 0 when add() already built them)",
     "crypto.pack": "R||S||k wire rows of one ladder or mesh dispatch built on the host (n/bucket/chunks = chunks the lanes went in/pool = run|busy|small|python: pooled, pool taken so packed inline, too few lanes, no native library)",
