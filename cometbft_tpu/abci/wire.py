@@ -137,9 +137,8 @@ def dec_finalize_req(buf: bytes) -> T.FinalizeBlockRequest:
 
 
 def enc_finalize_resp(r: T.FinalizeBlockResponse) -> bytes:
-    out = b""
-    for tr in r.tx_results:
-        out += pb.f_embedded(
+    parts = [
+        pb.f_embedded(
             1,
             pb.f_varint(1, tr.code)
             + pb.f_bytes(2, tr.data)
@@ -147,15 +146,19 @@ def enc_finalize_resp(r: T.FinalizeBlockResponse) -> bytes:
             + pb.f_varint(5, tr.gas_wanted)
             + pb.f_varint(6, tr.gas_used),
         )
-    for vu in r.validator_updates:
-        out += pb.f_embedded(
+        for tr in r.tx_results
+    ]
+    parts += [
+        pb.f_embedded(
             2,
             pb.f_bytes(1, vu.pub_key_bytes)
             + pb.f_string(2, vu.pub_key_type)
             + pb.f_varint(3, vu.power),
         )
-    out += pb.f_bytes(3, r.app_hash)
-    return out
+        for vu in r.validator_updates
+    ]
+    parts.append(pb.f_bytes(3, r.app_hash))
+    return b"".join(parts)
 
 
 def dec_finalize_resp(buf: bytes) -> T.FinalizeBlockResponse:

@@ -452,19 +452,30 @@ class BlockExecutor:
         if life:
             _txlife.stage_block(life, "commit", height=h_)
         fail_point()  # reference execution.go:301 (post-Commit, pre-save)
-        state_save_s = 0.0
-        if self.state_store is not None:
-            self.state_store.save(new_state)
-            self.state_store.save_finalize_response(
-                block.header.height, results_hash(resp.tx_results)
-            )
+        state_save_s = state_encode_s = state_write_s = 0.0
+        set_encodes = 0
+        store = self.state_store
+        if store is not None:
             from ..abci import wire as _W
 
-            self.state_store.save_abci_responses(
-                block.header.height, _W.enc_finalize_resp(resp)
+            # what the store has spent so far, and the sets encoded
+            # afresh so far: the span says this block's share
+            fresh = state_metrics().valset_encode_total
+            enc0, write0 = store.encode_seconds, store.write_seconds
+            fresh0 = fresh.values().get(("miss",), 0.0)
+            store.save(new_state)
+            store.save_finalize_response(
+                block.header.height, new_state.last_results_hash
             )
+            t_resp = _time.perf_counter()
+            payload = _W.enc_finalize_resp(resp)
+            state_encode_s = _time.perf_counter() - t_resp
+            store.save_abci_responses(block.header.height, payload)
             state_save_s = _time.perf_counter() - t_commit
             state_metrics().state_save_seconds.observe(state_save_s)
+            state_encode_s += store.encode_seconds - enc0
+            state_write_s = store.write_seconds - write0
+            set_encodes = int(fresh.values().get(("miss",), 0.0) - fresh0)
         if self.event_bus is not None:
             # fire events (reference execution.go:313 fireEvents)
             self.event_bus.publish_new_block(block, resp)
@@ -492,9 +503,14 @@ class BlockExecutor:
                 commit_ms=round((t_commit - t_update) * 1e3, 3),
                 rotation=rotation,
                 save_events_ms=round((t_end - t_commit) * 1e3, 3),
-                # inside validate_ms; inside save_events_ms
+                # inside validate_ms; inside save_events_ms; the two
+                # parts of state_save_ms, and the validator sets encoded
+                # afresh (not looked up) among them
                 data_hash_ms=round(data_hash_s * 1e3, 3),
                 state_save_ms=round(state_save_s * 1e3, 3),
+                state_encode_ms=round(state_encode_s * 1e3, 3),
+                state_write_ms=round(state_write_s * 1e3, 3),
+                set_encodes=set_encodes,
                 tx_bytes=sum(map(len, block.data.txs)),
             )
         return new_state

@@ -12,7 +12,7 @@ from dataclasses import dataclass, field, replace
 from ..encoding import proto as pb
 from ..types import BlockID, Timestamp, Validator, ValidatorSet, ZERO_TIME
 from ..types.basic import ZERO_BLOCK_ID
-from ..types.validator_set import decode_pub_key, encode_pub_key
+from ..types.validator_set import decode_pub_key
 
 
 @dataclass(frozen=True)
@@ -103,15 +103,6 @@ def decode_params(buf: bytes) -> ConsensusParams:
     )
 
 
-def _encode_validator(m, proposer_priority: int) -> bytes:
-    return (
-        pb.f_bytes(1, m.address)
-        + pb.f_embedded(2, encode_pub_key(m.pub_key))
-        + pb.f_varint(3, m.voting_power)
-        + pb.f_varint(4, proposer_priority)
-    )
-
-
 def _decode_validator(buf: bytes) -> Validator:
     d = pb.fields_to_dict(buf)
     key_fields = pb.fields_to_dict(pb.as_bytes(d.get(2, b"")))
@@ -125,12 +116,7 @@ def _decode_validator(buf: bytes) -> Validator:
 
 
 def encode_validator_set(vs: ValidatorSet) -> bytes:
-    out = b""
-    for m, priority in zip(vs.members, vs.priorities()):
-        out += pb.f_embedded(1, _encode_validator(m, priority))
-    prop = vs.get_proposer()
-    out += pb.f_bytes(2, prop.address)
-    return out
+    return vs.encode()
 
 
 def decode_validator_set(buf: bytes) -> ValidatorSet:
@@ -176,25 +162,25 @@ class State:
         return replace(self)
 
     def encode(self) -> bytes:
-        out = (
-            pb.f_string(1, self.chain_id)
-            + pb.f_varint(2, self.initial_height)
-            + pb.f_varint(3, self.last_block_height)
-            + pb.f_embedded(4, self.last_block_id.encode())
-            + pb.f_embedded(5, self.last_block_time.encode())
-            + pb.f_varint(8, self.last_height_validators_changed)
-            + pb.f_bytes(10, self.last_results_hash)
-            + pb.f_bytes(11, self.app_hash)
-            + pb.f_varint(12, self.last_height_params_changed)
-            + pb.f_embedded(13, encode_params(self.consensus_params))
-        )
-        if self.validators is not None:
-            out += pb.f_embedded(6, encode_validator_set(self.validators))
-        if self.last_validators is not None:
-            out += pb.f_embedded(7, encode_validator_set(self.last_validators))
-        if self.next_validators is not None:
-            out += pb.f_embedded(9, encode_validator_set(self.next_validators))
-        return out
+        parts = [
+            pb.f_string(1, self.chain_id),
+            pb.f_varint(2, self.initial_height),
+            pb.f_varint(3, self.last_block_height),
+            pb.f_embedded(4, self.last_block_id.encode()),
+            pb.f_embedded(5, self.last_block_time.encode()),
+            pb.f_varint(8, self.last_height_validators_changed),
+            pb.f_bytes(10, self.last_results_hash),
+            pb.f_bytes(11, self.app_hash),
+            pb.f_varint(12, self.last_height_params_changed),
+            pb.f_embedded(13, encode_params(self.consensus_params)),
+        ]
+        for field_no, vs in ((6, self.validators), (7, self.last_validators),
+                             (9, self.next_validators)):
+            if vs is not None:
+                enc = encode_validator_set(vs)
+                parts += (pb.tag(field_no, pb.WT_LEN), pb.uvarint(len(enc)),
+                          enc)
+        return b"".join(parts)
 
     @classmethod
     def decode(cls, buf: bytes) -> "State":

@@ -8,7 +8,8 @@ old heights (reference :297).
 
 from __future__ import annotations
 
-from ..encoding import proto as pb
+import time
+
 from .kv import KVStore
 
 _KEY_STATE = b"S:cur"
@@ -29,10 +30,21 @@ def _key_params(h: int) -> bytes:
 class StateStore:
     def __init__(self, db: KVStore):
         self._db = db
+        # what this store has spent, in all, building save()'s records
+        # and inside the key-value store's writes: ApplyBlock's span
+        # reads the difference a block
+        self.encode_seconds = 0.0
+        self.write_seconds = 0.0
+
+    def _write(self, write, *args) -> None:
+        t0 = time.perf_counter()
+        write(*args)
+        self.write_seconds += time.perf_counter() - t0
 
     def save(self, state) -> None:
         from ..state.types import encode_validator_set
 
+        t0 = time.perf_counter()
         # `validators` is the set for the NEXT height to commit; at genesis
         # (last_block_height == 0) that is initial_height, not 1 (reference
         # internal/state/store.go Bootstrap vs save split).
@@ -54,7 +66,8 @@ class StateStore:
             sets.append(
                 (_key_vals(next_height), encode_validator_set(state.validators))
             )
-        self._db.write_batch(sets)
+        self.encode_seconds += time.perf_counter() - t0
+        self._write(self._db.write_batch, sets)
 
     def load(self):
         from ..state.types import State
@@ -77,7 +90,7 @@ class StateStore:
         return decode_validator_set(raw) if raw else None
 
     def save_finalize_response(self, height: int, payload: bytes) -> None:
-        self._db.set(_key_abci(height), payload)
+        self._write(self._db.set, _key_abci(height), payload)
 
     def load_finalize_response(self, height: int) -> bytes | None:
         return self._db.get(_key_abci(height))
@@ -87,7 +100,7 @@ class StateStore:
         state/store.go SaveFinalizeBlockResponse) — what reindexing and
         /block_results serve; save_finalize_response keeps only the
         results hash the header commits to."""
-        self._db.set(b"AR:" + height.to_bytes(8, "big"), payload)
+        self._write(self._db.set, b"AR:" + height.to_bytes(8, "big"), payload)
 
     def load_abci_responses(self, height: int) -> bytes | None:
         return self._db.get(b"AR:" + height.to_bytes(8, "big"))
@@ -101,5 +114,5 @@ class StateStore:
                             b"AR:" + h.to_bytes(8, "big")]
                 pruned += 1
         if deletes:
-            self._db.write_batch([], deletes)
+            self._write(self._db.write_batch, [], deletes)
         return pruned

@@ -19,11 +19,18 @@ import numpy as np
 from ..crypto import merkle
 from ..crypto.keys import PubKey
 from ..encoding import proto as pb
+from ..utils.metrics import state_metrics
 
 I64_MAX = (1 << 63) - 1
 I64_MIN = -(1 << 63)
 MAX_TOTAL_VOTING_POWER = I64_MAX // 8
 PRIORITY_WINDOW_SIZE_FACTOR = 2
+
+
+# ValidatorSet.encode(): field 1 of the set (a Validator, embedded) and
+# field 4 of a Validator (the proposer priority, a varint)
+_TAG_VALIDATOR = pb.tag(1, pb.WT_LEN)
+_TAG_PRIORITY = pb.tag(4, pb.WT_VARINT)
 
 
 def _clip(v: int) -> int:
@@ -201,6 +208,8 @@ class ValidatorSet:
             raise ValueError("validator set must not be empty")
         self._frozen = False
         self._proposer: Member | None = None
+        # encode()'s bytes, kept once the set is frozen
+        self._enc: bytes | None = None
         self._set_membership(sorted(validators, key=_sort_key))
         if len(self._address_index()) != len(self.members):
             raise ValueError("duplicate validator address")
@@ -384,7 +393,40 @@ class ValidatorSet:
         vs._proposer = self._proposer
         vs._view = None
         vs._frozen = False
+        vs._enc = None
         return vs
+
+    def encode(self) -> bytes:
+        """The stored ValidatorSet proto: a Validator (address, public
+        key, power, proposer priority) a member under field 1, then the
+        proposer's address. A frozen set cannot change, so it keeps its
+        bytes (its own: the priorities are not the membership's); a
+        mutable one is encoded afresh every time and keeps nothing.
+        What never changes in a member's record (all but the priority)
+        is built once a membership."""
+        enc = self._enc
+        state_metrics().valset_encode_total.inc(
+            1.0, "miss" if enc is None else "hit")
+        if enc is not None:
+            return enc
+        consts = self._memo.get("enc_consts")
+        if consts is None:
+            consts = self._memo["enc_consts"] = [
+                pb.f_bytes(1, m.address)
+                + pb.f_embedded(2, encode_pub_key(m.pub_key))
+                + pb.f_varint(3, m.voting_power)
+                for m in self.members
+            ]
+        parts = []
+        for const, priority in zip(consts, self._prio.tolist()):
+            record = (const + _TAG_PRIORITY + pb.varint_i64(priority)
+                      if priority else const)
+            parts += (_TAG_VALIDATOR, pb.uvarint(len(record)), record)
+        parts.append(pb.f_bytes(2, self.get_proposer().address))
+        enc = b"".join(parts)
+        if self._frozen:
+            self._enc = enc
+        return enc
 
     # --- proposer priority machinery ---
 

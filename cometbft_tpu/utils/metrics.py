@@ -410,12 +410,19 @@ class StateMetrics:
             "ran: column (int64 numpy) or integer (Python ints, for a set "
             "whose priorities or powers could leave int64)",
             labels=("path",))
+        self.valset_encode_total = reg.counter(
+            "state", "valset_encode_total",
+            "ValidatorSet.encode() calls: hit = a frozen set handed back "
+            "the bytes it keeps, miss = the set was encoded afresh (one a "
+            "block on ApplyBlock's path with a state store: the new "
+            "next_validators)",
+            labels=("memo",))
         self.state_save_seconds = reg.histogram(
             "state", "state_save_seconds",
-            "Wall time of ApplyBlock's writes to the state store: the "
-            "state with both validator sets, the results' hash and the "
-            "encoded FinalizeBlockResponse (observed only with a state "
-            "store)", buckets=TX_STAGE_BUCKETS)
+            "Wall time of ApplyBlock's writes to the state store, their "
+            "encoding included: the state with both validator sets, the "
+            "results' hash and the encoded FinalizeBlockResponse (observed "
+            "only with a state store)", buckets=TX_STAGE_BUCKETS)
 
 
 class StoreMetrics:

@@ -169,6 +169,69 @@ def test_fuzz_vote_decoder():
     _fuzz(Vote.decode, [_sample_vote().encode()])
 
 
+def _finalize_resp(results: str, updates: int):
+    """A FinalizeBlockResponse of a seeded shape: `results` = none, plain
+    (code 0 and a value, as the kvstore answers), rich (data, logs,
+    non-zero codes, gas wanted and used, negative gas, non-ASCII logs) or
+    loaded (400 results of 1 KB, the QA load's block)."""
+    from cometbft_tpu.abci import types as T
+
+    rng = random.Random(f"{results}/{updates}")
+    if results == "none":
+        txs = []
+    elif results == "plain":
+        txs = [T.ExecTxResult(code=0, data=rng.randbytes(rng.randrange(40)))
+               for _ in range(12)]
+    elif results == "loaded":
+        txs = [T.ExecTxResult(code=0, data=rng.randbytes(1022))
+               for _ in range(400)]
+    else:
+        txs = [T.ExecTxResult(
+            code=rng.choice((0, 1, 2, 130, 1 << 20)),
+            data=rng.randbytes(rng.choice((0, 1, 127, 128, 300))),
+            log=rng.choice(("", "ok", "insufficient funds: 7 < 9",
+                            "pr\u00fcfung \u2713", "x" * 200)),
+            gas_wanted=rng.choice((0, 1, 200_000, -1)),
+            gas_used=rng.choice((0, 21_000, 1 << 40)))
+            for _ in range(40)]
+    vus = [T.ValidatorUpdate(
+        pub_key_bytes=rng.randbytes(33 if i % 3 == 1 else 32),
+        pub_key_type="secp256k1" if i % 3 == 1 else "ed25519",
+        power=rng.choice((0, 1, 100, 1 << 50))) for i in range(updates)]
+    return T.FinalizeBlockResponse(
+        tx_results=txs, validator_updates=vus,
+        app_hash=b"" if results == "none" else rng.randbytes(32))
+
+
+@pytest.mark.parametrize("updates", (0, 1, 5))
+@pytest.mark.parametrize("results", ("none", "plain", "rich", "loaded"))
+def test_finalize_response_encodes_as_the_reference_and_its_decoder_survives(
+        results, updates):
+    """abci/wire.enc_finalize_resp (the state store's AR: record and the
+    socket server's answer) joins its parts: the bytes are those of the
+    plain encoder that grows one `bytes` field by field, they decode back
+    to the response, and the decoder survives their mutations."""
+    import state_encoding_reference as ref
+
+    from cometbft_tpu.abci import wire
+
+    resp = _finalize_resp(results, updates)
+    enc = wire.enc_finalize_resp(resp)
+    assert enc == ref.finalize_resp(resp)
+    def rows(r):
+        return (r.app_hash,
+                [(t.code, t.data, t.log, t.gas_wanted, t.gas_used)
+                 for t in r.tx_results],
+                [(v.pub_key_bytes, v.pub_key_type, v.power)
+                 for v in r.validator_updates])
+
+    back = wire.dec_finalize_resp(enc)
+    assert rows(back) == rows(resp)
+    assert wire.enc_finalize_resp(back) == enc
+    if results != "loaded":  # (300 mutations of 411 KB say nothing more)
+        _fuzz(wire.dec_finalize_resp, [enc])
+
+
 def test_mconnection_rate_enforcement():
     """A 20 KiB burst over a 64 KB/s send-limited conn must take ~300ms;
     with limits off it completes near-instantly (reference flowrate

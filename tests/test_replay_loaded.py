@@ -19,6 +19,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import state_encoding_reference as enc_ref  # noqa: E402
 from benchmark.reference import kvstore_replay as ref  # noqa: E402
 from cometbft_tpu.abci import wire  # noqa: E402
 from cometbft_tpu.abci.client import AppConns  # noqa: E402
@@ -63,15 +64,20 @@ def loaded_chain(seed: int, **kw):
 
 
 class Recording(BlockExecutor):
-    """Keeps the app's store after every applied height."""
+    """Keeps the app's store, the state and the app's response after
+    every applied height."""
 
     def __init__(self, app, **kw):
         super().__init__(AppConns(app), backend="cpu", **kw)
-        self.kv_app, self.stores = app, {}
+        self.kv_app, self.stores, self.states, self.resps = app, {}, {}, {}
+        self.event_handlers.append(
+            lambda block, resp: self.resps.__setitem__(
+                block.header.height, resp))
 
     def apply_block_preverified(self, state, block_id, block):
         state = super().apply_block_preverified(state, block_id, block)
         self.stores[block.header.height] = dict(self.kv_app.store)
+        self.states[block.header.height] = state
         return state
 
 
@@ -251,6 +257,131 @@ def test_the_state_store_read_from_a_new_connection(tmp, seed, what):
         skv.close()
 
 
+def _key(prefix: bytes, h: int) -> bytes:
+    return prefix + h.to_bytes(8, "big")
+
+
+def stored_as_the_reference_encodes(path, genesis, ex, results_root, tip):
+    """Every key of the state store at `path`, read through a new
+    connection, against what the plain encoder (tests/
+    state_encoding_reference.py) gives for the states and responses the
+    executor `ex` went through; `results_root[h]` is the reference's.
+    Returns the keys by prefix."""
+    states = dict(ex.states)
+    states[0] = genesis
+    want = {b"S:cur": enc_ref.state(states[tip])}
+    for h in range(0, tip + 1):
+        # the state after block h carries the sets of h+1 and h+2 and
+        # the params that judge block h+1
+        st = states[h]
+        want[_key(b"SV:", h + 1)] = enc_ref.validator_set(st.validators)
+        want[_key(b"SV:", h + 2)] = enc_ref.validator_set(
+            st.next_validators)
+        want[_key(b"SP:", h + 1)] = enc_ref.params(st.consensus_params)
+    for h in range(1, tip + 1):
+        want[_key(b"SA:", h)] = results_root[h]
+        want[_key(b"AR:", h)] = enc_ref.finalize_resp(ex.resps[h])
+    skv = open_kv(path)
+    try:
+        got = dict(skv.iterate_prefix(b""))
+    finally:
+        skv.close()
+    assert sorted(got) == sorted(want)
+    for k in want:
+        assert got[k] == want[k], k
+    return {p: sum(k.startswith(p) for k in got)
+            for p in (b"S:cur", b"SV:", b"SP:", b"SA:", b"AR:")}
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_every_key_of_the_state_store_holds_the_reference_encoders_bytes(
+        tmp, seed):
+    kv, _, genesis, _ = loaded_chain(seed)
+    _, _, _, ex, path = replayed(seed, tmp)
+    r = reference_of(kv)
+    assert stored_as_the_reference_encodes(
+        path, genesis, ex, r.results_root, BLOCKS) == {
+            b"S:cur": 1, b"SV:": BLOCKS + 2, b"SP:": BLOCKS + 1,
+            b"SA:": BLOCKS, b"AR:": BLOCKS}
+
+
+@pytest.mark.parametrize("with_store", (True, False))
+def test_the_results_root_is_computed_once_a_block(
+        tmp_path, monkeypatch, with_store):
+    from cometbft_tpu.state import execution
+
+    calls = []
+
+    def counted(tx_results):
+        calls.append(len(tx_results))
+        return results_hash(tx_results)
+
+    monkeypatch.setattr(execution, "results_hash", counted)
+    kv, final, genesis, _ = loaded_chain(SEEDS[0])
+    state, _, _, _, skv = replay(
+        kv, genesis, str(tmp_path / "s.db") if with_store else None)
+    assert state.last_results_hash == final.last_results_hash
+    assert calls == [TXS] * BLOCKS
+    if skv is not None:
+        assert StateStore(skv).load_finalize_response(BLOCKS) == (
+            final.last_results_hash)
+        skv.close()
+
+
+CHURN = {"validators": 8, "window": 4, "blocks": 24, "update_every": 3,
+         "repowered_members": 2, "spare_keys": 5}  # the churn cell's rehearsal
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_a_chain_with_validator_updates_is_read_back_set_by_set(
+        tmp_path, seed):
+    """benchmark/configs/catchup-1000v-churn.json's rehearse schedule,
+    replayed with a state store: a block that changes the set still
+    encodes one set afresh (the changed one), and the store holds, for
+    every height, the set that height's header names."""
+    spare = fx.make_signers(CHURN["spare_keys"], seed=seed + 7)
+    churn = fx.ValsetChurn(
+        spare, seed=seed, every=CHURN["update_every"],
+        repowered=CHURN["repowered_members"], power_lo=30, power_hi=100)
+    tip, kv = CHURN["blocks"], MemKV()
+    store, final, genesis, _ = fx.make_chain(
+        tip, n_validators=CHURN["validators"], chain_id=CHAIN, seed=seed,
+        backend="cpu", powers=churn.genesis_powers(CHURN["validators"]),
+        extra_txs=churn, spare_signers=spare, block_store=BlockStore(kv))
+    assert churn.joins >= 3 and churn.repowerings >= 3
+    assert WINDOW == CHURN["window"]
+    path = str(tmp_path / "state.db")
+    (state, _, _, ex, skv), recs = _traced(
+        tmp_path, lambda: replay(kv, genesis, path))
+    skv.close()
+    assert state.encode() == final.encode()
+    blocks = _of(recs, "state.apply_block")
+    assert [r["height"] for r in blocks] == list(range(1, tip + 1))
+    assert [r["set_encodes"] for r in blocks] == [1] * tip
+    assert len(_of(recs, "state.valset_update")) == tip // CHURN[
+        "update_every"]
+    roots = {h: results_hash(ex.resps[h].tx_results)
+             for h in range(1, tip + 1)}
+    stored_as_the_reference_encodes(path, genesis, ex, roots, tip)
+    skv = open_kv(path)
+    try:
+        ss = StateStore(skv)
+        hashes = set()
+        for h in range(1, tip + 1):
+            hdr = store.load_block(h).header
+            vals = ss.load_validators(h)
+            assert vals.hash() == hdr.validators_hash, h
+            assert ss.load_validators(h + 1).hash() == (
+                hdr.next_validators_hash), h
+            assert enc_ref.validator_set(vals) == skv.get(_key(b"SV:", h))
+            hashes.add(hdr.validators_hash)
+        assert len(hashes) >= 6  # the set did change
+        assert ss.load_validators(tip + 2).hash() == (
+            final.next_validators.hash())
+    finally:
+        skv.close()
+
+
 # ---------------------------------------------------------------------
 # the two refusals
 
@@ -394,6 +525,13 @@ def test_the_new_span_fields_and_counters_add_up(tmp_path, depth, with_store):
         assert 0 < r["data_hash_ms"] <= r["validate_ms"], r
         assert 0 <= r["state_save_ms"] <= r["save_events_ms"], r
         assert (r["state_save_ms"] > 0) == with_store, r
+        # the save's two parts, and the mechanism's counter: of the five
+        # sets a block's records hold, one (next_validators) is new
+        assert (r["state_encode_ms"] > 0) == with_store == (
+            r["state_write_ms"] > 0), r
+        assert r["state_encode_ms"] + r["state_write_ms"] <= (
+            r["state_save_ms"] + 0.002), r
+        assert r["set_encodes"] == (1 if with_store else 0), r
         # the five stages still sum to the span
         assert sum(r[f] for f in (
             "validate_ms", "finalize_ms", "update_state_ms", "commit_ms",
@@ -409,6 +547,13 @@ def test_the_new_span_fields_and_counters_add_up(tmp_path, depth, with_store):
         BLOCKS if with_store else 0)
     if skv is not None:
         skv.close()
+        # untraced nodes read the same count: a block asks for five
+        # encodings and one is new; the bootstrap's save of the genesis
+        # state asks for four, of two sets (new unless an earlier test
+        # has had this cached genesis encoded)
+        encodes = state_metrics().valset_encode_total.values()
+        assert encodes[("miss",)] + encodes[("hit",)] == 4 + 5 * BLOCKS
+        assert BLOCKS <= encodes[("miss",)] <= BLOCKS + 2
 
 
 def test_untraced_replay_counts_and_takes_no_span_time(tmp_path):
