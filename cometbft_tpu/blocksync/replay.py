@@ -392,12 +392,23 @@ class ReplayEngine:
         (sound within a constant-validator-set span: each window's
         verification inputs — validator set and predecessor block id —
         are known before w is applied; across a set change the pipeline
-        drains and re-queues with the post-apply state)."""
+        drains and re-queues with the post-apply state).
+
+        Where the executor's event bus feeds an indexer, run() returns
+        (or raises) only once the index holds every block it applied."""
         tip = to_height or self.store.height()
         h = state.last_block_height + 1
         with _trace.span("blocksync.replay", to=tip, mode=self.verify_mode,
                          **{"from": h}) as span:
-            return self._run(state, tip, h, span)
+            try:
+                return self._run(state, tip, h, span)
+            finally:
+                # the tip is indexed when the replay says it is: what the
+                # applied blocks published is written before run() returns
+                # (or raises), and a failed index write fails the replay
+                bus = getattr(self.executor, "event_bus", None)
+                if bus is not None:
+                    bus.join()
 
     def _run(self, state, tip: int, h: int,
              span) -> tuple[object, ReplayStats]:

@@ -340,12 +340,12 @@ def cmd_compact_db(args) -> int:
 
 def cmd_reindex_event(args) -> int:
     """reference commands/reindex_event.go: rebuild the tx and block
-    indexes from the block store + stored ABCI responses."""
+    indexes from the block store + stored ABCI responses, a batch a block
+    into the files a node with `[tx_index] indexer = "kv"` opens."""
     from .abci import wire as W
     from .config import Config
     from .storage import BlockStore, StateStore, open_kv
-    from .storage.indexer import BlockIndexer, TxIndexer
-    from .crypto.keys import tmhash
+    from .storage.indexer import open_indexers
 
     p = _cfg_paths(args.home)
     cfg = Config.load(p["config_file"])
@@ -353,10 +353,14 @@ def cmd_reindex_event(args) -> int:
     if mem:
         print("mem backend holds no persisted blocks to reindex")
         return 1
-    bs = BlockStore(open_kv(os.path.join(args.home, "data/blockstore.db")))
-    ss = StateStore(open_kv(os.path.join(args.home, "data/state.db")))
-    txi = TxIndexer(open_kv(os.path.join(args.home, "data/tx_index.db")))
-    bli = BlockIndexer(open_kv(os.path.join(args.home, "data/block_index.db")))
+    if cfg.tx_index.indexer != "kv":
+        print(f'tx_index.indexer = "{cfg.tx_index.indexer}": this node '
+              f"keeps no index to rebuild")
+        return 1
+    data = os.path.join(args.home, "data")
+    bs = BlockStore(open_kv(os.path.join(data, "blockstore.db")))
+    ss = StateStore(open_kv(os.path.join(data, "state.db")))
+    txi, bli, dbs = open_indexers(data)
     start = args.start_height or bs.base() or 1
     end = args.end_height or bs.height()
     txs = blocks = 0
@@ -366,19 +370,11 @@ def cmd_reindex_event(args) -> int:
         if blk is None or raw is None:
             continue
         resp = W.dec_finalize_resp(raw)
-        bli.index(h, {"tm.event": ["NewBlock"],
-                      "block.height": [str(h)]})
+        bli.index(h, resp.events)
         blocks += 1
-        for i, tx in enumerate(blk.data.txs):
-            result = (
-                resp.tx_results[i] if i < len(resp.tx_results) else None
-            )
-            txi.index(h, i, tx, result, {
-                "tm.event": ["Tx"],
-                "tx.height": [str(h)],
-                "tx.hash": [tmhash(tx).hex().upper()],
-            })
-            txs += 1
+        txs += txi.add_batch(h, blk.data.txs, resp.tx_results).txs
+    for db in dbs:
+        db.close()
     print(f"reindexed heights [{start}, {end}]: "
           f"{blocks} blocks, {txs} txs")
     return 0

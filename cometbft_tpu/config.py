@@ -253,6 +253,25 @@ class StorageConfig:
 
 
 @dataclass
+class TxIndexConfig:
+    """What indexes committed transactions and blocks for /tx,
+    /tx_search and /block_search (reference config.go TxIndexConfig).
+
+    "kv" (the default, as the reference's): storage/indexer.py writes
+    data/tx_index.db and data/block_index.db, one batch a block, fed by
+    a service that holds ApplyBlock back rather than lose an entry.
+    "null": no indexer and no service; the three routes find nothing."""
+
+    indexer: str = "kv"
+
+    def validate(self) -> None:
+        if self.indexer not in ("kv", "null"):
+            raise ValueError(
+                f'tx_index.indexer must be "kv" or "null", '
+                f"got {self.indexer!r}")
+
+
+@dataclass
 class LightConfig:
     """Light-client streaming service (light/serve.py, ROADMAP #2).
 
@@ -503,6 +522,7 @@ class Config:
     blocksync: BlockSyncConfig = field(default_factory=BlockSyncConfig)
     statesync: StateSyncConfig = field(default_factory=StateSyncConfig)
     storage: StorageConfig = field(default_factory=StorageConfig)
+    tx_index: TxIndexConfig = field(default_factory=TxIndexConfig)
     light: LightConfig = field(default_factory=LightConfig)
     da: DAConfig = field(default_factory=DAConfig)
     replication: ReplicationConfig = field(
@@ -517,7 +537,7 @@ class Config:
     def validate(self) -> None:
         for section in (self.base, self.rpc, self.p2p, self.mempool,
                         self.consensus, self.blocksync, self.statesync,
-                        self.storage, self.light, self.da, self.replication,
+                        self.storage, self.tx_index, self.light, self.da, self.replication,
                         self.watchtower, self.sched, self.instrumentation):
             section.validate()
 
@@ -558,6 +578,7 @@ class Config:
             emit("blocksync", self.blocksync),
             emit("statesync", self.statesync),
             emit("storage", self.storage),
+            emit("tx_index", self.tx_index),
             emit("light", self.light),
             emit("da", self.da),
             emit("replication", self.replication),
@@ -600,6 +621,7 @@ class Config:
             blocksync=mk(BlockSyncConfig, d.get("blocksync", {})),
             statesync=mk(StateSyncConfig, d.get("statesync", {})),
             storage=mk(StorageConfig, d.get("storage", {})),
+            tx_index=mk(TxIndexConfig, d.get("tx_index", {})),
             light=mk(LightConfig, d.get("light", {})),
             da=mk(DAConfig, d.get("da", {})),
             replication=mk(ReplicationConfig, d.get("replication", {})),

@@ -30,8 +30,7 @@ from ..rpc.server import RPCServer
 from ..state.execution import BlockExecutor, make_genesis_state
 from ..state.handshake import Handshaker
 from ..storage import BlockStore, StateStore, open_kv
-from ..storage.indexer import BlockIndexer, IndexerService, TxIndexer
-from ..types.event_bus import EventBus
+from ..storage.indexer import open_indexing
 from ..types.genesis import GenesisDoc
 
 
@@ -58,6 +57,7 @@ class Node:
             _metrics.consensus_metrics, _metrics.mempool_metrics,
             _metrics.p2p_metrics, _metrics.state_metrics,
             _metrics.blocksync_metrics, _metrics.statesync_metrics,
+            _metrics.indexer_metrics,
             _metrics.light_metrics, _metrics.da_metrics,
             _metrics.replication_metrics, _metrics.crypto_metrics,
         ):
@@ -232,12 +232,16 @@ class Node:
             state_store=self.state_store, block_store=self.block_store,
             chain_id=self.genesis_doc.chain_id,
         )
-        self.event_bus = EventBus()
-        self.tx_indexer = TxIndexer()
-        self.block_indexer = BlockIndexer()
-        self.indexer_service = IndexerService(
-            self.event_bus, self.tx_indexer, self.block_indexer
+        # [tx_index]: "kv" = both indexers on files under data/ and the
+        # service that feeds them a block at a time; "null" = the bus alone
+        self.indexing = open_indexing(
+            config.tx_index.indexer,
+            None if mem else os.path.dirname(_p("data/tx_index.db")),
         )
+        self.event_bus = self.indexing.event_bus
+        self.tx_indexer = self.indexing.tx_indexer
+        self.block_indexer = self.indexing.block_indexer
+        self.indexer_service = self.indexing.service
         self.executor = BlockExecutor(
             self.app_conns,
             state_store=self.state_store,
@@ -668,7 +672,7 @@ class Node:
         self.consensus_reactor.stop()
         self.evidence_reactor.stop()
         self.switch.stop()
-        self.indexer_service.stop()
+        self.indexing.stop()  # writes what was published, closes the files
         if self.metrics_server is not None:
             self.metrics_server.stop()
         if self.rpc_server is not None:

@@ -476,17 +476,14 @@ class BlockExecutor:
             state_encode_s += store.encode_seconds - enc0
             state_write_s = store.write_seconds - write0
             set_encodes = int(fresh.values().get(("miss",), 0.0) - fresh0)
+        publish_s = index_wait_s = 0.0
         if self.event_bus is not None:
-            # fire events (reference execution.go:313 fireEvents)
-            self.event_bus.publish_new_block(block, resp)
-            for i, tx in enumerate(block.data.txs):
-                self.event_bus.publish_tx(
-                    block.header.height, i, tx, resp.tx_results[i]
-                )
-            if resp.validator_updates:
-                self.event_bus.publish_validator_set_updates(
-                    resp.validator_updates
-                )
+            # fire events (reference execution.go:313 fireEvents): the
+            # bus's own work on this thread, and apart from it the time a
+            # block subscriber (the indexer service) held this thread back
+            t_pub = _time.perf_counter()
+            index_wait_s = self.event_bus.publish_block(block, resp)
+            publish_s = _time.perf_counter() - t_pub - index_wait_s
         if life:
             # notify closes the lifecycle whether or not an event bus is
             # wired (without one there is simply nothing to wait on)
@@ -505,9 +502,12 @@ class BlockExecutor:
                 save_events_ms=round((t_end - t_commit) * 1e3, 3),
                 # inside validate_ms; inside save_events_ms; the two
                 # parts of state_save_ms, and the validator sets encoded
-                # afresh (not looked up) among them
+                # afresh (not looked up) among them; then, inside
+                # save_events_ms too, the event bus and the indexer's hold
                 data_hash_ms=round(data_hash_s * 1e3, 3),
                 state_save_ms=round(state_save_s * 1e3, 3),
+                publish_ms=round(publish_s * 1e3, 3),
+                index_wait_ms=round(index_wait_s * 1e3, 3),
                 state_encode_ms=round(state_encode_s * 1e3, 3),
                 state_write_ms=round(state_write_s * 1e3, 3),
                 set_encodes=set_encodes,

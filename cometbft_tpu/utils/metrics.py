@@ -439,6 +439,31 @@ class StoreMetrics:
             buckets=StoreMetrics.COMMIT_BUCKETS)
 
 
+class IndexerMetrics:
+    def __init__(self, reg: Registry | None = None):
+        reg = reg or DEFAULT_REGISTRY
+        self.txs_indexed_total = reg.counter(
+            "indexer", "txs_indexed_total",
+            "Transactions the indexer service wrote to the tx index "
+            "([tx_index] indexer = \"kv\"), one batch a block")
+        self.blocks_indexed_total = reg.counter(
+            "indexer", "blocks_indexed_total",
+            "Blocks whose events the indexer service wrote: one batch to "
+            "the tx index and one record to the block index")
+        self.events_dropped_total = reg.counter(
+            "indexer", "events_dropped_total",
+            "Events (a block's own and one a transaction) published to "
+            "the indexer service that the index does not hold: the block "
+            "whose write failed, the blocks queued behind it, a block "
+            "published after the service stopped. A slow indexer drops "
+            "none: it holds ApplyBlock back")
+        self.blocks_held = reg.gauge(
+            "indexer", "blocks_held",
+            "Blocks published to the indexer service and not yet written "
+            "(at most storage/indexer.MAX_BLOCKS_HELD: ApplyBlock waits "
+            "at that many)")
+
+
 class BlockSyncMetrics:
     # a window of empty 1000-validator blocks is 20 MB, one of blocks
     # that carry 400 transactions of 1 KB 33 MB
@@ -737,6 +762,10 @@ def state_metrics() -> StateMetrics:
 
 def blocksync_metrics() -> BlockSyncMetrics:
     return _bundle("blocksync", BlockSyncMetrics)
+
+
+def indexer_metrics() -> IndexerMetrics:
+    return _bundle("indexer", IndexerMetrics)
 
 
 def store_metrics() -> StoreMetrics:

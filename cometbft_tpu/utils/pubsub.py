@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import re
 import threading
+import time
 from dataclasses import dataclass, field
 
 
@@ -157,6 +158,99 @@ class Subscription:
             return out
 
 
+class HeldSubscription:
+    """A hand-off that holds its publisher back instead of losing items:
+    it holds at most `capacity` items its consumer has not FINISHED
+    (queued or in its hands), and publish() waits for a free place. It is
+    never cancelled for being slow (reference pubsub SubscribeUnbuffered,
+    which the indexer service takes: a slow indexer slows the publisher
+    and is never dropped). An item is whatever the publisher's grain is;
+    the event bus hands over a block's events as one.
+
+    The consumer's loop is next() ... done(), or fail(exc) when it gives
+    up: a failed subscription raises that error from every later
+    publish() and join(). close() ends it in order: what was published is
+    still handed out, a later publish() is not taken. `on_lost(item)` is
+    told of every item that will never be handed out (queued behind a
+    failure, or published after the close), so a loss is never silent."""
+
+    def __init__(self, capacity: int, on_lost=None):
+        if capacity < 1:
+            raise ValueError("a held subscription holds at least one item")
+        self.capacity = capacity
+        self._on_lost = on_lost or (lambda item: None)
+        self._q: list = []
+        self._cv = threading.Condition()
+        self.published = 0
+        self.finished = 0
+        self.max_held = 0  # the most it ever held
+        self.closed = False
+        self.error: BaseException | None = None
+
+    @property
+    def held(self) -> int:
+        return self.published - self.finished
+
+    def publish(self, item) -> float:
+        """Seconds the publisher was held back."""
+        with self._cv:
+            t0 = None
+            while (self.held >= self.capacity
+                   and not (self.closed or self.error)):
+                t0 = t0 or time.perf_counter()
+                self._cv.wait()
+            waited = time.perf_counter() - t0 if t0 else 0.0
+            if self.error is not None:
+                self._on_lost(item)
+                raise self.error
+            if self.closed:
+                self._on_lost(item)
+                return waited
+            self._q.append(item)
+            self.published += 1
+            self.max_held = max(self.max_held, self.held)
+            self._cv.notify_all()
+        return waited
+
+    def next(self):
+        """The next item, or None once the subscription is closed and
+        empty."""
+        with self._cv:
+            while not self._q:
+                if self.closed or self.error:
+                    return None
+                self._cv.wait()
+            return self._q.pop(0)
+
+    def done(self) -> None:
+        with self._cv:
+            self.finished += 1
+            self._cv.notify_all()
+
+    def fail(self, exc: BaseException) -> None:
+        with self._cv:
+            self.error = exc
+            for item in self._q:
+                self._on_lost(item)
+            self._q.clear()
+            self._cv.notify_all()
+
+    def close(self) -> None:
+        with self._cv:
+            self.closed = True
+            self._cv.notify_all()
+
+    def join(self) -> None:
+        """Waits until everything published so far is finished; raises the
+        consumer's error if it failed."""
+        with self._cv:
+            want = self.published
+            self._cv.wait_for(
+                lambda: self.finished >= want or self.error is not None)
+            if self.error is not None:
+                raise self.error
+
+
 class PubSubServer:
     def __init__(self):
         self._subs: dict[tuple[str, str], Subscription] = {}
@@ -180,6 +274,11 @@ class PubSubServer:
             gone = [k for k in self._subs if k[0] == client_id]
             for k in gone:
                 self._subs.pop(k).cancel()
+
+    def has_subscribers(self) -> bool:
+        """Whether a publish() could reach anyone: a publisher may skip
+        building its messages when not."""
+        return bool(self._subs)
 
     def publish(self, data, events: dict[str, list[str]] | None = None) -> None:
         msg = Message(data, events or {})
