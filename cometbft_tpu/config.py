@@ -259,7 +259,9 @@ class TxIndexConfig:
 
     "kv" (the default, as the reference's): storage/indexer.py writes
     data/tx_index.db and data/block_index.db, one batch a block, fed by
-    a service that holds ApplyBlock back rather than lose an entry.
+    a service that holds ApplyBlock back rather than lose an entry. A
+    new tx_index.db is made with 16 KB pages; one that exists keeps its
+    own.
     "null": no indexer and no service; the three routes find nothing."""
 
     indexer: str = "kv"

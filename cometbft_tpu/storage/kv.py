@@ -13,6 +13,8 @@ from typing import Iterator
 
 
 class KVStore(ABC):
+    page_bytes = 0  # the size of the file's pages; 0 where there is no file
+
     @abstractmethod
     def get(self, key: bytes) -> bytes | None: ...
 
@@ -72,18 +74,26 @@ class MemKV(KVStore):
 
 
 class SqliteKV(KVStore):
-    """Single-table SQLite KV; WAL mode for concurrent readers."""
+    """Single-table SQLite KV; WAL mode for concurrent readers.
 
-    def __init__(self, path: str):
+    `page_size` is the size a NEW file's pages are made with (sqlite
+    fixes it at a file's first write, so the pragma comes first); a file
+    that exists keeps its own. `page_bytes` is what the file has."""
+
+    def __init__(self, path: str, page_size: int | None = None):
         self._conn = sqlite3.connect(path, check_same_thread=False)
         self._lock = threading.Lock()
         with self._lock:
+            if page_size is not None:
+                self._conn.execute(f"PRAGMA page_size={int(page_size)}")
             self._conn.execute("PRAGMA journal_mode=WAL")
             self._conn.execute("PRAGMA synchronous=NORMAL")
             self._conn.execute(
                 "CREATE TABLE IF NOT EXISTS kv (k BLOB PRIMARY KEY, v BLOB NOT NULL)"
             )
             self._conn.commit()
+            self.page_bytes = self._conn.execute(
+                "PRAGMA page_size").fetchone()[0]
 
     def get(self, key):
         with self._lock:
@@ -143,8 +153,9 @@ class SqliteKV(KVStore):
             self._conn.close()
 
 
-def open_kv(path: str | None) -> KVStore:
-    """None/':memory:' -> MemKV; otherwise SQLite at path."""
+def open_kv(path: str | None, page_size: int | None = None) -> KVStore:
+    """None/':memory:' -> MemKV; otherwise SQLite at path, a new file
+    made with pages of `page_size` bytes (None: sqlite's default)."""
     if path in (None, ":memory:"):
         return MemKV()
-    return SqliteKV(path)
+    return SqliteKV(path, page_size)
