@@ -453,7 +453,10 @@ def _engine_timings(probe: Probe, lanes, engine: str, calls: int = 5):
         return (t1 - t0) * 1e3, (t2 - t0) * 1e3
 
     prof = os.path.join(probe.workdir, f"profile_{engine}_{len(lanes)}")
-    one()  # compiles or loads, fills the pubkey cache
+    # compiles or loads, fills the pubkey cache: of every device for the
+    # ladder, whose streamed placement takes the devices in turn
+    for _ in range(mesh.n_devices if mesh and engine == "ladder" else 1):
+        one()
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0  # device operations and our spans only
     # off the chip XLA:CPU books every thunk as a host event: 84 MB and
@@ -516,6 +519,9 @@ def phase_device_terms(probe: Probe, lanes, sizes, seed: int):
     crypto/ed25519.py assumes; beside them the host term, the packer at the
     calibration probe's lane count and at both sizes. Nothing is re-derived
     here: the printout is what a change of the constants is made from."""
+    import jax
+    import numpy as np
+
     from cometbft_tpu.crypto import ed25519 as E
 
     host = E._host_terms()
@@ -550,16 +556,25 @@ def phase_device_terms(probe: Probe, lanes, sizes, seed: int):
             # the ladder's program called again, its inputs on the device
             # and what an A-cache miss adds before it: the column's
             # decompression and the 128 doublings of the cached pair
-            r["kernel_ms"], r["miss_ms"] = (
-                [_median_call_s(*probe.latest(fn, b)) * 1e3
-                 for fn in ("verify_batch_cached_a", "decompress_pubkeys")]
-                if eng == "ladder" else (None, None))
+            # (the mesh's miss: its staging program on a column already
+            # on the shards)
+            if eng == "ladder":
+                r["kernel_ms"], r["miss_ms"] = (
+                    _median_call_s(*probe.latest(fn, b)) * 1e3
+                    for fn in ("verify_batch_cached_a", "decompress_pubkeys"))
+                blocked = (f"; blocked call {r['kernel_ms']:.3f} ms, an "
+                           f"A-cache miss's decompress_pubkeys "
+                           f"{r['miss_ms']:.3f} ms")
+            else:
+                column = jax.device_put(np.zeros((b, 32), np.uint8),
+                                        mesh._sharding)
+                r["kernel_ms"] = None
+                r["miss_ms"] = _median_call_s(mesh._stage, (column,), {}) * 1e3
+                blocked = (f"; an A-cache miss's sharded decompress_pubkeys "
+                           f"{r['miss_ms']:.3f} ms as a blocked call")
             dev = (f"{r['device_ms']:.3f} ms in the profile "
                    f"{r.get('by_scope_ms')}" if r["device_ms"] is not None
                    else "not in a profile")
-            blocked = (f"; blocked call {r['kernel_ms']:.3f} ms, an A-cache "
-                       f"miss's decompress_pubkeys {r['miss_ms']:.3f} ms"
-                       if r["kernel_ms"] is not None else "")
             log(f"     {eng:<6} {where}: {dev}{blocked}; submit() "
                 f"{r['submit_ms']:.3f} ms, submit -> verdict "
                 f"{r['submit_to_verdict_ms']:.3f} ms")
@@ -567,10 +582,9 @@ def phase_device_terms(probe: Probe, lanes, sizes, seed: int):
     n0, n1 = sizes
     for eng in engines:
         fixed, per_lane = E._DEV_LADDER_FIXED_MS, E._DEV_LADDER_US
-        if eng == "mesh":
-            fixed = (E._DEV_MESH_FIXED_MS
-                     + mesh.dispatch_terms()["collective_s"] * 1e3)
-            per_lane = E._DEV_MESH_US / mesh.n_devices
+        if eng == "mesh":  # the ladder's own line over the device count
+            fixed += mesh.dispatch_terms()["collective_s"] * 1e3
+            per_lane /= mesh.n_devices
         t0, t1 = (got[eng][n]["device_ms"] or got[eng][n]["kernel_ms"]
                   for n in sizes)
         if t0 is None or t1 is None:
@@ -751,9 +765,9 @@ def phase_node(workdir: str, heights: int, n_txs: int, seed: int):
 
 def phase_mesh(probe: Probe, n: int, seed: int):
     import jax
-    import numpy as np
 
     from cometbft_tpu.crypto import ed25519 as E
+    from cometbft_tpu.utils import trace
     from cometbft_tpu.utils.metrics import crypto_metrics
 
     eng = E._mesh_engine()
@@ -792,19 +806,35 @@ def phase_mesh(probe: Probe, n: int, seed: int):
             f"single-chip ladder, {bits_m.count(False)} lanes false")
     if len(seen_shard_devices) != 4:
         raise SystemExit(f"FAIL: shards sat on {seen_shard_devices}")
+    # a column is decompressed once and kept on the shards: the corrupted
+    # commit has the honest one's keys, the n-1 lanes are another column
+    trace.flush()
+    with open(probe.trace_path, encoding="utf-8") as f:
+        a_cache = [r.get("a_cache") for r in map(json.loads, f)
+                   if r.get("name") == "crypto.mesh_submit"]
+    log(f"   crypto.mesh_submit a_cache: {a_cache}")
+    if a_cache != ["miss", "hit", "miss", "hit"]:
+        raise SystemExit("FAIL: the mesh did not keep its staged columns")
 
-    # the sharded program must hold the kernels too
+    # both sharded programs must hold their kernels too: the staging
+    # program A's decompression and the 128 doublings, the verifier R's
+    # decompression and the ladder
     b = E._bucket(n)
-    rsk, live, _ = verifier(commit_lanes(vals, commit))._pack_rsk_live(n, b)
-    a = np.zeros((b, 32), np.uint8)
-    lowered = eng._fn(b).lower(
-        eng.stage_pubkeys(a), *jax.device_put((rsk, live), eng._sharding))
-    k = lowered.as_text().count("@tpu_custom_call")
-    if probe.on_chip and k == 0:
-        raise SystemExit("FAIL: the sharded verifier lowered without the "
-                         "Pallas kernel")
-    log(f"   sharded_verify_rsk[{b}/4 = {b // 4} a shard]: "
-        f"{k} tpu_custom_call" + ("" if probe.on_chip else " (off the chip)"))
+    rsk, live, pub_blob = verifier(
+        commit_lanes(vals, commit))._pack_rsk_live(n, b)
+    (ok_a, a_points), _ = eng.stage_pubkeys(pub_blob, b)
+    for label, lowered in (
+            ("sharded decompress_pubkeys", eng._stage.lower(jax.device_put(
+                eng._column(pub_blob, b), eng._sharding))),
+            ("sharded_verify_rsk", eng._fn(b).lower(
+                ok_a, a_points,
+                *jax.device_put((rsk, live), eng._sharding)))):
+        k = lowered.as_text().count("@tpu_custom_call")
+        if probe.on_chip and k < 2:
+            raise SystemExit(f"FAIL: {label} lowered with {k} of its 2 "
+                             "Pallas kernels")
+        log(f"   {label}[{b}/4 = {b // 4} a shard]: {k} tpu_custom_call"
+            + ("" if probe.on_chip else " (off the chip)"))
 
     # streamed commits: whole batches, round-robin over next_device()
     lanes = commit_lanes(vals, commit)

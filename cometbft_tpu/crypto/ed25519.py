@@ -53,20 +53,29 @@ BUCKETS = (64, 256, 1024, 4096, 10240, 16384, 65536)
 # tools/trace_analyze.py device), at the live lane counts of the two
 # buckets the benchmark's cells use. A term is the line through its two
 # readings, fixed + n * per-lane (`python chip_smoke.py --terms` measures
-# both again; with `--chips 4` the mesh's too):
-#   lanes (bucket)    one point, 64 windows    the cached pair, 32 windows
-#                     (2026-09-28, PR 25)      (2026-10-01, PR 39)
-#   10,000 (10240)    20.48 ms                 15.45 ms
-#   65,000 (65536)    130.87 ms                98.69 ms
-# The single chip's ladder is given the pair (A, [2^128]A) that _A_CACHE
-# keeps; the mesh's shards keep no decompressed column and run the
-# one-point program, so the mesh's term keeps that program's line (over
-# the device count, plus the collective). The RLC/MSM engine read 120.19 /
-# 278.31 ms there and was removed by PR 28.
+# both again; with `--chips 4` the mesh's line beside this one over d):
+#   lanes (bucket)    the cached pair, 32 windows (2026-10-01, PR 39)
+#   10,000 (10240)    15.45 ms
+#   65,000 (65536)    98.69 ms
+# Both device engines run this program: the single chip's ladder is given
+# the pair (A, [2^128]A) that _A_CACHE keeps, a mesh's shards the pair the
+# engine staged on them (parallel/mesh.py), so the mesh's term is this
+# line's per-lane part over the device count, plus the collective. Read
+# on four v5e beside it (`chip_smoke.py --terms --chips 4`, 2026-10-04,
+# PR 44; device time a shard from the profile, the mean over the devices):
+#   lanes (bucket)    a shard of the mesh    this line over 4 + collective
+#   10,000 (10240)     3.889 ms               4.16 ms
+#   65,000 (65536)    24.701 ms              24.97 ms
+# which is 0.10 ms + n * 0.378 us: the per-lane part to the digit, the
+# fixed part 0.27 ms under what the model carries (it errs toward the
+# single chip). A column the mesh has not staged pays the staging program
+# first: 3.72 / 13.46 ms as a blocked call at the two buckets (the single
+# chip's miss: 8.62 / 46.95 ms). The model describes a hit, for both.
+# (The one-point, 64-window program read 20.48 / 130.87 ms, PR 25; nothing
+# launches it since PR 44. The RLC/MSM engine read 120.19 / 278.31 ms
+# there and was removed by PR 28.)
 _DEV_LADDER_FIXED_MS = 0.32  # v5e profile, 2026-10-01, PR 39 (the pair)
 _DEV_LADDER_US = 1.513       # the same two readings
-_DEV_MESH_FIXED_MS = 0.41    # v5e profile, 2026-09-28, PR 25 (one point)
-_DEV_MESH_US = 2.007         # the same two readings; a lane of ONE chip
 # The host-side per-sig term is CALIBRATED at the first dispatch decision
 # (_host_terms: one small timed pack_rsk) because it moves with the host:
 # core speed, toolchain presence. This is the fallback when that fails:
@@ -170,8 +179,9 @@ def dispatch_model(n: int, b: int) -> dict:
     }
     eng = _mesh_engine()
     if eng is not None and eng.n_devices > 1:
-        # Sharded-mesh term: the per-lane part of the ladder's device
-        # time splits d ways (its fixed part is paid by every shard) but
+        # Sharded-mesh term: a shard runs the ladder's own program on
+        # its lanes, so the per-lane part of the ladder's device time
+        # splits d ways (its fixed part is paid by every shard) but
         # the wire stage pays d separate shard stagings (each with the
         # calibrated fixed per-transfer cost) and every launch pays one
         # psum across the mesh. Host packing is the same 96 B/lane rsk
@@ -182,8 +192,8 @@ def dispatch_model(n: int, b: int) -> dict:
         terms = eng.dispatch_terms()
         mesh = {
             "wire": _WIRE_LADDER_B * b / bw + d * terms["put_fixed_s"],
-            "device": (_DEV_MESH_FIXED_MS * 1e-3
-                       + n * _DEV_MESH_US * 1e-6 / d
+            "device": (_DEV_LADDER_FIXED_MS * 1e-3
+                       + n * _DEV_LADDER_US * 1e-6 / d
                        + terms["collective_s"]),
             "host": ladder["host"],
         }
@@ -208,9 +218,9 @@ def _mesh_beats_single(n: int, b: int) -> bool:
 # it (csrc/ed25519_ifma.inc), portable C++ otherwise.
 NATIVE_MAX = 1024
 
-# The device terms of the dispatch model (_DEV_LADDER_*, _DEV_MESH_*: PR
-# 39's and PR 25's readings) and the wire-byte term were measured on ONE
-# device kind, a TPU v5e, which jax reports as this device_kind.
+# The device terms of the dispatch model (_DEV_LADDER_*: PR 39's
+# readings) and the wire-byte term were measured on ONE device kind, a
+# TPU v5e, which jax reports as this device_kind.
 # They are not re-derived per device: an accelerator of another kind is
 # an error (_accel_backed raises), not a v5e with different numbers.
 DEVICE_KIND = "TPU v5 lite"
@@ -678,20 +688,18 @@ class Ed25519BatchVerifier(BatchVerifier):
         """Shard one mega-batch over every mesh device: same 96 B/lane
         prehashed wire as the ladder path, padded so B divides the mesh
         (dead lanes ride live=False and are masked from the psum), with
-        the pubkey column staged once per validator set in the engine's
-        sharded cache. Returns a PendingBatch over the un-fetched
-        replicated all-ok scalar + sharded bitmap."""
-        import hashlib
-
+        the pubkey column decompressed once per validator set and kept
+        on the engine's shards: a column the engine has seen costs this
+        call one hash of its bytes, and no array of it is built.
+        Returns a PendingBatch over the un-fetched replicated all-ok
+        scalar + sharded bitmap."""
         from ..parallel.mesh import pad_to_shards
 
         n = self.count()
         b = pad_to_shards(n, eng.n_devices, bucket=_bucket(n))
         rsk, live, pub_blob = self._pack_rsk_live(n, b)
-        a_bytes = np.zeros((b, 32), np.uint8)
-        a_bytes[:n] = np.frombuffer(bytes(pub_blob), np.uint8).reshape(n, 32)
-        fp = hashlib.sha256(bytes(pub_blob)).digest()
-        all_ok, bits = eng.submit(a_bytes, rsk, live, fp=fp)
+        # the engine reads the column only when it is new to it
+        all_ok, bits = eng.submit(pub_blob, rsk, live)
         return PendingBatch(bits, all_ok, n, list(self._precheck_fail))
 
 

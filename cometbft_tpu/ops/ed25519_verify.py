@@ -26,13 +26,13 @@ from . import scalar as SC
 
 
 def verify_batch_prehashed(a_bytes, r_bytes, s_bytes, k_bytes, live):
-    """Batched ZIP-215 verify with the challenge scalar computed host-side.
+    """The tests' second implementation: one point, 64 windows, A
+    decompressed in the call. No engine launches it since PR 44 (both run
+    decompress_pubkeys once a column and verify_batch_cached_a a batch);
+    tests/test_mesh.py and tests/test_curve.py hold those two to it.
 
     k_bytes: (B, 32) uint8 little-endian canonical k = SHA-512(R||A||M)
-    mod L, hashed on the host. Shipping the 32-byte scalar instead of the
-    256-byte padded message block cuts host->device bytes 2.75x — on a
-    bandwidth-limited link that transfer, not the curve math, bounds
-    sustained throughput. The curve-side check is
+    mod L, hashed on the host. The curve-side check is
     [8]([S]B + [k](-A) - R) == identity with liberal decoding.
     """
     # the phases are utils/trace.KERNEL_SCOPES: names on the operations,

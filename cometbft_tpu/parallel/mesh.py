@@ -67,15 +67,50 @@ def pad_to_shards(n: int, parts: int, bucket: int | None = None) -> int:
     return -(-b // parts) * parts
 
 
-def sharded_verify_rsk_fn(mesh: Mesh, axes: str | tuple[str, ...] = "sig"):
-    """The production mesh verifier: prehashed 96-byte R||S||k lanes.
+def _specs(axes_t: tuple[str, ...]):
+    """(lanes-first spec, lanes-last spec) over the named axes: the byte
+    and flag arrays are (B, ...), a point's limb arrays (NLIMBS, B)."""
+    ax = axes_t if len(axes_t) > 1 else axes_t[0]
+    return P(ax), P(None, ax)
 
-    Inputs: a_bytes (B,32)u8 pubkey encodings, rsk (B,96)u8 packed
-    R||S||k rows (k = SHA-512(R||A||M) mod L hashed host-side — the
-    same wire diet the single-chip ladder path won with), live (B,)
-    bool. B must divide by the product of the named mesh axes
-    (pad_to_shards). Pubkey decompression runs in-shard so the staged
-    a_bytes can stay device-resident across submits (engine cache).
+
+def sharded_decompress_pubkeys_fn(
+        mesh: Mesh, axes: str | tuple[str, ...] = "sig"):
+    """The mesh's staging program: ops/ed25519_verify.decompress_pubkeys
+    in every shard, no collective.
+
+    In: a_bytes (B,32)u8 sharded over the named axes. Out: what the
+    single chip's decompress_pubkeys_jit returns, (ok_a, (-A,
+    [2^128](-A))), left where it was computed: ok_a (B,) sharded like
+    the bytes, every limb array of the two points (NLIMBS, B) sharded on
+    its last axis, the lanes. The mapped function is decompress_pubkeys
+    itself, so the program is jit(decompress_pubkeys) to jax.monitoring
+    and to a device trace, as the single chip's is."""
+    axes_t = (axes,) if isinstance(axes, str) else tuple(axes)
+    spec_b, spec_limbs = _specs(axes_t)
+    fn = _shard_map(
+        ed25519_verify.decompress_pubkeys,
+        mesh=mesh,
+        in_specs=(spec_b,),
+        out_specs=(spec_b, spec_limbs),
+        check_vma=False,
+    )
+    return jax.jit(fn)
+
+
+def sharded_verify_rsk_fn(mesh: Mesh, axes: str | tuple[str, ...] = "sig"):
+    """The production mesh verifier: prehashed 96-byte R||S||k lanes
+    against a column that sharded_decompress_pubkeys_fn staged.
+
+    Inputs: ok_a (B,) bool and a_points, the pair (-A, [2^128](-A)) with
+    limb arrays (NLIMBS, B), both as the staging program left them on
+    the shards; rsk (B,96)u8 packed R||S||k rows (k = SHA-512(R||A||M)
+    mod L hashed host-side — the same wire diet the single-chip ladder
+    path won with), live (B,) bool. B must divide by the product of the
+    named mesh axes (pad_to_shards). Every shard runs what the single
+    chip runs, ed25519_verify.verify_batch_cached_a: R's decompression
+    and the 32-window ladder over the kept pair; nothing of A is
+    computed here.
 
     Returns (all_ok scalar replicated, bits (B,) sharded). The
     invalid-lane count psums innermost-axis-first: on a hierarchical
@@ -84,20 +119,19 @@ def sharded_verify_rsk_fn(mesh: Mesh, axes: str | tuple[str, ...] = "sig"):
     """
     axes_t = (axes,) if isinstance(axes, str) else tuple(axes)
 
-    def local(a, rsk, live):
-        bits, _ = ed25519_verify.verify_batch_prehashed(
-            a, rsk[:, :32], rsk[:, 32:64], rsk[:, 64:], live
-        )
+    def local(ok_a, a_points, rsk, live):
+        bits, _ = ed25519_verify.verify_batch_cached_a(
+            ok_a, a_points, rsk, live)
         bad = jnp.sum((~bits & live).astype(jnp.int32))
         for ax in reversed(axes_t):  # innermost (fast) axis first
             bad = jax.lax.psum(bad, ax)
         return bad == 0, bits
 
-    spec_b = P(axes_t if len(axes_t) > 1 else axes_t[0])
+    spec_b, spec_limbs = _specs(axes_t)
     fn = _shard_map(
         local,
         mesh=mesh,
-        in_specs=(spec_b,) * 3,
+        in_specs=(spec_b, spec_limbs, spec_b, spec_b),
         out_specs=(P(), spec_b),
         check_vma=False,
     )
@@ -118,7 +152,17 @@ _A_CACHE_SIZE = 4
 
 
 class MeshVerifyEngine:
-    """Owns a device mesh and the compiled sharded verifiers for it.
+    """Owns a device mesh, the compiled sharded programs for it and the
+    validator columns staged on its shards.
+
+    What is kept (_a_cache, _A_CACHE_SIZE columns, keyed by the column's
+    sha256 and the padded batch size): ops/ed25519_verify's decompressed
+    pair (ok_a, (-A, [2^128](-A))) of a pubkey column, every shard
+    holding its own lanes (704 B a lane: 1.8 MB a device an entry at the
+    10240 bucket). Replay and consensus verify the SAME validator set
+    height after height, so A's sqrt decompression and the 128 doublings
+    run once a set and every submit's ladder is the single chip's 32
+    windows; a column not seen before pays the staging program once.
 
     Two serving modes, both driven from ed25519's dispatch:
 
@@ -152,10 +196,12 @@ class MeshVerifyEngine:
         else:
             self.axes = ("sig",)
             self.mesh = Mesh(np.asarray(devices), self.axes)
-        self._spec = P(self.axes if len(self.axes) > 1 else self.axes[0])
+        self._spec = _specs(self.axes)[0]
         self._sharding = NamedSharding(self.mesh, self._spec)
         self._fns: dict[int, object] = {}  # padded B -> compiled verifier
-        self._a_cache: dict = {}  # (sha256(pub col), B) -> staged a_bytes
+        self._stage = sharded_decompress_pubkeys_fn(self.mesh, self.axes)
+        # (sha256(pub col), B) -> staged (ok_a, (-A, [2^128](-A)))
+        self._a_cache: dict = {}
         self._rr = 0
         self._terms: dict | None = None
         crypto_metrics().mesh_devices.set(float(self.n_devices))
@@ -212,40 +258,58 @@ class MeshVerifyEngine:
             fn = self._fns[b] = sharded_verify_rsk_fn(self.mesh, self.axes)
         return fn
 
-    def stage_pubkeys(self, a_bytes: np.ndarray, fp=None):
-        """Device-put the (B,32) pubkey column with the batch sharding,
-        cached by content hash: replay verifies the SAME validator set
-        every height, so its 32 B/lane never re-cross the host link
-        (decompression itself runs in-shard each submit — cheaper to
-        recompute than to keep a limb-layout pytree cached per mesh)."""
-        b = a_bytes.shape[0]
-        if fp is None:
-            fp = hashlib.sha256(a_bytes.tobytes()).digest()
-        key = (fp, b)
+    @staticmethod
+    def _column(pubkeys, b: int) -> np.ndarray:
+        """The (b,32) array of a column's encodings, zero rows behind
+        the last key (dead lanes: live=False masks them)."""
+        rows = np.frombuffer(pubkeys, np.uint8).reshape(-1, 32)
+        a_bytes = np.zeros((b, 32), np.uint8)
+        a_bytes[:len(rows)] = rows
+        return a_bytes
+
+    def stage_pubkeys(self, pubkeys, b: int):
+        """The decompressed pair of a pubkey column on the shards:
+        (staged, "hit" | "miss"). `pubkeys` is the column's 32-byte
+        encodings as one buffer, at most b of them.
+
+        A column seen before (same bytes, same b) is a hit and costs a
+        dict lookup: nothing of A is built, crosses the link or is
+        computed. A miss pads the column to b rows, puts it on the
+        shards and runs the staging program there once
+        (sharded_decompress_pubkeys_fn); what that returns stays where
+        it was computed and is what every later submit of the column
+        hands the verifier. The oldest of more than _A_CACHE_SIZE
+        columns goes. crypto_a_cache_total{result} counts both, as it
+        does for the single chip's _A_CACHE."""
+        key = (hashlib.sha256(pubkeys).digest(), b)
         staged = self._a_cache.get(key)
+        a_cache = "miss" if staged is None else "hit"
+        crypto_metrics().a_cache_total.inc(1.0, a_cache)
         if staged is None:
-            staged = jax.device_put(a_bytes, self._sharding)
+            staged = self._stage(
+                jax.device_put(self._column(pubkeys, b), self._sharding))
             self._a_cache[key] = staged
             while len(self._a_cache) > _A_CACHE_SIZE:
                 self._a_cache.pop(next(iter(self._a_cache)))
-        return staged
+        return staged, a_cache
 
-    def submit(self, a_bytes: np.ndarray, rsk: np.ndarray,
-               live: np.ndarray, fp=None):
+    def submit(self, pubkeys, rsk: np.ndarray, live: np.ndarray):
         """Launch one sharded verify; returns un-fetched device arrays
-        (all_ok scalar, bits (B,)). B = a_bytes.shape[0] must be a
+        (all_ok scalar, bits (B,)). B = rsk.shape[0] must be a
         pad_to_shards() multiple of n_devices; dead lanes carry
-        live=False and are masked from the psum."""
-        b = a_bytes.shape[0]
+        live=False and are masked from the psum. `pubkeys` is
+        stage_pubkeys': the column is hashed every call and read only
+        when it is not staged yet."""
+        b = rsk.shape[0]
         if b % self.n_devices:
             raise ValueError(
                 f"batch {b} does not shard over {self.n_devices} devices "
                 "(pad with pad_to_shards)"
             )
         t0 = _time.perf_counter()
-        a_dev = self.stage_pubkeys(a_bytes, fp=fp)
+        (ok_a, a_points), a_cache = self.stage_pubkeys(pubkeys, b)
         rsk_dev, live_dev = jax.device_put((rsk, live), self._sharding)
-        all_ok, bits = self._fn(b)(a_dev, rsk_dev, live_dev)
+        all_ok, bits = self._fn(b)(ok_a, a_points, rsk_dev, live_dev)
         m = crypto_metrics()
         for i in range(self.n_devices):
             m.mesh_batches_total.inc(1.0, str(i), "shard")
@@ -255,6 +319,8 @@ class MeshVerifyEngine:
                 dur_ms=round((_time.perf_counter() - t0) * 1e3, 3),
                 n=int(live.sum()), b=b, n_devices=self.n_devices,
                 shard_lanes=b // self.n_devices,
+                a_cache=a_cache,
+                **({"bytes": b * 32} if a_cache == "miss" else {}),
             )
         return all_ok, bits
 

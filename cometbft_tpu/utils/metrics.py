@@ -608,10 +608,12 @@ class CryptoMetrics:
             labels=("mode",))
         self.a_cache_total = reg.counter(
             "crypto", "a_cache_total",
-            "Ladder launches by whether the batch's pubkey column was "
-            "already decompressed on the device (crypto/ed25519.py "
-            "_A_CACHE, keyed by the whole column and the bucket): hit, "
-            "or miss (the column ships and decompresses again)",
+            "Ladder and mesh launches by whether the batch's pubkey "
+            "column was already decompressed on the device "
+            "(crypto/ed25519.py _A_CACHE, a mesh's shards: "
+            "parallel/mesh.py; keyed by the whole column and the "
+            "bucket): hit, or miss (the column ships and decompresses "
+            "again)",
             labels=("result",))
         self.commit_path_total = reg.counter(
             "crypto", "commit_path_total",

@@ -627,7 +627,7 @@ SPAN_REGISTRY = {
     "crypto.native_verify": "one batch judged by the host C++ engine, blame rescan included (n/ok)",
     "crypto.verdict_wait": "result() blocking on one batch's verdict (path/n/batch = id of its crypto.batch_verify/since_submit_ms/blame_rerun); path = sched: a caller's wait on a shared-scheduler handle (SchedPending.result), batch = id of the crypto.sched_coalesce its request rode in, wake_ms = from the instant the request's verdict was set (on the completion thread) to result() returning on the caller's, 0 where the verdict was in before the call",
     "crypto.commit_partition": "one curve's leg of one commit, launch to verdict, a child of types.verify_commit that its sibling legs overlap (curve/path/n/own_ms = the leg's own time on the thread that ran it: the host engine's call on its worker thread, or submit() plus the blocked result() of a device batch/waited_ms = what result() blocked the caller for)",
-    "crypto.mesh_submit": "one sharded mega-batch across the verify mesh (n/b/n_devices/shard_lanes)",
+    "crypto.mesh_submit": "one sharded mega-batch across the verify mesh (n/b/n_devices/shard_lanes/a_cache = hit: the column's decompressed pair was on the shards, miss: the column ships, bytes of it, and the staging program runs before the verifier; untraced nodes read crypto_a_cache_total{result})",
     "crypto.stream_place": "one streamed commit placed on a mesh device (device/n/b)",
     "crypto.sched_collect": "the drainer between two dispatches, one a batch taken: from the entry of _collect to the return of _take, opened before and closed after the scheduler's lock (idle_ms = nothing queued/slot_ms = work queued and both slots of _MAX_UNANSWERED taken/linger_ms = the coalescing window and the pop that ends it: the three sum to dur_ms; the one the drainer is stopped in carries none of them) (crypto/sched.py)",
     "crypto.sched_coalesce": "one shared-scheduler dispatch on the drainer's thread: the merge and the launch (submit(); a cpu-backend or non-coalescable verifier verifies and answers inside it); it closes behind the launch, the verdict is the completion side's; its crypto.batch_verify and the requests' crypto.sched_wait are its children (n_requests/sigs/lanes_bucket/tenants/sources/per_tenant_sigs/absorb_ms = the merge loop, absent on the pass-through/inflight = earlier batches ON THE DEVICE and unanswered when the drainer took this one, 0 or 1: a host-engine batch ahead is not counted) (crypto/sched.py)",

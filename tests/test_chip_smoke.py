@@ -61,6 +61,83 @@ def test_chip_smoke_terms_runs_the_device_terms_phase_alone():
             f"{E._DEV_LADDER_US:.3f} us") in p.stdout
 
 
+@pytest.mark.slow  # XLA:CPU compiles the ladder once a virtual device:
+# 11 min cold on this box (6 min for the --terms variant)
+def test_chip_smoke_mesh_rehearsal_keeps_its_columns_and_lowers_both():
+    """--chips 4 on four virtual devices: the four launches of the mesh
+    phase read miss, hit, miss, hit (a column's decompressed pair stays
+    on the shards) and both sharded programs are lowered to look for
+    their kernels."""
+    p = _run([SMOKE, "--rehearse", "--chips", "4", "--seed", "7"],
+             {"COMETBFT_TPU_MESH": "on",
+              "XLA_FLAGS": "--xla_force_host_platform_device_count=4"},
+             timeout=2400)
+    assert p.returncode == 0, f"stdout={p.stdout[-3000:]}\nstderr={p.stderr[-3000:]}"
+    assert "crypto.mesh_submit a_cache: ['miss', 'hit', 'miss', 'hit']" in p.stdout
+    assert "sharded decompress_pubkeys[64/4 = 16 a shard]: " in p.stdout
+    assert "sharded_verify_rsk[64/4 = 16 a shard]: " in p.stdout
+    assert "   phase mesh-4: ok in " in p.stdout
+
+
+def test_terms_print_the_mesh_beside_the_ladders_line_over_d(
+        monkeypatch, capsys):
+    """--terms --chips 4, without its compiles: with a mesh up the phase
+    fits the mesh's two readings and prints beside the fit what the
+    model assumes, the ladder's own line with its per-lane part over the
+    device count plus the collective, and what the staging program
+    costs a miss. The engines' timings are stand-ins that follow the
+    model, so fit == assumed to the digit."""
+    import jax
+
+    import chip_smoke as S
+    from cometbft_tpu.crypto import ed25519 as E
+    from cometbft_tpu.crypto import ed25519_ref as ref
+    from cometbft_tpu.parallel.mesh import MeshVerifyEngine
+
+    cpus = jax.devices("cpu")
+    if len(cpus) < 4:
+        pytest.skip("needs 4 virtual devices")
+    eng = MeshVerifyEngine(cpus[:4])
+    monkeypatch.setattr(E, "_mesh_engine", lambda: eng)
+    line = {"ladder": lambda n: E.dispatch_model(n, 64)["ladder"]["device"],
+            "mesh": lambda n: E.dispatch_model(n, 64)["mesh"]["device"]}
+    monkeypatch.setattr(
+        S, "_engine_timings",
+        lambda probe, lanes, engine: {
+            "submit_ms": 1.0, "submit_to_verdict_ms": 2.0,
+            "device_ms": line[engine](len(lanes)) * 1e3})
+    staged = []
+    monkeypatch.setattr(
+        S, "_median_call_s",
+        lambda fn, a, kw: staged.append(fn) or 0.0022)
+
+    class _Probe:
+        on_chip = False
+
+        def latest(self, name, lanes):
+            return (name, (), {})
+
+    seed = bytes([7]) * 32
+    pub = E.Ed25519PubKey(ref.pubkey_from_seed(seed))
+    lanes = [(pub, b"terms-%d" % i, ref.sign(seed, b"terms-%d" % i))
+             for i in range(48)]
+    S.phase_device_terms(_Probe(), lanes, (24, 48), seed=0)
+    out = capsys.readouterr().out
+    collective_ms = eng.dispatch_terms()["collective_s"] * 1e3
+    assumed = (f"assumed {E._DEV_LADDER_FIXED_MS + collective_ms:.2f} ms + "
+               f"n x {E._DEV_LADDER_US / 4:.3f} us")
+    assert "mesh, NOT A DEVICE NUMBER (rehearsal): fixed " in out
+    fit = [ln for ln in out.splitlines() if ln.startswith("   mesh, ")][0]
+    assert fit.endswith(assumed), fit
+    assert (f"fixed {E._DEV_LADDER_FIXED_MS + collective_ms:.2f} ms + n x "
+            f"{E._DEV_LADDER_US / 4:.3f} us through") in fit
+    assert ("mesh   NOT A DEVICE NUMBER (rehearsal): "
+            f"{line['mesh'](24) * 1e3:.3f} ms in the profile") in out
+    assert ("an A-cache miss's sharded decompress_pubkeys 2.200 ms as a "
+            "blocked call") in out
+    assert eng._stage in staged  # the mesh's miss is its staging program
+
+
 def test_chip_smoke_refuses_to_start_without_a_chip():
     """No accelerator and no --rehearse: non-zero before any work, and no
     result line on stdout."""

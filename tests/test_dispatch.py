@@ -231,8 +231,9 @@ class _StubMesh:
     def next_device(self):
         return None  # the default device: streamed placement is not the case
 
-    def submit(self, a_bytes, rsk, live, fp=None):
-        assert a_bytes.shape[0] % self.n_devices == 0
+    def submit(self, pubkeys, rsk, live):
+        assert rsk.shape[0] % self.n_devices == 0
+        assert len(pubkeys) == 32 * int(live.sum())  # the blob, unpadded
         return np.asarray(True), np.asarray(live)
 
 
@@ -245,18 +246,20 @@ def test_mesh_term_absent_without_engine(monkeypatch):
 
 
 def test_mesh_flips_device_bound_batch(monkeypatch):
-    """Fast link, 8 chips: the per-lane part of the ladder's device
-    stage splits 8 ways (its fixed part does not) and the mesh becomes
-    HOST-bound at 12 ms — below the ladder's device stage (15.45 ms
-    since PR 39's pair), so dispatch must flip to mesh exactly where
-    splitting device time is what the batch needed."""
+    """Fast link, 8 chips: a shard runs the ladder's own program (the
+    pair the engine staged, PR 44), so the per-lane part of the ladder's
+    device stage splits 8 ways (its fixed part does not) and the mesh
+    becomes HOST-bound at 12 ms — below the ladder's device stage
+    (15.45 ms since PR 39's pair), so dispatch must flip to mesh exactly
+    where splitting device time is what the batch needed."""
     e = _pin_model(monkeypatch, link_mbps=1000.0, ladder_us=1.2)
     monkeypatch.setattr(e, "_mesh_engine", lambda: _StubMesh())
     m = e.dispatch_model(10000, 10240)
     assert m["n_devices"] == 8
     assert m["mesh"]["device"] == pytest.approx(
-        e._DEV_MESH_FIXED_MS * 1e-3
-        + 10000 * e._DEV_MESH_US * 1e-6 / 8 + 60e-6)
+        e._DEV_LADDER_FIXED_MS * 1e-3
+        + 10000 * e._DEV_LADDER_US * 1e-6 / 8 + 60e-6)
+    assert not hasattr(e, "_DEV_MESH_US")  # one line for both engines
     assert m["t_ladder"] == pytest.approx(m["ladder"]["device"])
     assert m["t_mesh"] == pytest.approx(10000 * 1.2e-6)  # host binds
     assert e._mesh_beats_single(10000, 10240)

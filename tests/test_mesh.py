@@ -14,12 +14,49 @@ def _batch(n):
     return g._example_batch(n)
 
 
+def _identity_keys():
+    """chip_smoke's two non-canonical encodings of the identity that
+    ZIP-215 accepts, and a signature that verifies under either for any
+    message: A = identity, so R = [S]B is the whole equation (R = B,
+    S = 1)."""
+    from chip_smoke import noncanonical_identity_keys
+
+    from cometbft_tpu.crypto import ed25519_ref as ref
+
+    e0, e1 = noncanonical_identity_keys()
+    sig = ref._encode_point(ref._Bx, ref._By) + (1).to_bytes(32, "little")
+    assert ref.verify(e0, b"any", sig) and ref.verify(e1, b"other", sig)
+    return e0, e1, sig
+
+
+def _invalid_key():
+    """A 32-byte string that is no point of the curve under ZIP-215."""
+    from cometbft_tpu.crypto import ed25519_ref as ref
+
+    y = 2
+    while ref._decode_point(y.to_bytes(32, "little"), zip215=True) is not None:
+        y += 1
+    return y.to_bytes(32, "little")
+
+
+def _rsk_row(pub, msg, sig):
+    import hashlib
+
+    from cometbft_tpu.crypto import ed25519_ref as ref
+
+    k = int.from_bytes(
+        hashlib.sha512(sig[:32] + pub + msg).digest(), "little") % ref.L
+    return np.frombuffer(sig + k.to_bytes(32, "little"), np.uint8)
+
+
 def test_sharded_verify_1d_and_2d_agree():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from cometbft_tpu.ops.ed25519_verify import decompress_pubkeys_jit
     from cometbft_tpu.parallel.mesh import (
         make_mesh,
         make_mesh_2d,
+        sharded_decompress_pubkeys_fn,
         sharded_verify_rsk_fn,
     )
 
@@ -29,17 +66,25 @@ def test_sharded_verify_1d_and_2d_agree():
     raw = _batch(64)
 
     mesh = make_mesh(cpus[:8])
-    fn = sharded_verify_rsk_fn(mesh)
-    args = [jax.device_put(a, NamedSharding(mesh, P("sig"))) for a in raw]
-    ok1, bits1 = jax.block_until_ready(fn(*args))
+    stage, fn = sharded_decompress_pubkeys_fn(mesh), sharded_verify_rsk_fn(mesh)
+    sh1 = NamedSharding(mesh, P("sig"))
 
     mesh2 = make_mesh_2d(cpus[:8], hosts=2)
-    fn2 = sharded_verify_rsk_fn(mesh2, ("host", "sig"))
-    args2 = [
-        jax.device_put(a, NamedSharding(mesh2, P(("host", "sig"))))
-        for a in raw
-    ]
-    ok2, bits2 = jax.block_until_ready(fn2(*args2))
+    axes2 = ("host", "sig")
+    stage2 = sharded_decompress_pubkeys_fn(mesh2, axes2)
+    fn2 = sharded_verify_rsk_fn(mesh2, axes2)
+    sh2 = NamedSharding(mesh2, P(axes2))
+
+    def run(stage_, fn_, sh, a, rsk, live):
+        """The engine's two programs: the column's pair staged on the
+        shards, then the verifier against it. -> (staged, ok, bits)."""
+        staged = stage_(jax.device_put(a, sh))
+        ok, bits = jax.block_until_ready(
+            fn_(*staged, *jax.device_put((rsk, live), sh)))
+        return staged, bool(ok), np.asarray(bits)
+
+    _, ok1, bits1 = run(stage, fn, sh1, *raw)
+    _, ok2, bits2 = run(stage2, fn2, sh2, *raw)
 
     assert bool(ok1) and bool(ok2)
     assert np.asarray(bits1).all() and np.asarray(bits2).all()
@@ -48,16 +93,38 @@ def test_sharded_verify_1d_and_2d_agree():
     # verdict must reflect the single bad lane on whichever shard holds it
     bad = [np.array(a, copy=True) for a in raw]
     bad[1][17, 32] ^= 1  # S of lane 17
-    argsb = [jax.device_put(a, NamedSharding(mesh, P("sig"))) for a in bad]
-    okb, bitsb = jax.block_until_ready(fn(*argsb))
-    args2b = [
-        jax.device_put(a, NamedSharding(mesh2, P(("host", "sig"))))
-        for a in bad
-    ]
-    ok2b, bits2b = jax.block_until_ready(fn2(*args2b))
+    _, okb, bitsb = run(stage, fn, sh1, *bad)
+    _, ok2b, bits2b = run(stage2, fn2, sh2, *bad)
     assert not bool(okb) and not bool(ok2b)
     assert not np.asarray(bitsb)[17] and not np.asarray(bits2b)[17]
     assert np.asarray(bitsb).sum() == 63 and np.asarray(bits2b).sum() == 63
+
+    # what the shards keep is what the single chip keeps, leaf for leaf,
+    # on either layout: a column with two non-canonical ZIP-215 keys
+    # (lane 9 with a signature that verifies, lane 10 with another
+    # key's) and a key that is no point (lane 23: ok_a false)
+    e0, e1, sig = _identity_keys()
+    odd = [np.array(a, copy=True) for a in raw]
+    odd[0][9] = np.frombuffer(e0, np.uint8)
+    odd[1][9] = _rsk_row(e0, b"lane 9", sig)
+    odd[0][10] = np.frombuffer(e1, np.uint8)
+    odd[0][23] = np.frombuffer(_invalid_key(), np.uint8)
+    one = decompress_pubkeys_jit(odd[0])
+    flat_one, tree_one = jax.tree_util.tree_flatten(one)
+    assert not np.asarray(one[0])[23] and np.asarray(one[0]).sum() == 63
+    for stage_, fn_, sh in ((stage, fn, sh1), (stage2, fn2, sh2)):
+        staged, ok, bits = run(stage_, fn_, sh, *odd)
+        flat, tree = jax.tree_util.tree_flatten(staged)
+        assert tree == tree_one and len(flat) == 9  # ok_a + 2 points x 4
+        for got, want in zip(flat, flat_one):
+            assert got.shape == want.shape and got.dtype == want.dtype
+            assert (np.asarray(got) == np.asarray(want)).all()
+            # left where it was computed: lanes (the last axis) sharded
+            assert got.sharding.is_equivalent_to(
+                NamedSharding(sh.mesh, P(*[None] * (got.ndim - 1),
+                                         sh.spec[0])), got.ndim)
+        assert not ok
+        assert [i for i in range(64) if not bits[i]] == [10, 23]
 
 
 def test_mesh_2d_shape_validation():
@@ -89,8 +156,10 @@ def eng8():
     return MeshVerifyEngine(cpus[:8])
 
 
-def _signed_items(n, corrupt=()):
-    seeds = [bytes([i % 5 + 1]) * 32 for i in range(4)]
+def _signed_items(n, corrupt=(), keys=0):
+    """n signed lanes over four keys; `keys` picks another four (another
+    validator column)."""
+    seeds = [bytes([(i + 4 * keys) % 251 + 1]) * 32 for i in range(4)]
     out = []
     for i in range(n):
         seed = seeds[i % 4]
@@ -103,13 +172,18 @@ def _signed_items(n, corrupt=()):
     return out
 
 
+def _verifier(items):
+    bv = E.Ed25519BatchVerifier()
+    for pub, msg, sig in items:
+        bv.add(E.Ed25519PubKey(pub), msg, sig)
+    return bv
+
+
 def _packed(items, parts, bucket=None):
     """Production packing: Ed25519BatchVerifier rsk pack + mesh padding."""
     from cometbft_tpu.parallel.mesh import pad_to_shards
 
-    bv = E.Ed25519BatchVerifier()
-    for pub, msg, sig in items:
-        bv.add(E.Ed25519PubKey(pub), msg, sig)
+    bv = _verifier(items)
     n = bv.count()
     b = pad_to_shards(n, parts, bucket=bucket)
     rsk, live, pub_blob = bv._pack_rsk_live(n, b)
@@ -196,6 +270,98 @@ def test_submit_rejects_nondivisible(eng8):
     a = np.zeros((10, 32), np.uint8)
     with pytest.raises(ValueError, match="pad_to_shards"):
         eng8.submit(a, np.zeros((10, 96), np.uint8), np.zeros(10, bool))
+
+
+@pytest.fixture
+def shard_shape_16(monkeypatch):
+    """_launch_mesh pads to the production buckets (64 lanes for 13);
+    without them 13 lanes pad to the 16 this file's other engine tests
+    compile: one shard shape, 2 lanes a device."""
+    monkeypatch.setattr(E, "_bucket", lambda n: n)
+
+
+def test_invalid_and_noncanonical_keys_match_single_chip(
+        eng8, shard_shape_16):
+    """ok_a rides with the staged column: a key that is no point reads
+    false in the mesh's bitmap as in the single chip's, a non-canonical
+    ZIP-215 key with a good signature true, with another key's false."""
+    e0, e1, sig = _identity_keys()
+    items = _signed_items(13)
+    items[4] = (_invalid_key(),) + items[4][1:]
+    items[7] = (e0, b"under the identity", sig)
+    items[8] = (e1,) + items[8][1:]
+    a_bytes, rsk, live = _packed(items, eng8.n_devices)
+    all_ok, bits = eng8.submit(a_bytes, rsk, live)
+    bits_mesh = np.asarray(bits)
+    bits_one, ok_one = _single_chip_bits(a_bytes, rsk, live)
+    assert not bool(np.asarray(all_ok)) and not ok_one
+    assert (bits_mesh == bits_one).all(), "bitmaps must be bit-exact"
+    assert [i for i in range(13) if not bits_mesh[i]] == [4, 8]
+    assert bits_mesh[7] and not bits_mesh[13:].any()
+    # the engine's verdict through the production launch, blame included
+    ok, blame = _verifier(items)._launch_mesh(eng8).result()
+    assert not ok and [i for i, x in enumerate(blame) if not x] == [4, 8]
+
+
+def test_staged_column_hit_miss_and_eviction(
+        eng8, shard_shape_16, monkeypatch, tmp_path):
+    """The engine keeps a column's decompressed pair on its shards: the
+    second launch of a column is a hit (the counter, the span; the
+    staging program is not called and no (b, 32) array is built), another
+    column is a miss, and the fifth column evicts the first."""
+    import json
+
+    from cometbft_tpu.parallel import mesh as M
+    from cometbft_tpu.utils import trace
+    from cometbft_tpu.utils.metrics import crypto_metrics
+
+    staged, built = [], []
+    stage, column = eng8._stage, eng8._column
+    monkeypatch.setattr(
+        eng8, "_stage", lambda a: staged.append(a.shape) or stage(a))
+    monkeypatch.setattr(
+        eng8, "_column",
+        lambda pubkeys, b: built.append(b) or column(pubkeys, b))
+    monkeypatch.setattr(eng8, "_a_cache", {})
+    counter = crypto_metrics().a_cache_total
+
+    def launch(keys):
+        before = dict(counter.values())
+        ok, bits = _verifier(_signed_items(13, keys=keys))._launch_mesh(
+            eng8).result()
+        assert ok and all(bits)
+        after = counter.values()
+        moved = {k[0]: after[k] - before.get(k, 0.0) for k in after
+                 if after[k] != before.get(k, 0.0)}
+        return moved, len(staged), len(built)
+
+    sink = str(tmp_path / "mesh_a_cache.jsonl")
+    trace.configure(sink)
+    try:
+        assert launch(0) == ({"miss": 1.0}, 1, 1)
+        assert staged == [(16, 32)] and built == [16]
+        assert launch(0) == ({"hit": 1.0}, 1, 1)   # nothing of A is built
+        assert launch(1) == ({"miss": 1.0}, 2, 2)  # another column
+        assert launch(0) == ({"hit": 1.0}, 2, 2)   # both are kept
+        first = next(iter(eng8._a_cache))
+        for keys in (2, 3):
+            assert launch(keys)[0] == {"miss": 1.0}
+        assert len(eng8._a_cache) == M._A_CACHE_SIZE == 4
+        assert first in eng8._a_cache
+        assert launch(4)[0] == {"miss": 1.0}       # the fifth column
+        assert len(eng8._a_cache) == 4 and first not in eng8._a_cache
+        assert launch(0) == ({"miss": 1.0}, 6, 6)  # staged afresh
+        trace.flush()
+    finally:
+        trace.disable()
+    with open(sink, encoding="utf-8") as f:
+        spans = [r for r in map(json.loads, f)
+                 if r["name"] == "crypto.mesh_submit"]
+    assert [r["a_cache"] for r in spans] == [
+        "miss", "hit", "miss", "hit", "miss", "miss", "miss", "miss"]
+    # a miss says the column's bytes; a hit ships nothing of A
+    assert all((r.get("bytes") == 16 * 32) == (r["a_cache"] == "miss")
+               for r in spans)
 
 
 def test_next_device_round_robin(eng8):

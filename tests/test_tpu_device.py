@@ -132,10 +132,11 @@ def _S(shape, dtype, sh):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
 
-def _ladder_args(b, sh, points=2):
-    """verify_batch_cached_a's arguments: the pair decompress_pubkeys
-    returns (32 windows), or one point (64, what the mesh's shards run)."""
-    return (_S((b,), jnp.bool_, sh), (_point(b, sh),) * points,
+def _ladder_args(b, sh, limbs_sh=None):
+    """verify_batch_cached_a's arguments with the pair decompress_pubkeys
+    returns (32 windows); `limbs_sh` where a point's (NLIMBS, b) arrays
+    are laid out otherwise than the (b, ...) ones (a mesh: lanes last)."""
+    return (_S((b,), jnp.bool_, sh), (_point(b, limbs_sh or sh),) * 2,
             _S((b, 96), jnp.uint8, sh), _S((b,), jnp.bool_, sh))
 
 
@@ -199,30 +200,63 @@ def test_ladder_compiles_for_v5e(chip, b):
     assert _kernels(c) == 2
 
 
+def _four_chips(topo):
+    """(mesh, lanes-first sharding, lanes-last sharding, b): the engine's
+    layout of the 10240 bucket over the four described devices."""
+    mesh = Mesh(np.asarray(topo.devices), ("sig",))
+    b = M.pad_to_shards(10_000, 4, bucket=E._bucket(10_000))
+    return (mesh, NamedSharding(mesh, P("sig")),
+            NamedSharding(mesh, P(None, "sig")), b)
+
+
 def test_sharded_verifier_compiles_for_four_chips(topo, chip):
     """The shard_map verifier of MeshVerifyEngine over a 4-device Mesh
-    built from the described devices: 10240 lanes, 2560 a shard."""
-    mesh = Mesh(np.asarray(topo.devices), ("sig",))
-    sh = NamedSharding(mesh, P("sig"))
-    b = M.pad_to_shards(10_000, 4, bucket=E._bucket(10_000))
+    built from the described devices: 10240 lanes, 2560 a shard, against
+    the pair the staging program left on the shards (limbs sharded on
+    their last axis). A shard runs the single chip's program: nothing of
+    A is computed in it."""
+    mesh, sh, sh_limbs, b = _four_chips(topo)
     fn = M.sharded_verify_rsk_fn(mesh, ("sig",))
-    compiled = fn.lower(
-        _S((b, 32), jnp.uint8, sh), _S((b, 96), jnp.uint8, sh),
-        _S((b,), jnp.bool_, sh),
-    ).compile()
-    assert _kernels(compiled) == 3  # A, R decompress + ladder
-    assert "all-reduce" in compiled.as_text()  # the invalid-lane psum
+    compiled = fn.lower(*_ladder_args(b, sh, limbs_sh=sh_limbs)).compile()
+    assert _kernels(compiled) == 2  # R's decompression + the ladder
+    text = compiled.as_text()
+    assert "curve_ladder_sub_mul8" in text and "curve_mul_2_128" not in text
+    assert "all-reduce" in text  # the invalid-lane psum
+    assert compiled.memory_analysis().temp_size_in_bytes < TEMP_BUDGET_BYTES
+
+
+def test_sharded_staging_compiles_for_four_chips(topo, chip):
+    """The engine's staging program, decompress_pubkeys in every shard:
+    A's decompression and the 128 doublings at 2560 lanes a shard, no
+    collective, the pair left sharded on the lanes; and it is
+    jit(decompress_pubkeys) to jax.monitoring, which the benchmark's
+    "no verify program compiles inside the window" check matches."""
+    mesh, sh, sh_limbs, b = _four_chips(topo)
+    fn = M.sharded_decompress_pubkeys_fn(mesh, ("sig",))
+    lowered = fn.lower(_S((b, 32), jnp.uint8, sh))
+    assert "jit_decompress_pubkeys" in lowered.as_text()[:400]
+    compiled = lowered.compile()
+    assert _kernels(compiled) == 2
+    text = compiled.as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for kernel in ("curve_decompress", "curve_mul_2_128"):
+        assert any(n.endswith(f"/{kernel}/pallas_call") for n in names)
+    assert "all-reduce" not in text and "all-gather" not in text
+    ok_a, (neg_a, neg_a_hi) = compiled.output_shardings
+    assert ok_a.is_equivalent_to(sh, 1)
+    for limb in (*neg_a, *neg_a_hi):
+        assert limb.is_equivalent_to(sh_limbs, 2)
     assert compiled.memory_analysis().temp_size_in_bytes < TEMP_BUDGET_BYTES
 
 
 @pytest.mark.parametrize("b", [2560] + [
     pytest.param(b, marks=pytest.mark.slow) for b in (1024, 4096, 16384)])
-def test_one_point_ladder_compiles_at_the_mesh_shard_widths(chip, b):
-    """Given one point the ladder is the 64-window program the mesh's
-    shards run (verify_batch_prehashed keeps no column on the device):
-    a quarter of each bucket from MESH_MIN up."""
+def test_pair_ladder_compiles_at_the_mesh_shard_widths(chip, b):
+    """A mesh's shard runs the single chip's program on a quarter of
+    the bucket (the pair the engine staged, 32 windows): each bucket
+    from MESH_MIN up, at the width a shard of four sees."""
     assert b * 4 in E.BUCKETS and b * 4 >= E.MESH_MIN
-    c = _compile(EV.verify_batch_cached_a, *_ladder_args(b, chip, points=1),
+    c = _compile(EV.verify_batch_cached_a, *_ladder_args(b, chip),
                  kernels=("curve_decompress", "curve_ladder_sub_mul8"))
     assert _kernels(c) == 2
 
