@@ -459,7 +459,7 @@ def cmd_replica(args) -> int:
     bootstrap from a core node's replication snapshot, tail its feed,
     and serve the light/DA surfaces byte-identically with zero
     consensus state. Prints one JSON line with the bound addresses so
-    drivers (tools/workloads.py --city --replicas) can discover the
+    drivers (the load tools' --endpoints) can discover the
     ephemeral ports."""
     from .replication import Replica
 

@@ -434,7 +434,7 @@ class PeerState:
             self.round = m.round
             self.step = m.step
             self.last_commit_round = m.last_commit_round
-        # per-peer reactor state gauges (VERDICT Next #3: rejoin-stall
+        # per-peer reactor state gauges (rejoin-stall
         # debugging needs every peer's view of height/round exported)
         pid = getattr(self.peer, "id", "") or ""
         if pid:

@@ -810,15 +810,6 @@ class DonePending:
         return self._ok, self._bits
 
 
-# Kept only for benchmark/ (a `simplicity` PR may not edit it):
-# benchmark/harness/faults.py:20 patches E.PendingRLC beside PendingBatch
-# and DonePending. accept_all then wraps PendingBatch.result twice, which
-# is harmless: the outer wrapper discards the inner's bits. ROADMAP D9's
-# `benchmark` PR removes that read and this line. Nothing in the program,
-# its tests or its tools may use the name.
-PendingRLC = PendingBatch
-
-
 def collect_pending(pendings: list[PendingBatch]) -> list[tuple[bool, list[bool]]]:
     """Resolve many in-flight batches with ONE tiny device→host transfer.
 

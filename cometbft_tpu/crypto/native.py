@@ -423,15 +423,6 @@ def pack_rsk(n: int, sig_blob, pub_blob, msg_blob,
     )
 
 
-# Kept only for benchmark/ (a `simplicity` PR may not edit it):
-# benchmark/run.py:151 refuses to start unless this is true. The RLC
-# packer it asked about went with PR 28; ROADMAP D9's `benchmark` PR
-# removes that read and this function. Nothing in the program, its tests
-# or its tools may use the name.
-def rlc_available() -> bool:
-    return available()
-
-
 def commit_parse(buf: bytes):
     """Columnar parse of a Commit wire buffer's signature list in one C
     call. Returns (height_u64, round_u64, bid_span, cols) where cols =

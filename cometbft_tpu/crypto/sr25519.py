@@ -276,8 +276,8 @@ def _verify_rlc(items) -> bool:
     # mod-L residue + the Pippenger identity check in ONE ctypes call
     # (csrc/sr25519_native.inc). The per-signature Python below — one
     # sqrt chain per decode, ~8 keccaks of STROBE bookkeeping per
-    # transcript — was the ~200 ms/1000-sig wall PROFILE.md round 5
-    # charged to "sr residue"; it stays as oracle and fallback.
+    # transcript — cost about 200 ms a 1000 signatures on one host
+    # core before the native path; it stays as oracle and fallback.
     if any(len(p) != 32 or len(s) != SIG_SIZE for p, _, s in items):
         return False  # can't blob columnar; Python loop rejects too
     got = native.sr25519_batch_verify(

@@ -365,7 +365,7 @@ class P2PMetrics:
         self.message_send_bytes_total = reg.counter(
             "p2p", "message_send_bytes_total", "Bytes sent",
             labels=("chan",))
-        # Per-peer reactor state (VERDICT Next #3: the rejoin-stall
+        # Per-peer reactor state (the rejoin-stall
         # debugging data) — fed from the consensus reactor's PeerState.
         self.peer_height = reg.gauge(
             "p2p", "peer_height", "Last known consensus height per peer",

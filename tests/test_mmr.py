@@ -19,7 +19,7 @@ from cometbft_tpu.light.mmr import MMR, MMRProof, peak_heights, peak_positions
 from cometbft_tpu.light.store import MMRStore
 from cometbft_tpu.storage import MemKV
 
-PROOF_SIZE_C = 96  # bytes per log2(n) — the gate constant PROFILE.md pins
+PROOF_SIZE_C = 96  # bytes per log2(n): the bound the proof-size tests hold
 
 
 def _leaves(n, tag=b"hdr"):

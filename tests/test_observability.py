@@ -606,128 +606,6 @@ def test_exemplar_exposition_is_opt_in():
         srv.stop()
 
 
-def test_bench_compare_advisory_never_gates():
-    """tools/bench_compare.py --advisory: tier-1's regression guardrail
-    is informational — rc 0 regardless of what the diff says, and a
-    tight threshold still renders the table instead of failing."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    p = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "bench_compare.py"),
-         "--advisory", "--threshold", "0.001"],
-        capture_output=True, text=True, timeout=120,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert p.returncode == 0, p.stderr
-    assert "bench_compare:" in p.stdout
-
-
-def test_bench_compare_bls_advisory_never_gates():
-    """tools/bench_compare.py --bls --advisory: the ed25519-vs-BLS
-    crossover diff is informational in tier-1 — rc 0 whether the
-    WORKLOADS.json record exists on both sides, one side, or regressed
-    — and the crossover line always renders."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    p = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "bench_compare.py"),
-         "--bls", "--advisory", "--threshold", "0.001"],
-        capture_output=True, text=True, timeout=120,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert p.returncode == 0, p.stderr
-    assert "bls crossover" in p.stdout
-    assert "bench_compare:" in p.stdout
-
-
-def test_bench_compare_pc_advisory_never_gates():
-    """tools/bench_compare.py --pc --advisory: the polynomial-
-    commitment DAS diff is informational in tier-1 — rc 0 whether the
-    das_pc record exists on both sides, one side, or regressed — and
-    the lying-encoder line always renders."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    p = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "bench_compare.py"),
-         "--pc", "--advisory", "--threshold", "0.001"],
-        capture_output=True, text=True, timeout=120,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert p.returncode == 0, p.stderr
-    assert "das pc" in p.stdout
-    assert "bench_compare:" in p.stdout
-
-
-def test_bench_compare_city_advisory_never_gates():
-    """tools/bench_compare.py --city --advisory: the city-combined
-    workload diff (shared-scheduler coalesce factor first-class) is
-    informational in tier-1 — rc 0 whether the WORKLOADS.json record
-    exists on both sides, one side, or regressed — and the city line
-    always renders."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    p = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "bench_compare.py"),
-         "--city", "--advisory", "--threshold", "0.001"],
-        capture_output=True, text=True, timeout=120,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert p.returncode == 0, p.stderr
-    assert "city combined" in p.stdout
-    assert "bench_compare:" in p.stdout
-
-
-def test_bench_compare_replicated_advisory_never_gates():
-    """tools/bench_compare.py --replicas --advisory: the scale-out
-    serving-plane diff (zero-gap / byte-identity invariants
-    first-class) is informational in tier-1 — rc 0 whether the
-    city_replicated record exists on both sides, one side, or
-    regressed — and the replicated line always renders."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    p = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "bench_compare.py"),
-         "--replicas", "--advisory", "--threshold", "0.001"],
-        capture_output=True, text=True, timeout=120,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert p.returncode == 0, p.stderr
-    assert "city replicated" in p.stdout
-    assert "bench_compare:" in p.stdout
-
-
-def test_bench_compare_certnative_advisory_never_gates():
-    """tools/bench_compare.py --certnative --advisory: the certificate-
-    native diff (cert-vs-column verdict pins and the one-pairing-per-
-    block replay invariant first-class) is informational in tier-1 —
-    rc 0 whether the certnative record exists on both sides, one side,
-    or regressed — and the certnative line always renders."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    p = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "bench_compare.py"),
-         "--certnative", "--advisory", "--threshold", "0.001"],
-        capture_output=True, text=True, timeout=120,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert p.returncode == 0, p.stderr
-    assert "certnative" in p.stdout
-    assert "bench_compare:" in p.stdout
-
-
-def test_bench_compare_watchtower_advisory_never_gates():
-    """tools/bench_compare.py --watchtower --advisory: the auditor leg
-    is informational for throughput, but its two absolute invariants —
-    zero false positives on the clean leg and audit-latency p99 inside
-    its budget — are checked against the CURRENT record regardless of
-    whether a baseline exists. rc 0 either way in advisory mode, and
-    the watchtower line always renders."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    p = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "bench_compare.py"),
-         "--watchtower", "--advisory", "--threshold", "0.001"],
-        capture_output=True, text=True, timeout=120,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert p.returncode == 0, p.stderr
-    assert "watchtower" in p.stdout
-    assert "bench_compare:" in p.stdout
-
-
 def test_metrics_doc_is_current():
     """tools/metrics_doc.py --check: METRICS.md is generated from the
     registered bundles; a new or renamed metric without a regenerated

@@ -254,7 +254,7 @@ def test_pex_gossip_and_dial(tmp_path):
         # C dials B; pex request/response should teach C about A.
         # Load-adaptive: under a full-suite run the one-shot request can
         # race reactor startup, so re-ask periodically instead of
-        # sleeping a fixed schedule (VERDICT r3 flake #2).
+        # sleeping a fixed schedule (it flaked in round 3).
         from cometbft_tpu.p2p.pex import PEX_CHANNEL, encode_pex_request
 
         peer_b = sw_c.dial_peer(host_b, int(port_b))

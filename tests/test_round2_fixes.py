@@ -1,4 +1,4 @@
-"""Regression tests for round-2 correctness fixes (ADVICE r1 + VERDICT r1).
+"""Regression tests for round-2 correctness fixes (from round 1's review).
 
 Covers:
 - median_time reference semantics (NIL timestamps counted, >= total/2 pick)

@@ -20,8 +20,7 @@ serving surface:
 - the native GF(2^16) codec is timed against the numpy oracle on a
   proposal-sized payload (same parity, differentially checked here).
 
-Emits one JSON object on stdout; tools/workloads.py wraps it as the
-machine-gated `das_sampling_1000c` workload.
+Emits one JSON object on stdout.
 """
 
 from __future__ import annotations

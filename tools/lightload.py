@@ -20,8 +20,7 @@ against it:
   how many clients asked.
 
 A small tx producer keeps blocks committing underneath. Emits one JSON
-object on stdout; tools/workloads.py wraps it as the machine-gated
-`light_stream_10000c` workload.
+object on stdout.
 """
 
 from __future__ import annotations

@@ -19,8 +19,7 @@ Two admission modes make the tentpole comparison:
 `--signed` wraps every tx in the STX ed25519 envelope so admission
 exercises the batch signature-verify stage.
 
-Emits one JSON object on stdout; tools/workloads.py wraps this as the
-machine-gated `ingest_sustained_load` workload.
+Emits one JSON object on stdout.
 """
 
 from __future__ import annotations
@@ -108,7 +107,7 @@ def _build_node(home: str, mode: str, window: int, delay_ms: float,
         cfg.mempool.admission_max_delay_ms = delay_ms
     # both modes verify STX signatures when --signed: per-tx mode does a
     # native single-verify per tx, batched mode one batch verify per
-    # window — the comparison the PROFILE round records
+    # window: what --mode batched and --mode pertx differ by
     cfg.mempool.admission_verify_sigs = signed
     if lifecycle_rate is not None:
         # trace sink inside the tempdir home -> tx.lifecycle records land
