@@ -9,10 +9,31 @@ from __future__ import annotations
 
 from abc import ABC
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 from ..types import Timestamp, ZERO_TIME
 
 CODE_TYPE_OK = 0
+
+
+class EventAttribute(NamedTuple):
+    """One attribute of an ABCI event (reference abci/types Event
+    Attribute). `index` is the application's mark: an indexer writes a key
+    for a marked attribute and none for an unmarked one. A tuple, so an
+    application may share the attributes that never change."""
+
+    key: str
+    value: str
+    index: bool = False
+
+
+class Event(NamedTuple):
+    """One ABCI event: what an application reports of a transaction or of
+    a block, for subscribers and indexers. Never part of consensus:
+    last_results_hash leaves events out (ExecTxResult.encode)."""
+
+    type: str
+    attributes: list[EventAttribute]
 
 
 @dataclass
@@ -29,7 +50,7 @@ class ExecTxResult:
     log: str = ""
     gas_wanted: int = 0
     gas_used: int = 0
-    events: list = field(default_factory=list)
+    events: list[Event] = field(default_factory=list)
 
     def is_ok(self) -> bool:
         return self.code == CODE_TYPE_OK
@@ -149,7 +170,7 @@ class FinalizeBlockRequest:
 
 @dataclass
 class FinalizeBlockResponse:
-    events: list = field(default_factory=list)
+    events: list[Event] = field(default_factory=list)
     tx_results: list[ExecTxResult] = field(default_factory=list)
     validator_updates: list[ValidatorUpdate] = field(default_factory=list)
     consensus_param_updates: object | None = None

@@ -136,7 +136,37 @@ def dec_finalize_req(buf: bytes) -> T.FinalizeBlockRequest:
     )
 
 
+def _enc_events(field: int, events) -> bytes:
+    """Each event as the reference's Event {1 type, 2 repeated
+    EventAttribute {1 key, 2 value, 3 index}} under `field`; nothing for
+    no events."""
+    return b"".join(
+        pb.f_embedded(field, pb.f_string(1, etype) + b"".join(
+            pb.f_embedded(2, pb.f_string(1, key) + pb.f_string(2, value)
+                          + pb.f_varint(3, 1 if index else 0))
+            for key, value, index in attrs))
+        for etype, attrs in events)
+
+
+def _dec_event(buf: bytes) -> T.Event:
+    etype, attrs = "", []
+    for f, _, v in pb.parse_fields(buf):
+        if f == 1:
+            etype = pb.as_bytes(v).decode("utf-8", "replace")
+        elif f == 2:
+            d = pb.fields_to_dict(pb.as_bytes(v))
+            attrs.append(T.EventAttribute(
+                pb.as_bytes(d.get(1, b"")).decode("utf-8", "replace"),
+                pb.as_bytes(d.get(2, b"")).decode("utf-8", "replace"),
+                bool(d.get(3, 0))))
+    return T.Event(etype, attrs)
+
+
 def enc_finalize_resp(r: T.FinalizeBlockResponse) -> bytes:
+    """The response as the socket and gRPC transports frame it and as the
+    state store keeps it. A result's events are the reference's field 7,
+    the block's own events field 4; a response without events encodes to
+    the bytes it encoded to before events were carried."""
     parts = [
         pb.f_embedded(
             1,
@@ -144,7 +174,8 @@ def enc_finalize_resp(r: T.FinalizeBlockResponse) -> bytes:
             + pb.f_bytes(2, tr.data)
             + pb.f_string(3, tr.log)
             + pb.f_varint(5, tr.gas_wanted)
-            + pb.f_varint(6, tr.gas_used),
+            + pb.f_varint(6, tr.gas_used)
+            + (_enc_events(7, tr.events) if tr.events else b""),
         )
         for tr in r.tx_results
     ]
@@ -158,6 +189,8 @@ def enc_finalize_resp(r: T.FinalizeBlockResponse) -> bytes:
         for vu in r.validator_updates
     ]
     parts.append(pb.f_bytes(3, r.app_hash))
+    if r.events:
+        parts.append(_enc_events(4, r.events))
     return b"".join(parts)
 
 
@@ -165,13 +198,16 @@ def dec_finalize_resp(buf: bytes) -> T.FinalizeBlockResponse:
     resp = T.FinalizeBlockResponse()
     for f, _, v in pb.parse_fields(buf):
         if f == 1:
-            td = pb.fields_to_dict(pb.as_bytes(v))
+            fields = pb.parse_fields(pb.as_bytes(v))
+            td = {tf: tv for tf, _, tv in fields}
             resp.tx_results.append(T.ExecTxResult(
                 code=int(td.get(1, 0)),
                 data=pb.as_bytes(td.get(2, b"")),
                 log=pb.as_bytes(td.get(3, b"")).decode("utf-8", "replace"),
                 gas_wanted=pb.to_i64(td.get(5, 0)),
                 gas_used=pb.to_i64(td.get(6, 0)),
+                events=[_dec_event(pb.as_bytes(tv))
+                        for tf, _, tv in fields if tf == 7],
             ))
         elif f == 2:
             vd = pb.fields_to_dict(pb.as_bytes(v))
@@ -182,6 +218,8 @@ def dec_finalize_resp(buf: bytes) -> T.FinalizeBlockResponse:
             ))
         elif f == 3:
             resp.app_hash = pb.as_bytes(v)
+        elif f == 4:
+            resp.events.append(_dec_event(pb.as_bytes(v)))
     return resp
 
 

@@ -89,8 +89,10 @@ def cmd_start(args) -> int:
         # flag overrides config (reference --p2p.seed_mode)
         cfg.p2p.seed_mode = True
         cfg.validate()
+    # the built-in app answers as the reference's kvstore does: two `app`
+    # events a transaction, which the node's indexer writes keys for
     app = (
-        KVStoreApp(snapshot_interval=cfg.base.snapshot_interval)
+        KVStoreApp(snapshot_interval=cfg.base.snapshot_interval, events=True)
         if cfg.base.abci == "local" else None
     )
     node = Node(cfg, app=app)
@@ -340,8 +342,9 @@ def cmd_compact_db(args) -> int:
 
 def cmd_reindex_event(args) -> int:
     """reference commands/reindex_event.go: rebuild the tx and block
-    indexes from the block store + stored ABCI responses, a batch a block
-    into the files a node with `[tx_index] indexer = "kv"` opens."""
+    indexes from the block store + stored ABCI responses (whose events
+    give every attribute key back), a batch a block into the files a node
+    with `[tx_index] indexer = "kv"` opens."""
     from .abci import wire as W
     from .config import Config
     from .storage import BlockStore, StateStore, open_kv

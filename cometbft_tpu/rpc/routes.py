@@ -329,6 +329,16 @@ def commit(env, params):
     }
 
 
+def _events_json(events) -> list:
+    """ABCI events as the reference's RPC shows them."""
+    return [
+        {"type": etype,
+         "attributes": [{"key": key, "value": value, "index": bool(index)}
+                        for key, value, index in attrs]}
+        for etype, attrs in events
+    ]
+
+
 def block_results(env, params):
     h = _get_height(env, params)
     if env.state_store is None:
@@ -347,9 +357,11 @@ def block_results(env, params):
                 "log": tr.log,
                 "gas_wanted": str(tr.gas_wanted),
                 "gas_used": str(tr.gas_used),
+                "events": _events_json(tr.events),
             }
             for tr in resp.tx_results
         ]
+        out["finalize_block_events"] = _events_json(resp.events)
         out["validator_updates"] = [
             {
                 "pub_key": _hx(vu.pub_key_bytes),

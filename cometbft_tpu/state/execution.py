@@ -453,7 +453,10 @@ class BlockExecutor:
             _txlife.stage_block(life, "commit", height=h_)
         fail_point()  # reference execution.go:301 (post-Commit, pre-save)
         state_save_s = state_encode_s = state_write_s = 0.0
-        set_encodes = 0
+        set_encodes = response_bytes = 0
+        n_events = len(resp.events) + sum(
+            len(tr.events) for tr in resp.tx_results)
+        state_metrics().abci_events_total.inc(n_events)
         store = self.state_store
         if store is not None:
             from ..abci import wire as _W
@@ -470,6 +473,7 @@ class BlockExecutor:
             t_resp = _time.perf_counter()
             payload = _W.enc_finalize_resp(resp)
             state_encode_s = _time.perf_counter() - t_resp
+            response_bytes = len(payload)
             store.save_abci_responses(block.header.height, payload)
             state_save_s = _time.perf_counter() - t_commit
             state_metrics().state_save_seconds.observe(state_save_s)
@@ -512,6 +516,8 @@ class BlockExecutor:
                 state_write_ms=round(state_write_s * 1e3, 3),
                 set_encodes=set_encodes,
                 tx_bytes=sum(map(len, block.data.txs)),
+                events=n_events,
+                response_bytes=response_bytes,
             )
         return new_state
 

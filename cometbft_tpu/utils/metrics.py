@@ -423,6 +423,11 @@ class StateMetrics:
             "encoding included: the state with both validator sets, the "
             "results' hash and the encoded FinalizeBlockResponse (observed "
             "only with a state store)", buckets=TX_STAGE_BUCKETS)
+        self.abci_events_total = reg.counter(
+            "abci", "events_total",
+            "ABCI events in the FinalizeBlockResponses of applied blocks: "
+            "the block's own and every transaction result's (0 from an "
+            "application that emits none)")
 
 
 class StoreMetrics:
@@ -457,6 +462,12 @@ class IndexerMetrics:
             "whose write failed, the blocks queued behind it, a block "
             "published after the service stopped. A slow indexer drops "
             "none: it holds ApplyBlock back")
+        self.attr_keys_total = reg.counter(
+            "indexer", "attr_keys_total",
+            "Attribute keys the indexer service wrote to the tx index: one "
+            "a distinct (type.key, value) a transaction's events carried "
+            "marked for indexing (0 from an application that emits no "
+            "events)")
         self.blocks_held = reg.gauge(
             "indexer", "blocks_held",
             "Blocks published to the indexer service and not yet written "

@@ -607,8 +607,8 @@ SPAN_REGISTRY = {
     "consensus.finalize_commit": "block decided at height/round, with tx count",
     "consensus.propose_speculative": "one speculative proposal assembly overlapping the previous height's commit gap (height/txs/bytes)",
     "state.valset_update": "a block's validator updates applied to the set of two heights on, and the new set hashed (height/changes)",
-    "state.apply_block": "ApplyBlock with validate/finalize/commit/save stage breakdown (validate_ms/finalize_ms/update_state_ms = the next state built: the validator updates and the proposer rotation/commit_ms = the app's Commit and the mempool's update/save_events_ms: five stages in order, which sum to dur_ms less the clock reads; data_hash_ms = inside validate_ms, the transactions' hashes and their Merkle root; state_save_ms = inside save_events_ms, the block's three state-store records encoded and written: the state with both validator sets, the results' hash, the encoded FinalizeBlockResponse, 0 without a state store; state_encode_ms = the part of state_save_ms spent building those bytes; state_write_ms = the part inside the key-value store's write_batch / set, the three commits; set_encodes = validator sets encoded afresh for those records, the rest of the five were looked up on a frozen set (ValidatorSet.encode; untraced nodes read state_valset_encode_total{memo}); tx_bytes = bytes of the block's transactions; rotation = column|integer: the arithmetic that rotated the proposer, ValidatorSet._rotate; publish_ms = inside save_events_ms, the event bus on this thread (EventBus.publish_block: the buffered subscribers' messages, if anyone subscribed, and the hand-over to the indexer) less index_wait_ms = what the indexer service held this thread back for, MAX_BLOCKS_HELD blocks published and not yet written; both 0 without a bus, the second 0 without an indexer)",
-    "index.block": "one block's events written by the indexer service, on its own thread, a root (height/txs/tx_bytes/keys = key-value pairs of the batch: a record and a height key a transaction, a key an indexed attribute, the block's record/bytes = their keys and values/page_bytes = the size of the tx index file's pages, read once at open: TX_INDEX_PAGE_BYTES for a file made since PR 43, 4096 for one made before, 0 in memory/encode_ms = building the batch/write_ms = inside the two stores' write_batch and set: the tx index's one commit and the block index's/behind = blocks published and unwritten as this one's write ends, itself included: 1 when the service keeps up, MAX_BLOCKS_HELD when apply is being held back; untraced nodes read indexer_blocks_held, indexer_txs_indexed_total, indexer_blocks_indexed_total, indexer_events_dropped_total) (storage/indexer.py)",
+    "state.apply_block": "ApplyBlock with validate/finalize/commit/save stage breakdown (validate_ms/finalize_ms/update_state_ms = the next state built: the validator updates and the proposer rotation/commit_ms = the app's Commit and the mempool's update/save_events_ms: five stages in order, which sum to dur_ms less the clock reads; data_hash_ms = inside validate_ms, the transactions' hashes and their Merkle root; state_save_ms = inside save_events_ms, the block's three state-store records encoded and written: the state with both validator sets, the results' hash, the encoded FinalizeBlockResponse, 0 without a state store; state_encode_ms = the part of state_save_ms spent building those bytes; state_write_ms = the part inside the key-value store's write_batch / set, the three commits; set_encodes = validator sets encoded afresh for those records, the rest of the five were looked up on a frozen set (ValidatorSet.encode; untraced nodes read state_valset_encode_total{memo}); tx_bytes = bytes of the block's transactions; rotation = column|integer: the arithmetic that rotated the proposer, ValidatorSet._rotate; publish_ms = inside save_events_ms, the event bus on this thread (EventBus.publish_block: the buffered subscribers' messages, if anyone subscribed, and the hand-over to the indexer) less index_wait_ms = what the indexer service held this thread back for, MAX_BLOCKS_HELD blocks published and not yet written; both 0 without a bus, the second 0 without an indexer; events = ABCI events in the block's FinalizeBlockResponse, its own and every result's, 0 from an application that emits none; response_bytes = that response as enc_finalize_resp encodes it for the state store, events included, 0 without a state store; untraced nodes read abci_events_total)",
+    "index.block": "one block's events written by the indexer service, on its own thread, a root (height/txs/tx_bytes/keys = key-value pairs of the batch: a record and a height key a transaction, a key an indexed attribute, the block's record/bytes = their keys and values/attr_keys = of keys, the attribute keys: one a distinct (type.key, value) a transaction's events carried marked for indexing, 0 from an application that emits none/attr_bytes = of bytes, those keys with the hashes they point at and what the indexed attributes add to the records (field 6)/page_bytes = the size of the tx index file's pages, read once at open: TX_INDEX_PAGE_BYTES for a file made since PR 43, 4096 for one made before, 0 in memory/encode_ms = building the batch/write_ms = inside the two stores' write_batch and set: the tx index's one commit and the block index's/behind = blocks published and unwritten as this one's write ends, itself included: 1 when the service keeps up, MAX_BLOCKS_HELD when apply is being held back; untraced nodes read indexer_blocks_held, indexer_txs_indexed_total, indexer_blocks_indexed_total, indexer_attr_keys_total, indexer_events_dropped_total) (storage/indexer.py)",
     "types.verify_commit": "one verify_commit / verify_commit_light (height/n = signatures judged/light); self_ms is the entry layer from inside",
     "types.commit_items": "one commit turned into lanes: gates, address check, sign bytes (n/sign_bytes_ms = time inside the sign-bytes build/path = columnar: from the decode columns by validation.commit_lanes, no CommitSig built, or per_slot: the per-signature loop/reason, per_slot only = the gate that declined: no_columns, no_native, shape, key_type, address)",
     "types.verify_items_fill": "the lanes filled into their verifiers, up to the first submit: one add_batch plus the minority curves' rows on the columnar path, grouping by key type and the add() loop on the per-slot path (n/groups/singles)",
