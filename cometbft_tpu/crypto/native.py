@@ -313,6 +313,8 @@ def _bind(lib) -> None:
         ctypes.c_void_p, ctypes.c_char_p, ctypes.c_void_p,  # shards, present, out
         ctypes.c_int,                                       # nchunks
     ]
+    lib.commit_count.restype = ctypes.c_long
+    lib.commit_count.argtypes = [ctypes.c_char_p, ctypes.c_uint64]
     lib.commit_parse.restype = ctypes.c_long
     lib.commit_parse.argtypes = [
         ctypes.c_char_p, ctypes.c_uint64, ctypes.c_uint64,
@@ -424,42 +426,43 @@ def pack_rsk(n: int, sig_blob, pub_blob, msg_blob,
 
 
 def commit_parse(buf: bytes):
-    """Columnar parse of a Commit wire buffer's signature list in one C
-    call. Returns (height_u64, round_u64, bid_span, cols) where cols =
-    (count, flags, addr_lens, addrs, ts_s, ts_n, sig_lens, sigs, spans),
-    or None when the native lib is absent or the buffer needs the
-    (bug-compatible, stricter-error) Python path."""
+    """Columnar parse of a Commit wire buffer's signature list in two C
+    calls: `commit_count` walks the top-level fields for the number of
+    signature slots, `commit_parse` fills columns allocated for exactly
+    that many (a buffer's length says little: an absent slot is 4 bytes
+    on the wire and a signed one about 105). Returns (height_u64,
+    round_u64, bid_span, cols) where cols = (count, flags, addr_lens,
+    addrs, ts_s, ts_n, sig_lens, sigs, spans), every column `count`
+    slots long, or None when the native lib is absent or the buffer
+    needs the (bug-compatible, stricter-error) Python path."""
     lib = get_lib()
     if lib is None:
         return None
-    cap = len(buf) // 6 + 4
-    while True:
-        head = (ctypes.c_uint64 * 4)()
-        flags = ctypes.create_string_buffer(cap)
-        addr_lens = ctypes.create_string_buffer(cap)
-        addrs = ctypes.create_string_buffer(cap * 20)
-        ts_s = (ctypes.c_int64 * cap)()
-        ts_n = (ctypes.c_int64 * cap)()
-        sig_lens = ctypes.create_string_buffer(cap)
-        sigs = ctypes.create_string_buffer(cap * 64)
-        spans = (ctypes.c_uint64 * (cap * 2))()
-        rc = lib.commit_parse(
-            buf, len(buf), cap, head, flags, addr_lens, addrs,
-            ts_s, ts_n, sig_lens, sigs, spans,
-        )
-        if rc == -2:
-            cap *= 2
-            continue
-        if rc < 0:
-            return None
-        n = int(rc)
-        return (
-            int(head[0]),
-            int(head[1]),
-            (int(head[2]), int(head[3])),
-            (n, flags.raw, addr_lens.raw, addrs.raw, ts_s, ts_n,
-             sig_lens.raw, sigs.raw, spans),
-        )
+    n = lib.commit_count(buf, len(buf))
+    if n < 0:
+        return None
+    head = (ctypes.c_uint64 * 4)()
+    flags = ctypes.create_string_buffer(n)
+    addr_lens = ctypes.create_string_buffer(n)
+    addrs = ctypes.create_string_buffer(n * 20)
+    ts_s = (ctypes.c_int64 * n)()
+    ts_n = (ctypes.c_int64 * n)()
+    sig_lens = ctypes.create_string_buffer(n)
+    sigs = ctypes.create_string_buffer(n * 64)
+    spans = (ctypes.c_uint64 * (n * 2))()
+    rc = lib.commit_parse(
+        buf, len(buf), n, head, flags, addr_lens, addrs,
+        ts_s, ts_n, sig_lens, sigs, spans,
+    )
+    if rc != n:  # an entry the parser refuses (-1): the Python path's
+        return None
+    return (
+        int(head[0]),
+        int(head[1]),
+        (int(head[2]), int(head[3])),
+        (n, flags.raw, addr_lens.raw, addrs.raw, ts_s, ts_n,
+         sig_lens.raw, sigs.raw, spans),
+    )
 
 
 _KECCAK_FN = None  # resolved once: the permutation runs ~6k times per

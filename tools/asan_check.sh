@@ -3,7 +3,10 @@
 # sanitizer leg for csrc; the Python suite covers the logic, this
 # catches memory errors the .so build would hide). Covers the ed25519
 # engine's batch and wire-packing entry points (the packer inline and
-# in chunks over the worker pool), the secp256k1 verify
+# in chunks over the worker pool), the commit codec (commit_count, then
+# commit_parse into buffers of exactly the counted slots, over
+# truncations, mutations and garbage: the count is what the parse fills,
+# one slot fewer is refused inside its buffers), the secp256k1 verify
 # engine (r/s boundary values, bad point
 # encodings, multi-verify chunk determinism), the sr25519 unit
 # (ristretto decode rejects, merlin challenge, batch residue s >= L,
